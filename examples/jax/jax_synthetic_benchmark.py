@@ -10,6 +10,7 @@ Run:  python jax_synthetic_benchmark.py --batch-size 64 --num-iters 3
 
 import argparse
 import timeit
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -42,16 +43,18 @@ def main():
     rng = jax.random.PRNGKey(0)
     batch = jnp.zeros((args.batch_size, args.image_size,
                        args.image_size, 3), dtype)
-    variables = model.init(rng, batch, train=False)
-    params = variables["params"]
-    batch_stats = variables.get("batch_stats", {})
     tx = optax.sgd(0.01, momentum=0.9)
-    opt_state = tx.init(params)
 
+    # One compiled init placed on the mesh; unjitted, flax runs the
+    # forward op by op on the first chip.
+    @partial(jax.jit, out_shardings=replicated(mesh))
+    def init(rng, batch):
+        variables = model.init(rng, batch, train=False)
+        params = variables["params"]
+        return params, variables.get("batch_stats", {}), tx.init(params)
+
+    params, batch_stats, opt_state = init(rng, batch)
     x_sharding = sharded(mesh, "dp")
-    params = jax.device_put(params, replicated(mesh))
-    opt_state = jax.device_put(opt_state, replicated(mesh))
-    batch_stats = jax.device_put(batch_stats, replicated(mesh))
 
     @jax.jit
     def train_step(params, batch_stats, opt_state, x, y):

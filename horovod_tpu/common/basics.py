@@ -149,21 +149,7 @@ def _maybe_init_jax_distributed(info: RankInfo):
         # coordination service's error polling TERMINATES survivor
         # processes outright (client.h fatal on peer heartbeat
         # timeout), so recovery never runs.
-        try:
-            jax.config.update("jax_enable_recoverability", True)
-        except AttributeError:
-            # jax 0.4.x: no recoverability support — survivors of a
-            # peer HARD-death die with it (the coordination service
-            # marks the dead task errored; propagating that error is
-            # unconditionally process-fatal in this jaxlib: the
-            # default missed-heartbeat/error callback LOG(FATAL)s,
-            # and installing a custom python callback crashes the
-            # error-poll thread with std::bad_cast; a barrier-free
-            # client drop makes CLEAN departures look like failures
-            # instead — measured, not speculation).  Death-recovery
-            # elastic tests skip on such jax versions; see
-            # jax_peer_death_recoverable() in tests/test_elastic_run.py.
-            pass
+        jax.config.update("jax_enable_recoverability", True)
     heartbeat = env_mod.env_str_opt("HOROVOD_JAX_HEARTBEAT_TIMEOUT")
     kwargs = {}
     if heartbeat:

@@ -2840,11 +2840,20 @@ class NetworkController(Controller):
                 metrics_interval <= 0 and not _fp.ENABLED and \
                 not selfheal and not tree and not _sg.ENABLED:
             try:
-                from ..native import NativeCoordinatorServer, available
+                import jax
+                from ..native import (NativeCoordinatorServer, available,
+                                      enabled)
+                # On a TPU the native coordinator is the one that runs,
+                # so a build that fails there is raised like an explicit
+                # HOROVOD_TPU_NATIVE=1, never demoted to Python quietly.
+                strict_native = strict_native or (
+                    enabled() and jax.devices()[0].platform == "tpu")
                 if strict_native and not available():
                     raise RuntimeError(
-                        "HOROVOD_TPU_NATIVE is set but the native "
-                        "coordinator could not be built/loaded")
+                        "the native coordinator could not be built/"
+                        "loaded (HOROVOD_TPU_NATIVE=1, or unset on a "
+                        "TPU); HOROVOD_TPU_NATIVE=0 selects the Python "
+                        "coordinator")
                 if available():
                     return NativeCoordinatorServer(
                         self.size, port=port,

@@ -19,10 +19,8 @@ Two training paths:
   and XLA fuses the gradient reduction into the step program.
 
 For use *inside* jit/shard_map, the in-graph primitives are re-exported
-from :mod:`horovod_tpu.parallel`; build the enclosing program with the
-re-exported ``hvd.shard_map`` — the ``common/jax_compat`` shim that
-spells ``jax.shard_map`` / ``jax.experimental.shard_map`` identically
-across JAX versions.
+from :mod:`horovod_tpu.parallel`; build the enclosing program with
+``jax.shard_map``.
 """
 
 import pickle
@@ -45,7 +43,6 @@ from ..ops import (allgather, allgather_async, allreduce, allreduce_async,
                    grouped_allreduce_async, join, poll, reducescatter,
                    synchronize)
 from ..ops.compression import Compression
-from ..common.jax_compat import shard_map
 from .. import parallel
 from . import checkpoint
 
@@ -58,7 +55,7 @@ __all__ = [
     "Average", "Sum", "Adasum", "Min", "Max", "Product",
     "allreduce_gradients", "DistributedOptimizer", "broadcast_parameters",
     "broadcast_optimizer_state", "broadcast_object", "allgather_object",
-    "metric_average", "parallel", "shard_map",
+    "metric_average", "parallel",
 ]
 
 

@@ -18,10 +18,6 @@ import jax
 import numpy as np
 
 from ..common.basics import Average, global_process_set
-# The version-stable shard_map shim: the enclosing SPMD program for
-# SyncBatchNorm is built with it (jax.shard_map is an AttributeError
-# on jax 0.4.x).
-from ..common.jax_compat import shard_map  # noqa: F401  (re-export)
 from .. import ops as _ops
 
 

@@ -5,12 +5,15 @@ binding is ctypes over a plain C API instead of per-framework extension
 modules).
 
 The library builds lazily with g++ on first use (a few seconds, cached
-by source mtime under ``native/build/``); when no toolchain is
-available everything falls back to the pure-Python implementations.
+under ``native/build/`` beside a hash of the sources it was built
+from); when no toolchain is available everything falls back to the
+pure-Python implementations, except on a TPU, where the coordinator
+raises (``common/controller_net.py``).
 Set ``HOROVOD_TPU_NATIVE=0`` to force the Python paths.
 """
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -24,31 +27,52 @@ _SRC = os.path.join(_DIR, "coordinator.cc")
 _SRC_COLL = os.path.join(_DIR, "collectives.cc")
 _BUILD_DIR = os.path.join(_DIR, "build")
 _LIB = os.path.join(_BUILD_DIR, "libhvdtpu_coord.so")
+_LIB_HASH = _LIB + ".sha256"
 
 _lock = threading.Lock()
 _lib = None
 _tried = False
 
 
-def _env_enabled() -> bool:
+def enabled() -> bool:
     from ..common import env as env_mod
     return env_mod.env_str("HOROVOD_TPU_NATIVE", "1").strip().lower() \
         not in ("0", "false", "off", "no")
 
 
+def _sources_hash(srcs) -> str:
+    h = hashlib.sha256()
+    for path in srcs:
+        with open(path, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def _built_hash() -> str:
+    try:
+        with open(_LIB_HASH) as f:
+            return f.read().strip()
+    except OSError:
+        return ""
+
+
 def ensure_built(force: bool = False) -> bool:
-    """Compile the shared library if missing/stale; returns success."""
+    """Compile the shared library if missing/stale; returns success.
+
+    Stale is judged by content: the library is kept only while the hash
+    written beside it equals the hash of the sources.  File times say
+    nothing after a copy of the tree."""
     if not os.path.exists(_SRC):
         return False
     srcs = [_SRC]
     if os.path.exists(_SRC_COLL):
         srcs.append(_SRC_COLL)
-    if not force and os.path.exists(_LIB) and all(
-            os.path.getmtime(_LIB) >= os.path.getmtime(s) for s in srcs):
+    want = _sources_hash(srcs)
+    if not force and os.path.exists(_LIB) and _built_hash() == want:
         return True
     os.makedirs(_BUILD_DIR, exist_ok=True)
     # Unique tmp per process: concurrent builders (multi-proc tests
-    # racing a stale mtime) must never interleave writes into one tmp
+    # racing a stale library) must never interleave writes into one tmp
     # file — each builds privately, the atomic replace makes the last
     # one win with a complete .so either way.
     tmp = "%s.tmp.%d" % (_LIB, os.getpid())
@@ -63,6 +87,9 @@ def ensure_built(force: bool = False) -> bool:
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, _LIB)
+        with open(tmp, "w") as f:
+            f.write(want)
+        os.replace(tmp, _LIB_HASH)
         logger.info("built native coordinator: %s", _LIB)
         return True
     except (subprocess.CalledProcessError, subprocess.TimeoutExpired,
@@ -84,7 +111,7 @@ def load():
         if _tried:
             return _lib
         _tried = True
-        if not _env_enabled():
+        if not enabled():
             return None
         if not ensure_built():
             return None

@@ -35,7 +35,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..common.jax_compat import shard_map
 
 
 def _is_pow2(n: int) -> bool:
@@ -125,7 +124,7 @@ def _adasum_global_fn(mesh, n_tensors: int, size: int, prescale: float,
             out.append(y)
         return tuple(out)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=tuple(P("world") for _ in range(n_tensors)),
         out_specs=tuple(P() for _ in range(n_tensors)), check_vma=False))

@@ -29,8 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..common import env as env_mod
 from ..common import metrics
-from ..common.jax_compat import shard_map
 from .backend import Backend, even_row_counts
 
 logger = logging.getLogger("horovod_tpu.xla_ops")
@@ -76,17 +76,39 @@ class XlaMeshBackend(Backend):
             raise RuntimeError(
                 f"jax sees {len(by_proc)} processes but HOROVOD_SIZE="
                 f"{self.size}; was jax.distributed initialized?")
+        # Every rank's devices, in HOROVOD_RANK order.
+        by_rank = [sorted(by_proc[p], key=lambda d: d.id)
+                   for p in self._process_index_by_rank()]
         # One representative device per process carries the flat eager
-        # data plane; in-graph training uses the full device set.  Rank
-        # order must match HOROVOD_RANK order == jax process index order
-        # (the launcher assigns both from the same slot plan).
-        self._reps = [sorted(v, key=lambda d: d.id)[0]
-                      for _, v in sorted(by_proc.items())]
+        # data plane; in-graph training uses the full device set.
+        self._reps = [v[0] for v in by_rank]
         self.mesh = Mesh(np.array(self._reps), ("world",))
-        self.rep_device = self._reps[jax.process_index()]
-        self._init_hierarchy(by_proc, state.rank_info)
+        self.rep_device = self._reps[self.rank]
+        self._init_hierarchy(by_rank, state.rank_info)
 
-    def _init_hierarchy(self, by_proc, ri):
+    def _process_index_by_rank(self) -> List[int]:
+        """The jax process index of every rank.  The launcher hands
+        jax.distributed each rank as its process id, but a backend may
+        number its processes another way: the TPU runtime goes by where
+        a process's chip sits on the host (measured on two v5e 2x2
+        machines: ranks 0..3 came up as processes 3, 2, 0, 1 on one and
+        1, 3, 2, 0 on the other).  So the order is
+        exchanged through the coordination service, never assumed; a
+        world mesh in process order would hand every rank-indexed
+        collective (allgather, broadcast, alltoall) another rank's
+        slot."""
+        from jax._src import distributed
+        client = distributed.global_state.client
+        if client is None:
+            raise RuntimeError("jax.distributed is not initialized")
+        key = "hvd_xla/process_index/%d"
+        client.key_value_set(key % self.rank, str(jax.process_index()),
+                             allow_overwrite=True)
+        timeout_ms = int(env_mod.start_timeout() * 1000)
+        return [int(client.blocking_key_value_get(key % r, timeout_ms))
+                for r in range(self.size)]
+
+    def _init_hierarchy(self, by_rank, ri):
         """Build the 2-level (cross, local) mesh behind
         HOROVOD_HIERARCHICAL_ALLREDUCE (reference:
         NCCLHierarchicalAllreduce, ops/nccl_operations.cc:188-360 —
@@ -105,12 +127,10 @@ class XlaMeshBackend(Backend):
         """
         self._hier = None
         self._hier_kind = None
-        self.local_devices = sorted(by_proc[jax.process_index()],
-                                    key=lambda d: d.id)
-        ndev = min(len(v) for v in by_proc.values())
+        self.local_devices = by_rank[self.rank]
+        ndev = min(len(v) for v in by_rank)
         if ndev > 1:
-            grid = np.array([sorted(v, key=lambda d: d.id)[:ndev]
-                             for _, v in sorted(by_proc.items())])
+            grid = np.array([v[:ndev] for v in by_rank])
             self._hier = Mesh(grid, ("cross", "local"))
             self._hier_kind = "device"
             self._hier_nlocal = ndev
@@ -195,7 +215,7 @@ class XlaMeshBackend(Backend):
                 out.append(y)
             return tuple(out)
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=tuple(P("world") for _ in range(n)),
             out_specs=tuple(P() for _ in range(n)), check_vma=False))
@@ -249,7 +269,7 @@ class XlaMeshBackend(Backend):
                 out.append(y)
             return tuple(out)
         n = len(shapes)
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=tuple(P("cross", "local") for _ in range(n)),
             out_specs=tuple(P() for _ in range(n)), check_vma=False))
@@ -277,7 +297,7 @@ class XlaMeshBackend(Backend):
                 out.append(y)
             return tuple(out)
         n = len(shapes)
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=tuple(P("cross", "local") for _ in range(n)),
             out_specs=tuple(P() for _ in range(n)), check_vma=False))
@@ -361,7 +381,7 @@ class XlaMeshBackend(Backend):
                 out.append(jnp.concatenate(pieces, axis=0))
             return tuple(out)
         n = len(tsizes_per_tensor)
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=tuple(P("world") for _ in range(n)),
             out_specs=tuple(P() for _ in range(n)), check_vma=False))
@@ -408,7 +428,7 @@ class XlaMeshBackend(Backend):
                 masked = jnp.where(idx == root, x, jnp.zeros_like(x))
                 out.append(jax.lax.psum(masked, "world"))
             return tuple(out)
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=tuple(P("world") for _ in range(n)),
             out_specs=tuple(P() for _ in range(n)), check_vma=False))
@@ -437,7 +457,7 @@ class XlaMeshBackend(Backend):
             y = jax.lax.all_to_all(x[0], "world", split_axis=0,
                                    concat_axis=0, tiled=True)
             return y[None]
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=P("world"), out_specs=P("world"),
             check_vma=False))
 
@@ -540,7 +560,7 @@ class XlaMeshBackend(Backend):
                         x, "world", scatter_dimension=0, tiled=True)
                 out.append(y[None])
             return tuple(out)
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=tuple(P("world") for _ in range(n)),
             out_specs=tuple(P("world") for _ in range(n)),

@@ -4,11 +4,11 @@ from .collectives import (allgather, allreduce_max, allreduce_mean,
                           allreduce_min, allreduce_prod, allreduce_sum,
                           alltoall, axis_index, axis_size, broadcast,
                           hierarchical_allreduce_sum, neighbor_shift,
-                          ppermute, reduce_scatter, shard_map)
+                          ppermute, reduce_scatter)
 
 __all__ = [
     "build_mesh", "build_hierarchical_mesh", "local_mesh", "sharded",
-    "replicated", "mesh_axis_size", "parse_mesh_axes", "shard_map",
+    "replicated", "mesh_axis_size", "parse_mesh_axes",
     "allreduce_sum", "allreduce_mean", "allreduce_min", "allreduce_max",
     "allreduce_prod", "allgather", "reduce_scatter", "broadcast",
     "alltoall", "ppermute", "neighbor_shift", "axis_index", "axis_size",

@@ -1,24 +1,14 @@
-"""JAX version-compat shims shared by the parallel modules."""
+"""Varying-axis typing helper shared by the parallel modules."""
 
 from jax import lax
 
 
 def pvary(x, axis_names):
-    """Mark x as device-varying over the given axes (pcast on newer
-    JAX, pvary on older), skipping axes it already varies over."""
+    """Mark x as device-varying over the given axes, skipping axes it
+    already varies over (``lax.pcast`` rejects those)."""
     if isinstance(axis_names, str):
         axis_names = (axis_names,)
-    try:
-        current = set(getattr(x.aval, "vma", ()))
-    except Exception:
-        current = set()
-    missing = tuple(a for a in axis_names if a not in current)
+    missing = tuple(a for a in axis_names if a not in x.aval.vma)
     if not missing:
         return x
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, missing, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, missing)
-    # jax 0.4.x: no varying-axis (vma) typing exists, so there is
-    # nothing to mark — identity is exactly right.
-    return x
+    return lax.pcast(x, missing, to="varying")

@@ -14,11 +14,9 @@ forms:
   (2 all-to-alls, best when heads ≥ axis size and ICI all-to-all is
   cheap).
 
-Both are called inside ``shard_map`` with the sequence dimension
-sharded over ``axis_name`` (use the re-exported version-stable shim —
-``jax.shard_map`` is an AttributeError on jax 0.4.x); both match full
-(unsharded) softmax attention numerically, including causal masking
-with global positions.
+Both are called inside ``jax.shard_map`` with the sequence dimension
+sharded over ``axis_name``; both match full (unsharded) softmax
+attention numerically, including causal masking with global positions.
 """
 
 import math
@@ -28,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..common.jax_compat import shard_map  # noqa: F401  (re-export)
 from ._compat import pvary as _pvary
 
 

@@ -8,12 +8,6 @@ functions, lower to XLA collective HLOs, and ride the ICI mesh.  The eager
 API in :mod:`horovod_tpu.ops` builds fused batches out of exactly these
 primitives.
 
-Use the re-exported :func:`shard_map` (the ``common/jax_compat`` shim)
-to build the enclosing program — it spells the entry point identically
-across JAX versions (``jax.shard_map`` vs
-``jax.experimental.shard_map``); a direct ``jax.shard_map`` reference
-is an AttributeError on jax 0.4.x.
-
 Every function takes ``axis_name`` — one or more mesh axis names — the
 analog of choosing a communicator.
 """
@@ -23,8 +17,6 @@ from typing import Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from ..common.jax_compat import shard_map  # noqa: F401  (re-export)
 
 AxisNames = Union[str, Sequence[str]]
 

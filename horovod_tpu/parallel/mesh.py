@@ -72,7 +72,9 @@ def build_mesh(axis_sizes: Optional[Dict[str, int]] = None,
 
     On real TPU slices ``jax.experimental.mesh_utils`` is used so the axis
     order maps contiguous ICI neighborhoods to the innermost axes (the
-    scaling-book recipe: put the heavy-traffic axis on ICI).
+    scaling-book recipe: put the heavy-traffic axis on ICI); a shape it
+    cannot lay out on the slice raises.  Off the TPU, and for a single
+    device, the order is row-major.
     """
     devices = list(devices if devices is not None else jax.devices())
     n = len(devices)
@@ -83,14 +85,11 @@ def build_mesh(axis_sizes: Optional[Dict[str, int]] = None,
     shape = _factor(n, list(axis_sizes.values()))
 
     if devices[0].platform == "tpu" and n > 1:
-        try:
-            from jax.experimental import mesh_utils
-            dev_array = mesh_utils.create_device_mesh(
-                tuple(shape), devices=devices,
-                allow_split_physical_axes=allow_split_physical_axes)
-            return Mesh(dev_array, names)
-        except Exception:
-            pass  # fall back to row-major order below
+        from jax.experimental import mesh_utils
+        dev_array = mesh_utils.create_device_mesh(
+            tuple(shape), devices=devices,
+            allow_split_physical_axes=allow_split_physical_axes)
+        return Mesh(dev_array, names)
     return Mesh(np.array(devices).reshape(tuple(shape)), names)
 
 

@@ -14,10 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-# Version-stable shard_map for the enclosing program (jax.shard_map is
-# an AttributeError on jax 0.4.x; the shim spells both).
-from ..common.jax_compat import shard_map  # noqa: F401  (re-export)
-
 
 def top1_dispatch(gate_logits: jax.Array, capacity: int):
     """Build the Switch dispatch/combine tensors for top-1 routing.

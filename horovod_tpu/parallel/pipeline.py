@@ -14,7 +14,6 @@ of one shape to the same shape; stage parameters are a pytree whose
 leaves carry a leading stage dimension sharded over ``pp``.
 """
 
-from functools import partial
 from typing import Callable
 
 import jax
@@ -22,28 +21,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from ._compat import pvary as _pvary
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(1,))
-def _bcast_from_last(masked, axis_name):
-    """Broadcast-from-last-stage as masked psum, with the cotangent
-    rule pinned: psum's transpose on a replicated cotangent is the
-    identity (pbroadcast).  jax 0.4.x's shard_map has no varying-axis
-    typing and transposes it to another psum, over-counting gradients
-    by exactly the axis size; the custom vjp spells the correct rule
-    on every version (newer jax infers the same thing on its own)."""
-    return lax.psum(masked, axis_name)
-
-
-def _bcast_fwd(masked, axis_name):
-    return lax.psum(masked, axis_name), None
-
-
-def _bcast_bwd(axis_name, _res, ct):
-    return (ct,)
-
-
-_bcast_from_last.defvjp(_bcast_fwd, _bcast_bwd)
 
 
 def pipeline_apply(stage_fn: Callable, stage_params, x_microbatches,
@@ -87,11 +64,10 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_microbatches,
     (buf, outputs), _ = lax.scan(tick, (buf, outputs),
                                  jnp.arange(total))
     # Outputs live on the last stage; replicate so every stage (and the
-    # caller's loss) sees them.  Masked psum = broadcast-from-last,
-    # with the transpose pinned by _bcast_from_last (see above).
+    # caller's loss) sees them.  Masked psum = broadcast-from-last.
     outputs = jnp.where(idx == n - 1, outputs,
                         jnp.zeros_like(outputs))
-    return _bcast_from_last(outputs, axis_name)
+    return lax.psum(outputs, axis_name)
 
 
 def stack_stage_params(init_fn, rngs, n_stages: int):

@@ -18,7 +18,7 @@ have a slot at the same ``local_rank``.
 import collections
 import dataclasses
 import re
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 
 class HostInfo:
@@ -158,4 +158,53 @@ def slot_env_vars(slot: SlotInfo) -> Dict[str, str]:
         "HOROVOD_LOCAL_SIZE": str(slot.local_size),
         "HOROVOD_CROSS_RANK": str(slot.cross_rank),
         "HOROVOD_CROSS_SIZE": str(slot.cross_size),
+    }
+
+
+# The grid a host's processes form when each owns one TPU chip
+# (TPU_PROCESS_BOUNDS), by the number of local slots.  Four processes on
+# the 2x2 of a v5e host ran on the chip (PR 21).  Two processes on two
+# of its four chips ("2,1,1", chips 0 and 1) both exited at start-up
+# with code 1 and no message, so that count is refused, not guessed at.
+_TPU_PROCESS_GRID = {4: "2,2,1"}
+
+
+def tpu_chip_env(slot: SlotInfo, ports: Sequence[int],
+                 tpu_host: bool = False) -> Dict[str, str]:
+    """What the TPU runtime reads to bind one local slot to one chip
+    of its host and to find its peers, for a job on a single host:
+    which chip the process may open, the one-chip bounds of a process,
+    the grid the processes form, every process's address, this
+    process's port and its task id.  ``ports`` holds one free port per
+    local slot, the same list for every slot.
+
+    A host with one slot gets none of it: that process owns every chip
+    it finds.  Several slots a host with no known layout (any count but
+    four, or a job over several hosts) are refused on a ``tpu_host``,
+    where the workers would otherwise all open every chip and hang; off
+    the TPU the variables mean nothing and any layout runs."""
+    if slot.local_size == 1:
+        return {}
+    grid = _TPU_PROCESS_GRID.get(slot.local_size) \
+        if slot.cross_size == 1 else None
+    if grid is None:
+        if tpu_host:
+            raise ValueError(
+                "cannot give each of %d local slots on %d host(s) its "
+                "own TPU chip; the layouts known to work are one slot "
+                "a host (one process drives every chip) and four slots "
+                "on a single 2x2 host"
+                % (slot.local_size, slot.cross_size))
+        return {}
+    return {
+        "TPU_VISIBLE_CHIPS": str(slot.local_rank),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": grid,
+        "TPU_PROCESS_ADDRESSES": ",".join(
+            "localhost:%d" % p for p in ports),
+        "TPU_PROCESS_PORT": str(ports[slot.local_rank]),
+        "CLOUD_TPU_TASK_ID": str(slot.local_rank),
+        # Each process loads libtpu for itself; without this the
+        # second one to start refuses, seeing the first one's lock.
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
     }

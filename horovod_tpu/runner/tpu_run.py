@@ -16,6 +16,13 @@ Worker env contract per slot (beyond the rank vars of
                                            (rank-0 host:port)
     HOROVOD_CONTROLLER_ADDR                rank-0 negotiation TCP server
     HOROVOD_CONTROLLER=tcp                 controller kind
+    JAX_COMPILATION_CACHE_DIR              the launcher's own, else
+                                           <checkout>/.jax_cache
+    TPU_VISIBLE_CHIPS, TPU_PROCESS_* ...   one chip per local slot
+                                           (``hosts.tpu_chip_env``)
+
+The launcher itself never initialises a JAX backend: on a TPU the
+process that does so holds the chips its workers need.
 """
 
 import functools
@@ -27,11 +34,13 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional
 
+from ..common import compile_cache
 from ..common import env as env_mod
 from . import safe_shell_exec
 from .hosts import SlotInfo, get_host_assignments, parse_hosts, \
-    slot_env_vars
+    slot_env_vars, tpu_chip_env
 from . import job_secret
+from . import tpu_metadata
 from .http_server import RendezvousServer, find_ports, local_addresses
 
 logger = logging.getLogger("horovod_tpu.run")
@@ -208,14 +217,26 @@ def launch_static(command: List[str],
         common_env[env_mod.HOROVOD_START_TIMEOUT] = str(start_timeout)
     if extra_worker_env:
         common_env.update(extra_worker_env)
+    launcher_env = env or dict(os.environ)
+    # Every worker compiles into one cache, the launcher's if it names
+    # one: the path is part of the cache's key.
+    common_env[compile_cache.ENV] = compile_cache.cache_dir(launcher_env)
+    # One chip per local slot (nothing for one slot a host).  A host
+    # says it is a TPU host through TPU_ACCELERATOR_TYPE; a metadata
+    # query would cost every CPU launch a network timeout.  Worked out
+    # here, before any worker starts, so that a refusal stops the launch.
+    chip_ports = find_ports(max(s.local_size for s in slots))
+    tpu_host = bool(launcher_env.get(tpu_metadata.TPU_ACCELERATOR_TYPE))
+    chip_env = {s.rank: tpu_chip_env(s, chip_ports, tpu_host)
+                for s in slots}
 
     run_command = " ".join(shlex.quote(c) for c in command)
     results = WorkerResults(len(slots))
     events = [results.any_failed] if kill_all_on_failure else []
 
     def _run_slot(slot: SlotInfo):
-        cmd = slot_command(run_command, slot, env or dict(os.environ),
-                           common_env)
+        cmd = slot_command(run_command, slot, launcher_env,
+                           {**common_env, **chip_env[slot.rank]})
         local = is_local(slot.hostname)
         cmd, exec_env, stdin_data = secret_transport(cmd, secret, local)
         if not local:

@@ -20,7 +20,6 @@ import optax
 from flax.training import train_state
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .common.jax_compat import shard_map
 from .models.bert import BertConfig, BertForMaskedLM, mlm_loss
 from .parallel.sharding import (bert_partition_rules, infer_shardings,
                                 Rules)
@@ -198,7 +197,7 @@ def run_pipeline_moe_dry_run(n_devices: int, microbatches: int = 4,
             lambda g: jax.lax.pmean(g, "dp"), grads)
         return jax.lax.pmean(loss, ("dp", "ep")), grads
 
-    run = jax.jit(shard_map(
+    run = jax.jit(jax.shard_map(
         grads_fn, mesh=mesh,
         in_specs=(P("pp"), P("pp"), P("pp", "ep"), P(None, "dp")),
         out_specs=(P(), (P("pp"), P("pp"), P("pp", "ep")))))
@@ -226,7 +225,7 @@ def run_ring_attention_dry_run(n_devices: int, seq_per_dev: int = 8,
         return jnp.mean(
             ring_attention(q, k, v, axis_name="sp", causal=True) ** 2)
 
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         jax.grad(loss), mesh=mesh, in_specs=(P(None, "sp"),) * 3,
         out_specs=P(None, "sp")))
     g = f(q, k, v)
@@ -282,12 +281,17 @@ def make_gpt_train_step(config, mesh, learning_rate: float = 1e-2,
     batch_sharding = NamedSharding(mesh, P(batch_axis, None))
     rules = gpt_partition_rules(fsdp=fsdp)
 
-    def init_fn(rng, ids):
+    def _init(rng, ids):
         params = model.init(rng, ids)["params"]
-        params = jax.tree.map(
-            jax.device_put, params,
-            infer_shardings(params, mesh, rules))
         return params, tx.init(params)
+
+    def init_fn(rng, ids):
+        # Jitted with out_shardings like BERT's: every array is born
+        # on its own shard, so a model that only fits sharded never
+        # passes through one chip.
+        shardings = infer_shardings(
+            jax.eval_shape(_init, rng, ids), mesh, rules)
+        return jax.jit(_init, out_shardings=shardings)(rng, ids)
 
     @partial(jax.jit, donate_argnums=(0, 1))
     def step_fn(params, opt_state, ids):

@@ -17,12 +17,15 @@ from typing import List, Optional, Tuple
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-_PRELUDE = """
+_IMPORTS = """
 import os, sys
 import jax
 jax.config.update("jax_platforms", "cpu")
 import numpy as np
 import horovod_tpu as hvd
+"""
+
+_INIT = """
 hvd.init()
 RANK = hvd.rank()
 SIZE = hvd.size()
@@ -53,15 +56,18 @@ def free_ports(n: int) -> List[int]:
 
 def run_workers(body: str, nproc: int = 2, timeout: float = 180.0,
                 extra_env: Optional[dict] = None,
-                per_rank_env=None) -> List[Tuple[int, str]]:
+                per_rank_env=None,
+                before_init: str = "") -> List[Tuple[int, str]]:
     """Run ``body`` (dedented python source, sees RANK/SIZE/np/hvd/jax)
     in ``nproc`` worker processes.  Returns [(returncode, output)].
+    ``before_init`` runs after the imports and before ``hvd.init()``.
 
     ``per_rank_env(rank) -> dict`` overrides the env contract per rank
     (e.g. to simulate a two-tier host topology on localhost).
     """
     coord_port, ctrl_port = free_ports(2)
-    code = _PRELUDE + textwrap.dedent(body)
+    code = _IMPORTS + textwrap.dedent(before_init) + _INIT + \
+        textwrap.dedent(body)
     procs = []
     for rank in range(nproc):
         env = dict(os.environ)

@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from horovod_tpu.parallel import build_mesh, shard_map
+from horovod_tpu.parallel import build_mesh
 from horovod_tpu.parallel.attention import (reference_attention,
                                             ring_attention,
                                             ulysses_attention)
@@ -32,7 +32,7 @@ def mesh():
 
 
 def _run_sharded(fn, mesh, q, k, v, causal):
-    sharded = shard_map(
+    sharded = jax.shard_map(
         lambda q, k, v: fn(q, k, v, axis_name="sp", causal=causal),
         mesh=mesh, in_specs=(P(None, "sp"),) * 3,
         out_specs=P(None, "sp"))

@@ -18,42 +18,6 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def jax_peer_death_recoverable() -> bool:
-    """Can elastic SURVIVORS outlive a peer's hard death on this jax?
-
-    Root cause of the death-recovery failures on jax 0.4.x (e.g.
-    jaxlib 0.4.37): when any task hard-dies, the coordination service
-    marks it errored and propagates the error to every surviving
-    agent, and that propagation is unconditionally process-fatal in
-    the jaxlib client — the default missed-heartbeat/error callback
-    is a LOG(FATAL) ("Terminating process because the JAX distributed
-    service detected fatal errors", client.h), and installing a
-    custom python callback via get_distributed_runtime_client crashes
-    the error-poll thread with std::bad_cast; skipping the client
-    shutdown barrier instead makes CLEAN departures get marked as
-    failures too (all three measured on jaxlib 0.4.37).  So no
-    horovod_tpu-side machinery can keep survivors alive there.  Newer
-    jax ships task recoverability (the ``jax_enable_recoverability``
-    config), which ``common/basics._maybe_init_jax_distributed``
-    enables in elastic mode — these scenarios run and must pass on
-    such versions.  Clean resizes (no death) work on every version
-    and are always tested (test_elastic_world_grows)."""
-    import jax
-    try:
-        prev = jax.config.jax_enable_recoverability
-    except AttributeError:
-        return False
-    del prev
-    return True
-
-
-death_recovery = pytest.mark.skipif(
-    not jax_peer_death_recoverable(),
-    reason="jax<0.5 coordination service kills elastic survivors on "
-           "any peer hard-death (LOG(FATAL)/std::bad_cast in jaxlib; "
-           "see jax_peer_death_recoverable above and "
-           "common/basics._maybe_init_jax_distributed)")
-
 WORKER_SCRIPT = """
 import os, sys, time
 import numpy as np
@@ -208,7 +172,6 @@ print(f"DONE rank={hvd.rank()} epoch={state.epoch} "
 """
 
 
-@death_recovery
 def test_elastic_worker_death_shrinks_world(tmp_path):
     """A worker hard-dies (os._exit, no cleanup) mid-run: the driver
     records the failure, blacklists that host, survivors unwind via
@@ -340,7 +303,6 @@ print(f"DONE rank={hvd.rank()} epoch={state.epoch} "
 """
 
 
-@death_recovery
 def test_elastic_two_tier_host_loss(tmp_path):
     """VERDICT r3 item 6 (elastic leg): a 2-host x 2-slot world loses
     a whole 'host' mid-run; survivors re-rendezvous as 1 host x 2
@@ -511,7 +473,6 @@ print(f"DONE rank={hvd.rank()} epoch={state.epoch} "
 """
 
 
-@death_recovery
 def test_elastic_in_graph_tf_survives_resize(tmp_path):
     """VERDICT r3 item 5: elastic TF2 trains through a resize WITH
     in-graph collectives on both sides of it (HOROVOD_TF_ELASTIC_GRAPH

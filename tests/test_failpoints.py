@@ -285,16 +285,12 @@ def test_disabled_sites_never_enter_the_registry(monkeypatch,
 
 
 def test_disabled_path_overhead_stays_one_attribute_check():
-    """Perf pin for the r05 smoke-regression audit (VERDICT r5 weak
-    #1): with HOROVOD_FAILPOINTS unset, a site costs ONE module-
-    attribute check — tens of nanoseconds.  The absolute bound below
-    is ~20x the measured cost on an idle rig, loose enough for CI
-    noise but tight enough that reintroducing per-call work (registry
-    lookup, rule matching, getattr chains — each ~10x the guard) fails
-    immediately.  The r05 regression itself was NOT this path: the
-    smoke train loop contains no horovod code at all; it was CPU
-    contention from leaked TPU-probe descendants (see bench.py
-    _sweep_marked_processes)."""
+    """Perf pin: with HOROVOD_FAILPOINTS unset, a site costs ONE
+    module-attribute check — tens of nanoseconds.  The absolute bound
+    below is ~20x the measured cost on an idle rig, loose enough for
+    CI noise but tight enough that reintroducing per-call work
+    (registry lookup, rule matching, getattr chains — each ~10x the
+    guard) fails immediately."""
     import timeit
 
     assert not fp.ENABLED

@@ -141,8 +141,9 @@ def test_sigusr2_dump_trigger(tmp_path):
     deadline = time.monotonic() + 5.0
     files = []
     while time.monotonic() < deadline and not files:
+        # The dump is written as .tmp and renamed; wait for the rename.
         files = [f for f in os.listdir(str(tmp_path))
-                 if "sigusr2" in f]
+                 if "sigusr2" in f and not f.endswith(".tmp")]
         time.sleep(0.02)
     assert files, "SIGUSR2 did not produce a dump"
     with open(tmp_path / files[0]) as f:

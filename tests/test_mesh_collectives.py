@@ -16,9 +16,7 @@ from horovod_tpu import parallel as par
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    # par.shard_map: the jax_compat shim (jax.shard_map is an
-    # AttributeError on jax 0.4.x).
-    return jax.jit(par.shard_map(fn, mesh=mesh, in_specs=in_specs,
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                                  out_specs=out_specs))
 
 
@@ -67,7 +65,7 @@ def test_reduce_scatter(cpu_mesh8):
     # Every member contributes a full (8, 8); each receives its summed
     # (1, 8) shard.
     x = jnp.ones((8, 8))
-    f = jax.jit(par.shard_map(
+    f = jax.jit(jax.shard_map(
         lambda a: par.reduce_scatter(a, "dp", axis=0), mesh=mesh,
         in_specs=P(None, None), out_specs=P("dp", None),
         check_vma=False))
@@ -111,7 +109,7 @@ def test_hierarchical_allreduce(cpu_mesh8):
     from horovod_tpu.parallel import build_mesh
     mesh = build_mesh({"cross": 2, "local": 4})
     x = jnp.arange(8.0).reshape(2, 4)
-    f = jax.jit(par.shard_map(
+    f = jax.jit(jax.shard_map(
         lambda a: par.hierarchical_allreduce_sum(a, "local", "cross"),
         mesh=mesh, in_specs=P("cross", "local"),
         out_specs=P("cross", "local")))
@@ -125,7 +123,7 @@ def test_hierarchical_allreduce_uneven_padding(cpu_mesh8):
     mesh = build_mesh({"cross": 2, "local": 4})
     def body(a):
         return par.hierarchical_allreduce_sum(a, "local", "cross")
-    f = jax.jit(par.shard_map(
+    f = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=P(None, None), out_specs=P(None, None),
         check_vma=False))
     x = jnp.ones((3, 5))

@@ -76,5 +76,17 @@ def test_bert_flash_attention_matches_einsum():
     m_e, m_f = BertForMaskedLM(cfg_e), BertForMaskedLM(cfg_f)
     params = m_e.init(rng, ids)
     out_e = np.asarray(m_e.apply(params, ids).astype(jnp.float32))
-    out_f = np.asarray(m_f.apply(params, ids).astype(jnp.float32))
+    # The model calls the kernel compiled; off the TPU the test asks
+    # Pallas for interpret mode here, by name.
+    from jax.experimental.pallas import tpu as pltpu
+    with pltpu.force_tpu_interpret_mode():
+        out_f = np.asarray(m_f.apply(params, ids).astype(jnp.float32))
     np.testing.assert_allclose(out_f, out_e, atol=3e-2, rtol=3e-2)
+
+
+def test_flash_compiled_is_refused_off_tpu(qkv):
+    """No quiet interpret default: off the TPU the compiled kernel
+    raises instead of running some other code in its place."""
+    q, k, v = qkv
+    with pytest.raises(ValueError, match="interpret mode"):
+        flash_attention(q, k, v)

@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from horovod_tpu.parallel import build_mesh, shard_map
+from horovod_tpu.parallel import build_mesh
 from horovod_tpu.parallel.moe import moe_ffn, top1_dispatch
 from horovod_tpu.parallel.pipeline import pipeline_apply
 
@@ -27,7 +27,7 @@ def test_pipeline_matches_sequential():
         W, b = params
         return jnp.tanh(h @ W[0] + b[0])
 
-    run = jax.jit(shard_map(
+    run = jax.jit(jax.shard_map(
         lambda W, b, xm: pipeline_apply(stage, (W, b), xm,
                                         axis_name="pp"),
         mesh=mesh,
@@ -58,7 +58,7 @@ def test_pipeline_is_differentiable():
         out = pipeline_apply(stage, (W, b), xm, axis_name="pp")
         return jnp.mean(out ** 2)
 
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         lambda W, b, xm: jax.grad(loss_sharded)(W, b, xm),
         mesh=mesh, in_specs=(P("pp"), P("pp"), P(None, None)),
         out_specs=P("pp")))
@@ -129,7 +129,7 @@ def test_moe_matches_per_token_expert():
     def expert_fn(W, h):
         return jnp.tanh(h @ W[0])
 
-    run = jax.jit(shard_map(
+    run = jax.jit(jax.shard_map(
         lambda x, gw, W: moe_ffn(x, gw, expert_fn, W,
                                  axis_name="ep",
                                  capacity_factor=8.0),

@@ -79,6 +79,39 @@ def test_slot_env_vars():
     assert env["HOROVOD_HOSTNAME"] == "a"
 
 
+def test_tpu_chip_env_one_chip_per_local_slot():
+    """Four local slots get four distinct chips and one consistent
+    peer list; one slot gets none of it (that process owns every chip);
+    a count with no known grid is refused on a TPU host only."""
+    from horovod_tpu.runner.hosts import tpu_chip_env
+    ports = [7001, 7002, 7003, 7004]
+    slots = get_host_assignments(parse_hosts("localhost:4"), 4)
+    envs = [tpu_chip_env(s, ports) for s in slots]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert [e["CLOUD_TPU_TASK_ID"] for e in envs] == ["0", "1", "2", "3"]
+    assert [e["TPU_PROCESS_PORT"] for e in envs] == \
+        ["7001", "7002", "7003", "7004"]
+    for e in envs:
+        assert e["TPU_PROCESS_ADDRESSES"] == \
+            "localhost:7001,localhost:7002,localhost:7003,localhost:7004"
+        assert e["TPU_PROCESS_BOUNDS"] == "2,2,1"
+        assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+
+    one = get_host_assignments(parse_hosts("localhost:1"), 1)[0]
+    assert tpu_chip_env(one, ports[:1]) == {}
+    # One slot on each of several hosts: each owns its host's chips.
+    pod = get_host_assignments(parse_hosts("h1:1,h2:1"), 2)
+    assert [tpu_chip_env(s, ports[:1]) for s in pod] == [{}, {}]
+
+    # Layouts with no known grid: 2 or 3 slots, 4 slots on two hosts.
+    for hosts, n in (("localhost:2", 2), ("localhost:3", 3),
+                     ("h1:4,h2:4", 8)):
+        slot = get_host_assignments(parse_hosts(hosts), n)[0]
+        assert tpu_chip_env(slot, ports) == {}
+        with pytest.raises(ValueError, match="local slots"):
+            tpu_chip_env(slot, ports, tpu_host=True)
+
+
 # ---------------------------------------------------------------------
 # CLI
 # ---------------------------------------------------------------------

@@ -6,6 +6,7 @@ standing in for a cluster (SURVEY §4).
 """
 
 import os
+import subprocess
 import sys
 import textwrap
 
@@ -53,6 +54,34 @@ def test_launch_static_two_procs(tmp_path):
     for rank in (0, 1):
         stdout = (outdir / f"rank.{rank}" / "stdout").read_text()
         assert f"OK rank={rank} size=2" in stdout
+
+
+def test_launcher_parent_stays_off_jax(tmp_path):
+    """A chip belongs to one process at a time: the launcher imports
+    JAX with the package but must never initialise a backend, or its
+    workers could not open the chip.  It also hands every worker the
+    compile cache directory and, to several local slots, a chip each."""
+    script = tmp_path / "env.py"
+    script.write_text(
+        "import os\n"
+        "print('CHIP', os.environ.get('TPU_VISIBLE_CHIPS'),"
+        " os.environ['JAX_COMPILATION_CACHE_DIR'])\n")
+    parent = textwrap.dedent("""
+        import sys
+        from horovod_tpu.runner.tpu_run import launch_static
+        launch_static([sys.executable, %r], "localhost:4", 4,
+                      output_filename=%r)
+        from jax._src import xla_bridge
+        assert not xla_bridge._backends, xla_bridge._backends
+    """ % (str(script), str(tmp_path / "logs")))
+    env = _worker_env()
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    subprocess.run([sys.executable, "-c", parent], env=env, check=True,
+                   timeout=120)
+    for rank in range(4):
+        out = (tmp_path / "logs" / f"rank.{rank}" / "stdout").read_text()
+        assert out.split(":", 1)[1].split() == \
+            ["CHIP", str(rank), str(tmp_path / "cache")]
 
 
 def test_launch_static_failure_propagates(tmp_path):

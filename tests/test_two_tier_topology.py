@@ -135,3 +135,46 @@ def test_hier_proc_per_rank_transfer_is_size_over_nlocal():
     ar_line = hlo[ar.start():hlo.index("\n", ar.start())]
     assert "{0,1,2,3}" in rs_line and "{4,5,6,7}" in rs_line, rs_line
     assert "{0,4}" in ar_line and "{3,7}" in ar_line, ar_line
+
+
+def test_world_mesh_follows_ranks_not_process_indices():
+    """A backend may number its processes another way than the
+    launcher's ranks: on a v5e host with one chip a process, ranks
+    0..3 came up as jax processes 3, 2, 0, 1.  Rehearsed here by
+    joining jax.distributed under exactly that numbering before
+    ``hvd.init()``: every rank-indexed collective must still see rank
+    order."""
+    results = run_workers("""
+        from horovod_tpu.common import basics
+        be = basics._state().backend
+        assert type(be).__name__ == "XlaMeshBackend", type(be)
+        assert jax.process_index() == PROCESS_OF_RANK[RANK]
+        assert [d.process_index for d in be._reps] == PROCESS_OF_RANK
+
+        g = np.asarray(hvd.allgather(
+            np.full((RANK + 1, 2), float(RANK), np.float32), name="ro.ag"))
+        np.testing.assert_array_equal(
+            g[:, 0], np.repeat(np.arange(SIZE), np.arange(SIZE) + 1))
+        b = np.asarray(hvd.broadcast(np.full(3, float(RANK), np.float32),
+                                     root_rank=1, name="ro.bc"))
+        np.testing.assert_array_equal(b, 1.0)
+        y, recv = hvd.alltoall(
+            np.full(SIZE, float(RANK), np.float32),
+            splits=np.ones(SIZE, np.int64), name="ro.a2a")
+        np.testing.assert_array_equal(np.asarray(y), np.arange(SIZE))
+        rs = np.asarray(hvd.reducescatter(
+            np.arange(SIZE, dtype=np.float32) + 10.0 * RANK, op=hvd.Sum,
+            name="ro.rs"))
+        np.testing.assert_array_equal(
+            rs, [RANK * SIZE + 10.0 * sum(range(SIZE))])
+        print("ORDER OK", RANK)
+    """, nproc=NPROC, extra_env={"HOROVOD_CPU_OPERATIONS": "XLA"},
+        before_init="""
+        PROCESS_OF_RANK = [3, 2, 0, 1]
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
+        jax.distributed.initialize(
+            coordinator_address=os.environ["HOROVOD_TPU_COORDINATOR"],
+            num_processes=int(os.environ["HOROVOD_SIZE"]),
+            process_id=PROCESS_OF_RANK[int(os.environ["HOROVOD_RANK"])])
+    """)
+    assert_all_ok(results)
