@@ -14,6 +14,10 @@ TPU-VM hosts.  See ``horovod_tpu.parallel`` for the in-graph mesh API
 reference's data-parallel-only feature set.
 """
 
+import time as _time
+
+_import_start = _time.time()
+
 from .version import __version__
 
 from .common.basics import (Adasum, Average, Max, Min, Product, Sum,
@@ -26,7 +30,7 @@ from .common.basics import (Adasum, Average, Max, Min, Product, Sum,
                             mpi_enabled,
                             mpi_threads_supported, nccl_built, num_chips,
                             rank, remove_process_set, shutdown, size,
-                            slo_status,
+                            slo_status, spans,
                             start_timeline, status, stop_timeline,
                             cuda_built,
                             rocm_built, ccl_built, tune_status,
@@ -55,7 +59,7 @@ __all__ = [
     "ccl_built", "xla_built", "xla_enabled",
     "start_timeline", "stop_timeline",
     "metrics_snapshot", "cluster_metrics_snapshot", "tune_status",
-    "status", "slo_status",
+    "status", "slo_status", "spans",
     "ProcessSet", "global_process_set", "add_process_set",
     "remove_process_set",
     # ops & op constants
@@ -70,3 +74,10 @@ __all__ = [
     # subpackages
     "parallel", "serve", "sparse",
 ]
+
+from .common import timeline as _timeline
+
+# hvd/import: the first to the last line of this file (the imports of
+# jax, flax and optax among them), a cold span like hvd/init.
+_timeline.record("import", _import_start, _time.time())
+del _time, _timeline, _import_start

@@ -21,6 +21,7 @@ import threading
 from typing import List, Optional, Sequence
 
 from . import env as env_mod
+from . import timeline as timeline_mod
 from .env import Knobs, RankInfo
 from .exceptions import NotInitializedError
 
@@ -183,6 +184,17 @@ def init(comm=None, process_sets=None):
                     if getattr(ps, "process_set_id", -1) in (-1, None):
                         add_process_set(ps)
             return
+        # The span's end is the wall-clock instant init() returned: what
+        # places a later window on the cold spans' clock (hvd.spans()).
+        with timeline_mod.span("init", cold=True):
+            _init_locked(state, comm, process_sets)
+
+
+def _init_locked(state: HorovodTpuState, comm, process_sets):
+    # The four children cover init between them, so that what the
+    # parent holds beyond their sum is the `with` statements alone.
+    span = timeline_mod.span
+    with span("init/rendezvous", cold=True):
         state.knobs = Knobs.from_env()
         # Opt-in lock-order witness (docs/static_analysis.md): arm
         # BEFORE any control-plane object constructs its locks so the
@@ -237,10 +249,12 @@ def init(comm=None, process_sets=None):
             os.environ["HOROVOD_CONTROLLER_ADDR"] = \
                 eps["controller_addr"]
 
-        if state.rank_info.size > 1:
+    if state.rank_info.size > 1:
+        with span("init/distributed", cold=True):
             state.distributed_client_owned = _maybe_init_jax_distributed(
                 state.rank_info)
 
+    with span("init/backend", cold=True):
         # Failpoint rank= predicates resolve against the final rank of
         # this incarnation (elastic rendezvous above may have changed
         # the env contract since import time).
@@ -266,16 +280,15 @@ def init(comm=None, process_sets=None):
         from ..ops.backend import create_backend
         state.backend = create_backend(state)
 
+    with span("init/runtime", cold=True):
         from .runtime import BackgroundRuntime
         state.runtime = BackgroundRuntime(state)
         state.runtime.start()
 
         if state.knobs.timeline:
-            from .timeline import Timeline
-            state.timeline = Timeline(
+            _set_timeline(state, timeline_mod.Timeline(
                 state.knobs.timeline, rank=state.rank_info.rank,
-                mark_cycles=state.knobs.timeline_mark_cycles)
-            state.runtime.timeline = state.timeline
+                mark_cycles=state.knobs.timeline_mark_cycles))
 
         if state.knobs.metrics_port is not None and \
                 state.metrics_server is None:
@@ -306,11 +319,22 @@ def init(comm=None, process_sets=None):
             for ps in process_sets:
                 add_process_set(ps)
 
-        state.init_generation += 1
-        state.initialized = True
-        logger.debug("horovod_tpu initialized: rank=%d size=%d local=%d/%d",
-                     state.rank_info.rank, state.rank_info.size,
-                     state.rank_info.local_rank, state.rank_info.local_size)
+    state.init_generation += 1
+    state.initialized = True
+    logger.debug("horovod_tpu initialized: rank=%d size=%d local=%d/%d",
+                 state.rank_info.rank, state.rank_info.size,
+                 state.rank_info.local_rank, state.rank_info.local_size)
+
+
+def _set_timeline(state: HorovodTpuState, timeline):
+    """Open (or, with None, close) the Timeline file: the state's, the
+    runtime's and the one finished spans are written to."""
+    if state.timeline is not None:
+        state.timeline.close()
+    state.timeline = timeline
+    if state.runtime is not None:
+        state.runtime.timeline = timeline
+    timeline_mod.set_sink(timeline)
 
 
 def _teardown_jax_distributed():
@@ -337,6 +361,13 @@ def shutdown():
     with state.init_lock:
         if not state.initialized:
             return
+        with timeline_mod.span("shutdown", cold=True):
+            _shutdown_locked(state)
+
+
+def _shutdown_locked(state: HorovodTpuState):
+    span = timeline_mod.span
+    with span("shutdown/runtime", cold=True):
         if state.runtime is not None:
             # Quiesce (not detach): halts the cycle loop AND disables
             # recv-thread response dispatch before the backend closes,
@@ -344,43 +375,45 @@ def shutdown():
             # communicator; the controller attachment itself stays up
             # as the teardown-ordering signal (below).
             state.runtime.quiesce()
-        if state.timeline is not None:
-            state.timeline.close()
-            state.timeline = None
+        _set_timeline(state, None)
         if state.metrics_server is not None:
             state.metrics_server.stop()
             state.metrics_server = None
+    with span("shutdown/backend", cold=True):
         if state.backend is not None and hasattr(state.backend, "close"):
             state.backend.close()
         state.backend = None
-        # Teardown ORDER is load-bearing for elastic resets: the jax
-        # coordination service (hosted by rank 0) dying under a
-        # still-attached client is PROCESS-FATAL for that client
-        # (LOG(FATAL) in the disconnect RPC — recoverability does not
-        # cover leader loss).  So in elastic mode non-leader ranks
-        # disconnect their jax client FIRST, while still attached to
-        # the rank-0 controller; rank 0's controller shutdown
-        # drain-waits on those attachments, and only then takes the
-        # coordination service down.  Elastic-only: recoverable tasks
-        # skip jax's client-side shutdown barrier, so the early
-        # disconnect returns immediately — in non-elastic mode it
-        # would block on the barrier against rank 0, which is itself
-        # waiting in the controller drain (a deadlock ridden out by
-        # timeouts).
-        is_leader = state.rank_info.rank == 0
-        if state.distributed_client_owned and not is_leader and \
-                state.knobs.elastic:
+    # Teardown ORDER is load-bearing for elastic resets: the jax
+    # coordination service (hosted by rank 0) dying under a
+    # still-attached client is PROCESS-FATAL for that client
+    # (LOG(FATAL) in the disconnect RPC — recoverability does not
+    # cover leader loss).  So in elastic mode non-leader ranks
+    # disconnect their jax client FIRST, while still attached to
+    # the rank-0 controller; rank 0's controller shutdown
+    # drain-waits on those attachments, and only then takes the
+    # coordination service down.  Elastic-only: recoverable tasks
+    # skip jax's client-side shutdown barrier, so the early
+    # disconnect returns immediately — in non-elastic mode it
+    # would block on the barrier against rank 0, which is itself
+    # waiting in the controller drain (a deadlock ridden out by
+    # timeouts).
+    is_leader = state.rank_info.rank == 0
+    if state.distributed_client_owned and not is_leader and \
+            state.knobs.elastic:
+        with span("shutdown/distributed", cold=True):
             _teardown_jax_distributed()
-            state.distributed_client_owned = False
+        state.distributed_client_owned = False
+    with span("shutdown/detach", cold=True):
         if state.runtime is not None:
             state.runtime.detach()
             state.runtime = None
         state.tune_session = None
         state.parameter_manager = None
-        if state.distributed_client_owned:
+    if state.distributed_client_owned:
+        with span("shutdown/distributed", cold=True):
             _teardown_jax_distributed()
-            state.distributed_client_owned = False
-        state.initialized = False
+        state.distributed_client_owned = False
+    state.initialized = False
 
 
 atexit.register(shutdown)
@@ -500,6 +533,18 @@ def metrics_snapshot() -> dict:
     docs/observability.md."""
     from . import metrics as metrics_mod
     return metrics_mod.snapshot()
+
+
+def spans() -> List[dict]:
+    """This process's cold spans in the order they ended: start-up (``hvd/import``,
+    ``hvd/init`` and its children), every compilation phase
+    (``hvd/compile/<phase>`` with ``program=``) and ``hvd/shutdown``,
+    each ``{"name", "start", "end", "thread", "parent", "args"}`` with
+    wall-clock seconds.  The end of ``hvd/init`` is the instant
+    ``hvd.init()`` returned.  Hot spans keep only count and seconds:
+    ``metrics_snapshot()["histograms"]["hvd_span_seconds"]``.  See
+    docs/observability.md."""
+    return timeline_mod.spans()
 
 
 def cluster_metrics_snapshot():
@@ -626,23 +671,14 @@ def start_timeline(file_path: str, mark_cycles: bool = False):
     horovod_start_timeline, operations.cc:738-764)."""
     state = _state()
     state.require_init()
-    from .timeline import Timeline
-    if state.timeline is not None:
-        state.timeline.close()
-    state.timeline = Timeline(file_path, rank=state.rank_info.rank,
-                              mark_cycles=mark_cycles)
-    if state.runtime is not None:
-        state.runtime.timeline = state.timeline
+    _set_timeline(state, timeline_mod.Timeline(
+        file_path, rank=state.rank_info.rank, mark_cycles=mark_cycles))
 
 
 def stop_timeline():
     state = _state()
     state.require_init()
-    if state.timeline is not None:
-        state.timeline.close()
-        state.timeline = None
-    if state.runtime is not None:
-        state.runtime.timeline = None
+    _set_timeline(state, None)
 
 
 def add_process_set(ranks) -> ProcessSet:

@@ -17,6 +17,7 @@ the reference (SURVEY §7 hard parts).
 from typing import List
 
 from . import metrics
+from . import timeline as tl
 from .message import Response, ResponseType, dtype_size
 
 
@@ -113,6 +114,12 @@ def fuse_responses(responses: List[Response], entry_sizes,
     joins) pass through unchanged, preserving overall order determinism
     so every rank builds the identical plan.
     """
+    with tl.span("fuse", responses=len(responses)):
+        return _fuse(responses, entry_sizes, threshold_bytes, group_ids)
+
+
+def _fuse(responses: List[Response], entry_sizes, threshold_bytes: int,
+          group_ids) -> List[Response]:
     out: List[Response] = []
     queue = _premerge_groups(responses, group_ids)
     while queue:

@@ -77,6 +77,12 @@ def _label_key(labels: Dict[str, object]) -> str:
                     for k in sorted(labels))
 
 
+def label_key(**labels) -> str:
+    """The child key of ``labels``, for a hot caller that observes one
+    child over and over and makes its key once (``observe_key``)."""
+    return _label_key(labels)
+
+
 class _Metric:
     kind = "untyped"
 
@@ -166,10 +172,13 @@ class Histogram(_Metric):
         self.bounds = tuple(bounds)
 
     def observe(self, value: float, **labels):
+        self.observe_key(_label_key(labels) if labels else "", value)
+
+    def observe_key(self, key: str, value: float):
+        """``observe`` into the child whose ``label_key`` is ``key``."""
         value = float(value)
         # Slot i counts values <= bounds[i]; the final slot is +Inf.
         idx = bisect.bisect_left(self.bounds, value)
-        key = _label_key(labels) if labels else ""
         with self._lock:
             h = self._children.get(key)
             if h is None:

@@ -56,12 +56,10 @@ _JOIN_ZEROS = metrics.counter(
     "did not submit")
 
 
-def _latency_wrapped(cb, collector=None):
-    """Stamp submit time into the completion callback so the
-    submit-to-callback latency histogram sees every path (negotiated,
-    inline cache hit, error flush)."""
-    t0 = time.perf_counter()
-
+def _latency_wrapped(cb, t0, collector=None):
+    """Stamp submit time (``t0``: the ``hvd/submit`` span's start) into
+    the completion callback so the submit-to-callback latency histogram
+    sees every path (negotiated, inline cache hit, error flush)."""
     def wrapped(ok, result):
         dt = time.perf_counter() - t0
         _SUBMIT_LATENCY.observe(dt)
@@ -250,7 +248,12 @@ class BackgroundRuntime:
             _fr.record(_fr.SUBMIT, rank=self.state.rank_info.rank,
                        name=request.tensor_name,
                        type=request.request_type.name)
-        entry.callback = _latency_wrapped(entry.callback,
+        with tl.span("submit", tensor=request.tensor_name) as sp:
+            self._submit(request, entry, sp.t0)
+
+    def _submit(self, request: Request, entry: TensorTableEntry,
+                t0: float):
+        entry.callback = _latency_wrapped(entry.callback, t0,
                                           self.phase_collector)
         nelem = 1
         for d in request.tensor_shape:
@@ -332,9 +335,16 @@ class BackgroundRuntime:
             # Grouped submissions negotiate (group atomicity is the
             # coordinator's job); they also invalidate a frozen cycle.
             self.replay.note_disruption("group")
+        with tl.span("submit", tensors=len(requests),
+                     tensor=requests[0].tensor_name if requests else ""
+                     ) as sp:
+            self._submit_group(requests, entries, sp.t0)
+
+    def _submit_group(self, requests: List[Request],
+                      entries: List[TensorTableEntry], t0: float):
         group_id = next(self._group_counter)
         for entry in entries:
-            entry.callback = _latency_wrapped(entry.callback,
+            entry.callback = _latency_wrapped(entry.callback, t0,
                                               self.phase_collector)
         for request in requests:
             request.group_id = group_id
@@ -487,20 +497,40 @@ class BackgroundRuntime:
             _fp.maybe_fail("runtime.cycle",
                            rank=self.state.rank_info.rank)
         _CYCLES.inc()
-        if self.timeline:
-            self.timeline.mark_cycle_start()
-        t0 = time.perf_counter()
+        if self.state.rank_info.size == 1 and \
+                not self.tensor_queue.pending_count():
+            # A world of one has nobody to hear from: nothing queued,
+            # nothing to do, and no span in a trace for every idle wake.
+            _QUEUE_DEPTH.set(self.tensor_queue.outstanding())
+            return
+        # One timing of the cycle feeds the span, hvd_cycle_seconds and
+        # the SLO plane; the open Timeline marks CYCLE_START from it.
+        with tl.span("cycle") as cycle:
+            worked = self._cycle()
+            if not worked:
+                cycle.discard()   # an idle poll is not a work cycle
+        if worked:
+            _CYCLE_SECONDS.observe(cycle.seconds)
+            if _slo.ENABLED:
+                # SLO cycle-time SLI (common/slo.py): O(1) append
+                # under the tracker's leaf lock, evaluated cold at
+                # ~1 Hz.  Disabled cost: this one attribute check.
+                tr = _slo.tracker()
+                if tr is not None:
+                    tr.note_cycle(cycle.seconds)
+
+    def _cycle(self) -> bool:
+        """Queue drain through response dispatch; False if there was
+        nothing to do."""
         pending = self.tensor_queue.pop_pending()
         _QUEUE_DEPTH.set(self.tensor_queue.outstanding())
-        if not pending and self.state.rank_info.size == 1:
-            return
-        if self.timeline and pending:
-            self.timeline.counter("queue_depth", {
-                "pending": len(pending),
-                "outstanding": self.tensor_queue.outstanding()})
-        responses, leftovers = self.controller.compute_response_list(
-            pending, self._entry_sizes,
-            self.state.knobs.fusion_threshold_bytes)
+        with tl.span("negotiate", requests=len(pending)) as negotiate:
+            responses, leftovers = self.controller.compute_response_list(
+                pending, self._entry_sizes,
+                self.state.knobs.fusion_threshold_bytes)
+            worked = bool(pending or responses)
+            if not worked:
+                negotiate.discard()   # nothing sent, nothing heard
         if leftovers:
             self.tensor_queue.push_back(leftovers)
         if self.stall_inspector is not None:
@@ -516,16 +546,7 @@ class BackgroundRuntime:
             self.stall_inspector.check()
         for resp in responses:
             self._perform_operation(resp)
-        if pending or responses:
-            cycle_dt = time.perf_counter() - t0
-            _CYCLE_SECONDS.observe(cycle_dt)
-            if _slo.ENABLED:
-                # SLO cycle-time SLI (common/slo.py): O(1) append
-                # under the tracker's leaf lock, evaluated cold at
-                # ~1 Hz.  Disabled cost: this one attribute check.
-                tr = _slo.tracker()
-                if tr is not None:
-                    tr.note_cycle(cycle_dt)
+        return worked
 
     # ------------------------------------------------------------------
     # execution (PerformOperation analog)
@@ -582,59 +603,18 @@ class BackgroundRuntime:
         if not entries:
             return
 
-        names = [e.tensor_name for e in entries]
-        tl_name = names[0]
         ps_ranks = tuple(resp.process_set_ranks)
-        sg_t0 = time.perf_counter() if _sg.ENABLED else 0.0
-        if self.timeline:
-            self.timeline.counter("fused_bytes", {"bytes": int(sum(
-                getattr(e.tensor, "nbytes", 0) for e in entries))})
+        # One timing of the backend call feeds the span, the Timeline's
+        # XLA_<op> activity on the first tensor's lane and the
+        # straggler's fused→executed phase.
         try:
-            if self.timeline:
-                self.timeline.start_activity(
-                    tl_name, f"XLA_{resp.response_type.name}")
-            if resp.response_type in (ResponseType.ALLREDUCE,):
-                arrays = [e.tensor for e in entries]
-                results = backend.allreduce(
-                    arrays, resp.reduce_op, resp.prescale_factor,
-                    resp.postscale_factor, ps_ranks)
-            elif resp.response_type == ResponseType.ADASUM:
-                arrays = [e.tensor for e in entries]
-                results = backend.adasum_allreduce(
-                    arrays, resp.prescale_factor, resp.postscale_factor,
-                    ps_ranks)
-            elif resp.response_type == ResponseType.ALLGATHER:
-                results = backend.allgather(
-                    [e.tensor for e in entries], resp.tensor_sizes,
-                    ps_ranks)
-            elif resp.response_type == ResponseType.BROADCAST:
-                results = backend.broadcast(
-                    [e.tensor for e in entries], resp.root_rank,
-                    ps_ranks)
-            elif resp.response_type == ResponseType.ALLTOALL:
-                # tensor_sizes carries the coordinator-assembled
-                # group×group send-split matrix (one alltoall per
-                # response — the type is never fused), so the backend
-                # skips its own split-exchange collective.
-                results = []
-                matrix = resp.tensor_sizes or None
-                for e in entries:
-                    out, recv_splits = backend.alltoall(
-                        e.tensor, e.splits, ps_ranks,
-                        split_matrix=matrix)
-                    results.append((out, recv_splits))
-            elif resp.response_type == ResponseType.REDUCESCATTER:
-                results = backend.reducescatter(
-                    [e.tensor for e in entries], resp.reduce_op,
-                    ps_ranks)
-            else:
-                raise RuntimeError(
-                    f"Unknown response type {resp.response_type}")
-            if self.timeline:
-                self.timeline.end_activity(tl_name)
+            with tl.span("dispatch", op=resp.response_type.name,
+                         tensor=entries[0].tensor_name,
+                         tensors=len(entries),
+                         bytes=metrics.list_nbytes(
+                             e.tensor for e in entries)) as dispatch:
+                results = self._execute(backend, resp, entries, ps_ranks)
         except Exception as err:
-            if self.timeline:
-                self.timeline.end_activity(tl_name)
             for e in entries:
                 e.callback(False, err)
             return
@@ -643,8 +623,7 @@ class BackgroundRuntime:
             # The fused→executed phase slice (the e2e EWMA comes from
             # the latency wrapper above); per-rank publication happens
             # on the cold MR-reply path, never here.
-            self.phase_collector.note_exec(
-                time.perf_counter() - sg_t0)
+            self.phase_collector.note_exec(dispatch.seconds)
         if _slo.ENABLED:
             # SLO throughput SLI: one fused response completes
             # len(entries) collective ops.  Disabled cost: this one
@@ -654,3 +633,34 @@ class BackgroundRuntime:
                 tr.note_op(len(entries))
         for e, result in zip(entries, results):
             e.callback(True, result)
+
+    @staticmethod
+    def _execute(backend, resp: Response, entries, ps_ranks):
+        """The backend call of one fused response."""
+        if resp.response_type == ResponseType.ALLREDUCE:
+            return backend.allreduce(
+                [e.tensor for e in entries], resp.reduce_op,
+                resp.prescale_factor, resp.postscale_factor, ps_ranks)
+        if resp.response_type == ResponseType.ADASUM:
+            return backend.adasum_allreduce(
+                [e.tensor for e in entries], resp.prescale_factor,
+                resp.postscale_factor, ps_ranks)
+        if resp.response_type == ResponseType.ALLGATHER:
+            return backend.allgather(
+                [e.tensor for e in entries], resp.tensor_sizes, ps_ranks)
+        if resp.response_type == ResponseType.BROADCAST:
+            return backend.broadcast(
+                [e.tensor for e in entries], resp.root_rank, ps_ranks)
+        if resp.response_type == ResponseType.ALLTOALL:
+            # tensor_sizes carries the coordinator-assembled
+            # group×group send-split matrix (one alltoall per
+            # response — the type is never fused), so the backend
+            # skips its own split-exchange collective.
+            matrix = resp.tensor_sizes or None
+            return [backend.alltoall(e.tensor, e.splits, ps_ranks,
+                                     split_matrix=matrix)
+                    for e in entries]
+        if resp.response_type == ResponseType.REDUCESCATTER:
+            return backend.reducescatter(
+                [e.tensor for e in entries], resp.reduce_op, ps_ranks)
+        raise RuntimeError(f"Unknown response type {resp.response_type}")

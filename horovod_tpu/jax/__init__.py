@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import optax
 
 from ..common import basics
+from ..common import timeline as tl
 from ..common.basics import (Adasum, Average, Max, Min, Product, Sum,
                              ProcessSet, global_process_set, init,
                              is_initialized, local_rank, local_size,
@@ -77,7 +78,14 @@ def allreduce_gradients(grads, op=Average, compression=Compression.none,
                         process_set: ProcessSet = global_process_set):
     """Allreduce a gradient pytree through the background runtime as one
     fused group (reference analog: _make_allreduce_grads_fn,
-    tensorflow/__init__.py:334-381)."""
+    tensorflow/__init__.py:334-381).  One ``hvd/exchange`` span holds
+    the ``hvd/submit`` and ``hvd/wait`` of every leaf."""
+    with tl.span("exchange", prefix=name_prefix):
+        return _allreduce_gradients(grads, op, compression, name_prefix,
+                                    process_set)
+
+
+def _allreduce_gradients(grads, op, compression, name_prefix, process_set):
     leaves, treedef = jax.tree_util.tree_flatten(grads)
     names = _tree_names(grads, name_prefix)
     compressed, ctxs = [], []

@@ -18,6 +18,7 @@ from typing import Any, List, Optional, Sequence
 import numpy as np
 
 from ..common import basics
+from ..common import timeline as tl
 from ..common.basics import (Adasum, Average, Max, Min, Product, Sum,
                              ProcessSet, global_process_set)
 from ..common.exceptions import HorovodInternalError
@@ -51,7 +52,9 @@ class Handle:
         return self._event.is_set()
 
     def wait(self, timeout: Optional[float] = None):
-        if not self._event.wait(timeout):
+        with tl.span("wait", tensor=self.name):
+            done = self._event.wait(timeout)
+        if not done:
             raise TimeoutError(
                 f"Collective {self.name!r} did not complete in time.")
         if not self.ok:

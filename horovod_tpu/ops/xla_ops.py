@@ -199,7 +199,7 @@ class XlaMeshBackend(Backend):
     @lru_cache(maxsize=512)
     def _allreduce_fn(mesh, n: int, reduce_op: str, prescale: float,
                       postscale: float):
-        def body(*xs):
+        def hvd_allreduce(*xs):
             out = []
             for x in xs:
                 x = x[0]  # this process's shard (1, ...) -> (...)
@@ -216,7 +216,7 @@ class XlaMeshBackend(Backend):
             return tuple(out)
 
         return jax.jit(jax.shard_map(
-            body, mesh=mesh,
+            hvd_allreduce, mesh=mesh,
             in_specs=tuple(P("world") for _ in range(n)),
             out_specs=tuple(P() for _ in range(n)), check_vma=False))
 
@@ -252,7 +252,7 @@ class XlaMeshBackend(Backend):
         """Each rank holds a full copy: reduce-scatter over the local
         (intra-host) axis, allreduce the shards over the cross axis,
         allgather back over local.  Input/output: flat padded buffers."""
-        def body(*xs):
+        def hvd_hier_allreduce_proc(*xs):
             out = []
             for x in xs:
                 x = x[0, 0]
@@ -270,7 +270,7 @@ class XlaMeshBackend(Backend):
             return tuple(out)
         n = len(shapes)
         return jax.jit(jax.shard_map(
-            body, mesh=mesh,
+            hvd_hier_allreduce_proc, mesh=mesh,
             in_specs=tuple(P("cross", "local") for _ in range(n)),
             out_specs=tuple(P() for _ in range(n)), check_vma=False))
 
@@ -282,7 +282,7 @@ class XlaMeshBackend(Backend):
         chips: allreduce each shard over the cross axis (parallel
         per-chip streams), allgather over local to rebuild the full
         tensor.  Input: (nproc, nlocal, chunk) globals."""
-        def body(*xs):
+        def hvd_hier_allreduce_dev(*xs):
             out = []
             for x in xs:
                 x = x[0, 0]
@@ -298,7 +298,7 @@ class XlaMeshBackend(Backend):
             return tuple(out)
         n = len(shapes)
         return jax.jit(jax.shard_map(
-            body, mesh=mesh,
+            hvd_hier_allreduce_dev, mesh=mesh,
             in_specs=tuple(P("cross", "local") for _ in range(n)),
             out_specs=tuple(P() for _ in range(n)), check_vma=False))
 
@@ -372,7 +372,7 @@ class XlaMeshBackend(Backend):
         ops/collective_operations.cc).  ``tsizes_per_tensor`` is static
         per executable — a different row layout compiles a new program,
         same as any shape change."""
-        def body(*xs):
+        def hvd_allgather(*xs):
             out = []
             for x, tsizes in zip(xs, tsizes_per_tensor):
                 full = jax.lax.all_gather(x[0], "world", axis=0,
@@ -382,7 +382,7 @@ class XlaMeshBackend(Backend):
             return tuple(out)
         n = len(tsizes_per_tensor)
         return jax.jit(jax.shard_map(
-            body, mesh=mesh,
+            hvd_allgather, mesh=mesh,
             in_specs=tuple(P("world") for _ in range(n)),
             out_specs=tuple(P() for _ in range(n)), check_vma=False))
 
@@ -420,7 +420,7 @@ class XlaMeshBackend(Backend):
     @staticmethod
     @lru_cache(maxsize=256)
     def _bcast_fn(mesh, n: int, root: int):
-        def body(*xs):
+        def hvd_broadcast(*xs):
             out = []
             for x in xs:
                 x = x[0]
@@ -429,7 +429,7 @@ class XlaMeshBackend(Backend):
                 out.append(jax.lax.psum(masked, "world"))
             return tuple(out)
         return jax.jit(jax.shard_map(
-            body, mesh=mesh,
+            hvd_broadcast, mesh=mesh,
             in_specs=tuple(P("world") for _ in range(n)),
             out_specs=tuple(P() for _ in range(n)), check_vma=False))
 
@@ -453,13 +453,13 @@ class XlaMeshBackend(Backend):
     @staticmethod
     @lru_cache(maxsize=256)
     def _a2a_fn(mesh):
-        def body(x):
+        def hvd_alltoall(x):
             y = jax.lax.all_to_all(x[0], "world", split_axis=0,
                                    concat_axis=0, tiled=True)
             return y[None]
         return jax.jit(jax.shard_map(
-            body, mesh=mesh, in_specs=P("world"), out_specs=P("world"),
-            check_vma=False))
+            hvd_alltoall, mesh=mesh, in_specs=P("world"),
+            out_specs=P("world"), check_vma=False))
 
     @staticmethod
     @lru_cache(maxsize=256)
@@ -472,7 +472,7 @@ class XlaMeshBackend(Backend):
         gsize = len(send_splits)
 
         @jax.jit
-        def pack(x):
+        def hvd_alltoall_pack(x):
             chunks = jnp.zeros((gsize, maxchunk) + x.shape[1:],
                                dtype=x.dtype)
             off = 0
@@ -483,7 +483,7 @@ class XlaMeshBackend(Backend):
                         jax.lax.slice_in_dim(x, off, off + c, axis=0))
                 off += c
             return chunks
-        return pack
+        return hvd_alltoall_pack
 
     @staticmethod
     @lru_cache(maxsize=256)
@@ -492,14 +492,14 @@ class XlaMeshBackend(Backend):
         gsize = len(recv_splits)
 
         @jax.jit
-        def unpack(y):
+        def hvd_alltoall_unpack(y):
             pieces = [jax.lax.slice_in_dim(y[r], 0, recv_splits[r],
                                            axis=0)
                       for r in range(gsize) if recv_splits[r]]
             if not pieces:
                 return y[0, :0]
             return jnp.concatenate(pieces, axis=0)
-        return unpack
+        return hvd_alltoall_unpack
 
     @metrics.timed_collective("xla", "ALLTOALL", metrics.one_nbytes)
     def alltoall(self, array, splits, ps_ranks=(), split_matrix=None):
@@ -547,7 +547,7 @@ class XlaMeshBackend(Backend):
     @staticmethod
     @lru_cache(maxsize=256)
     def _rs_fn(mesh, n: int, reduce_op: str):
-        def body(*xs):
+        def hvd_reducescatter(*xs):
             out = []
             for x in xs:
                 x = x[0]  # (group*chunk, ...) contribution
@@ -561,7 +561,7 @@ class XlaMeshBackend(Backend):
                 out.append(y[None])
             return tuple(out)
         return jax.jit(jax.shard_map(
-            body, mesh=mesh,
+            hvd_reducescatter, mesh=mesh,
             in_specs=tuple(P("world") for _ in range(n)),
             out_specs=tuple(P("world") for _ in range(n)),
             check_vma=False))
@@ -579,7 +579,7 @@ class XlaMeshBackend(Backend):
             starts.append(starts[-1] + c)
 
         @jax.jit
-        def pack(arr):
+        def hvd_reducescatter_pack(arr):
             padded = jnp.zeros((gsize, chunk) + arr.shape[1:], arr.dtype)
             for r in range(gsize):
                 if counts[r]:
@@ -588,7 +588,7 @@ class XlaMeshBackend(Backend):
                                              starts[r] + counts[r],
                                              axis=0))
             return padded.reshape((gsize * chunk,) + arr.shape[1:])
-        return pack
+        return hvd_reducescatter_pack
 
     @metrics.timed_collective("xla", "REDUCESCATTER", metrics.list_nbytes)
     def reducescatter(self, arrays, reduce_op, ps_ranks=()):

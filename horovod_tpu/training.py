@@ -110,14 +110,18 @@ def make_bert_pretrain_step(
         logits = model.apply({"params": params}, batch["input_ids"],
                              attention_mask=batch.get("attention_mask"),
                              deterministic=deterministic, rngs=rngs)
-        return mlm_loss(logits, batch["labels"], batch["mask"])
+        with jax.named_scope("loss"):
+            return mlm_loss(logits, batch["labels"], batch["mask"])
 
     def _step(state, batch):
         dropout_rng = jax.random.fold_in(
             jax.random.PRNGKey(dropout_seed), state.step)
         loss, grads = jax.value_and_grad(_loss_fn)(
             state.params, batch, dropout_rng)
-        new_state = state.apply_gradients(grads=grads)
+        # Named, so that a device trace puts AdamW's fusions under a
+        # path of their own and not under the step's bare name.
+        with jax.named_scope("optimizer"):
+            new_state = state.apply_gradients(grads=grads)
         return new_state, loss
 
     # Shapes of the state determine its sharding tree; evaluate
@@ -296,10 +300,14 @@ def make_gpt_train_step(config, mesh, learning_rate: float = 1e-2,
     @partial(jax.jit, donate_argnums=(0, 1))
     def step_fn(params, opt_state, ids):
         def loss_fn(p):
-            return lm_loss(model.apply({"params": p}, ids), ids)
+            logits = model.apply({"params": p}, ids)
+            with jax.named_scope("loss"):
+                return lm_loss(logits, ids)
         loss, grads = jax.value_and_grad(loss_fn)(params)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state, loss
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+        return params, opt_state, loss
 
     return init_fn, step_fn, batch_sharding
 
