@@ -29,6 +29,14 @@ class TrainState(train_state.TrainState):
     pass
 
 
+# XLA's SPMD partitioner keeps an RngBitGenerator whole by default:
+# every chip would make the global array of bits and keep its slice.
+# With this option (jax.random's documented way to shard "rbg"
+# generation; libtpu's compiler alone knows it) each chip makes its
+# shard's bits from the key offset by its partition id.
+_RBG_PARTITIONED = {"xla_tpu_spmd_rng_bit_generator_unsafe": True}
+
+
 def factor_mesh_axes(n_devices: int,
                      names: Tuple[str, ...] = ("dp", "tp", "sp"),
                      absorb: str = "dp") -> Dict[str, int]:
@@ -81,8 +89,17 @@ def make_bert_pretrain_step(
 
     * params/opt-state sharded by Megatron-style rules (tp [+ fsdp]);
     * batch sharded (dp, sp) over (batch, sequence);
-    * dropout active whenever the config's dropout rates are non-zero,
-      with the rng folded from the step counter (deterministic replay);
+    * dropout active whenever the config's dropout rates are non-zero.
+      The masks' bits come from XLA's ``RngBitGenerator`` (an ``"rbg"``
+      key: on the TPU the core's own generator, each chip making its
+      shard's bits), 32 an element at the exact rate; threefry only
+      folds ``state.step`` and the module path into the key.  The key
+      is a function of ``(dropout_seed, step)`` alone, so a replayed
+      step repeats its masks under the same program, mesh layout and
+      XLA; under another layout, backend or XLA version it draws other
+      masks of the same distribution.  ``jax_default_prng_impl`` is not
+      touched: initial weights come from the key the caller hands
+      ``init_fn``, as before;
     * gradient reduction over dp and the tp/sp collectives are inserted
       by XLA (GSPMD) — on TPU hardware they ride ICI.
     """
@@ -98,6 +115,7 @@ def make_bert_pretrain_step(
                    "sp" if "sp" in mesh.shape else None)
     batch_sharding = NamedSharding(mesh, batch_spec)
     repl = NamedSharding(mesh, P())
+    on_tpu = mesh.devices.flat[0].platform == "tpu"
 
     def _init(rng, batch):
         params = model.init(rng, batch["input_ids"],
@@ -115,7 +133,7 @@ def make_bert_pretrain_step(
 
     def _step(state, batch):
         dropout_rng = jax.random.fold_in(
-            jax.random.PRNGKey(dropout_seed), state.step)
+            jax.random.key(dropout_seed, impl="rbg"), state.step)
         loss, grads = jax.value_and_grad(_loss_fn)(
             state.params, batch, dropout_rng)
         # Named, so that a device trace puts AdamW's fusions under a
@@ -137,7 +155,8 @@ def make_bert_pretrain_step(
                           jax.tree.map(lambda _: batch_sharding,
                                        example_batch)),
             out_shardings=(state_sharding, repl),
-            donate_argnums=(0,) if donate else ())
+            donate_argnums=(0,) if donate else (),
+            compiler_options=_RBG_PARTITIONED if on_tpu else None)
         return init_fn, step_fn
 
     return make_jitted, batch_sharding

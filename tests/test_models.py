@@ -228,3 +228,110 @@ def test_gpt_fsdp_train_step_shards_params_and_learns():
         params, opt_state, loss = step_fn(params, opt_state, ids)
         losses.append(float(loss))
     assert losses[-1] < losses[0], losses
+
+
+# ---------------------------------------------------------------------------
+# BERT's dropout: the masks' bits come from XLA's RngBitGenerator
+# ---------------------------------------------------------------------------
+
+def _bert_dropout_step(rate, batch_size=8, seq_len=32):
+    """The tiny BERT step on one device, dropout at ``rate`` at every
+    site, with its state and batch."""
+    from horovod_tpu.training import (make_bert_batch,
+                                      make_bert_pretrain_step)
+    from horovod_tpu.parallel.mesh import build_mesh
+
+    cfg = bert_tiny_config(hidden_dropout=rate, attention_dropout=rate,
+                           max_position_embeddings=seq_len)
+    mesh = build_mesh({"dp": 1}, jax.devices()[:1])
+    make_jitted, batch_sharding = make_bert_pretrain_step(
+        cfg, mesh, donate=False)
+    batch = make_bert_batch(batch_size, seq_len, cfg.vocab_size)
+    batch = jax.tree.map(lambda x: jax.device_put(x, batch_sharding),
+                         batch)
+    init_fn, step_fn = make_jitted(batch)
+    return cfg, step_fn, init_fn(jax.random.PRNGKey(0), batch), batch
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) \
+                    else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def _tensor_sizes(line):
+    """Element counts of the ``tensor<..>`` types on a line of MLIR."""
+    import re
+    return [int(np.prod([int(d) for d in dims.split("x") if d] or [1]))
+            for dims in re.findall(r"tensor<((?:\d+x)*)[a-z]", line)]
+
+
+def test_bert_dropout_bits_come_from_rng_bit_generator():
+    """Every dropout site (3 a layer + 1) draws its mask from an
+    ``rbg`` key at the site's shape, the lowered step makes those bits
+    with ``rng_bit_generator``, and what threefry is left (folding the
+    step and the module path into the key) works on a key's few words,
+    never on an array of a mask's size."""
+    from collections import Counter
+    b, s = 8, 32
+    cfg, step_fn, state, batch = _bert_dropout_step(0.1, b, s)
+    hidden = (b, s, cfg.hidden_size)
+    probs = (b, cfg.num_heads, s, s)
+
+    draws = [(str(e.invars[0].aval.dtype), tuple(e.params["shape"]))
+             for e in _eqns(jax.make_jaxpr(step_fn)(state, batch).jaxpr)
+             if e.primitive.name == "random_bits"]
+    assert Counter(draws) == {
+        ("key<rbg>", hidden): 2 * cfg.num_layers + 1,
+        ("key<rbg>", probs): cfg.num_layers}, draws
+
+    text = step_fn.lower(state, batch).as_text()
+    generated = {line.rsplit("tensor<", 1)[1].split("xui32")[0]
+                 for line in text.splitlines()
+                 if "stablehlo.rng_bit_generator" in line}
+    assert generated == {"x".join(map(str, shape))
+                         for shape in (hidden, probs)}, generated
+    threefry = [line for line in text.splitlines() if "threefry" in line]
+    assert threefry, "the key is still folded with threefry"
+    assert max(n for line in threefry for n in _tensor_sizes(line)) <= 4
+
+
+def test_bert_without_dropout_holds_no_generator():
+    _, step_fn, state, batch = _bert_dropout_step(0.0)
+    assert not any(
+        e.primitive.name == "random_bits"
+        for e in _eqns(jax.make_jaxpr(step_fn)(state, batch).jaxpr))
+    assert "rng_bit_generator" not in step_fn.lower(state, batch).as_text()
+
+
+def test_bert_dropout_replays_a_step_and_moves_on_at_the_next():
+    """The masks are a function of ``(dropout_seed, state.step)``."""
+    _, step_fn, state, batch = _bert_dropout_step(0.1)
+    state1, first = step_fn(state, batch)
+    _, again = step_fn(state, batch)
+    assert float(first) == float(again)
+    # The same parameters at the next step: only the masks differ.
+    _, other = step_fn(state.replace(step=state.step + 1), batch)
+    assert float(other) != float(first)
+    assert int(state1.step) == int(state.step) + 1
+
+
+def test_bert_dropout_mask_has_the_exact_rate_and_scale():
+    """A million draws under the step's kind of key: the kept share
+    within four binomial standard deviations of 0.9, the kept values
+    scaled by 1 / 0.9."""
+    import flax.linen as nn
+    n, keep = 1 << 20, 0.9
+    key = jax.random.fold_in(jax.random.key(0, impl="rbg"), 7)
+    y = np.asarray(nn.Dropout(1.0 - keep).apply(
+        {}, jnp.ones((1024, 1024), jnp.float32), deterministic=False,
+        rngs={"dropout": key}))
+    kept = y != 0.0
+    assert abs(kept.mean() - keep) < 4 * np.sqrt(keep * (1 - keep) / n)
+    np.testing.assert_allclose(y[kept], 1.0 / keep, rtol=1e-6)
