@@ -3,10 +3,12 @@
 ``python chip_smoke.py`` needs one TPU chip and drives the main path
 once through the entry points a user would call:
 
-* ``flash``   - the Pallas flash-attention forward, compiled by Mosaic,
+* ``flash``   - the Pallas flash-attention kernels, compiled by Mosaic,
   against a float32 ``jax.numpy`` softmax attention at BERT-large's
-  heads (8, 512, 16, 64) and GPT-2's (4, 1024, 12, 64, causal), both
-  bf16, and one pass of its custom VJP for finite gradients;
+  heads (8, 512, 16, 64), GPT-2's (4, 1024, 12, 64, causal) and the
+  GPT cell's step (16, 1024, 16, 64, causal), all bf16: the forward's
+  output, and dQ, dK, dV from the two backward kernels against the
+  reference's gradients;
 * ``trainer`` - a trainer started by the launcher
   (``python -m horovod_tpu.runner.launch -np 1``) that calls
   ``hvd.init()``, a few eager ops on ``jax.Array`` inputs, builds its
@@ -47,11 +49,25 @@ import time
 HERE = os.path.dirname(os.path.abspath(__file__))
 RESULT_MARK = "CHIP_SMOKE_RESULT "
 
-# [B, S, H, D], causal: BERT-large's heads at S=512, GPT-2's at S=1024.
-FLASH_CASES = (((8, 512, 16, 64), False), ((4, 1024, 12, 64), True))
+# [B, S, H, D], causal: BERT-large's heads at S=512, GPT-2's at S=1024,
+# what one step of the benchmark's GPT cell hands the kernels, and two
+# sequences longer than a grid step holds (SEQ_BLOCK = 1024 rows): four
+# blocks, causal, and one and a half, where the kernels' mask alone
+# hides the padded half.
+FLASH_CASES = (((8, 512, 16, 64), False), ((4, 1024, 12, 64), True),
+               ((16, 1024, 16, 64), True), ((1, 4096, 16, 64), True),
+               ((2, 1536, 16, 64), False))
 # bf16 inputs and output against a float32 reference: the output's own
 # rounding is 2^-9 relative, the kernel's P.V product runs on the MXU.
 FLASH_ATOL = FLASH_RTOL = 2e-2
+# dQ, dK, dV as relative L2 error of the whole array: each is rounded
+# to bf16 on its way out (2^-9 an element, 1.1e-3 of the norm) and p
+# and ds are rounded to bf16 before their second product, as the
+# einsum path rounds its probabilities.  The v5e read 2.5e-3 to 2.8e-3
+# at (16, 1024, 16, 64) causal and the einsum path 3.9e-3 to 4.4e-3
+# (PERF.md, PR 25); fp8 operands (2^-4 a rounding) would read thirty
+# times that.
+FLASH_GRAD_REL_L2 = 1e-2
 # Sharded against one device: same math, other reduction orders, bf16
 # matmuls, three optimizer steps.
 SHARDED_LOSS_RTOL = 2e-3
@@ -122,9 +138,9 @@ def phase_flash(cases=FLASH_CASES, platform: str = "tpu",
     compile_cache.enable()
     _device(platform)
     for shape, causal in cases:
-        keys = jax.random.split(jax.random.PRNGKey(0), 3)
+        keys = jax.random.split(jax.random.PRNGKey(0), 4)
         q, k, v = (jax.random.normal(kk, shape, jnp.bfloat16)
-                   for kk in keys)
+                   for kk in keys[:3])
         flash = functools.partial(flash_attention, causal=causal,
                                   interpret=interpret)
         out = jax.jit(flash)(q, k, v)
@@ -141,16 +157,32 @@ def phase_flash(cases=FLASH_CASES, platform: str = "tpu",
               "max abs err %.2e (atol %.0e rtol %.0e)"
               % (shape, causal, err, FLASH_ATOL, FLASH_RTOL))
 
-        def loss(f, q, k, v):
-            return jnp.mean(f(q, k, v).astype(jnp.float32) ** 2)
+        # The same cotangent through both; the reference's gradients
+        # are float32 arrays of the inputs' shape.
+        do = jax.random.normal(keys[3], shape, jnp.float32)
 
+        def loss(f, q, k, v):
+            return jnp.sum(f(q, k, v).astype(jnp.float32) * do)
+
+        argnums = (0, 1, 2)
         grads = jax.jit(jax.grad(functools.partial(loss, flash),
-                                 argnums=(0, 1, 2)))(q, k, v)
-        for name, g in zip("qkv", grads):
-            assert g.shape == shape and bool(jnp.isfinite(g).all()), \
-                "flash VJP d%s not finite" % name
-        _info("flash custom VJP %s causal=%s: finite dq, dk, dv"
-              % (shape, causal))
+                                 argnums))(q, k, v)
+        wants = jax.jit(jax.grad(functools.partial(loss, functools.partial(
+            _softmax_attention_f32, causal=causal)), argnums))(q, k, v)
+        errs = {}
+        for name, g, w in zip("qkv", grads, wants):
+            assert g.shape == shape and g.dtype == jnp.bfloat16, \
+                (name, g.shape, g.dtype)
+            assert _on_platform(g, platform)
+            g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+            errs[name] = float(np.linalg.norm(g - w) / np.linalg.norm(w))
+            assert errs[name] <= FLASH_GRAD_REL_L2, \
+                "flash d%s off the float32 reference: relative L2 %.2e " \
+                "(bound %.0e)" % (name, errs[name], FLASH_GRAD_REL_L2)
+        _info("flash backward %s causal=%s matches float32 reference: "
+              "relative L2 dq %.2e dk %.2e dv %.2e (bound %.0e)"
+              % (shape, causal, errs["q"], errs["k"], errs["v"],
+                 FLASH_GRAD_REL_L2))
     _result("flash")
 
 

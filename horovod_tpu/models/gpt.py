@@ -6,21 +6,24 @@ own; this zoo is what the framework's benchmarks, Adasum runs and
 sharded-training paths exercise — SURVEY §2 model-family rows).
 
 TPU-first design mirrors bert.py: all matmuls in bfloat16 (fp32
-params), static shapes, attention as batched einsums that tile onto
-the MXU (or the Pallas flash kernel with ``causal=True`` for O(S)
-memory), pre-LayerNorm residual blocks, optional per-layer
+params), static shapes, attention through the Pallas flash kernels
+(``ops/pallas_attention.py``, forward and backward, no S x S array in
+HBM) on a TPU and as batched einsums elsewhere or where attention
+dropout is applied, pre-LayerNorm residual blocks, optional per-layer
 ``jax.checkpoint`` rematerialisation, and parameter naming matched by
 :func:`horovod_tpu.parallel.sharding.gpt_partition_rules` so kernels
 map onto tensor-parallel mesh axes.
 """
 
 import dataclasses
+import functools
 import math
-from typing import Any
+from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
 
 
 @dataclasses.dataclass(frozen=True)
@@ -35,9 +38,11 @@ class GPTConfig:
     layer_norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     remat: bool = False
-    # "einsum": plain XLA attention; "flash": the Pallas kernel
-    # (ops/pallas_attention.py, causal=True).
-    attention_impl: str = "einsum"
+    # "auto": the Pallas kernels (ops/pallas_attention.py) on a TPU
+    # wherever no attention dropout is applied, plain XLA einsums
+    # elsewhere.  "einsum" and "flash" name one path; the CPU tests
+    # name "flash" to run the kernels in interpret mode.
+    attention_impl: str = "auto"
 
 
 def gpt2_small_config(**kw) -> GPTConfig:
@@ -60,8 +65,36 @@ def gpt_tiny_config(**kw) -> GPTConfig:
     return GPTConfig(**defaults)
 
 
+def _flash_causal(q, k, v, sharding: Optional[NamedSharding]):
+    from ..ops.pallas_attention import flash_attention
+    attend = functools.partial(flash_attention, causal=True)
+    if sharding is not None and sharding.mesh.size > 1:
+        # GSPMD does not partition a Mosaic kernel; attention is
+        # independent per sequence and per head, so each chip runs
+        # the kernels on the shard the step builder gives it.
+        attend = jax.shard_map(
+            attend, mesh=sharding.mesh, in_specs=(sharding.spec,) * 3,
+            out_specs=sharding.spec, check_vma=False)
+    return attend(q, k, v)
+
+
+def attention_impl(config: GPTConfig, mesh, kernels_apply: bool) -> str:
+    """``config.attention_impl`` with "auto" resolved: the kernels on a
+    TPU (the mesh's platform, or the default backend where there is no
+    mesh) wherever they apply, the einsums elsewhere."""
+    if config.attention_impl != "auto":
+        return config.attention_impl
+    platform = (mesh.devices.flat[0].platform if mesh is not None
+                else jax.default_backend())
+    return "flash" if platform == "tpu" and kernels_apply else "einsum"
+
+
 class CausalSelfAttention(nn.Module):
     config: GPTConfig
+    # How the step this model is traced in shards ``[B, S, H, D]``
+    # (the step builder says; its mesh also tells the platform); None
+    # where the model is applied directly.
+    qkv_sharding: Optional[NamedSharding] = None
 
     @nn.compact
     def __call__(self, x, deterministic: bool = True):
@@ -73,14 +106,20 @@ class CausalSelfAttention(nn.Module):
         q = dense("query")(x)
         k = dense("key")(x)
         v = dense("value")(x)
-        if cfg.attention_impl == "flash":
-            if cfg.dropout > 0.0 and not deterministic:
+        dropout_applied = cfg.dropout > 0.0 and not deterministic
+        # The kernels apply no attention dropout; and ``init`` wants
+        # the parameters' shapes and nothing of the attention, so no
+        # kernel is traced and lowered for it.
+        kernels_apply = not dropout_applied and not self.is_initializing()
+        mesh = (None if self.qkv_sharding is None
+                else self.qkv_sharding.mesh)
+        if attention_impl(cfg, mesh, kernels_apply) == "flash":
+            if dropout_applied:
                 raise NotImplementedError(
                     "attention_impl='flash' does not apply attention "
                     "dropout; set dropout=0 or use 'einsum' (same "
                     "guard as the BERT family).")
-            from ..ops.pallas_attention import flash_attention
-            ctx = flash_attention(q, k, v, causal=True).astype(cfg.dtype)
+            ctx = _flash_causal(q, k, v, self.qkv_sharding).astype(cfg.dtype)
         else:
             seq = x.shape[1]
             scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
@@ -101,6 +140,7 @@ class CausalSelfAttention(nn.Module):
 class GPTBlock(nn.Module):
     """Pre-LN residual block (GPT-2 layout)."""
     config: GPTConfig
+    qkv_sharding: Optional[NamedSharding] = None
 
     @nn.compact
     def __call__(self, x, deterministic: bool = True):
@@ -108,7 +148,7 @@ class GPTBlock(nn.Module):
         norm = lambda name: nn.LayerNorm(
             epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
             param_dtype=jnp.float32, name=name)
-        h = CausalSelfAttention(cfg, name="attention")(
+        h = CausalSelfAttention(cfg, self.qkv_sharding, name="attention")(
             norm("attention_norm")(x), deterministic)
         h = nn.Dropout(cfg.dropout)(h, deterministic=deterministic)
         x = x + h
@@ -125,6 +165,7 @@ class GPTBlock(nn.Module):
 class GPTLMHeadModel(nn.Module):
     """Decoder stack + tied-embedding LM head."""
     config: GPTConfig
+    qkv_sharding: Optional[NamedSharding] = None
 
     @nn.compact
     def __call__(self, input_ids, deterministic: bool = True):
@@ -140,9 +181,17 @@ class GPTLMHeadModel(nn.Module):
         x = nn.Dropout(cfg.dropout)(x, deterministic=deterministic)
         block = GPTBlock
         if cfg.remat:
-            block = nn.remat(GPTBlock, static_argnums=(2,))
+            # A block is recomputed in the backward pass but for what
+            # the flash kernels name (their output and its row
+            # statistics, which their backward reads): recomputing
+            # those would be the forward kernel over again.
+            block = nn.remat(
+                GPTBlock, static_argnums=(2,),
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    "flash_out", "flash_lse"))
         for i in range(cfg.num_layers):
-            x = block(cfg, name=f"layer_{i}")(x, deterministic)
+            x = block(cfg, self.qkv_sharding, name=f"layer_{i}")(
+                x, deterministic)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                          param_dtype=jnp.float32, name="final_norm")(x)
         logits = jnp.einsum("bsh,vh->bsv", x,

@@ -1,22 +1,44 @@
-"""Flash attention as a Pallas TPU kernel.
+"""Flash attention as Pallas TPU kernels, forward and backward.
 
-The hot op of transformer training, written TPU-first: Q/K/V blocks
-stream HBM→VMEM, scores hit the MXU per (q-block, kv-block) tile, and
-the softmax is accumulated online in VMEM scratch across the kernel
-grid's sequential last dimension (the canonical TPU flash pattern —
-grid iterations over kv blocks execute in order per q block, so the
-running max / denominator / weighted-sum live in scratch between
-iterations).
+The hot op of transformer training, written TPU-first.  No array with
+two sequence dimensions ever exists in HBM: each kernel holds a block
+of queries and a block of keys and values in VMEM (``SEQ_BLOCK`` rows
+of each; for sequences up to that length, the whole head) and walks it
+in tiles of scores that live and die in VMEM.
+
+* ``hvd_flash_fwd``: online softmax over the tiles of a query block;
+  writes the output and each row's log-sum-exp (one float32 a row,
+  rows along the lanes).
+* ``hvd_flash_bwd_dkv``: grid over kv blocks, q blocks the sequential
+  last dimension; recomputes ``p = exp(s - lse)`` tile by tile and
+  accumulates dK and dV in float32 scratch.
+* ``hvd_flash_bwd_dq``: grid over q blocks, kv blocks sequential; the
+  same recomputation, accumulates dQ.
+
+The kernels read and write the models' own ``[B, S, H, D]`` arrays,
+seen as ``[B, S, H * D]``: a block holds as many heads as fill the 128
+lanes (two of GPT-2's), each head a lane slice of it, so XLA moves
+nothing around the kernels and every store is lane-dense.
+
+Tiles wholly above the causal diagonal are skipped, tiles on it are
+masked, tiles below it carry no mask arithmetic.  Both products of a
+tile take their operands in the dtype they arrive in (bf16 in the
+models) and accumulate in float32; ``p`` and ``ds`` are cast to the
+operand dtype before the second product, as the einsum path casts its
+probabilities; softmax statistics, ``di`` and accumulators stay
+float32.  Float32 inputs compute in float32 throughout.
 
 Pairs with the mesh-level sequence parallelism in
 :mod:`horovod_tpu.parallel.attention`: ring attention rotates K/V
-shards between chips while THIS kernel computes each local block.
+shards between chips while these kernels compute each local block.
 
-The public :func:`flash_attention` carries a custom VJP whose backward
-recomputes attention in plain XLA (exact, O(S²) memory in backward;
-kernelizing the backward is a further optimization).  The kernel is
-compiled by Mosaic unless the caller passes ``interpret=True``, which
-is how the CPU tests run the same kernel body.
+The kernels are compiled by Mosaic unless the caller passes
+``interpret=True``, which is how the CPU tests run the same bodies.
+Each is called through one jitted function, so the unrolled layers of
+a model share one lowering.  The forward names its output and its
+log-sum-exp ``flash_out`` and ``flash_lse`` (``checkpoint_name``): a
+model that recomputes its layers keeps those two by policy, and the
+forward kernel runs once.
 """
 
 import functools
@@ -25,143 +47,402 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
+# The tile of scores (queries x keys) the kernels compute at a time,
+# and the rows of queries and of keys a grid step holds.  Picked on a
+# TPU v5 lite at [16, 1024, 16, 64] causal bf16 (PERF.md, PR 25).  A
+# grid step costs about a third of a microsecond and one head's causal
+# square a microsecond of MXU time: so a step holds a whole head
+# (SEQ_BLOCK rows) and the kernel walks it in tiles, deciding
+# statically which to skip.  Large tiles feed the MXU better, small
+# ones skip more of the square above the diagonal; and every tile is
+# unrolled code that every start loads with the step, about a tenth of
+# a second a tile (dK/dV alone runs 13 % faster at 256 x 256 and 22 %
+# faster at 128 x 128, at 1.5 and 8 s of every warm start).
+TILE = (512, 512)
+SEQ_BLOCK = 1024
+LANES = 128
 
-def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                 scale: float, causal: bool, bq: int, bk: int,
-                 skv: int):
-    i = pl.program_id(1)          # q-block index
-    j = pl.program_id(2)          # kv-block index (sequential)
-    nk = pl.num_programs(2)
+_NT = (((1,), (1,)), ((), ()))     # a @ b.T
 
-    @pl.when(j == 0)
-    def _init():
+
+def _when(cond, fn):
+    """``pl.when`` that decides at trace time where it can."""
+    if isinstance(cond, bool):
+        if cond:
+            fn()
+    else:
+        pl.when(cond)(fn)
+
+
+def _block_ids(nq: int, nk: int, q_axis: int):
+    """The q and kv block of this grid step (grid: batch, head group,
+    then the two); a plain 0 where the grid has one block, so that
+    every causal decision of a whole head is made while tracing."""
+    i = pl.program_id(q_axis) if nq > 1 else 0
+    j = pl.program_id(5 - q_axis) if nk > 1 else 0
+    return i, j
+
+
+def _tiles(fn, i, j, *, causal, bq, bk, sq, sk, skv=None,
+           keys_outer=False):
+    """Walk block (i, j) of the scores tile by tile: ``fn(masked, rows,
+    cols, q0, k0)`` for every tile that is neither wholly above the
+    causal diagonal nor wholly padding; ``masked`` says whether the
+    tile straddles the diagonal or the end of the keys (``skv``: the
+    true number of keys, given where the keys are padded at all)."""
+    pairs = [(a, c) for a in range(bq // sq) for c in range(bk // sk)]
+    if keys_outer:
+        pairs.sort(key=lambda ac: (ac[1], ac[0]))
+    for a, c in pairs:
+        q0, k0 = i * bq + a * sq, j * bk + c * sk
+        tile = functools.partial(
+            fn, rows=slice(a * sq, (a + 1) * sq),
+            cols=slice(c * sk, (c + 1) * sk), q0=q0, k0=k0)
+        run, masked = True, False
+        if causal:
+            run = k0 <= q0 + (sq - 1)
+            masked = k0 + (sk - 1) > q0
+        if skv is not None:
+            run = run & (k0 < skv)
+            masked = masked | (k0 + sk > skv)
+        if isinstance(masked, bool):
+            _when(run, functools.partial(tile, masked))
+        else:
+            _when(run & masked, functools.partial(tile, True))
+            _when(run & jnp.logical_not(masked),
+                  functools.partial(tile, False))
+
+
+def _offsets(causal: bool, shape, q_dim: int):
+    """``qpos - kpos`` over a tile that starts at query 0 and key 0:
+    made once a kernel, so that a tile on the diagonal pays one
+    comparison with a scalar for its mask.  ``q_dim`` is the tile's
+    query dimension (1 where the scores are transposed)."""
+    if not causal:
+        return None
+    return (jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
+            - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim))
+
+
+def _mask(s, offsets, q0, k0, q_dim: int, skv=None):
+    """The scores of a masked tile with those that do not count at
+    ``NEG_INF``: keys after the query (``offsets`` of a causal kernel)
+    and, where ``skv`` is given, keys past the end of the sequence."""
+    keep = None if offsets is None else offsets >= k0 - q0
+    if skv is not None:
+        kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_dim)
+        keep = (kpos < skv) if keep is None else keep & (kpos < skv)
+    return jnp.where(keep, s, NEG_INF)
+
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
+                vt_ref, *, causal, nq, nk, heads, d, bq, bk, sq, sk, skv):
+    """Scores, statistics and accumulator all transposed ([keys,
+    queries], [1, queries], [D, queries]): the running max and sum of
+    a query then lie along the lanes, a few registers a tile, and
+    reducing over keys is elementwise between registers.  With queries
+    down the sublanes every statistic costs a register per eight
+    rows, more than the tile's own arithmetic at 512 keys."""
+    i, j = _block_ids(nq, nk, 2)
+
+    def init():
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
+    _when(j == 0, init)
+    # The values once a grid step as [heads * D, keys], for the
+    # transposed accumulator: a head's are then a sublane slice.
+    vt_ref[:] = v_ref[0].T
 
-    # Causal: whole block is masked out when its lowest k position
-    # exceeds this q block's highest position.
-    run = True
-    if causal:
-        run = (j * bk) <= (i * bq + bq - 1)
+    offsets = _offsets(causal, (sk, sq), 1)
+    for h in range(heads):
+        lanes = slice(h * d, (h + 1) * d)
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)              # [bq, D]
-        k = k_ref[0].astype(jnp.float32)              # [bk, D]
-        v = v_ref[0].astype(jnp.float32)              # [bk, D]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [bq, bk]
-        kpos = j * bk + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, bk), 1)
-        if skv % bk != 0:
-            # Tail block: positions past the sequence end are padding.
-            s = jnp.where(kpos < skv, s, NEG_INF)
-        if causal:
-            qpos = i * bq + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 0)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
-        m_prev = m_ref[:, 0]                          # [bq]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])               # [bq, bk]
-        corr = jnp.exp(m_prev - m_new)                # [bq]
-        l_new = l_ref[:, 0] * corr + jnp.sum(p, axis=1)
-        acc_ref[:] = acc_ref[:] * corr[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_ref[:] = m_new[:, None]
-        l_ref[:] = l_new[:, None]
+        def tile(masked, rows, cols, q0, k0, h=h, lanes=lanes):
+            vt = vt_ref[lanes, cols]                        # [D, sk]
+            st = _dot(k_ref[0, cols, lanes], q_ref[0, rows, lanes],
+                      _NT)                          # q arrives scaled
+            if masked:
+                st = _mask(st, offsets, q0, k0, 1, skv)     # [sk, sq]
+            m_prev = m_ref[h, :, rows]                      # [1, sq]
+            m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
+            pt = jnp.exp(st - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h, :, rows] = l_ref[h, :, rows] * corr + jnp.sum(
+                pt, axis=0, keepdims=True)
+            acc_ref[h, :, rows] = acc_ref[h, :, rows] * corr + _dot(
+                vt, pt.astype(vt.dtype))                    # [D, sq]
+            m_ref[h, :, rows] = m_new
 
-    @pl.when(j == nk - 1)
-    def _finalize():
-        l = l_ref[:, 0]
+        _tiles(tile, i, j, causal=causal, bq=bq, bk=bk, sq=sq, sk=sk,
+               skv=skv, keys_outer=True)
+
+    def finalize():
+        l = l_ref[:]                                        # [heads, 1, bq]
         l = jnp.where(l == 0.0, 1.0, l)               # fully-masked rows
-        o_ref[0] = (acc_ref[:] / l[:, None]).astype(o_ref.dtype)
+        out_t = (acc_ref[:] / l).reshape(heads * d, bq)
+        o_ref[0] = out_t.T.astype(o_ref.dtype)              # [bq, heads * D]
+        lse_ref[0] = m_ref[:] + jnp.log(l)
+    _when(j == nk - 1, finalize)
 
 
-def _flash_fwd(q, k, v, scale: float, causal: bool, bq: int, bk: int,
-               interpret: bool):
-    """q/k/v: [BH, S, D] → [BH, S, D]."""
-    BH, Sq, D = q.shape
-    Skv = k.shape[1]
-    bq = min(bq, Sq)
-    bk = min(bk, Skv)
-    # Pallas clamps partial blocks to fit, which would mis-position the
-    # tail; pad to block multiples instead (the key mask hides padded
-    # keys; padded q rows are sliced off the output).
-    pad_q = (-Sq) % bq
-    pad_k = (-Skv) % bk
-    if pad_q:
-        q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0)))
-    if pad_k:
-        k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0)))
-    Sq_p, Skv_p = Sq + pad_q, Skv + pad_k
-    nq = Sq_p // bq
-    nk = Skv_p // bk
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
+                dk_ref, dv_ref, dk_acc, dv_acc,
+                *, causal, nq, nk, heads, d, bq, bk, sq, sk, skv):
+    """Scores transposed, as in the forward: ``lse`` and ``di`` come
+    as rows and broadcast down the sublanes."""
+    i, j = _block_ids(nq, nk, 3)
 
-    scratch = [pltpu.VMEM((bq, 1), jnp.float32),
-               pltpu.VMEM((bq, 1), jnp.float32),
-               pltpu.VMEM((bq, D), jnp.float32)]
+    def init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+    _when(i == 0, init)
 
-    kernel = functools.partial(_attn_kernel, scale=scale, causal=causal,
-                               bq=bq, bk=bk, skv=Skv)
-    out = pl.pallas_call(
-        kernel,
-        grid=(BH, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, Sq_p, D), q.dtype),
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(q, k, v)
-    return out[:, :Sq] if pad_q else out
+    offsets = _offsets(causal, (sk, sq), 1)
+    for h in range(heads):
+        lanes = slice(h * d, (h + 1) * d)
+
+        def tile(masked, rows, cols, q0, k0, h=h, lanes=lanes):
+            q, do = q_ref[0, rows, lanes], do_ref[0, rows, lanes]
+            st = _dot(k_ref[0, cols, lanes], q, _NT)        # [sk, sq]
+            if masked:
+                st = _mask(st, offsets, q0, k0, 1, skv)
+            pt = jnp.exp(st - lse_ref[0, h, :, rows])       # rows: [1, sq]
+            dv_acc[cols, lanes] += _dot(pt.astype(do.dtype), do)
+            dpt = _dot(v_ref[0, cols, lanes], do, _NT)
+            dst = pt * (dpt - di_ref[0, h, :, rows])
+            dk_acc[cols, lanes] += _dot(dst.astype(q.dtype), q)  # q scaled
+
+        _tiles(tile, i, j, causal=causal, bq=bq, bk=bk, sq=sq, sk=sk,
+               skv=skv, keys_outer=True)
+
+    def finalize():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+    _when(i == nq - 1, finalize)
 
 
-def _ref_attn_bhsd(q, k, v, scale, causal):
-    s = jnp.einsum("bqd,bkd->bqk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * scale
-    if causal:
-        Sq, Sk = s.shape[-2], s.shape[-1]
-        mask = jnp.arange(Sq)[:, None] >= jnp.arange(Sk)[None, :]
-        s = jnp.where(mask[None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    return p, jnp.einsum("bqk,bkd->bqd", p,
-                         v.astype(jnp.float32)).astype(q.dtype)
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dq_acc,
+               *, scale, causal, nq, nk, heads, d, bq, bk, sq, sk, skv):
+    """Scores as [queries, keys], so that dQ = dS K is a plain
+    product; the row statistics are turned from lanes to sublanes."""
+    i, j = _block_ids(nq, nk, 2)
+
+    def init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+    _when(j == 0, init)
+
+    offsets = _offsets(causal, (sq, sk), 0)
+    for h in range(heads):
+        lanes = slice(h * d, (h + 1) * d)
+
+        def tile(masked, rows, cols, q0, k0, h=h, lanes=lanes):
+            k = k_ref[0, cols, lanes]
+            s = _dot(q_ref[0, rows, lanes], k, _NT)         # [sq, sk]
+            if masked:
+                s = _mask(s, offsets, q0, k0, 0, skv)
+            p = jnp.exp(s - lse_ref[0, h, 0, rows][:, None])
+            dp = _dot(do_ref[0, rows, lanes], v_ref[0, cols, lanes], _NT)
+            ds = p * (dp - di_ref[0, h, 0, rows][:, None])
+            dq_acc[rows, lanes] += _dot(ds.astype(k.dtype), k)
+
+        _tiles(tile, i, j, causal=causal, bq=bq, bk=bk, sq=sq, sk=sk,
+               skv=skv)
+
+    def finalize():
+        dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
+    _when(j == nk - 1, finalize)
+
+
+def _blocking(seq: int, tile: int, seq_block: int):
+    """``(tile, block, padded)``: the tile is the caller's, cut to the
+    sequence; a block is as many whole tiles as ``seq_block`` holds;
+    the sequence is padded to whole blocks (Pallas would clamp a
+    partial block to fit, which mis-positions the tail)."""
+    tile = min(tile, seq)
+    n_tiles = -(-seq // tile)
+    block = tile * min(n_tiles, max(1, seq_block // tile))
+    return tile, block, -(-seq // block) * block
+
+
+def _heads_per_block(heads: int, d: int) -> int:
+    """As few heads as make a block's last dimension whole lanes; all
+    of them where no such count divides ``heads`` (a block as wide as
+    the array is always allowed)."""
+    for g in range(1, heads):
+        if heads % g == 0 and (g * d) % LANES == 0:
+            return g
+    return heads
+
+
+def _pad_seq(x, rows: int):
+    pad = rows - x.shape[1]
+    return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
+
+
+class _Plan:
+    """Blocks, grid and index maps of one kernel call on ``[B, S, H *
+    D]`` arrays.  The grid is (batch, head groups, x, y), y sequential:
+    x the q blocks and y the kv blocks (``q_axis`` 2), or the other
+    way round (``q_axis`` 3)."""
+
+    def __init__(self, q, k, heads, tile, seq_block, q_axis):
+        self.batch, self.sq_len, width = q.shape
+        self.skv_len = k.shape[1]
+        self.d = width // heads
+        self.g = _heads_per_block(heads, self.d)
+        self.sq, self.bq, self.sq_pad = _blocking(self.sq_len, tile[0],
+                                                  seq_block)
+        self.sk, self.bk, self.skv_pad = _blocking(self.skv_len, tile[1],
+                                                   seq_block)
+        self.nq = self.sq_pad // self.bq
+        self.nk = self.skv_pad // self.bk
+        blocks = (self.nq, self.nk) if q_axis == 2 else (self.nk, self.nq)
+        self.grid = (self.batch, heads // self.g) + blocks
+        self.q_at, self.k_at = q_axis, 5 - q_axis
+        # What every kernel is told; ``skv`` only where keys are
+        # padded, for the kernels to hide them: a padded key scores 0,
+        # not nothing.
+        self.sizes = dict(nq=self.nq, nk=self.nk, heads=self.g, d=self.d,
+                          bq=self.bq, bk=self.bk, sq=self.sq, sk=self.sk,
+                          skv=(self.skv_len if self.skv_pad != self.skv_len
+                               else None))
+
+    def _rows(self, block, at):
+        return pl.BlockSpec((1, block, self.g * self.d),
+                            lambda *ids: (ids[0], ids[at], ids[1]))
+
+    def q_rows(self):
+        """A block of queries (or of anything shaped like them)."""
+        return self._rows(self.bq, self.q_at)
+
+    def k_rows(self):
+        return self._rows(self.bk, self.k_at)
+
+    def q_stats(self):
+        """Row statistics, [B, H, 1, Sq]: a row of floats a head."""
+        return pl.BlockSpec((1, self.g, 1, self.bq),
+                            lambda *ids: (ids[0], ids[1], 0, ids[self.q_at]))
+
+    def call(self, kernel, name, in_specs, out_specs, out_shape, scratch,
+             interpret):
+        return pl.pallas_call(
+            kernel, grid=self.grid, in_specs=in_specs, out_specs=out_specs,
+            out_shape=out_shape, scratch_shapes=scratch,
+            compiler_params=pltpu.CompilerParams(dimension_semantics=(
+                "parallel", "parallel", "parallel", "arbitrary")),
+            interpret=interpret, name=name)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "causal", "tile", "seq_block", "interpret"))
+def _fwd_call(q, k, v, *, heads, causal, tile, seq_block, interpret):
+    """``q`` (scaled), ``k``, ``v``: [B, S, H * D].  Returns the output
+    [B, Sq, H * D] and the log-sum-exp of every (padded) row,
+    [B, H, 1, Sq padded]."""
+    p = _Plan(q, k, heads, tile, seq_block, q_axis=2)
+    out, lse = p.call(
+        functools.partial(_fwd_kernel, causal=causal, **p.sizes),
+        "hvd_flash_fwd",
+        [p.q_rows(), p.k_rows(), p.k_rows()], [p.q_rows(), p.q_stats()],
+        [jax.ShapeDtypeStruct((p.batch, p.sq_pad, heads * p.d), q.dtype),
+         jax.ShapeDtypeStruct((p.batch, heads, 1, p.sq_pad), jnp.float32)],
+        [pltpu.VMEM((p.g, 1, p.bq), jnp.float32),
+         pltpu.VMEM((p.g, 1, p.bq), jnp.float32),
+         pltpu.VMEM((p.g, p.d, p.bq), jnp.float32),
+         pltpu.VMEM((p.g * p.d, p.bk), v.dtype)],
+        interpret,
+    )(_pad_seq(q, p.sq_pad), _pad_seq(k, p.skv_pad), _pad_seq(v, p.skv_pad))
+    return out[:, :p.sq_len], lse
+
+
+def _bwd_operands(p: _Plan, q, k, v, lse, do, di):
+    """What both backward kernels read, padded to ``p``'s blocks, and
+    the specs to read it by.  Padded query rows have do = 0 and di = 0,
+    so they add nothing; ``lse`` comes padded to the forward's blocks
+    and is cut to the sequence first."""
+    stats = [jnp.pad(x[..., :p.sq_len],
+                     ((0, 0),) * 3 + ((0, p.sq_pad - p.sq_len),))
+             for x in (lse, di)]
+    args = (_pad_seq(q, p.sq_pad), _pad_seq(k, p.skv_pad),
+            _pad_seq(v, p.skv_pad), _pad_seq(do, p.sq_pad), *stats)
+    specs = [p.q_rows(), p.k_rows(), p.k_rows(), p.q_rows(),
+             p.q_stats(), p.q_stats()]
+    return args, specs
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "scale", "causal", "tile", "seq_block", "interpret"))
+def _bwd_call(q, k, v, lse, do, di, *, heads, scale, causal, tile,
+              seq_block, interpret):
+    """dQ, dK, dV of ``_fwd_call`` (``q`` scaled; dQ is for the
+    unscaled one).  ``di`` is ``sum(o * do)`` a row, [B, H, 1, Sq]."""
+    p = _Plan(q, k, heads, tile, seq_block, q_axis=2)
+    args, specs = _bwd_operands(p, q, k, v, lse, do, di)
+    dq = p.call(
+        functools.partial(_dq_kernel, scale=scale, causal=causal, **p.sizes),
+        "hvd_flash_bwd_dq", specs, p.q_rows(),
+        jax.ShapeDtypeStruct(args[0].shape, q.dtype),
+        [pltpu.VMEM((p.bq, p.g * p.d), jnp.float32)], interpret)(*args)
+
+    p = _Plan(q, k, heads, tile, seq_block, q_axis=3)
+    args, specs = _bwd_operands(p, q, k, v, lse, do, di)
+    like_k = jax.ShapeDtypeStruct(args[1].shape, k.dtype)
+    dk, dv = p.call(
+        functools.partial(_dkv_kernel, causal=causal, **p.sizes),
+        "hvd_flash_bwd_dkv", specs, [p.k_rows(), p.k_rows()],
+        [like_k, like_k],
+        [pltpu.VMEM((p.bk, p.g * p.d), jnp.float32)] * 2, interpret)(*args)
+    return dq[:, :p.sq_len], dk[:, :p.skv_len], dv[:, :p.skv_len]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, scale, causal, bq, bk, interpret):
-    return _flash_fwd(q, k, v, scale, causal, bq, bk, interpret)
+def _flash(q, k, v, scale, causal, tile, seq_block, interpret):
+    """``flash_attention`` with every choice spelt out; ``seq_block``
+    is here for the tests, which cannot hold ``SEQ_BLOCK`` rows."""
+    return _flash_vjp_fwd(q, k, v, scale, causal, tile, seq_block,
+                          interpret)[0]
 
 
-def _flash_vjp_fwd(q, k, v, scale, causal, bq, bk, interpret):
-    out = _flash_fwd(q, k, v, scale, causal, bq, bk, interpret)
-    return out, (q, k, v)
+def _flash_vjp_fwd(q, k, v, scale, causal, tile, seq_block, interpret):
+    B, Sq, H, D = q.shape
+    # [B, S, H, D] is [B, S, H * D] for free.  The scale rides on q,
+    # in q's dtype (exact for a head size that is a power of four):
+    # the kernels then spend nothing on it per score, and dK = dS^T
+    # (scale q) comes out scaled by itself.
+    q, k, v = ((q * scale).reshape(B, Sq, H * D),
+               k.reshape(B, -1, H * D), v.reshape(B, -1, H * D))
+    out, lse = _fwd_call(q, k, v, heads=H, causal=causal, tile=tile,
+                         seq_block=seq_block, interpret=interpret)
+    # Named for a caller's checkpoint policy: a model that recomputes
+    # its layers in the backward pass can keep these two and spare the
+    # forward kernel's second run.
+    out = checkpoint_name(out.reshape(B, Sq, H, D), "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")
+    return out, (q, k, v, out, lse)
 
 
-def _flash_vjp_bwd(scale, causal, bq, bk, interpret, res, do):
-    q, k, v = res
-    p, _ = _ref_attn_bhsd(q, k, v, scale, causal)
-    do32 = do.astype(jnp.float32)
-    dv = jnp.einsum("bqk,bqd->bkd", p, do32)
-    dp = jnp.einsum("bqd,bkd->bqk", do32, v.astype(jnp.float32))
-    ds = p * (dp - jnp.sum(dp * p, axis=-1, keepdims=True))
-    dq = jnp.einsum("bqk,bkd->bqd", ds,
-                    k.astype(jnp.float32)) * scale
-    dk = jnp.einsum("bqk,bqd->bkd", ds,
-                    q.astype(jnp.float32)) * scale
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+def _flash_vjp_bwd(scale, causal, tile, seq_block, interpret, res, do):
+    q, k, v, out, lse = res
+    B, Sq, H, D = do.shape
+    di = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+    di = di.transpose(0, 2, 1)[:, :, None, :]               # [B, H, 1, Sq]
+    grads = _bwd_call(q, k, v, lse, do.reshape(B, Sq, H * D), di, heads=H,
+                      scale=scale, causal=causal, tile=tile,
+                      seq_block=seq_block, interpret=interpret)
+    return tuple(g.reshape(B, -1, H, D) for g in grads)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -170,21 +451,20 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False,
                     scale: Optional[float] = None,
-                    block_q: int = 128, block_k: int = 128,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: bool = False) -> jax.Array:
-    """Flash attention on ``[B, S, H, D]`` tensors.
+    """Flash attention on ``[B, S, H, D]`` tensors, differentiable.
 
-    The kernel is compiled for the TPU; off the TPU that fails loudly.
-    ``interpret=True`` runs the kernel body in the Pallas interpreter
+    ``block_q`` x ``block_k`` is the tile of scores the kernels compute
+    at a time (by default ``TILE``, picked on the chip); a grid step
+    holds ``SEQ_BLOCK`` rows of queries and of keys in VMEM.  The
+    kernels are compiled for the TPU; off the TPU that fails loudly.
+    ``interpret=True`` runs the kernel bodies in the Pallas interpreter
     (the CPU tests ask for it by name).
     """
-    B, Sq, H, D = q.shape
     if scale is None:
-        scale = 1.0 / math.sqrt(D)
-
-    def bhsd(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * H, x.shape[1], D)
-
-    out = _flash(bhsd(q), bhsd(k), bhsd(v), float(scale), bool(causal),
-                 int(block_q), int(block_k), bool(interpret))
-    return out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
+        scale = 1.0 / math.sqrt(q.shape[-1])
+    tile = (int(block_q or TILE[0]), int(block_k or TILE[1]))
+    return _flash(q, k, v, float(scale), bool(causal), tile, SEQ_BLOCK,
+                  bool(interpret))

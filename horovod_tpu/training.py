@@ -298,11 +298,16 @@ def make_gpt_train_step(config, mesh, learning_rate: float = 1e-2,
     from .models.gpt import GPTLMHeadModel, lm_loss
     from .parallel.sharding import gpt_partition_rules, infer_shardings
 
-    model = GPTLMHeadModel(config)
     tx = optax.adam(learning_rate)
     batch_axis = fsdp or "dp"
     batch_sharding = NamedSharding(mesh, P(batch_axis, None))
     rules = gpt_partition_rules(fsdp=fsdp)
+    # The model reads the platform off the mesh: on TPU devices its
+    # attention runs the Pallas kernels, each chip on its share of the
+    # batch and (the rules' "tp") of the heads.
+    heads_axis = "tp" if "tp" in mesh.axis_names else None
+    model = GPTLMHeadModel(config, qkv_sharding=NamedSharding(
+        mesh, P(batch_axis, None, heads_axis, None)))
 
     def _init(rng, ids):
         params = model.init(rng, ids)["params"]

@@ -1,5 +1,5 @@
-"""The flash-attention forward and BERT's dropout step compile for the
-chip, asked without one.
+"""The flash-attention kernels, the GPT step that calls them and BERT's
+dropout step compile for the chip, asked without one.
 
 The TPU's compiler is installed beside JAX and compiles for a v5e that
 is described, not attached (the ``on-chip-measurement`` guide, section
@@ -10,6 +10,7 @@ Nothing runs, so results and times are the chip's to give
 """
 
 import functools
+import math
 import os
 import re
 from collections import Counter
@@ -29,11 +30,14 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
 from horovod_tpu.models.bert import bert_tiny_config
+from horovod_tpu.models.gpt import gpt_tiny_config
 from horovod_tpu.ops.pallas_attention import flash_attention
 from horovod_tpu.parallel.mesh import build_mesh
 from horovod_tpu.parallel.sharding import (bert_partition_rules,
+                                           gpt_partition_rules,
                                            infer_shardings)
-from horovod_tpu.training import make_bert_pretrain_step
+from horovod_tpu.training import (make_bert_pretrain_step,
+                                  make_gpt_train_step)
 
 
 @pytest.fixture(scope="module")
@@ -76,6 +80,80 @@ def test_flash_forward_compiles_for_v5e(v5e_chip, shape, dtype, causal):
     compiled = jax.jit(functools.partial(
         flash_attention, causal=causal)).lower(x, x, x).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("shape,dtype,causal", [
+    ((16, 1024, 16, 64), jnp.bfloat16, True),   # the GPT cell's step
+    ((8, 512, 16, 64), jnp.bfloat16, False),    # BERT-large heads, S=512
+    ((2, 500, 16, 64), jnp.float32, False),     # ragged length, float32
+    # Longer than SEQ_BLOCK: the grid's sequential dimension, causal
+    # decisions from program ids, both variants of a tile emitted.
+    ((4, 4096, 16, 64), jnp.bfloat16, True),
+    ((2, 1536, 16, 64), jnp.bfloat16, False),   # and a padded block
+], ids=["gpt2-16x1024-causal", "bert-s512", "ragged-s500-f32",
+        "4x4096-causal", "s1536-padded-block"])
+def test_flash_backward_compiles_for_v5e(v5e_chip, shape, dtype, causal):
+    """dQ and dK/dV are Mosaic kernels beside the forward's, and no
+    score square is left for XLA to hold."""
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=causal)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    kernels = Counter(re.findall(
+        r'custom_call_target="tpu_custom_call".*?/(hvd_flash_\w+)/', text))
+    assert kernels == {"hvd_flash_fwd": 1, "hvd_flash_bwd_dq": 1,
+                       "hvd_flash_bwd_dkv": 1}, kernels
+    # No array anywhere near the size of the scores (H * D may equal
+    # S, as in this cell, so sizes are compared and not dimensions).
+    batch, seq, heads, _ = shape
+    largest = max(math.prod(int(n) for n in dims.split(","))
+                  for dims in re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", text))
+    assert largest * 4 <= batch * heads * seq * seq, largest
+
+
+@pytest.mark.parametrize("axes,fsdp,remat", [
+    ({"dp": 1}, None, False), ({"dp": 2, "tp": 2}, None, False),
+    ({"dp": 2, "fsdp": 2}, "fsdp", False), ({"dp": 1}, None, True)],
+    ids=["1chip", "dp2xtp2", "dp2xfsdp2", "1chip-remat"])
+def test_gpt_step_runs_the_kernels_in_every_layer(v5e_2x2, axes, fsdp,
+                                                  remat):
+    """``make_gpt_train_step`` on a mesh of TPU devices, nothing else
+    said: forward, dQ and dK/dV kernels in every layer, once each (the
+    forward's output is kept where the layers are recomputed; shard by
+    shard where the mesh has several chips, which GSPMD alone refuses,
+    the batch over the axis the step builder shards it by), and no S x
+    S array in the compiled step."""
+    chips = math.prod(axes.values())
+    batch, seq = 4 * chips, 96
+    cfg = gpt_tiny_config(remat=remat)
+    assert cfg.attention_impl == "auto"
+    mesh = build_mesh(axes, v5e_2x2.devices[:chips])
+    init_fn, step_fn, batch_sharding = make_gpt_train_step(cfg, mesh,
+                                                           fsdp=fsdp)
+    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
+                               sharding=batch_sharding)
+    # ``init_fn`` jits inside; its shapes are all that is wanted here.
+    state = jax.eval_shape(init_fn, jax.ShapeDtypeStruct((2,), jnp.uint32),
+                           ids)
+    state = jax.tree.map(
+        lambda leaf, sharding: jax.ShapeDtypeStruct(
+            leaf.shape, leaf.dtype, sharding=sharding),
+        state, infer_shardings(state, mesh, gpt_partition_rules(fsdp=fsdp)))
+    text = step_fn.lower(*state, ids).compile().as_text()
+
+    kernels = Counter(re.findall(
+        r'custom_call_target="tpu_custom_call".*?'
+        r'(layer_\d+)/attention/[^"]*?/(hvd_flash_\w+)/', text))
+    assert kernels == {
+        ("layer_%d" % i, name): 1 for i in range(cfg.num_layers)
+        for name in ("hvd_flash_fwd", "hvd_flash_bwd_dq",
+                     "hvd_flash_bwd_dkv")}, kernels
+    assert not re.search(r"(f32|bf16)\[[0-9,]*\b%d,%d\b" % (seq, seq),
+                         text)
 
 
 @pytest.mark.parametrize("chips", [1, 4])

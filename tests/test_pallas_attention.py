@@ -1,12 +1,18 @@
 """Pallas flash-attention kernel correctness (interpreter mode on CPU —
 the same kernel code compiles via Mosaic on TPU)."""
 
+import dataclasses
+import functools
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import NamedSharding, PartitionSpec as P
 
+from horovod_tpu.ops import pallas_attention
 from horovod_tpu.ops.pallas_attention import flash_attention
 from horovod_tpu.parallel.attention import reference_attention
 
@@ -38,22 +44,139 @@ def test_flash_uneven_blocks(qkv):
     np.testing.assert_allclose(got, exp, atol=2e-5, rtol=2e-5)
 
 
-def test_flash_gradients_match(qkv):
-    q, k, v = qkv
+def _grads(attend, q, k, v):
+    def loss(q, k, v):
+        return jnp.mean(attend(q, k, v).astype(jnp.float32) ** 2)
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
-    def loss_flash(q, k, v):
-        return jnp.mean(flash_attention(q, k, v, causal=True,
-                                        block_q=16, block_k=16,
-                                        interpret=True) ** 2)
 
-    def loss_ref(q, k, v):
-        return jnp.mean(reference_attention(q, k, v, causal=True) ** 2)
+# ``test_flash_gradients_match`` of PR 21 is the first case.
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-4),
+                                       (jnp.bfloat16, 3e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("blocks", [(16, 16), (48, 24)],
+                         ids=["16x16", "48x24"])
+@pytest.mark.parametrize("seq", [64, 50], ids=["even", "ragged"])
+@pytest.mark.parametrize("causal", [True, False],
+                         ids=["causal", "full"])
+def test_flash_backward_kernels_match_reference(qkv, causal, seq, blocks,
+                                                dtype, tol):
+    """dQ, dK, dV from the two backward kernels (interpret mode)
+    against the gradients of the plain reference."""
+    q, k, v = (t[:, :seq].astype(dtype) for t in qkv)
+    got = _grads(functools.partial(
+        flash_attention, causal=causal, block_q=blocks[0],
+        block_k=blocks[1], interpret=True), q, k, v)
+    want = _grads(functools.partial(reference_attention, causal=causal),
+                  *(t.astype(jnp.float32) for t in (q, k, v)))
+    for name, a, b in zip("qkv", got, want):
+        assert a.dtype == dtype and a.shape == b.shape
+        b = np.asarray(b)
+        np.testing.assert_allclose(
+            np.asarray(a.astype(jnp.float32)), b, rtol=tol,
+            atol=tol * float(np.abs(b).max()), err_msg="d" + name)
 
-    g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g1, g2):
+
+# 50 rows leave the last 8-wide tile ragged; 48 are whole 16-wide tiles
+# and half a block of padding, which the kernels must hide all the same.
+@pytest.mark.parametrize("seq,tile", [(50, (16, 8)), (48, (16, 16))],
+                         ids=["ragged-tile", "whole-tiles-ragged-block"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_flash_blocks_accumulate_across_grid_steps(qkv, causal, seq, tile):
+    """Sequences longer than ``seq_block``: the online softmax and the
+    gradient accumulators carry over the grid's sequential dimension,
+    the causal decisions are made from program ids, and keys that pad
+    the last block count for nothing."""
+    q, k, v = (t[:, :seq] for t in qkv)
+
+    def attend(q, k, v):
+        return pallas_attention._flash(q, k, v, D ** -0.5, causal, tile,
+                                       32, True)
+    ref = functools.partial(reference_attention, causal=causal)
+    np.testing.assert_allclose(np.asarray(attend(q, k, v)),
+                               np.asarray(ref(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+    for a, b in zip(_grads(attend, q, k, v), _grads(ref, q, k, v)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-5, rtol=2e-4)
+
+
+def test_flash_default_tiles_hide_a_padded_block():
+    """The tiles and blocks the chip runs, on a sequence of whole tiles
+    that fills its last block by half (1536 = 3 x 512 in blocks of
+    1024), not causal: nothing but the kernels' own mask hides the
+    padding."""
+    rng = np.random.RandomState(1)
+    q, k, v = (jnp.asarray(rng.randn(1, 1536, 2, 64).astype(np.float32))
+               for _ in range(3))
+    attend = functools.partial(flash_attention, interpret=True)
+    np.testing.assert_allclose(np.asarray(attend(q, k, v)),
+                               np.asarray(reference_attention(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+    for a, b in zip(_grads(attend, q, k, v),
+                    _grads(reference_attention, q, k, v)):
+        b = np.asarray(b)
+        np.testing.assert_allclose(np.asarray(a), b, rtol=2e-4,
+                                   atol=2e-4 * float(np.abs(b).max()))
+
+
+def _shapes(jaxpr, found):
+    """Every array shape in ``jaxpr`` and the jaxprs nested in it,
+    kernel bodies excepted: what a ``pallas_call`` holds lives in
+    VMEM."""
+    for eqn in jaxpr.eqns:
+        found.update(tuple(v.aval.shape) for v in eqn.outvars
+                     if hasattr(v.aval, "shape"))
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _shapes(sub, found)
+    return found
+
+
+def test_flash_gradient_holds_no_square_of_the_sequence():
+    """Forward and backward: no array of rank 3 or more with two
+    dimensions equal to S outside the kernels."""
+    seq = 96
+    x = jnp.ones((2, seq, 2, 16), jnp.float32)
+    attend = functools.partial(flash_attention, causal=True, block_q=32,
+                               block_k=32, interpret=True)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: _grads(attend, q, k, v))(x, x, x)
+    assert "pallas_call" in str(jaxpr)
+    shapes = _shapes(jaxpr.jaxpr, set())
+    assert (2, seq, 2 * 16) in shapes, shapes   # what the kernels are fed
+    square = [s for s in shapes if len(s) >= 3 and s.count(seq) >= 2]
+    assert not square, square
+    # The plain path does hold one: the check can see it.
+    plain = jax.make_jaxpr(lambda q, k, v: _grads(functools.partial(
+        reference_attention, causal=True), q, k, v))(x, x, x)
+    assert any(len(s) >= 3 and s.count(seq) >= 2
+               for s in _shapes(plain.jaxpr, set()))
+
+
+def test_checkpoint_policy_spares_the_forward_kernel(qkv):
+    """What ``models/gpt.py`` asks of ``jax.checkpoint``: the kernels
+    name their output and its row statistics, a policy that keeps those
+    names leaves the recomputation no forward kernel to run, and the
+    gradients are the same."""
+    q, k, v = qkv
+    attend = functools.partial(flash_attention, causal=True, block_q=16,
+                               block_k=16, interpret=True)
+
+    def grads(policy):
+        layer = jax.checkpoint(lambda q, k, v: attend(2 * q, k, v),
+                               policy=policy)
+        fn = functools.partial(_grads, layer)
+        return fn(q, k, v), str(jax.make_jaxpr(fn)(q, k, v))
+
+    keep = jax.checkpoint_policies.save_only_these_names(
+        "flash_out", "flash_lse")
+    (kept, kept_text), (redone, redone_text) = grads(keep), grads(None)
+    assert kept_text.count("name=hvd_flash_fwd") == 1
+    assert redone_text.count("name=hvd_flash_fwd") == 2
+    assert kept_text.count("name=hvd_flash_bwd_dq") == 1
+    for a, b in zip(kept, redone):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_flash_bf16(qkv):
@@ -67,7 +190,6 @@ def test_flash_bf16(qkv):
 def test_bert_flash_attention_matches_einsum():
     from horovod_tpu.models.bert import (BertForMaskedLM,
                                          bert_tiny_config)
-    import dataclasses
     cfg_e = bert_tiny_config(dtype=jnp.float32)
     cfg_f = dataclasses.replace(cfg_e, attention_impl="flash")
     rng = jax.random.PRNGKey(0)
@@ -78,10 +200,79 @@ def test_bert_flash_attention_matches_einsum():
     out_e = np.asarray(m_e.apply(params, ids).astype(jnp.float32))
     # The model calls the kernel compiled; off the TPU the test asks
     # Pallas for interpret mode here, by name.
-    from jax.experimental.pallas import tpu as pltpu
     with pltpu.force_tpu_interpret_mode():
         out_f = np.asarray(m_f.apply(params, ids).astype(jnp.float32))
     np.testing.assert_allclose(out_f, out_e, atol=3e-2, rtol=3e-2)
+
+
+def _leaf(tree, path):
+    for key in path.split("/"):
+        tree = tree[key]
+    return np.asarray(tree)
+
+
+@pytest.mark.parametrize("axes", [None, {"dp": 2, "tp": 2}],
+                         ids=["direct", "dp2xtp2"])
+def test_gpt_flash_kernels_match_einsum(axes):
+    """GPT-tiny through the kernels (forced to interpret mode) against
+    the einsum path: logits, and the gradient of three leaves.  On a
+    mesh of several devices the kernels run shard by shard."""
+    from horovod_tpu.models.gpt import (GPTLMHeadModel, gpt_tiny_config,
+                                        lm_loss)
+    from horovod_tpu.parallel.mesh import build_mesh
+    sharding = axes and NamedSharding(
+        build_mesh(axes, jax.devices()[:4]), P("dp", None, "tp", None))
+    cfg_e = gpt_tiny_config(dtype=jnp.float32, attention_impl="einsum")
+    cfg_f = dataclasses.replace(cfg_e, attention_impl="flash")
+    ids = jnp.asarray(np.random.RandomState(0).randint(
+        0, cfg_e.vocab_size, (4, 48), dtype=np.int32))
+    m_e, m_f = GPTLMHeadModel(cfg_e), GPTLMHeadModel(
+        cfg_f, qkv_sharding=sharding)
+    params = m_e.init(jax.random.PRNGKey(0), ids)["params"]
+
+    def value_and_grad(model):
+        def loss(p):
+            logits = model.apply({"params": p}, ids)
+            return lm_loss(logits, ids), logits
+        (_, logits), grads = jax.jit(
+            jax.value_and_grad(loss, has_aux=True))(params)
+        return logits, grads
+
+    logits_e, grads_e = value_and_grad(m_e)
+    with pltpu.force_tpu_interpret_mode():
+        logits_f, grads_f = value_and_grad(m_f)
+    np.testing.assert_allclose(np.asarray(logits_f), np.asarray(logits_e),
+                               atol=2e-4, rtol=2e-4)
+    for path in ("layer_0/attention/query/kernel",
+                 "layer_1/attention/value/kernel",
+                 "word_embeddings/embedding"):
+        want = _leaf(grads_e, path)
+        np.testing.assert_allclose(
+            _leaf(grads_f, path), want, rtol=1e-3,
+            atol=1e-4 * float(np.abs(want).max()), err_msg=path)
+
+
+def test_gpt_picks_its_attention_by_platform_and_dropout():
+    """``attention_impl="auto"``: the kernels on a TPU where no
+    attention dropout is applied (and not for ``init``), the einsums
+    elsewhere.  The mesh the step builder hands over decides before
+    the default backend."""
+    from horovod_tpu.models.gpt import attention_impl, gpt_tiny_config
+    cfg = gpt_tiny_config(dropout=0.1)
+    assert cfg.attention_impl == "auto"
+    assert jax.default_backend() == "cpu"
+    assert attention_impl(cfg, None, kernels_apply=True) == "einsum"
+
+    class Device:
+        platform = "tpu"
+
+    class OnTpu:
+        devices = np.array([Device()])
+
+    assert attention_impl(cfg, OnTpu(), kernels_apply=True) == "flash"
+    assert attention_impl(cfg, OnTpu(), kernels_apply=False) == "einsum"
+    named = dataclasses.replace(cfg, attention_impl="flash")
+    assert attention_impl(named, None, kernels_apply=True) == "flash"
 
 
 def test_flash_compiled_is_refused_off_tpu(qkv):
