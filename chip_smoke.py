@@ -22,7 +22,10 @@ with:
 
 * ``sharded`` - one process, four chips: BERT-large and GPT-2 widths
   at depth 4 on a dp=2 x tp=2 mesh against the same seed and global
-  batch on a one-device mesh;
+  batch on a one-device mesh; and, on one of the chips, the GPT step's
+  own loss (over chunks of the sequence, never the logits) at
+  gpt2-medium's widths and vocabulary against the benchmark's plain
+  reference and against the logits path;
 * ``eager``   - ``horovodrun -np 4``, one chip a process: the eager
   collectives of ``XlaMeshBackend`` on device arrays against numpy, and
   an MLP through ``hvd.jax.DistributedOptimizer`` against the same
@@ -71,6 +74,11 @@ FLASH_GRAD_REL_L2 = 1e-2
 # Sharded against one device: same math, other reduction orders, bf16
 # matmuls, three optimizer steps.
 SHARDED_LOSS_RTOL = 2e-3
+# The gradient leaves of the GPT step's loss that are held against the
+# reference: the tied embedding (the head's and the lookup's gradients
+# summed), a projection below every block, the last LayerNorm's scale.
+GPT_CHECK_LEAVES = ("word_embeddings/embedding",
+                    "layer_0/attention/query/kernel", "final_norm/scale")
 
 
 def _info(msg: str):
@@ -341,14 +349,66 @@ def _gpt_losses(config, mesh, batch_size, seq_len, steps, check):
     return losses
 
 
+def _gpt_step_loss_check(config, batch_size, seq_len):
+    """The loss ``make_gpt_train_step`` differentiates and three of its
+    gradient leaves, on one device: against the benchmark's plain
+    float32 reference and against the same model's logits through
+    ``lm_loss`` (bf16's rounding of the logits apart, the same
+    arithmetic), both within the benchmark's tolerances."""
+    import jax
+    import numpy as np
+    from benchmarks.reference import common as reference
+    from benchmarks.reference import gpt as reference_gpt
+    from horovod_tpu.models.gpt import GPTLMHeadModel, lm_loss
+    from horovod_tpu.training import gpt_step_loss
+
+    model = GPTLMHeadModel(config)
+    ids = jax.random.randint(jax.random.PRNGKey(2), (batch_size, seq_len),
+                             0, config.vocab_size)
+    params = jax.jit(model.init)(jax.random.PRNGKey(3), ids)["params"]
+    picked = {n: reference.get_leaf(params, n) for n in GPT_CHECK_LEAVES}
+
+    def value_and_grad(loss):
+        loss, grads = jax.jit(jax.value_and_grad(
+            lambda leaves: loss(reference.with_leaves(params, leaves))))(
+                picked)
+        return float(loss), {n: np.asarray(g, np.float32)
+                             for n, g in grads.items()}
+
+    got_loss, got = value_and_grad(lambda p: gpt_step_loss(model, p, ids))
+    others = {"logits path": value_and_grad(lambda p: lm_loss(
+        model.apply({"params": p}, ids), ids))}
+    # The reference alone runs at the highest matmul precision (the
+    # flash kernels take none).
+    with jax.default_matmul_precision("highest"):
+        others["reference"] = value_and_grad(lambda p: reference_gpt.loss(
+            p, {"input_ids": ids},
+            {"layer_norm_epsilon": config.layer_norm_eps,
+             "n_layer": config.num_layers}))
+    for other, (want_loss, want) in sorted(others.items()):
+        loss_err = abs(got_loss - want_loss) / abs(want_loss)
+        leaf_err = {n: float(np.linalg.norm(got[n] - want[n])
+                             / np.linalg.norm(want[n])) for n in want}
+        _info("sharded: gpt step loss %.6f, hidden %d, %d layers, "
+              "vocabulary %d, batch %d x %d, against the %s, %.6f: "
+              "relative error %.2e (limit %.0e), leaves %s (limit %.0e)"
+              % (got_loss, config.hidden_size, config.num_layers,
+                 config.vocab_size, batch_size, seq_len, other, want_loss,
+                 loss_err, reference.LOSS_RTOL, json.dumps(leaf_err),
+                 reference.GRAD_REL_L2))
+        assert loss_err <= reference.LOSS_RTOL, other
+        assert max(leaf_err.values()) <= reference.GRAD_REL_L2, other
+
+
 def phase_sharded(bert_config=None, gpt_config=None,
                   bert_batch=(64, 128), gpt_batch=(8, 512),
+                  gpt_check_config=None, gpt_check_batch=(2, 1024),
                   steps: int = 3, platform: str = "tpu"):
     import jax
     import numpy as np
     from horovod_tpu.common import compile_cache
     from horovod_tpu.models.bert import bert_large_config
-    from horovod_tpu.models.gpt import gpt2_small_config
+    from horovod_tpu.models.gpt import gpt2_medium_config, gpt2_small_config
     from horovod_tpu.parallel import build_mesh
 
     compile_cache.enable()
@@ -377,6 +437,10 @@ def phase_sharded(bert_config=None, gpt_config=None,
         _info("sharded: %s state on four devices, a tensor-parallel weight"
               " in two distinct shards, all-reduce in the compiled step"
               % name)
+    _gpt_step_loss_check(
+        gpt_check_config or gpt2_medium_config(num_layers=2, dropout=0.,
+                                               remat=True),
+        *gpt_check_batch)
     _result("sharded")
 
 

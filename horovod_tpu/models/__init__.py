@@ -3,8 +3,9 @@ from .resnet import (ResNet, ResNet18, ResNet34, ResNet50, ResNet101,
 from .bert import (BertConfig, BertEncoder, BertForMaskedLM,
                    bert_base_config, bert_large_config, bert_tiny_config,
                    mlm_loss)
-from .gpt import (GPTConfig, GPTLMHeadModel, gpt2_medium_config,
-                  gpt2_small_config, gpt_tiny_config, lm_loss)
+from .gpt import (GPTConfig, GPTLMHeadModel, chunked_lm_loss,
+                  gpt2_medium_config, gpt2_small_config, gpt_tiny_config,
+                  lm_loss)
 from .mnist import MnistCNN, MnistMLP, cross_entropy_loss
 from .dlrm import (DLRMConfig, DLRMDense, bce_logits_loss,
                    dlrm_tiny_config, synthetic_click_batch)
@@ -15,7 +16,7 @@ __all__ = [
     "BertConfig", "BertEncoder", "BertForMaskedLM", "bert_base_config",
     "bert_large_config", "bert_tiny_config", "mlm_loss",
     "GPTConfig", "GPTLMHeadModel", "gpt2_small_config",
-    "gpt2_medium_config", "gpt_tiny_config", "lm_loss",
+    "gpt2_medium_config", "gpt_tiny_config", "lm_loss", "chunked_lm_loss",
     "MnistCNN", "MnistMLP", "cross_entropy_loss",
     "DLRMConfig", "DLRMDense", "bce_logits_loss", "dlrm_tiny_config",
     "synthetic_click_batch",

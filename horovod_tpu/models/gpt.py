@@ -9,21 +9,45 @@ TPU-first design mirrors bert.py: all matmuls in bfloat16 (fp32
 params), static shapes, attention through the Pallas flash kernels
 (``ops/pallas_attention.py``, forward and backward, no S x S array in
 HBM) on a TPU and as batched einsums elsewhere or where attention
-dropout is applied, pre-LayerNorm residual blocks, optional per-layer
-``jax.checkpoint`` rematerialisation, and parameter naming matched by
-:func:`horovod_tpu.parallel.sharding.gpt_partition_rules` so kernels
-map onto tensor-parallel mesh axes.
+dropout is applied, pre-LayerNorm residual blocks, and parameter naming
+matched by :func:`horovod_tpu.parallel.sharding.gpt_partition_rules` so
+kernels map onto tensor-parallel mesh axes.
+
+What a training step holds in HBM between its forward and its backward
+pass is decided here.  With ``GPTConfig.remat`` a block is recomputed
+in the backward pass but for the arrays it names (``REMAT_NAMES``): the
+flash kernels' output and row statistics and the outputs of its five
+matmuls, so that the recomputation is two LayerNorms, the GELU and the
+residual adds; :func:`remat_names` drops the matmuls' outputs where
+they would not fit the device (``make_gpt_train_step`` asks it when the
+step is traced).  And the step's loss (:func:`chunked_lm_loss`) walks
+chunks of the sequence, so the ``[B, S, V]`` logits never exist.
 """
 
 import dataclasses
 import functools
 import math
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import NamedSharding
+
+# What a recomputed block may keep from its forward pass, by
+# ``checkpoint_name``: what the flash kernels name (recomputing those
+# is the forward kernel over again) and the outputs of the block's
+# five matmuls but ``output``, which nothing of the block reads.  The
+# width of each is in ``remat_bytes``.
+FLASH_NAMES = ("flash_out", "flash_lse")
+MATMUL_NAMES = ("query", "key", "value", "attention_out", "intermediate")
+REMAT_NAMES = FLASH_NAMES + MATMUL_NAMES
+# Positions of the sequence in one chunk of ``chunked_lm_loss``: 16 x
+# 128 tokens x 50257 fp32 logits are 0.41 GB where all 1024 positions
+# were 3.29, and a chunk still feeds the MXU (chosen on the v5e, PERF.md
+# PR 26).
+LOSS_CHUNK = 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -37,6 +61,12 @@ class GPTConfig:
     dropout: float = 0.1
     layer_norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
+    # Recompute every block in the backward pass but for what it names
+    # (REMAT_NAMES): by default the kernels' output and the five
+    # matmuls' outputs stay and the cheap rest is recomputed; where the
+    # step builder finds (``remat_names``, from the shapes and the
+    # device's memory) that the matmuls' outputs do not fit, the
+    # kernels' output alone stays and the matmuls run twice.
     remat: bool = False
     # "auto": the Pallas kernels (ops/pallas_attention.py) on a TPU
     # wherever no attention dropout is applied, plain XLA einsums
@@ -103,9 +133,9 @@ class CausalSelfAttention(nn.Module):
         dense = lambda name: nn.DenseGeneral(
             features=(cfg.num_heads, head_dim), axis=-1, dtype=cfg.dtype,
             param_dtype=jnp.float32, name=name)
-        q = dense("query")(x)
-        k = dense("key")(x)
-        v = dense("value")(x)
+        q = checkpoint_name(dense("query")(x), "query")
+        k = checkpoint_name(dense("key")(x), "key")
+        v = checkpoint_name(dense("value")(x), "value")
         dropout_applied = cfg.dropout > 0.0 and not deterministic
         # The kernels apply no attention dropout; and ``init`` wants
         # the parameters' shapes and nothing of the attention, so no
@@ -132,9 +162,10 @@ class CausalSelfAttention(nn.Module):
             probs = nn.Dropout(cfg.dropout)(probs,
                                             deterministic=deterministic)
             ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-        return nn.DenseGeneral(features=cfg.hidden_size, axis=(-2, -1),
-                               dtype=cfg.dtype, param_dtype=jnp.float32,
-                               name="out")(ctx)
+        out = nn.DenseGeneral(features=cfg.hidden_size, axis=(-2, -1),
+                              dtype=cfg.dtype, param_dtype=jnp.float32,
+                              name="out")(ctx)
+        return checkpoint_name(out, "attention_out")
 
 
 class GPTBlock(nn.Module):
@@ -155,7 +186,7 @@ class GPTBlock(nn.Module):
         m = nn.Dense(cfg.intermediate_size, dtype=cfg.dtype,
                      param_dtype=jnp.float32, name="intermediate")(
             norm("mlp_norm")(x))
-        m = nn.gelu(m, approximate=True)
+        m = nn.gelu(checkpoint_name(m, "intermediate"), approximate=True)
         m = nn.Dense(cfg.hidden_size, dtype=cfg.dtype,
                      param_dtype=jnp.float32, name="output")(m)
         m = nn.Dropout(cfg.dropout)(m, deterministic=deterministic)
@@ -166,9 +197,15 @@ class GPTLMHeadModel(nn.Module):
     """Decoder stack + tied-embedding LM head."""
     config: GPTConfig
     qkv_sharding: Optional[NamedSharding] = None
+    # What a recomputed block keeps (``config.remat``); the step
+    # builder hands over what ``remat_names`` chose for its shapes.
+    remat_names: Tuple[str, ...] = REMAT_NAMES
 
     @nn.compact
-    def __call__(self, input_ids, deterministic: bool = True):
+    def hidden_and_embedding(self, input_ids, deterministic: bool = True):
+        """The decoder stack: the final hidden states ``[B, S, H]`` and
+        the token embedding ``[V, H]`` that the head is tied to (what
+        ``chunked_lm_loss`` takes, and ``__call__`` makes logits of)."""
         cfg = self.config
         seq = input_ids.shape[1]
         wte = nn.Embed(cfg.vocab_size, cfg.hidden_size,
@@ -181,22 +218,54 @@ class GPTLMHeadModel(nn.Module):
         x = nn.Dropout(cfg.dropout)(x, deterministic=deterministic)
         block = GPTBlock
         if cfg.remat:
-            # A block is recomputed in the backward pass but for what
-            # the flash kernels name (their output and its row
-            # statistics, which their backward reads): recomputing
-            # those would be the forward kernel over again.
             block = nn.remat(
                 GPTBlock, static_argnums=(2,),
                 policy=jax.checkpoint_policies.save_only_these_names(
-                    "flash_out", "flash_lse"))
+                    *self.remat_names))
         for i in range(cfg.num_layers):
             x = block(cfg, self.qkv_sharding, name=f"layer_{i}")(
                 x, deterministic)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                          param_dtype=jnp.float32, name="final_norm")(x)
+        return x, wte.embedding
+
+    def __call__(self, input_ids, deterministic: bool = True):
+        x, embedding = self.hidden_and_embedding(input_ids, deterministic)
         logits = jnp.einsum("bsh,vh->bsv", x,
-                            wte.embedding.astype(cfg.dtype))
+                            embedding.astype(self.config.dtype))
         return logits.astype(jnp.float32)
+
+
+def remat_bytes(names, tokens: int, hidden: int, intermediate: int,
+                heads: int, layers: int, itemsize: int) -> int:
+    """Bytes one device keeps across ``remat`` for ``names``, with
+    ``tokens`` of the batch on it: every name is one ``hidden`` wide in
+    the compute dtype but ``intermediate`` and the kernels' fp32
+    statistics, one a head.  Tensor parallelism (which splits all but
+    ``attention_out``) is not counted: the figure errs high."""
+    widths = dict.fromkeys(REMAT_NAMES, hidden * itemsize)
+    widths["intermediate"] = intermediate * itemsize
+    widths["flash_lse"] = heads * 4
+    return tokens * layers * sum(widths[name] for name in names)
+
+
+def remat_names(tokens: int, hidden: int, intermediate: int, heads: int,
+                layers: int, itemsize: int, state_bytes: int,
+                memory_limit: Optional[int]) -> Tuple[str, ...]:
+    """The names a recomputed block keeps: all of ``REMAT_NAMES`` where
+    they fit one device's ``memory_limit`` bytes beside the state the
+    step is handed (``state_bytes``: parameters and optimizer state on
+    that device) and a margin of a quarter of the memory (the
+    gradients, the layers' inputs, a chunk of the loss, the compiler's
+    own temporaries: 1.9 GB of 16.9 in the benchmark's cell); the
+    kernels' names alone where they do not, and every name where the
+    device reports no limit (a CPU, a chip that is only described)."""
+    if memory_limit is None:
+        return REMAT_NAMES
+    kept = remat_bytes(REMAT_NAMES, tokens, hidden, intermediate, heads,
+                       layers, itemsize)
+    fits = kept + state_bytes + memory_limit // 4 <= memory_limit
+    return REMAT_NAMES if fits else FLASH_NAMES
 
 
 def lm_loss(logits, input_ids, mask=None):
@@ -210,3 +279,114 @@ def lm_loss(logits, input_ids, mask=None):
         return -ll.mean()
     m = mask[:, 1:].astype(jnp.float32)
     return -(ll * m).sum() / jnp.maximum(m.sum(), 1.0)
+
+
+def loss_chunks(seq: int) -> Tuple[int, int]:
+    """``(count, length)`` of the chunks ``chunked_lm_loss`` walks: at
+    most ``LOSS_CHUNK`` positions each, one chunk for a short sequence,
+    and as even as a length that the count does not divide allows."""
+    count = -(-seq // LOSS_CHUNK)
+    return count, -(-seq // count)
+
+
+def _chunk_nll(h, table, targets):
+    """One chunk: fp32 logits ``[B, C, V]`` of ``h`` ``[B, C, H]``
+    (products on ``h``'s dtype, accumulated in fp32), each position's
+    log-sum-exp and its negative log-likelihood of ``targets``."""
+    logits = jnp.einsum("bch,vh->bcv", h, table,
+                        preferred_element_type=jnp.float32)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    at_target = jnp.take_along_axis(
+        logits, targets[..., None], axis=-1)[..., 0]
+    return logits, lse, lse - at_target
+
+
+def _chunked(hidden, targets, weights):
+    """``[B, S, ...]`` as ``[count, B, length, ...]`` for a scan over
+    chunks of the sequence; the padding weighs nothing."""
+    count, length = loss_chunks(hidden.shape[1])
+    pad = count * length - hidden.shape[1]
+
+    def split(a):
+        a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        a = a.reshape(a.shape[0], count, length, *a.shape[2:])
+        return jnp.moveaxis(a, 1, 0)
+    return split(hidden), split(targets), split(weights)
+
+
+@jax.custom_vjp
+def _weighted_nll(hidden, embedding, targets, weights):
+    """``sum(weights * nll)`` over ``[B, S]``, a chunk of the sequence
+    at a time."""
+    table = embedding.astype(hidden.dtype)
+
+    def one(total, chunk_of):
+        h, t, w = chunk_of
+        return total + (w * _chunk_nll(h, table, t)[2]).sum(), None
+    total, _ = jax.lax.scan(one, jnp.zeros((), jnp.float32),
+                            _chunked(hidden, targets, weights))
+    return total
+
+
+def _weighted_nll_fwd(hidden, embedding, targets, weights):
+    """The same pass over a chunk's logits gives its gradients too:
+    ``(softmax - onehot) * weights``, cast to the compute dtype as
+    autodiff's transpose of the logits' ``astype`` does, times the
+    embedding (to the hidden states) and times the hidden states (to
+    the embedding, summed over chunks in fp32).  Those two arrays are
+    the residuals; no chunk's logits outlive its step of the scan."""
+    table = embedding.astype(hidden.dtype)
+
+    def one(carry, chunk_of):
+        total, d_table = carry
+        h, t, w = chunk_of
+        logits, lse, nll = _chunk_nll(h, table, t)
+        hit = jax.lax.broadcasted_iota(
+            jnp.int32, logits.shape, 2) == t[..., None]
+        d_logits = jnp.exp(logits - lse[..., None]) - hit
+        d_logits = (d_logits * w[..., None]).astype(hidden.dtype)
+        d_h = jnp.einsum("bcv,vh->bch", d_logits, table)
+        d_table = d_table + jnp.einsum(
+            "bcv,bch->vh", d_logits, h,
+            preferred_element_type=jnp.float32)
+        return (total + (w * nll).sum(), d_table), (d_h, nll)
+
+    (total, d_table), (d_hidden, nll) = jax.lax.scan(
+        one, (jnp.zeros((), jnp.float32),
+              jnp.zeros(embedding.shape, jnp.float32)),
+        _chunked(hidden, targets, weights))
+
+    def whole(a):  # [count, B, length, ...] back to [B, S, ...]
+        a = jnp.moveaxis(a, 0, 1)
+        a = a.reshape(a.shape[0], -1, *a.shape[3:])
+        return a[:, :hidden.shape[1]]
+    return total, (whole(d_hidden), d_table.astype(embedding.dtype),
+                   whole(nll))
+
+
+def _weighted_nll_bwd(residuals, g):
+    d_hidden, d_embedding, nll = residuals
+    return ((g * d_hidden).astype(d_hidden.dtype),
+            (g * d_embedding).astype(d_embedding.dtype), None, g * nll)
+
+
+_weighted_nll.defvjp(_weighted_nll_fwd, _weighted_nll_bwd)
+
+
+def chunked_lm_loss(hidden, embedding, input_ids, mask=None):
+    """``lm_loss`` of the tied head's logits without the logits: from
+    the final hidden states ``[B, S, H]`` and the token embedding ``[V,
+    H]`` (``GPTLMHeadModel.hidden_and_embedding``), a chunk of at most
+    ``LOSS_CHUNK`` positions of the sequence at a time, so that the batch
+    stays sharded as it is and the vocabulary stays whole.  A chunk's
+    fp32 logits are made once, where a gradient is asked for too (a
+    custom VJP)."""
+    targets = jnp.roll(input_ids, -1, axis=1)
+    # Position t is weighed by its TARGET's mask; the last has none.
+    counts = (jnp.ones(input_ids.shape, jnp.float32) if mask is None
+              else jnp.roll(mask, -1, axis=1).astype(jnp.float32))
+    counts = counts.at[:, -1].set(0.0)
+    total = counts.sum()
+    if mask is not None:
+        total = jnp.maximum(total, 1.0)
+    return _weighted_nll(hidden, embedding, targets, counts / total)

@@ -20,6 +20,7 @@ import optax
 from flax.training import train_state
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .common import metrics
 from .models.bert import BertConfig, BertForMaskedLM, mlm_loss
 from .parallel.sharding import (bert_partition_rules, infer_shardings,
                                 Rules)
@@ -279,6 +280,48 @@ def run_bert_dry_run(n_devices: int, config: Optional[BertConfig] = None,
     return float(loss), mesh
 
 
+def gpt_step_loss(model, params, ids):
+    """The loss of ``make_gpt_train_step``'s step: the decoder's final
+    hidden states, then the loss a chunk of the sequence at a time
+    (``models/gpt.py`` ``chunked_lm_loss``): the value and gradients of
+    ``lm_loss(model.apply(...), ids)`` without the ``[B, S, V]``
+    logits.  No dropout: ``deterministic`` stays at its default."""
+    from .models.gpt import GPTLMHeadModel, chunked_lm_loss
+    hidden, embedding = model.apply(
+        {"params": params}, ids,
+        method=GPTLMHeadModel.hidden_and_embedding)
+    with jax.named_scope("loss"):
+        return chunked_lm_loss(hidden, embedding, ids)
+
+
+def _bytes_on_one_device(tree, shardings) -> int:
+    return sum(
+        int(np.prod(sharding.shard_shape(leaf.shape), dtype=np.int64))
+        * leaf.dtype.itemsize
+        for leaf, sharding in zip(jax.tree.leaves(tree),
+                                  jax.tree.leaves(shardings)))
+
+
+def _memory_limit(device) -> Optional[int]:
+    """The device's memory in bytes; None where it reports none (the
+    CPU backend, a chip that is described and not attached)."""
+    try:
+        stats = device.memory_stats()
+    except jax.errors.JaxRuntimeError:  # described: no client to ask
+        return None
+    return (stats or {}).get("bytes_limit")
+
+
+_REMAT_KEPT = metrics.gauge(
+    "hvd_gpt_remat_kept_bytes",
+    "Bytes one device keeps across the GPT step's remat, by the names "
+    "kept (set when the step is traced)")
+_LOSS_CHUNKS = metrics.gauge(
+    "hvd_gpt_loss_chunks",
+    "Chunks of the sequence the GPT step's loss walks (set when the "
+    "step is traced)")
+
+
 def make_gpt_train_step(config, mesh, learning_rate: float = 1e-2,
                         fsdp: Optional[str] = None):
     """Sharded dp x tp causal-LM training step for the GPT family —
@@ -291,11 +334,17 @@ def make_gpt_train_step(config, mesh, learning_rate: float = 1e-2,
     data axis under FSDP), and XLA turns the annotations into the
     all-gather-on-use / reduce-scatter-of-grads schedule (SURVEY §2.3:
     reduce-scatter is the FSDP building block the reference never
-    exposed)."""
+    exposed).
+
+    What the step holds between its forward and backward pass: never
+    the logits (``gpt_step_loss``), and with ``config.remat`` what
+    ``models.gpt.remat_names`` chooses when the step is traced, from
+    the batch's tokens on one device, the state the step is handed and
+    the memory the mesh's device reports."""
     import optax
     from functools import partial
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from .models.gpt import GPTLMHeadModel, lm_loss
+    from .models import gpt
     from .parallel.sharding import gpt_partition_rules, infer_shardings
 
     tx = optax.adam(learning_rate)
@@ -306,8 +355,8 @@ def make_gpt_train_step(config, mesh, learning_rate: float = 1e-2,
     # attention runs the Pallas kernels, each chip on its share of the
     # batch and (the rules' "tp") of the heads.
     heads_axis = "tp" if "tp" in mesh.axis_names else None
-    model = GPTLMHeadModel(config, qkv_sharding=NamedSharding(
-        mesh, P(batch_axis, None, heads_axis, None)))
+    qkv_sharding = NamedSharding(mesh, P(batch_axis, None, heads_axis, None))
+    model = gpt.GPTLMHeadModel(config, qkv_sharding=qkv_sharding)
 
     def _init(rng, ids):
         params = model.init(rng, ids)["params"]
@@ -321,13 +370,30 @@ def make_gpt_train_step(config, mesh, learning_rate: float = 1e-2,
             jax.eval_shape(_init, rng, ids), mesh, rules)
         return jax.jit(_init, out_shardings=shardings)(rng, ids)
 
+    def traced_model(state, ids):
+        """The model of this trace: with ``remat``, keeping what fits
+        beside ``state`` at ``ids``'s shape."""
+        _LOSS_CHUNKS.set(gpt.loss_chunks(ids.shape[1])[0])
+        if not config.remat:
+            return model
+        sizes = (ids.size // mesh.shape[batch_axis], config.hidden_size,
+                 config.intermediate_size, config.num_heads,
+                 config.num_layers, np.dtype(config.dtype).itemsize)
+        names = gpt.remat_names(
+            *sizes,
+            state_bytes=_bytes_on_one_device(
+                state, infer_shardings(state, mesh, rules)),
+            memory_limit=_memory_limit(mesh.devices.flat[0]))
+        _REMAT_KEPT.set(gpt.remat_bytes(names, *sizes),
+                        names="+".join(names))
+        return gpt.GPTLMHeadModel(config, qkv_sharding=qkv_sharding,
+                                  remat_names=names)
+
     @partial(jax.jit, donate_argnums=(0, 1))
     def step_fn(params, opt_state, ids):
-        def loss_fn(p):
-            logits = model.apply({"params": p}, ids)
-            with jax.named_scope("loss"):
-                return lm_loss(logits, ids)
-        loss, grads = jax.value_and_grad(loss_fn)(params)
+        loss, grads = jax.value_and_grad(partial(
+            gpt_step_loss, traced_model((params, opt_state), ids)))(
+                params, ids)
         with jax.named_scope("optimizer"):
             updates, opt_state = tx.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)

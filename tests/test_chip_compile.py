@@ -120,13 +120,15 @@ def test_flash_backward_compiles_for_v5e(v5e_chip, shape, dtype, causal):
     ({"dp": 2, "fsdp": 2}, "fsdp", False), ({"dp": 1}, None, True)],
     ids=["1chip", "dp2xtp2", "dp2xfsdp2", "1chip-remat"])
 def test_gpt_step_runs_the_kernels_in_every_layer(v5e_2x2, axes, fsdp,
-                                                  remat):
+                                                  remat, monkeypatch):
     """``make_gpt_train_step`` on a mesh of TPU devices, nothing else
     said: forward, dQ and dK/dV kernels in every layer, once each (the
     forward's output is kept where the layers are recomputed; shard by
     shard where the mesh has several chips, which GSPMD alone refuses,
     the batch over the axis the step builder shards it by), and no S x
-    S array in the compiled step."""
+    S array in the compiled step.  Where the layers are recomputed, no
+    matmul is: their outputs are kept (a described chip reports no
+    memory, so every name fits)."""
     chips = math.prod(axes.values())
     batch, seq = 4 * chips, 96
     cfg = gpt_tiny_config(remat=remat)
@@ -144,6 +146,21 @@ def test_gpt_step_runs_the_kernels_in_every_layer(v5e_2x2, axes, fsdp,
             leaf.shape, leaf.dtype, sharding=sharding),
         state, infer_shardings(state, mesh, gpt_partition_rules(fsdp=fsdp)))
     text = step_fn.lower(*state, ids).compile().as_text()
+
+    def recomputed_matmuls(text):
+        return [line for line in text.splitlines()
+                if re.search(r"rematted_computation/layer_\d+", line)
+                and re.search(r" (convolution|dot)\(", line)]
+    if remat:
+        assert "rematted_computation/layer_0" in text
+        assert not recomputed_matmuls(text)
+        # On a device too small for them the matmuls' outputs go and
+        # the matmuls come back: the check can see one.
+        monkeypatch.setattr("horovod_tpu.training._memory_limit",
+                            lambda device: 1 << 20)
+        small = make_gpt_train_step(cfg, mesh, fsdp=fsdp)[1]
+        assert recomputed_matmuls(
+            small.lower(*state, ids).compile().as_text())
 
     kernels = Counter(re.findall(
         r'custom_call_target="tpu_custom_call".*?'

@@ -85,8 +85,11 @@ def test_rehearse_sharded_phase_on_four_devices(capsys, no_cache_dir):
     chip_smoke.phase_sharded(
         bert_config=bert_tiny_config(num_layers=1),
         gpt_config=gpt_tiny_config(num_layers=1),
-        bert_batch=(8, 32), gpt_batch=(8, 32), platform="cpu")
+        bert_batch=(8, 32), gpt_batch=(8, 32),
+        gpt_check_config=gpt_tiny_config(remat=True),
+        gpt_check_batch=(2, 40), platform="cpu")
     out = capsys.readouterr().out
+    assert "against the reference," in out and "against the logits path," in out
     assert out.count("a tensor-parallel weight in two distinct shards") == 2
     assert chip_smoke.RESULT_MARK + '{"phase": "sharded", "ok": true' in out
 

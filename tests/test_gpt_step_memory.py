@@ -1,0 +1,225 @@
+"""What the GPT step holds between its forward and its backward pass
+(``models/gpt.py``, ``training.make_gpt_train_step``): never the
+logits, and across ``remat`` what the rule of sizes chooses.
+"""
+
+import dataclasses
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import horovod_tpu as hvd
+from horovod_tpu.models import gpt
+from horovod_tpu.parallel.mesh import build_mesh
+from horovod_tpu.training import gpt_step_loss, make_gpt_train_step
+from test_pallas_attention import _shapes
+
+
+def _value_and_grad(loss, params):
+    return jax.jit(jax.value_and_grad(loss))(params)
+
+
+def _rel_l2(got, want):
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["", "remat"])
+@pytest.mark.parametrize("seq", [256, 301], ids=["s256", "s301-ragged"])
+@pytest.mark.parametrize("masked", [False, True], ids=["", "mask"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_chunked_loss_is_lm_loss_of_the_logits(dtype, masked, seq, remat):
+    """Value and the gradient of EVERY parameter leaf, against
+    ``lm_loss(model(ids), ids)`` without ``remat``: float32 to 1e-5;
+    bf16 no further from the float32 answer than the logits path is
+    (its own rounding, the band), which the chunked loss sits inside
+    because it never rounds the logits to bf16.  The names ``remat``
+    keeps change no number."""
+    plain = gpt.gpt_tiny_config(dtype=dtype, attention_impl="einsum",
+                                max_position_embeddings=seq)
+    cfg = dataclasses.replace(plain, remat=remat)
+    rng = np.random.RandomState(seq)
+    ids = jnp.asarray(rng.randint(0, cfg.vocab_size, (2, seq),
+                                  dtype=np.int32))
+    mask = jnp.asarray(rng.rand(2, seq) < 0.7) if masked else None
+    params = gpt.GPTLMHeadModel(plain).init(
+        jax.random.PRNGKey(0), ids)["params"]
+    # Two whole chunks; three of 101 positions, the last two padding.
+    assert gpt.loss_chunks(seq) == ((2, 128) if seq == 256 else (3, 101))
+
+    def of_logits(config):
+        model = gpt.GPTLMHeadModel(config)
+        return lambda p: gpt.lm_loss(model.apply({"params": p}, ids), ids,
+                                     mask)
+
+    def chunked(config, **kw):
+        model = gpt.GPTLMHeadModel(config, **kw)
+
+        def loss(p):
+            hidden, embedding = model.apply(
+                {"params": p}, ids,
+                method=gpt.GPTLMHeadModel.hidden_and_embedding)
+            return gpt.chunked_lm_loss(hidden, embedding, ids, mask)
+        return loss
+
+    want_loss, want = _value_and_grad(of_logits(plain), params)
+    got_loss, got = _value_and_grad(chunked(cfg), params)
+    # Without a gradient the primal function runs, not the VJP's
+    # forward: the same value.
+    np.testing.assert_allclose(jax.jit(chunked(cfg))(params), got_loss,
+                               rtol=1e-6)
+    leaves = jax.tree_util.tree_leaves_with_path
+    # A key's bias moves no softmax: its gradient is rounding alone,
+    # so absolute errors are held against the largest gradient.
+    scale = max(float(jnp.abs(w).max()) for w in jax.tree.leaves(want))
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got_loss, want_loss, rtol=1e-5)
+        for (path, g), (_, w) in zip(leaves(got), leaves(want)):
+            np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5 * scale,
+                                       err_msg=str(path))
+    else:
+        exact = dataclasses.replace(plain, dtype=jnp.float32)
+        exact_loss, exact_grads = _value_and_grad(of_logits(exact), params)
+        assert abs(got_loss - exact_loss) <= \
+            abs(want_loss - exact_loss) + 2e-3 * abs(exact_loss)
+        for (path, g), (_, w), (_, e) in zip(
+                leaves(got), leaves(want), leaves(exact_grads)):
+            if float(jnp.abs(e).max()) > 1e-4 * scale:
+                assert _rel_l2(g, e) <= 1.25 * _rel_l2(w, e) + 2e-3, path
+    if remat:
+        # Kept or recomputed, the same arithmetic.
+        other_loss, other = _value_and_grad(
+            chunked(cfg, remat_names=gpt.FLASH_NAMES), params)
+        # (bf16: XLA rounds a kept array where a recomputed one stays
+        # in a fusion's float32.)
+        tol = 1e-6 if dtype == jnp.float32 else 3e-2
+        np.testing.assert_allclose(other_loss, got_loss, rtol=tol)
+        for (path, g), (_, o) in zip(leaves(got), leaves(other)):
+            np.testing.assert_allclose(
+                np.asarray(g, np.float32), np.asarray(o, np.float32),
+                rtol=tol, atol=tol * scale, err_msg=str(path))
+
+
+def _tiny_step(remat: bool, batch=4, seq=2 * gpt.LOSS_CHUNK):
+    # Sized so that a chunk's logits are the step's largest array (the
+    # CPU's attention holds [B, heads, S, S] scores).
+    cfg = gpt.gpt_tiny_config(remat=remat, max_position_embeddings=seq,
+                              vocab_size=2048, num_heads=2)
+    mesh = build_mesh({"dp": 1}, jax.devices()[:1])
+    init_fn, step_fn, batch_sharding = make_gpt_train_step(cfg, mesh)
+    ids = jax.device_put(jnp.zeros((batch, seq), jnp.int32), batch_sharding)
+    params, opt_state = init_fn(jax.random.PRNGKey(0), ids)
+    return cfg, step_fn, (params, opt_state, ids)
+
+
+def test_gpt_step_holds_no_array_of_the_whole_logits():
+    """Forward and backward, the step's jaxpr has nothing of B x S x V
+    elements: two chunks here, so half of it at most.  The logits path
+    does hold one: the check can see it."""
+    cfg, step_fn, args = _tiny_step(remat=True)
+    batch, seq = args[2].shape
+    whole = batch * seq * cfg.vocab_size
+    shapes = _shapes(jax.make_jaxpr(step_fn)(*args).jaxpr, set())
+    assert (batch, gpt.LOSS_CHUNK, cfg.vocab_size) in shapes, shapes
+    assert max(map(math.prod, shapes)) * 2 <= whole, shapes
+
+    model = gpt.GPTLMHeadModel(cfg)
+    plain = jax.make_jaxpr(jax.grad(lambda p, ids: gpt.lm_loss(
+        model.apply({"params": p}, ids), ids)))(args[0], args[2])
+    assert (batch, seq, cfg.vocab_size) in _shapes(plain.jaxpr, set())
+
+
+# gpt2-medium at 16 x 1024 on one v5e: what the benchmark's cell asks.
+CELL = dict(tokens=16 * 1024, hidden=1024, intermediate=4096, heads=16,
+            layers=24, itemsize=2)
+CELL_STATE = 3 * 4 * 354_823_168      # fp32 parameters and Adam's two
+V5E = 16_911_433_728                   # memory_stats()["bytes_limit"]
+
+
+def test_remat_keeps_what_fits_the_device():
+    """The rule on integers: the cell's shapes keep every name on a v5e
+    (268 MB of matmul outputs a layer), the same shapes on a sixteenth
+    of the memory keep the kernels' names alone, and a device that
+    reports no limit keeps every name."""
+    sizes = tuple(CELL.values())
+    matmuls = gpt.remat_bytes(gpt.MATMUL_NAMES, *sizes)
+    assert matmuls == 24 * 268_435_456
+    assert gpt.remat_bytes(gpt.FLASH_NAMES, *sizes) == \
+        24 * 16384 * (1024 * 2 + 16 * 4)
+    assert gpt.remat_names(**CELL, state_bytes=CELL_STATE,
+                           memory_limit=V5E) == gpt.REMAT_NAMES
+    assert gpt.remat_names(**CELL, state_bytes=CELL_STATE,
+                           memory_limit=V5E // 16) == gpt.FLASH_NAMES
+    assert gpt.remat_names(**CELL, state_bytes=CELL_STATE,
+                           memory_limit=None) == gpt.REMAT_NAMES
+    # Twice the batch no longer fits beside the state and the margin.
+    assert gpt.remat_names(**dict(CELL, tokens=32 * 1024),
+                           state_bytes=CELL_STATE,
+                           memory_limit=V5E) == gpt.FLASH_NAMES
+
+
+def test_the_step_decides_by_its_device_memory(monkeypatch):
+    """``make_gpt_train_step`` hands the rule what it can see when the
+    step is traced: a device that reports little memory keeps the
+    kernels' names, and the compiled step's loss is the same."""
+    cfg, step_fn, args = _tiny_step(remat=True)
+    text = str(jax.make_jaxpr(step_fn)(*args))
+    kept = hvd.metrics_snapshot()["gauges"]["hvd_gpt_remat_kept_bytes"]
+    assert "names=" + "+".join(gpt.REMAT_NAMES) in kept
+
+    monkeypatch.setattr("horovod_tpu.training._memory_limit",
+                        lambda device: 1 << 20)
+    _, small_step, _ = _tiny_step(remat=True)
+    small_text = str(jax.make_jaxpr(small_step)(*args))
+    kept = hvd.metrics_snapshot()["gauges"]["hvd_gpt_remat_kept_bytes"]
+    batch, seq = args[2].shape
+    assert kept["names=flash_out+flash_lse"] == gpt.remat_bytes(
+        gpt.FLASH_NAMES, batch * seq, cfg.hidden_size,
+        cfg.intermediate_size, cfg.num_heads, cfg.num_layers, 2)
+    # The matmuls whose outputs went are traced a second time.
+    assert small_text.count("dot_general") > text.count("dot_general")
+
+
+def test_gauges_show_in_the_metrics_snapshot():
+    """Tracing a tiny step sets the bytes kept across ``remat`` (by the
+    names kept) and the loss's chunk count."""
+    cfg, step_fn, args = _tiny_step(remat=True, seq=3 * gpt.LOSS_CHUNK + 5)
+    step_fn.lower(*args)
+    gauges = hvd.metrics_snapshot()["gauges"]
+    assert gauges["hvd_gpt_loss_chunks"] == 4
+    batch, seq = args[2].shape
+    per_token = 2 * cfg.num_layers * (
+        5 * cfg.hidden_size + cfg.intermediate_size) \
+        + 4 * cfg.num_layers * cfg.num_heads
+    assert gauges["hvd_gpt_remat_kept_bytes"][
+        "names=" + "+".join(gpt.REMAT_NAMES)] == batch * seq * per_token
+
+
+def test_step_loss_is_the_logits_loss_on_a_mesh():
+    """The step's own loss function on dp2 x tp2 against the logits
+    path on one device: the chunks leave the batch sharded and the
+    vocabulary to GSPMD."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from horovod_tpu.parallel.sharding import (gpt_partition_rules,
+                                               infer_shardings)
+    cfg = gpt.gpt_tiny_config(dtype=jnp.float32, remat=True)
+    mesh = build_mesh({"dp": 2, "tp": 2}, jax.devices()[:4])
+    model = gpt.GPTLMHeadModel(cfg)
+    ids = jnp.asarray(np.random.RandomState(0).randint(
+        0, cfg.vocab_size, (4, 40), dtype=np.int32))
+    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+    want_loss, want = _value_and_grad(
+        lambda p: gpt.lm_loss(model.apply({"params": p}, ids), ids), params)
+    sharded = jax.device_put(
+        params, infer_shardings(params, mesh, gpt_partition_rules()))
+    got_loss, got = jax.jit(jax.value_and_grad(gpt_step_loss, argnums=1),
+                            static_argnums=0)(
+        model, sharded, jax.device_put(ids, NamedSharding(mesh, P("dp"))))
+    np.testing.assert_allclose(got_loss, want_loss, rtol=1e-5)
+    scale = max(float(jnp.abs(w).max()) for w in jax.tree.leaves(want))
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5 * scale)
