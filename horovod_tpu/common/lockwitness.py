@@ -51,9 +51,9 @@ Scope and honesty notes:
 """
 
 import os
+import sys
 import threading
-import traceback
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Iterator, List, Optional, Tuple
 
 ENV_ENABLE = "HOROVOD_LOCKWITNESS"
 
@@ -66,9 +66,18 @@ _THREADING_FILE = os.path.abspath(
     threading.__file__).rstrip("co")
 
 
-def _is_internal_frame(filename: str) -> bool:
-    f = os.path.abspath(filename).rstrip("co")
-    return f == _SELF_FILE or f == _THREADING_FILE
+def _caller_frames() -> Iterator[Tuple[object, str]]:
+    """(frame, absolute .py path) of the calling thread's stack,
+    innermost first, without this module's own frames.  Starts at the
+    frame that iterates (``sys._getframe(1)`` of a running generator)
+    and follows ``f_back``: where ``traceback.walk_stack(None)``
+    starts is an implementation detail of the interpreter."""
+    frame = sys._getframe(1)
+    while frame is not None:
+        fn = os.path.abspath(frame.f_code.co_filename).rstrip("co")
+        if fn != _SELF_FILE:
+            yield frame, fn
+        frame = frame.f_back
 
 # THE disabled-path gate: every wrapped acquire/release checks this
 # one module attribute before any graph work.  enable()/disable() are
@@ -128,21 +137,19 @@ def _depths() -> Dict[int, int]:
 def _creation_site() -> str:
     """file:line of the nearest stack frame outside this module and
     outside threading.py — the code that asked for the lock."""
-    for frame, lineno in traceback.walk_stack(None):
-        fn = frame.f_code.co_filename
-        if _is_internal_frame(fn):
-            continue
-        return "%s:%d" % (fn, lineno)
+    for frame, fn in _caller_frames():
+        if fn != _THREADING_FILE:
+            return "%s:%d" % (frame.f_code.co_filename, frame.f_lineno)
     return "<unknown>"
 
 
 def _witness_stack() -> str:
     out = []
-    for frame, lineno in traceback.walk_stack(None):
-        fn = frame.f_code.co_filename
-        if _is_internal_frame(fn):
+    for frame, fn in _caller_frames():
+        if fn == _THREADING_FILE:
             continue
-        out.append("%s:%d %s" % (fn, lineno, frame.f_code.co_name))
+        out.append("%s:%d %s" % (frame.f_code.co_filename,
+                                 frame.f_lineno, frame.f_code.co_name))
         if len(out) >= _STACK_LIMIT:
             break
     return " <- ".join(out)
@@ -345,16 +352,14 @@ def _caller_wants_witness() -> bool:
     """True when the frame that called threading.Lock()/RLock() lives
     under the package filter (skipping threading.py itself, so
     Condition/Event internals stay unwrapped)."""
-    for frame, _ in traceback.walk_stack(None):
-        fn = os.path.abspath(frame.f_code.co_filename).rstrip("co")
-        if fn == _SELF_FILE:
-            continue
-        if fn == _THREADING_FILE:
-            # Immediate creator is threading internals (Condition /
-            # Event building their own RLock): never wrap those.
-            return False
-        return _package_filter in frame.f_code.co_filename
-    return False
+    creator = next(_caller_frames(), None)
+    if creator is None:
+        return False
+    frame, fn = creator
+    # Immediate creator is threading internals (Condition / Event
+    # building their own RLock): never wrap those.
+    return (fn != _THREADING_FILE
+            and _package_filter in frame.f_code.co_filename)
 
 
 def _lock_factory():

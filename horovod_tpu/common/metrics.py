@@ -19,7 +19,7 @@ Design constraints (this sits ON the hot paths):
 Three read paths:
 
   * ``snapshot()`` → plain nested dict (the ``hvd.metrics_snapshot()``
-    API, also what bench.py embeds in BENCH artifacts);
+    API);
   * ``render_snapshot()`` / ``MetricsRegistry.render_prometheus()`` →
     Prometheus text exposition, served by :class:`MetricsServer` when
     ``HOROVOD_METRICS_PORT`` is set (guarded by the same job-secret

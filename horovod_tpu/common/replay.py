@@ -5,8 +5,8 @@ step submits the same tensors in the same order — the property PyTorch
 DDP exploits with static-graph bucketing and negotiation skipping
 (Li et al., VLDB '20, PAPERS.md).  The response-cache fast path already
 detects this (every submission is a CH bit, every response a CB batch)
-but still pays one coordinator round-trip per op: the measured tiny-op
-floor (BENCH_r05: 0.435 ms median) is that round trip.
+but still pays one coordinator round-trip per op: the tiny-op floor
+is that round trip.
 
 This module removes it.  Each rank tracks its own submission stream
 against the CB frames it receives.  A *cycle* is the span between two
@@ -467,8 +467,9 @@ class SteadyStateReplay:
             self.warmup = max(1, int(cycles))
 
     def set_enabled(self, flag: bool):
-        """Runtime toggle (bench lanes measure the negotiated floor by
-        disabling replay, then re-enable it for the replay floor)."""
+        """Runtime toggle: ``False`` exits an active replay (flushing
+        a partial batch) and stops tracking; the runtime calls it
+        before it tears the backend down."""
         with self._lock:
             self.enabled = bool(flag)
             if flag:

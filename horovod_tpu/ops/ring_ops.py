@@ -546,9 +546,8 @@ class RingBackend(Backend):
         """Zero-copy host view of a CPU jax array via dlpack — the
         ingestion half of the jax fast path (_rewrap is the egress
         half).  ``np.asarray`` on a jax array materializes a fresh
-        host copy per call (measured: the 0.665 numpy vs 0.553 jax
-        GB/s gap at 1 MB in BENCH_r05); the dlpack view aliases the
-        XLA buffer instead.  The view is read-only and only ever read
+        host copy per call; the dlpack view aliases the XLA buffer
+        instead.  The view is read-only and only ever read
         (staged into the ring's own working buffer).  Falls back to a
         copy for non-CPU buffers, bf16 (numpy's dlpack has no bf16),
         and plain numpy/list inputs."""

@@ -29,7 +29,7 @@ replay must never freeze — ``hvd_steady_state_exits{reason=alltoall}``
 labels both the submit-side and delivery-side exits.
 
 See docs/sparse_embedding.md for the exchange protocol and
-models/dlrm.py + bench.py (``--only dlrm``) for the workload.
+models/dlrm.py for the workload.
 """
 
 from .embedding import (EmbeddingBag, ShardedEmbedding,

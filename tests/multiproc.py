@@ -94,7 +94,7 @@ def run_workers(body: str, nproc: int = 2, timeout: float = 180.0,
         # warm-up/fallback path so this coverage stays load-bearing;
         # replay has its own opt-in suite
         # (tests/test_steady_state_replay.py passes the env
-        # explicitly), the chaos kill drill, and the bench lanes.
+        # explicitly) and the chaos kill drill.
         supplied.setdefault("HOROVOD_STEADY_STATE_REPLAY", "0")
         # Liveness ON by default with tight (test-scale) values: a
         # wedged or killed worker surfaces within seconds instead of

@@ -74,24 +74,21 @@ def test_assemble_table_requires_full_coverage():
 # single-rank chain: bit-identity, bounds, fallback, GC
 # ---------------------------------------------------------------------------
 
-def _table_state(num_rows=32, dim=2):
-    return np.zeros((num_rows, dim), np.float32)
-
-
-def _save_chain(m, tmp_path, steps, touch, chain_max_n):
+def _save_chain(m, tmp_path, steps, touch, chain_max_n, num_rows=32,
+                dim=2):
     """Drive `steps` saves with deterministic sparse touches; returns
     the live table after each committed step."""
-    table = _table_state()
+    table = np.zeros((num_rows, dim), np.float32)
     history = {}
     for s in range(1, steps + 1):
         rows = touch(s)
         table[rows] += np.float32(0.5 * s)
         parent = m.delta_plan()
         if parent is None:
-            item = RowDelta(np.arange(32), table.copy(), 32)
+            item = RowDelta(np.arange(num_rows), table.copy(), num_rows)
         else:
             item = RowDelta(np.array(rows, np.int64),
-                            table[rows].copy(), 32)
+                            table[rows].copy(), num_rows)
         m.save(s, {"dense": np.float32(s)},
                local_items={"sparse/t/rows.r00000": item},
                delta_of=parent)
@@ -99,13 +96,26 @@ def _save_chain(m, tmp_path, steps, touch, chain_max_n):
     return history
 
 
-def test_chain_roundtrip_bit_identical_to_full(tmp_path, chain_max):
+def _step_bytes(tmp_path, step):
+    man = mf.read_manifest(mf.step_dir(str(tmp_path), step))
+    return sum(int(e.get("nbytes", 0)) for e in man.shards)
+
+
+@pytest.mark.parametrize("num_rows,dim,touched,delta_bytes_bound",
+                         [(32, 2, 2, None), (4096, 16, 82, 0.1)])
+def test_chain_roundtrip_bit_identical_to_full(tmp_path, chain_max,
+                                               num_rows, dim, touched,
+                                               delta_bytes_bound):
     """Base + K deltas replays to exactly the live state (acceptance:
-    bit-identical to a full checkpoint after base + K deltas)."""
+    bit-identical to a full checkpoint after base + K deltas).  The
+    large case is a table at a 2 % touch rate a step: there a delta
+    step's shards are at most a tenth of the base step's bytes, the
+    Check-N-Run compression claim as a count."""
     chain_max(4)
     m = CheckpointManager(str(tmp_path), keep=None)
-    touch = lambda s: [(s * 3) % 32, (s * 7) % 32]
-    history = _save_chain(m, tmp_path, 5, touch, 4)
+    touch = lambda s: sorted({(s * 3 + k * 7) % num_rows
+                              for k in range(touched)})
+    history = _save_chain(m, tmp_path, 5, touch, 4, num_rows, dim)
     # steps: 1=base, 2..5 deltas (chain_max 4)
     assert m.chain_of(5) == [1, 2, 3, 4, 5]
     for s, expected in history.items():
@@ -114,6 +124,11 @@ def test_chain_roundtrip_bit_identical_to_full(tmp_path, chain_max):
         np.testing.assert_array_equal(tab, expected)
         assert tab.dtype == expected.dtype
         assert items["dense"] == np.float32(s)
+    if delta_bytes_bound is not None:
+        full = _step_bytes(tmp_path, 1)
+        for s in range(2, 6):
+            assert _step_bytes(tmp_path, s) <= delta_bytes_bound * full, \
+                (s, _step_bytes(tmp_path, s), full)
     m.close()
 
 

@@ -24,6 +24,7 @@ sys.path.insert(0, REPO)
 
 from tools.hvdlint import (CHECKS, Project, apply_baseline, gate,  # noqa: E402
                            load_baseline, run_checks)
+from tools.hvdlint.check_registry_drift import doc_paths  # noqa: E402
 
 pytestmark = pytest.mark.lint
 
@@ -56,6 +57,27 @@ def test_tree_is_clean_under_baseline():
     assert not result.stale, \
         "stale baseline entries (violation fixed — delete them): %r" \
         % result.stale
+
+
+_DOCS = ["README.md"] + sorted(
+    "docs/" + fn for fn in os.listdir(os.path.join(REPO, "docs"))
+    if fn.endswith(".md"))
+
+
+@pytest.fixture(scope="module")
+def missing_doc_paths():
+    by_doc = {}
+    for v in doc_paths(Project.from_root(REPO)):
+        by_doc.setdefault(v.path, []).append(v.render())
+    return by_doc
+
+
+@pytest.mark.parametrize("doc", _DOCS)
+def test_document_names_only_files_that_exist(doc, missing_doc_paths):
+    """registry-drift's doc-paths direction, a case a document so that
+    a failure names the document at fault."""
+    assert not missing_doc_paths.get(doc), \
+        "\n".join(missing_doc_paths[doc])
 
 
 def test_cli_exits_zero_on_head():
@@ -336,6 +358,28 @@ def test_registry_drift_failpoint_sites_and_env_knobs():
     assert "ghost.site" in idents             # cataloged, dead
     assert "HOROVOD_PLANTED_KNOB" in idents   # read, undocumented
     assert "HOROVOD_GHOST_KNOB" in idents     # cataloged, dead
+
+
+def test_registry_drift_doc_paths():
+    docs = {"README.md": (
+        "`tools/ghost.py` and `python ghost_tool.py --smoke` are gone;\n"
+        "```\npython tools/fenced_ghost.py --x\n```\n"
+        "`horovod_tpu/common/widget.py:12-40`, `common/widget.py`,\n"
+        "`widget.py`, `horovod_tpu/common/` and `docs/guide.md` exist;\n"
+        "`tests/test_widget.py::test_it` does not; out of scope:\n"
+        "`horovod/common/ops.cc`, `examples/pytorch/mnist.py`,\n"
+        "`docs/benchmarks.rst`, `tests/test_*.py`, `<file>.py`,\n"
+        "`https://example.org/x.py`, `hvd.init()`.\n"),
+        "docs/guide.md": "nothing to see"}
+    project = Project.from_strings(
+        {"horovod_tpu/common/widget.py": "x = 1\n"}, docs)
+    found = doc_paths(project)
+    assert _idents(found) == {"tools/ghost.py", "ghost_tool.py",
+                              "tools/fenced_ghost.py",
+                              "tests/test_widget.py"}
+    assert {v.path for v in found} == {"README.md"}
+    assert set(_keys(found)) <= set(_keys(
+        run_checks(project, ["registry-drift"])))
 
 
 def test_frame_parity_unhandled_kind_and_oos_tables():

@@ -247,6 +247,68 @@ def test_filter_excludes_foreign_and_condition_locks(witness):
                                              "_WitnessRLock")
 
 
+# Two helper "modules" compiled under file names of their own, so that
+# which frame the factory judges is pinned from both sides: this test
+# file matches neither filter below.
+_INSIDE = os.path.join(os.path.dirname(__file__), "lw_inside_pkg.py")
+_OUTSIDE = os.path.join(os.path.dirname(__file__), "lw_elsewhere.py")
+_INSIDE_SRC = """\
+import threading
+LOCK = threading.Lock()
+def _inner():
+    return threading.RLock()
+def nested():
+    return _inner()
+def make_via(make):
+    return make()
+"""
+_OUTSIDE_SRC = """\
+import threading
+def make():
+    return threading.Lock()
+"""
+
+
+def _helper(path, src):
+    ns = {"__name__": os.path.basename(path)[:-3]}
+    exec(compile(src, path, "exec"), ns)
+    return ns
+
+
+@pytest.fixture
+def inside_witness():
+    lw.reset()
+    lw.enable(package_filter="lw_inside_pkg")
+    yield lw
+    lw.disable()
+    lw.reset()
+
+
+def test_module_level_creation_is_wrapped_and_sited(inside_witness):
+    """The shallowest stack: the creating frame is a helper's
+    ``<module>`` and its caller (this file) is outside the filter.  A
+    walk that starts one frame too far out sees only this file and
+    hands back a raw lock."""
+    inside = _helper(_INSIDE, _INSIDE_SRC)
+    assert type(inside["LOCK"]).__name__ == "_WitnessLock"
+    assert inside["LOCK"].site == "%s:2" % _INSIDE
+
+
+def test_nested_creation_is_judged_by_its_immediate_creator(
+        inside_witness):
+    """From a nested call: wrapped, with the innermost helper line as
+    its site, when the immediate creator is inside the filter; raw
+    when only the creator's CALLER is (a walk that starts too far out
+    would judge by that caller and wrap a foreign lock)."""
+    inside = _helper(_INSIDE, _INSIDE_SRC)
+    outside = _helper(_OUTSIDE, _OUTSIDE_SRC)
+    nested = inside["nested"]()
+    assert type(nested).__name__ == "_WitnessRLock"
+    assert nested.site == "%s:4" % _INSIDE
+    foreign = inside["make_via"](outside["make"])
+    assert type(foreign).__name__ == "lock"
+
+
 def test_factory_reference_captured_while_armed_survives_disable():
     """`from threading import Lock` executed while the witness is
     patched binds the factory; after disable() that reference must

@@ -30,11 +30,19 @@ from horovod_tpu.common import straggler as sg  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
-def _disarm():
+def _disarm(monkeypatch):
     # The hot-share gauge is rank×k×frame labeled: an earlier test's
     # digest (e.g. a drill in another file) would otherwise bleed into
     # this file's extractions, so start from a clean registry too.
     metrics.REGISTRY.reset()
+    # A pytest-xdist worker has a thread of the runner's own, execnet's
+    # receiver, blocked in ``gateway_base:read`` for the whole session.
+    # The sampler sees a leaf frame and not the syscall under it, so
+    # that thread is in every sample and ties with the busy thread for
+    # the top of the digest.  It is idle plumbing like the stdlib's:
+    # say so here, where the runner is known, and assert as before.
+    monkeypatch.setattr(prof, "_IDLE_MODULES",
+                        prof._IDLE_MODULES | {"gateway_base"})
     for mod in (prof, slo, sg, fp):
         mod.reset()
     yield

@@ -372,7 +372,7 @@ def test_strict_native_rejects_fanout(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# scale probe (the bench lane's engine)
+# scale probe (protocol-only clients; run_scale_lane sweeps it)
 # ---------------------------------------------------------------------------
 
 def test_negotiation_scale_probe_shape_and_fanout_bound():

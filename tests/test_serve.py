@@ -14,7 +14,8 @@ import urllib.request
 import numpy as np
 import pytest
 
-from horovod_tpu.checkpoint import CheckpointManager, RowDelta
+from horovod_tpu.checkpoint import (CheckpointManager, RowDelta,
+                                    assemble_table)
 from horovod_tpu.common import env as henv
 from horovod_tpu.common import failpoints, metrics
 from horovod_tpu.runner import job_secret
@@ -292,7 +293,8 @@ def test_train_commit_serve_verify_smoke(tmp_path, monkeypatch):
     """The whole pipeline in one process: a trainer thread commits a
     delta chain while the replica's tail thread follows; every
     concurrent read must equal the closed-form table at its OWN step
-    stamp — the bit-consistency contract the bench lane gates on."""
+    stamp, and equal what a fresh read-only manager restores at that
+    step — the bit-consistency contract of docs/serving.md."""
     monkeypatch.setenv(henv.HOROVOD_SERVE_POLL_SECONDS, "0.02")
     m = CheckpointManager(str(tmp_path), keep=None)
     _commit(m, 1)
@@ -313,15 +315,25 @@ def test_train_commit_serve_verify_smoke(tmp_path, monkeypatch):
     t = threading.Thread(target=trainer)
     t.start()
     reads = 0
+    served = {}
     while not stop.is_set() or reads == 0:
         ids = np.array([0, 3, 17, 31])
         rows, step = plane.replica.lookup("tbl", ids)
         assert np.array_equal(rows, _table_at(step)[ids]), \
             "torn/stale read at served step %d" % step
+        served[step] = (ids, rows)
         reads += 1
         time.sleep(0.005)
     t.join()
     assert not errs, errs
+    # Each served step's rows against the committed chain itself,
+    # restored by a manager that never saw the trainer's state.
+    ro = CheckpointManager(str(tmp_path), rank=0, world_size=1,
+                           keep=None)
+    for step, (ids, rows) in served.items():
+        tab = assemble_table(ro.restore(step), "sparse/tbl/rows")
+        assert np.array_equal(tab[ids], rows), step
+    ro.close()
     deadline = time.monotonic() + 10.0
     while (plane.replica.freshness()[0] < 8
            and time.monotonic() < deadline):

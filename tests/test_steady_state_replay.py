@@ -453,6 +453,20 @@ assert c("hvd_steady_state_entries").value() >= 1
 assert rt.replay.stats()["active"]
 assert c("hvd_steady_state_cycles_replayed").value() >= 1
 
+# The zero-wire count: a replayed window puts no CH and no RQ frame
+# on the uplink.  Bounded retries, as in tests/test_tune.py: a
+# transient exit on a loaded machine legally negotiates a few cycles.
+for attempt in range(4):
+    while not rt.replay.stats()["active"]:
+        loop("rp.t0", 1)
+    f0 = dict(rt.controller.stats)
+    loop("rp.t0", 12)
+    f1 = dict(rt.controller.stats)
+    frames = sum(f1[k] - f0[k] for k in ("rq_frames", "ch_frames"))
+    if frames == 0:
+        break
+assert frames == 0, ("uplink frames during the replay window", frames)
+
 # Phase 2: unseen tensor exits; both names then stay correct.
 loop("rp.t1", 2, scale=2.0)
 assert c("hvd_steady_state_exits").value(reason="unseen_tensor") >= 1

@@ -13,7 +13,7 @@ SIGUSR2, chaos-drill end).  Output:
   * one machine-readable **verdict**: the failed rank and/or relay,
     the first divergent event, and a detect→promote→restore→resume
     span breakdown whose segments partition fault→resumption — the
-    numbers the MTTR bench lane embeds in its artifact instead of
+    numbers the MTTR drill (tools/chaos_soak.py) records instead of
     coarse wall-clock timers.
 
 Clock alignment: each dump's events carry wall-clock stamps from its

@@ -14,7 +14,14 @@ helpers — a regex over source misses what the interpreter sees):
 * **env knobs** — every ``HOROVOD_*`` string constant in the source
   tree must be documented *somewhere* under ``docs/`` or the README
   (``docs/env_knobs.md`` is the canonical catalog), and every knob
-  row in ``docs/env_knobs.md`` must still exist in source.
+  row in ``docs/env_knobs.md`` must still exist in source;
+* **doc paths** — in the README and every ``docs/*.md``, a backticked
+  token or a ``python <file>`` command that has the form of a path in
+  this repository (``tools/x.py``, ``horovod_tpu/...``,
+  ``benchmarks/...``, ``tests/...``, ``docs/x.md``, a bare ``x.py``)
+  must name a file or directory of the tree.  Paths quoted from the
+  reference (``/root/reference``, ``horovod/...``, ``docs/*.rst``,
+  ``examples/...``) are out of scope.
 
 ``common/failpoints.py`` is the infrastructure for sites (its own
 ``maybe_fail`` forwards a ``site`` variable), so site extraction skips
@@ -41,6 +48,13 @@ _SITE_TOKEN = re.compile(r"`([a-z0-9_]+(?:\.[a-z0-9_]+)+)`")
 _KNOB_TOKEN = re.compile(r"\bHOROVOD_[A-Z0-9_]+\b")
 
 _SITE_INFRA = ("horovod_tpu/common/failpoints.py",)
+
+_BACKTICKED = re.compile(r"`([^`\n]+)`")
+_PY_COMMAND = re.compile(r"\bpython3?\s+(\S+\.py)\b")
+# A path, and what may trail it (`x.py:12-40`, `t.py::test_name`).
+_PATH_TOKEN = re.compile(r"[A-Za-z0-9_.-]+(?:/[A-Za-z0-9_.-]+)*/?")
+_REPO_TOPS = ("horovod_tpu/", "tools/", "benchmarks/", "tests/")
+_REFERENCE_TOPS = ("horovod/", "examples/")
 
 
 def _source_metrics(project: Project) -> Dict[str, Tuple[str, int]]:
@@ -91,6 +105,52 @@ def _site_catalog_text(doc: str) -> str:
     m = re.search(r"^#{2,4}\s+Site catalog\s*$(.*?)(?=^#{1,4}\s|\Z)",
                   doc, re.M | re.S)
     return m.group(1) if m else ""
+
+
+def _doc_path_candidates(text: str) -> Set[str]:
+    """Tokens of ``text`` that have the form of a path in this
+    repository (see the module docstring for the forms)."""
+    spans = [m.group(1) for m in _BACKTICKED.finditer(text)]
+    words = [w for span in spans for w in span.split()]
+    words += _PY_COMMAND.findall(text)
+    out: Set[str] = set()
+    for word in words:
+        m = _PATH_TOKEN.match(word)
+        if m is None or word[m.end():m.end() + 1] not in ("", ":"):
+            continue            # a glob, a placeholder, a URL, a call
+        tok = m.group(0)
+        if tok.startswith(_REFERENCE_TOPS):
+            continue
+        if tok.startswith(_REPO_TOPS) or tok.endswith(".py") or \
+                (tok.startswith("docs/") and tok.endswith(".md")):
+            out.add(tok)
+    return out
+
+
+def doc_paths(project: Project) -> List[Violation]:
+    """The doc-paths direction alone (tests/test_hvdlint.py runs it a
+    case a document)."""
+    dirs = {p[:i + 1] for p in project.paths
+            for i, ch in enumerate(p) if ch == "/"}
+    known = project.paths | dirs | {d.rstrip("/") for d in dirs}
+
+    def exists(tok: str) -> bool:
+        # `common/metrics.py` and `metrics.py` name a file by the end
+        # of its path; a token that starts at the root must be exact.
+        if tok in known:
+            return True
+        return not tok.startswith(_REPO_TOPS + ("docs/",)) and \
+            any(p.endswith("/" + tok) for p in known)
+
+    out: List[Violation] = []
+    for doc, text in sorted(project.docs.items()):
+        for tok in sorted(_doc_path_candidates(text)):
+            if not exists(tok):
+                out.append(Violation(
+                    CHECK, doc, _doc_line(text, tok), tok,
+                    "%s names %s, which is not in the tree"
+                    % (doc, tok)))
+    return out
 
 
 def _doc_line(doc: str, token: str) -> int:
@@ -153,4 +213,7 @@ def run(project: Project) -> List[Violation]:
             CHECK, _KNOB_DOC, _doc_line(knob_doc, knob), knob,
             "cataloged env knob %s appears nowhere in source (dead "
             "doc entry)" % knob))
+
+    # --- paths the docs name -> the tree ------------------------------
+    out.extend(doc_paths(project))
     return out

@@ -31,7 +31,7 @@ import re
 from typing import Dict, Iterable, List, Optional, Tuple
 
 # Directories never scanned (generated/vendored/bytecode).
-_SKIP_DIRS = {"__pycache__", ".git", ".pytest_cache"}
+_SKIP_DIRS = {"__pycache__", ".git", ".pytest_cache", "chiprun_out"}
 
 # The annotation grammar.  The reason must be non-empty; it may wrap
 # across consecutive comment-only continuation lines until the
@@ -126,9 +126,14 @@ class Project:
     the real tree (:meth:`from_root`)."""
 
     def __init__(self, files: List[SourceFile],
-                 docs: Dict[str, str]):
+                 docs: Dict[str, str],
+                 paths: Optional[Iterable[str]] = None):
         self.files = files
         self.docs = docs
+        #: every file of the tree, repo-relative (what a doc may name)
+        self.paths = frozenset(
+            paths if paths is not None
+            else [f.relpath for f in files] + list(docs))
         self._by_path = {f.relpath: f for f in files}
 
     def get(self, relpath: str) -> Optional[SourceFile]:
@@ -156,13 +161,6 @@ class Project:
                     with open(p, "r", encoding="utf-8",
                               errors="replace") as fh:
                         files.append(SourceFile(rel, fh.read()))
-        # bench.py is part of the emitting surface (bench-lane knobs
-        # and metrics live there) even though it sits at the top level.
-        bench = os.path.join(root, "bench.py")
-        if os.path.exists(bench):
-            with open(bench, "r", encoding="utf-8",
-                      errors="replace") as fh:
-                files.append(SourceFile("bench.py", fh.read()))
         docs: Dict[str, str] = {}
         docs_dir = os.path.join(root, "docs")
         if os.path.isdir(docs_dir):
@@ -176,7 +174,15 @@ class Project:
             with open(readme, "r", encoding="utf-8",
                       errors="replace") as fh:
                 docs["README.md"] = fh.read()
-        return cls(files, docs)
+        paths: List[str] = []
+        for dirpath, dirnames, filenames in os.walk(root):
+            dirnames[:] = [d for d in dirnames
+                           if d not in _SKIP_DIRS
+                           and not d.startswith(".")]
+            rel = os.path.relpath(dirpath, root).replace(os.sep, "/")
+            paths.extend(fn if rel == "." else rel + "/" + fn
+                         for fn in filenames)
+        return cls(files, docs, paths)
 
     @classmethod
     def from_strings(cls, sources: Dict[str, str],

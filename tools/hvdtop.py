@@ -13,7 +13,7 @@ Signs requests with the job secret (``HOROVOD_SECRET_KEY`` or
 ``--secret``) using the same HMAC contract as every rendezvous/metrics
 request; against a secretless endpoint it fetches unsigned.  ``--once``
 prints one plain-text frame and exits 0 (the scriptable/CI mode the
-straggler bench lane uses); without it, a curses screen refreshes at
+straggler drill uses); without it, a curses screen refreshes at
 ``--interval`` (falling back to plain-text polling when stdout is not
 a tty or curses is unavailable).
 """

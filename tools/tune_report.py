@@ -9,8 +9,7 @@ Usage:
 A profile is the frozen output of an autotune-then-freeze session
 (horovod_tpu/tune, docs/autotune.md): per-cycle-class knob winners +
 objective scores plus the process-wide worker knobs.  The diff mode
-shows knob deltas and the objective movement between two rounds —
-the artifact-to-artifact comparison the bench lanes gate on.
+shows knob deltas and the objective movement between two profiles.
 
 Exit codes: 0 ok, 1 usage, 2 unreadable/invalid profile.
 """
