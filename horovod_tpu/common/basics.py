@@ -158,7 +158,8 @@ def _maybe_init_jax_distributed(info: RankInfo):
     jax.distributed.initialize(
         coordinator_address=coordinator,
         num_processes=info.size,
-        process_id=info.rank, **kwargs)
+        process_id=info.rank,
+        initialization_timeout=int(env_mod.start_timeout()), **kwargs)
     return True
 
 

@@ -2553,6 +2553,15 @@ class CoordinatorServer:
 
     def stop(self):
         self._stop.set()
+        # shutdown() before close(): it takes the socket out of LISTEN
+        # now and wakes the accept thread.  close() alone leaves the
+        # socket listening, with no descriptor, for as long as that
+        # thread's pending poll holds it (up to its 0.5 s), and the
+        # next incarnation's bind of the same port meets EADDRINUSE.
+        try:
+            self._srv.shutdown(socket.SHUT_RDWR)
+        except OSError:
+            pass
         try:
             self._srv.close()
         except OSError:

@@ -43,7 +43,7 @@ def _launch(tmp_path, np, body, **extra):
     out = subprocess.run(
         [sys.executable, "-m", "horovod_tpu.runner.launch", "-np",
          str(np), sys.executable, str(script)], env=_env(**extra),
-        check=True, capture_output=True, text=True, timeout=300).stdout
+        check=True, capture_output=True, text=True, timeout=240).stdout
     return [json.loads(line.split(chip_smoke.RESULT_MARK, 1)[1])
             for line in out.splitlines() if chip_smoke.RESULT_MARK in line]
 
@@ -51,7 +51,7 @@ def _launch(tmp_path, np, body, **extra):
 def test_chip_smoke_fails_without_a_tpu():
     r = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
                        env=_env(), capture_output=True, text=True,
-                       timeout=300)
+                       timeout=240)
     assert r.returncode != 0
     last = json.loads(r.stdout.strip().splitlines()[-1])
     assert last["ok"] is False and last["device"] is None

@@ -195,7 +195,8 @@ def test_concurrent_submission_order_does_not_matter_at_8():
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(timeout=10)
+            assert not t.is_alive()
         for conn in conns:
             seen = []
             while len(seen) < len(names):

@@ -57,81 +57,111 @@ print(f"DONE rank={hvd.rank()} epoch={state.epoch}", flush=True)
 """
 
 
-def _scan_logs(outdir):
-    text = ""
-    for root, _, files in os.walk(outdir):
-        for f in files:
-            with open(os.path.join(root, f),
-                      errors="replace") as fh:
-                text += fh.read()
-    return text
+class _ElasticRun:
+    """``launch_elastic`` on a thread of its own, over a discovery
+    script that prints ``hosts_file``, with ONE deadline for the whole
+    run: every wait takes what is left of it."""
 
+    LIMIT_S = 240.0
 
-def test_elastic_world_grows(tmp_path):
-    from horovod_tpu.runner.elastic.discovery import HostDiscoveryScript
-    from horovod_tpu.runner.elastic_run import launch_elastic
+    def __init__(self, tmp_path):
+        self.tmp_path = tmp_path
+        self.hosts_file = tmp_path / "hosts.txt"
+        self.stop_file = tmp_path / "stop"
+        self.outdir = tmp_path / "out"
+        self.result = {}
 
-    hosts_file = tmp_path / "hosts.txt"
-    hosts_file.write_text("localhost:2\n")
-    script = tmp_path / "discover.sh"
-    script.write_text(f"#!/bin/sh\ncat {hosts_file}\n")
-    script.chmod(0o755)
-    stop_file = tmp_path / "stop"
-    worker_py = tmp_path / "worker.py"
-    worker_py.write_text(WORKER_SCRIPT)
-    outdir = tmp_path / "out"
+    def start(self, worker_src, hosts, extra_worker_env, **launch):
+        from horovod_tpu.runner.elastic.discovery import \
+            HostDiscoveryScript
+        from horovod_tpu.runner.elastic_run import launch_elastic
 
-    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+        self.hosts_file.write_text(hosts)
+        script = self.tmp_path / "discover.sh"
+        script.write_text(f"#!/bin/sh\ncat {self.hosts_file}\n")
+        script.chmod(0o755)
+        worker_py = self.tmp_path / "worker.py"
+        worker_py.write_text(worker_src)
 
-    result = {}
+        env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+        env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
 
-    def run_launcher():
-        try:
-            result["codes"] = launch_elastic(
-                [sys.executable, str(worker_py)],
-                discovery=HostDiscoveryScript(str(script), 1),
-                np=2, min_np=2, max_np=3,
-                elastic_timeout=60,
-                output_filename=str(outdir),
-                env=env,
-                extra_worker_env={
-                    "HOROVOD_TPU_FORCE_CPU": "1",
-                    "TEST_STOP_FILE": str(stop_file),
-                    "HOROVOD_START_TIMEOUT": "60",
-                })
-        except Exception as e:   # surfaced in the main thread
-            result["error"] = e
+        def run_launcher():
+            try:
+                self.result["codes"] = launch_elastic(
+                    [sys.executable, str(worker_py)],
+                    discovery=HostDiscoveryScript(str(script), 1),
+                    output_filename=str(self.outdir),
+                    env=env,
+                    extra_worker_env={
+                        "HOROVOD_TPU_FORCE_CPU": "1",
+                        "TEST_STOP_FILE": str(self.stop_file),
+                        **extra_worker_env},
+                    **launch)
+            except Exception as e:   # surfaced in the main thread
+                self.result["error"] = e
 
-    t = threading.Thread(target=run_launcher, daemon=True)
-    t.start()
+        self.deadline = time.monotonic() + self.LIMIT_S
+        self.thread = threading.Thread(target=run_launcher, daemon=True)
+        self.thread.start()
 
-    def wait_for(pattern, timeout=120):
-        deadline = time.monotonic() + timeout
-        while time.monotonic() < deadline:
-            if re.search(pattern, _scan_logs(outdir)):
+    def logs(self):
+        text = ""
+        for root, _, files in os.walk(self.outdir):
+            for f in files:
+                with open(os.path.join(root, f),
+                          errors="replace") as fh:
+                    text += fh.read()
+        return text
+
+    def wait_for(self, pattern):
+        while time.monotonic() < self.deadline:
+            if re.search(pattern, self.logs()):
                 return
-            if not t.is_alive():
+            if not self.thread.is_alive():
                 raise AssertionError(
-                    f"launcher exited early: {result}\n"
-                    f"logs:\n{_scan_logs(outdir)[-3000:]}")
+                    f"launcher exited early: {self.result}\n"
+                    f"logs:\n{self.logs()[-3000:]}")
             time.sleep(0.5)
         raise AssertionError(
-            f"pattern {pattern!r} never appeared; logs:\n"
-            f"{_scan_logs(outdir)[-3000:]}")
+            f"pattern {pattern!r} did not appear within "
+            f"{self.LIMIT_S:g} s of the start; logs:\n"
+            f"{self.logs()[-3000:]}")
 
+    def finish(self):
+        """Stop; everyone exits cleanly.  Returns the launcher's exit
+        codes and the logs."""
+        self.stop_file.write_text("")
+        self.thread.join(
+            timeout=max(0.0, self.deadline - time.monotonic()))
+        assert not self.thread.is_alive(), "launcher did not finish"
+        assert "error" not in self.result, self.result.get("error")
+        return self.result["codes"], self.logs()
+
+
+@pytest.fixture
+def elastic(tmp_path, no_worker_outlives_its_test):
+    """After a wait that failed the launcher's thread is a daemon: the
+    discovery script then offers it no host to start another worker
+    on, and the workers it has, with this run's stop file in their
+    environment, are killed."""
+    run = _ElasticRun(tmp_path)
+    yield run
+    run.hosts_file.write_text("")
+
+
+def test_elastic_world_grows(elastic):
+    elastic.start(WORKER_SCRIPT, "localhost:2\n",
+                  {"HOROVOD_START_TIMEOUT": "60"},
+                  np=2, min_np=2, max_np=3, elastic_timeout=60)
     # Phase 1: two workers train at size 2.
-    wait_for(r"EPOCH \d+ rank=\d size=2")
+    elastic.wait_for(r"EPOCH \d+ rank=\d size=2")
     # Phase 2: a third slot appears; world re-forms at size 3.
-    hosts_file.write_text("localhost:3\n")
-    wait_for(r"EPOCH \d+ rank=2 size=3")
+    elastic.hosts_file.write_text("localhost:3\n")
+    elastic.wait_for(r"EPOCH \d+ rank=2 size=3")
     # Phase 3: stop; everyone exits cleanly.
-    stop_file.write_text("")
-    t.join(timeout=120)
-    assert not t.is_alive(), "launcher did not finish"
-    assert "error" not in result, result.get("error")
-    assert set(result["codes"].values()) == {0}
-    logs = _scan_logs(outdir)
+    codes, logs = elastic.finish()
+    assert set(codes.values()) == {0}
     assert len(re.findall(r"DONE rank=\d", logs)) == 3
 
 
@@ -172,7 +202,7 @@ print(f"DONE rank={hvd.rank()} epoch={state.epoch} "
 """
 
 
-def test_elastic_worker_death_shrinks_world(tmp_path):
+def test_elastic_worker_death_shrinks_world(elastic):
     """A worker hard-dies (os._exit, no cleanup) mid-run: the driver
     records the failure, blacklists that host, survivors unwind via
     HorovodInternalError, restore committed state, and continue at the
@@ -180,75 +210,22 @@ def test_elastic_worker_death_shrinks_world(tmp_path):
     test/integration/elastic_common.py; failure path SURVEY §5).
     Two distinct host strings (localhost / 127.0.0.1) both resolve
     locally, so blacklisting the doomed 'host' spares the survivor."""
-    from horovod_tpu.runner.elastic.discovery import HostDiscoveryScript
-    from horovod_tpu.runner.elastic_run import launch_elastic
-
-    hosts_file = tmp_path / "hosts.txt"
-    hosts_file.write_text("localhost:1\n127.0.0.1:1\n")
-    script = tmp_path / "discover.sh"
-    script.write_text(f"#!/bin/sh\ncat {hosts_file}\n")
-    script.chmod(0o755)
-    stop_file = tmp_path / "stop"
-    worker_py = tmp_path / "worker.py"
-    worker_py.write_text(KILLABLE_WORKER)
-    outdir = tmp_path / "out"
-
-    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-
-    result = {}
-
-    def run_launcher():
-        try:
-            result["codes"] = launch_elastic(
-                [sys.executable, str(worker_py)],
-                discovery=HostDiscoveryScript(str(script), 1),
-                np=2, min_np=1, max_np=2,
-                elastic_timeout=60,
-                output_filename=str(outdir),
-                env=env,
-                extra_worker_env={
-                    "HOROVOD_TPU_FORCE_CPU": "1",
-                    "TEST_STOP_FILE": str(stop_file),
-                    "TEST_DOOMED_HOST": "127.0.0.1",
-                    "HOROVOD_START_TIMEOUT": "60",
-                })
-        except Exception as e:
-            result["error"] = e
-
-    t = threading.Thread(target=run_launcher, daemon=True)
-    t.start()
-
-    def wait_for(pattern, timeout=120):
-        deadline = time.monotonic() + timeout
-        while time.monotonic() < deadline:
-            if re.search(pattern, _scan_logs(outdir)):
-                return
-            if not t.is_alive():
-                raise AssertionError(
-                    f"launcher exited early: {result}\n"
-                    f"logs:\n{_scan_logs(outdir)[-3000:]}")
-            time.sleep(0.5)
-        raise AssertionError(
-            f"pattern {pattern!r} never appeared; logs:\n"
-            f"{_scan_logs(outdir)[-3000:]}")
-
+    elastic.start(KILLABLE_WORKER, "localhost:1\n127.0.0.1:1\n",
+                  {"TEST_DOOMED_HOST": "127.0.0.1",
+                   "HOROVOD_START_TIMEOUT": "60"},
+                  np=2, min_np=1, max_np=2, elastic_timeout=60)
     # Phase 1: both workers train at size 2; the doomed one dies.
-    wait_for(r"EPOCH \d+ rank=\d size=2")
-    wait_for(r"DYING")
+    elastic.wait_for(r"EPOCH \d+ rank=\d size=2")
+    elastic.wait_for(r"DYING")
     # Phase 2: the survivor re-forms at size 1, resuming from a
     # committed epoch >= 3 (state survived the membership change).
-    wait_for(r"EPOCH [3-9]\d* rank=0 size=1")
+    elastic.wait_for(r"EPOCH [3-9]\d* rank=0 size=1")
     # Phase 3: stop; survivor exits cleanly.
-    stop_file.write_text("")
-    t.join(timeout=120)
-    assert not t.is_alive(), "launcher did not finish"
-    assert "error" not in result, result.get("error")
-    logs = _scan_logs(outdir)
+    codes, logs = elastic.finish()
     m = re.search(r"DONE rank=0 epoch=(\d+) size=1", logs)
     assert m and int(m.group(1)) >= 3, logs[-2000:]
     # The dead slot's non-zero code is recorded, not fatal.
-    assert any(c != 0 for c in result["codes"].values()), result
+    assert any(c != 0 for c in codes.values()), codes
 
 
 TWO_TIER_WORKER = """
@@ -303,85 +280,33 @@ print(f"DONE rank={hvd.rank()} epoch={state.epoch} "
 """
 
 
-def test_elastic_two_tier_host_loss(tmp_path):
+def test_elastic_two_tier_host_loss(elastic):
     """VERDICT r3 item 6 (elastic leg): a 2-host x 2-slot world loses
     a whole 'host' mid-run; survivors re-rendezvous as 1 host x 2
     slots with the local/cross contract recomputed (cross_size 2 -> 1)
     and the hierarchical mesh re-formed over the new topology."""
-    from horovod_tpu.runner.elastic.discovery import HostDiscoveryScript
-    from horovod_tpu.runner.elastic_run import launch_elastic
-
-    hosts_file = tmp_path / "hosts.txt"
-    hosts_file.write_text("localhost:2\n127.0.0.1:2\n")
-    script = tmp_path / "discover.sh"
-    script.write_text(f"#!/bin/sh\ncat {hosts_file}\n")
-    script.chmod(0o755)
-    stop_file = tmp_path / "stop"
-    worker_py = tmp_path / "worker.py"
-    worker_py.write_text(TWO_TIER_WORKER)
-    outdir = tmp_path / "out"
-
-    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-
-    result = {}
-
-    def run_launcher():
-        try:
-            result["codes"] = launch_elastic(
-                [sys.executable, str(worker_py)],
-                discovery=HostDiscoveryScript(str(script), 1),
-                np=4, min_np=2, max_np=4,
-                elastic_timeout=60,
-                output_filename=str(outdir),
-                env=env,
-                extra_worker_env={
-                    "HOROVOD_TPU_FORCE_CPU": "1",
-                    "HOROVOD_CPU_OPERATIONS": "XLA",
-                    # One virtual device per worker: the host tier is
-                    # simulated by PROCESS groups, so the conftest's
-                    # 8-device XLA_FLAGS must not leak in (it would
-                    # flip the hierarchy to device-kind).
-                    "XLA_FLAGS":
-                        "--xla_force_host_platform_device_count=1",
-                    "TEST_STOP_FILE": str(stop_file),
-                    "TEST_DOOMED_HOST": "127.0.0.1",
-                    "HOROVOD_START_TIMEOUT": "90",
-                })
-        except Exception as e:
-            result["error"] = e
-
-    t = threading.Thread(target=run_launcher, daemon=True)
-    t.start()
-
-    def wait_for(pattern, timeout=180):
-        deadline = time.monotonic() + timeout
-        while time.monotonic() < deadline:
-            if re.search(pattern, _scan_logs(outdir)):
-                return
-            if not t.is_alive():
-                raise AssertionError(
-                    f"launcher exited early: {result}\n"
-                    f"logs:\n{_scan_logs(outdir)[-3000:]}")
-            time.sleep(0.5)
-        raise AssertionError(
-            f"pattern {pattern!r} never appeared; logs:\n"
-            f"{_scan_logs(outdir)[-3000:]}")
-
+    elastic.start(
+        TWO_TIER_WORKER, "localhost:2\n127.0.0.1:2\n",
+        {"HOROVOD_CPU_OPERATIONS": "XLA",
+         # One virtual device per worker: the host tier is simulated
+         # by PROCESS groups, so the conftest's 8-device XLA_FLAGS
+         # must not leak in (it would flip the hierarchy to
+         # device-kind).
+         "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
+         "TEST_DOOMED_HOST": "127.0.0.1",
+         "HOROVOD_START_TIMEOUT": "90"},
+        np=4, min_np=2, max_np=4, elastic_timeout=60)
     # Phase 1: 4 workers, two-tier (cross_size=2, local_size=2).
-    wait_for(r"EPOCH \d+ rank=\d size=4 lr=\d ls=2 cr=\d cs=2")
-    wait_for(r"DYING")
+    elastic.wait_for(r"EPOCH \d+ rank=\d size=4 lr=\d ls=2 cr=\d cs=2")
+    elastic.wait_for(r"DYING")
     # Phase 2: the dead host's pair is blacklisted; the surviving host
     # re-forms as one tier (size 2, cross_size 1) from committed state.
-    wait_for(r"EPOCH [2-9]\d* rank=\d size=2 lr=\d ls=2 cr=0 cs=1")
+    elastic.wait_for(
+        r"EPOCH [2-9]\d* rank=\d size=2 lr=\d ls=2 cr=0 cs=1")
     # Phase 3: stop; survivors exit cleanly.
-    stop_file.write_text("")
-    t.join(timeout=120)
-    assert not t.is_alive(), "launcher did not finish"
-    assert "error" not in result, result.get("error")
-    logs = _scan_logs(outdir)
+    codes, logs = elastic.finish()
     assert len(re.findall(r"DONE rank=\d epoch=\d+ size=2", logs)) == 2
-    assert any(c != 0 for c in result["codes"].values()), result
+    assert any(c != 0 for c in codes.values()), codes
 
 
 TF_GRAPH_ELASTIC_WORKER = """
@@ -473,78 +398,25 @@ print(f"DONE rank={hvd.rank()} epoch={state.epoch} "
 """
 
 
-def test_elastic_in_graph_tf_survives_resize(tmp_path):
+def test_elastic_in_graph_tf_survives_resize(elastic):
     """VERDICT r3 item 5: elastic TF2 trains through a resize WITH
     in-graph collectives on both sides of it (HOROVOD_TF_ELASTIC_GRAPH
     context-reset re-formation): 3 workers train with CollectiveReduceV2
     in the traced graph, one hard-dies, the survivors re-form at size 2
     and the retraced step still carries CollectiveReduceV2 — never
     py_function. The collective path and per-step time are in the log."""
-    from horovod_tpu.runner.elastic.discovery import HostDiscoveryScript
-    from horovod_tpu.runner.elastic_run import launch_elastic
-
-    hosts_file = tmp_path / "hosts.txt"
-    hosts_file.write_text("localhost:2\n127.0.0.1:1\n")
-    script = tmp_path / "discover.sh"
-    script.write_text(f"#!/bin/sh\ncat {hosts_file}\n")
-    script.chmod(0o755)
-    stop_file = tmp_path / "stop"
-    worker_py = tmp_path / "worker.py"
-    worker_py.write_text(TF_GRAPH_ELASTIC_WORKER)
-    outdir = tmp_path / "out"
-
-    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-
-    result = {}
-
-    def run_launcher():
-        try:
-            result["codes"] = launch_elastic(
-                [sys.executable, str(worker_py)],
-                discovery=HostDiscoveryScript(str(script), 1),
-                np=3, min_np=2, max_np=3,
-                elastic_timeout=90,
-                output_filename=str(outdir),
-                env=env,
-                extra_worker_env={
-                    "HOROVOD_TPU_FORCE_CPU": "1",
-                    "HOROVOD_TF_ELASTIC_GRAPH": "1",
-                    "TEST_STOP_FILE": str(stop_file),
-                    "TEST_DOOMED_HOST": "127.0.0.1",
-                    "HOROVOD_START_TIMEOUT": "120",
-                    "TF_CPP_MIN_LOG_LEVEL": "2",
-                })
-        except Exception as e:
-            result["error"] = e
-
-    t = threading.Thread(target=run_launcher, daemon=True)
-    t.start()
-
-    def wait_for(pattern, timeout=300):
-        deadline = time.monotonic() + timeout
-        while time.monotonic() < deadline:
-            if re.search(pattern, _scan_logs(outdir)):
-                return
-            if not t.is_alive():
-                raise AssertionError(
-                    f"launcher exited early: {result}\n"
-                    f"logs:\n{_scan_logs(outdir)[-3000:]}")
-            time.sleep(0.5)
-        raise AssertionError(
-            f"pattern {pattern!r} never appeared; logs:\n"
-            f"{_scan_logs(outdir)[-3000:]}")
-
+    elastic.start(TF_GRAPH_ELASTIC_WORKER, "localhost:2\n127.0.0.1:1\n",
+                  {"HOROVOD_TF_ELASTIC_GRAPH": "1",
+                   "TEST_DOOMED_HOST": "127.0.0.1",
+                   "HOROVOD_START_TIMEOUT": "120",
+                   "TF_CPP_MIN_LOG_LEVEL": "2"},
+                  np=3, min_np=2, max_np=3, elastic_timeout=90)
     # Phase 1: 3 workers on the compiled collective path.
-    wait_for(r"EPOCH \d+ rank=\d size=3 path=collective_v2")
-    wait_for(r"DYING")
+    elastic.wait_for(r"EPOCH \d+ rank=\d size=3 path=collective_v2")
+    elastic.wait_for(r"DYING")
     # Phase 2: survivors re-form at size 2, STILL in-graph.
-    wait_for(r"EPOCH \d+ rank=\d size=2 path=collective_v2")
-    stop_file.write_text("")
-    t.join(timeout=180)
-    assert not t.is_alive(), "launcher did not finish"
-    assert "error" not in result, result.get("error")
-    logs = _scan_logs(outdir)
+    elastic.wait_for(r"EPOCH \d+ rank=\d size=2 path=collective_v2")
+    _, logs = elastic.finish()
     assert "path=py_function" not in logs
     assert len(re.findall(
         r"DONE rank=\d epoch=\d+ size=2 path=collective_v2",

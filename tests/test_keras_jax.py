@@ -68,7 +68,7 @@ print("KERAS-JAX-OK", round(w, 3), round(b, 3))
 @pytest.mark.parametrize("nproc", [2])
 def test_keras_jax_fit_distributed(nproc):
     results = run_workers(
-        _KERAS_JAX_BODY, nproc=nproc, timeout=300,
+        _KERAS_JAX_BODY, nproc=nproc, timeout=240,
         extra_env={"KERAS_BACKEND": "jax"})
     assert_all_ok(results)
     assert all("KERAS-JAX-OK" in out for _, out in results)
@@ -169,7 +169,7 @@ def test_keras_jax_spmd_multiproc_multidevice():
     process, gradient plane in-graph (no host staging, no io_callback
     refusal)."""
     results = run_workers(
-        _SPMD_BODY, nproc=2, timeout=360,
+        _SPMD_BODY, nproc=2, timeout=240,
         extra_env={"KERAS_BACKEND": "jax",
                    "XLA_FLAGS":
                        "--xla_force_host_platform_device_count=4"})
@@ -211,7 +211,7 @@ print("KERAS-JAX-NODIST-OK")
 
 def test_keras_jax_multidevice_without_distribution_falls_back():
     results = run_workers(
-        _MULTIDEV_NODIST_BODY, nproc=2, timeout=360,
+        _MULTIDEV_NODIST_BODY, nproc=2, timeout=240,
         extra_env={"KERAS_BACKEND": "jax",
                    "XLA_FLAGS":
                        "--xla_force_host_platform_device_count=4"})
@@ -301,7 +301,7 @@ def test_keras_jax_backward_passes_compiled():
     keras-native accumulation), syncing the wire only on update
     steps."""
     results = run_workers(
-        _BPS_BODY, nproc=2, timeout=360,
+        _BPS_BODY, nproc=2, timeout=240,
         extra_env={"KERAS_BACKEND": "jax"})
     assert_all_ok(results)
     assert all("KERAS-JAX-BPS-OK" in out for _, out in results)
@@ -309,7 +309,7 @@ def test_keras_jax_backward_passes_compiled():
 
 def test_keras_jax_local_distribution_with_world_raises():
     results = run_workers(
-        _LOCAL_DIST_BODY, nproc=2, timeout=300,
+        _LOCAL_DIST_BODY, nproc=2, timeout=240,
         extra_env={"KERAS_BACKEND": "jax",
                    "XLA_FLAGS":
                        "--xla_force_host_platform_device_count=4"})
@@ -365,7 +365,7 @@ def test_keras_elastic_reset_rebuilds_data_parallel():
     keras/elastic._reset() must rebuild an installed
     keras.distribution DataParallel over the new world's devices."""
     results = run_workers(
-        _RESET_DP_BODY, nproc=2, timeout=360,
+        _RESET_DP_BODY, nproc=2, timeout=240,
         extra_env={"KERAS_BACKEND": "jax",
                    "XLA_FLAGS":
                        "--xla_force_host_platform_device_count=2"})

@@ -218,6 +218,10 @@ def test_graph_survives_lock_gc_without_phantom_cycles(witness):
     the dead lock's edges (phantom-cycle regression)."""
     import gc
     base = threading.Lock()
+    # Every round's collection is of the young generations: whatever
+    # cycle could hold a lock of this round was made in this round.  A
+    # full collection walks the whole heap of a pytest worker that has
+    # run JAX for minutes, and a hundred of them took 45 to 63 s.
     for _ in range(50):
         tmp = threading.Lock()
 
@@ -227,14 +231,14 @@ def test_graph_survives_lock_gc_without_phantom_cycles(witness):
                     pass
         _run(lambda: order(base, tmp))
         del tmp
-        gc.collect()
+        gc.collect(1)
         # A fresh lock at a possibly-recycled address, acquired in
         # the OPPOSITE role: must never close a cycle with a dead
         # lock's edges.
         fresh = threading.Lock()
         _run(lambda: order(fresh, base))
         del fresh
-        gc.collect()
+        gc.collect(1)
     assert witness.cycles() == []
 
 

@@ -199,9 +199,15 @@ def test_bert_flash_attention_matches_einsum():
     params = m_e.init(rng, ids)
     out_e = np.asarray(m_e.apply(params, ids).astype(jnp.float32))
     # The model calls the kernel compiled; off the TPU the test asks
-    # Pallas for interpret mode here, by name.
+    # Pallas for interpret mode here, by name.  That interpreter runs
+    # JAX operations of its own from callbacks on XLA's threads, and
+    # under load they deadlock against a main thread that dispatches
+    # the model's operations one by one (PR 28: the worker then waits
+    # for ever with no CPU used).  So the forward pass is one compiled
+    # call, and the main thread only waits for it.
     with pltpu.force_tpu_interpret_mode():
-        out_f = np.asarray(m_f.apply(params, ids).astype(jnp.float32))
+        out_f = np.asarray(
+            jax.jit(m_f.apply)(params, ids).astype(jnp.float32))
     np.testing.assert_allclose(out_f, out_e, atol=3e-2, rtol=3e-2)
 
 
@@ -239,6 +245,9 @@ def test_gpt_flash_kernels_match_einsum(axes):
         return logits, grads
 
     logits_e, grads_e = value_and_grad(m_e)
+    # Under ``jax.jit`` (in ``value_and_grad``) for the reason given in
+    # ``test_bert_flash_attention_matches_einsum``: this interpreter's
+    # callbacks deadlock against eager dispatch.
     with pltpu.force_tpu_interpret_mode():
         logits_f, grads_f = value_and_grad(m_f)
     np.testing.assert_allclose(np.asarray(logits_f), np.asarray(logits_e),

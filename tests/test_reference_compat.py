@@ -26,6 +26,9 @@ def _worker_env():
     return env
 
 
+pytestmark = pytest.mark.usefixtures("no_worker_outlives_its_test")
+
+
 @pytest.mark.skipif(not os.path.exists(TF2_BENCH),
                     reason="reference checkout unavailable")
 def test_reference_tf2_synthetic_benchmark_unmodified(tmp_path):
@@ -46,7 +49,7 @@ def test_reference_tf2_synthetic_benchmark_unmodified(tmp_path):
              "--batch-size", "1", "--num-warmup-batches", "1",
              "--num-batches-per-iter", "1", "--num-iters", "2"],
             "localhost:2", 2, env=_worker_env(),
-            output_filename=str(outdir), verbose=1, start_timeout=600)
+            output_filename=str(outdir), verbose=1, start_timeout=240)
     except RuntimeError:
         codes = None
     stdout = (outdir / "rank.0" / "stdout").read_text()
@@ -73,7 +76,7 @@ def test_reference_pytorch_synthetic_benchmark_unmodified(tmp_path):
          "--batch-size", "1", "--num-warmup-batches", "1",
          "--num-batches-per-iter", "1", "--num-iters", "2", "--no-cuda"],
         "localhost:2", 2, env=_worker_env(),
-        output_filename=str(outdir), verbose=1, start_timeout=600)
+        output_filename=str(outdir), verbose=1, start_timeout=240)
     assert codes == {0: 0, 1: 0}
     stdout = (outdir / "rank.0" / "stdout").read_text()
     assert "Total img/sec on 2 CPU(s)" in stdout, stdout[-2000:]

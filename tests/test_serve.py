@@ -324,7 +324,8 @@ def test_train_commit_serve_verify_smoke(tmp_path, monkeypatch):
         served[step] = (ids, rows)
         reads += 1
         time.sleep(0.005)
-    t.join()
+    t.join(timeout=30)
+    assert not t.is_alive()
     assert not errs, errs
     # Each served step's rows against the committed chain itself,
     # restored by a manager that never saw the trainer's state.

@@ -276,7 +276,7 @@ def two_processes(tmp_path_factory):
     out = []
     for _ in range(2):
         done = subprocess.run([sys.executable, "-c", _COMPILES], env=env,
-                              capture_output=True, text=True, timeout=300)
+                              capture_output=True, text=True, timeout=120)
         assert done.returncode == 0, done.stderr[-3000:]
         out.append(json.loads(done.stdout.strip().splitlines()[-1]))
     return out

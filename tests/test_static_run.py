@@ -40,6 +40,9 @@ def _worker_env():
     return env
 
 
+pytestmark = pytest.mark.usefixtures("no_worker_outlives_its_test")
+
+
 def test_launch_static_two_procs(tmp_path):
     from horovod_tpu.runner.tpu_run import launch_static
     script = tmp_path / "train.py"
@@ -125,7 +128,7 @@ def test_elastic_tf2_resnet50_example_static(tmp_path):
          "--num-warmup-batches", "1", "--num-batches-per-iter", "2",
          "--num-iters", "2"],
         "localhost:2", 2, env=_worker_env(),
-        output_filename=str(outdir), verbose=1, start_timeout=300)
+        output_filename=str(outdir), verbose=1, start_timeout=240)
     assert codes == {0: 0, 1: 0}
     stdout = (outdir / "rank.0" / "stdout").read_text()
     assert "img/sec per worker" in stdout

@@ -719,7 +719,7 @@ print("A2A_EXCLUSION_OK", RANK)
 hvd.shutdown()
 """
     results = run_workers(
-        body, nproc=8, timeout=300,
+        body, nproc=8, timeout=240,
         extra_env={"HOROVOD_STEADY_STATE_REPLAY": "1"})
     assert_all_ok(results)
     for _, out in results:

@@ -61,7 +61,7 @@ for it in range(60):
 
 hvd.barrier()
 print("SOAK OK rank=%d" % RANK)
-""", nproc=4, timeout=600)
+""", nproc=4, timeout=240)
     assert_all_ok(results)
 
 
@@ -176,6 +176,25 @@ def test_formation_stall_attributed_and_failed():
         srv.stop()
 
 
+def test_a_stopped_coordinator_frees_its_port_at_once():
+    """``hvd.shutdown()`` then ``hvd.init()``: rank 0's next
+    incarnation binds the port of the last one a few milliseconds
+    after ``stop()``.  The stopped server's socket must not go on
+    listening while its accept thread's poll runs out (PR 28:
+    ``EADDRINUSE`` in ``test_init_shutdown_churn_nproc3``)."""
+    import time
+
+    from horovod_tpu.common.controller_net import CoordinatorServer
+
+    srv = CoordinatorServer(3, port=0)
+    port = srv.port
+    for _ in range(5):
+        time.sleep(0.1)     # the accept thread is in its poll
+        srv.stop()
+        srv = CoordinatorServer(3, port=port)
+    srv.stop()
+
+
 def test_init_shutdown_churn_nproc3():
     """Repeated shutdown+init cycles with collectives in between: each
     incarnation re-forms the controller, ring (incl. the shm segment,
@@ -206,7 +225,7 @@ for cycle in range(4):
 # host-global and other jobs' files are not ours to assert about.
 leftover = set(glob.glob("/dev/shm/hvdring*")) - pre_existing
 print("CHURN OK rank=%d leftover=%d" % (RANK, len(leftover)))
-""", nproc=3, timeout=300)
+""", nproc=3, timeout=240)
     assert_all_ok(results)
     for _, out in results:
         assert "CHURN OK" in out

@@ -86,7 +86,7 @@ print("TWO-TIER-OK")
 
 def test_hierarchical_allreduce_two_tier():
     results = run_workers(
-        _HIER_BODY, nproc=NPROC, timeout=300,
+        _HIER_BODY, nproc=NPROC, timeout=240,
         extra_env={"HOROVOD_CPU_OPERATIONS": "XLA",
                    "HOROVOD_HIERARCHICAL_ALLREDUCE": "1"},
         per_rank_env=two_tier_env)
