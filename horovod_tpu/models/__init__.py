@@ -6,6 +6,8 @@ from .bert import (BertConfig, BertEncoder, BertForMaskedLM,
 from .gpt import (GPTConfig, GPTLMHeadModel, chunked_lm_loss,
                   gpt2_medium_config, gpt2_small_config, gpt_tiny_config,
                   lm_loss)
+from .granite import (GraniteConfig, GraniteLMHeadModel,
+                      granite_tiny_config)
 from .mnist import MnistCNN, MnistMLP, cross_entropy_loss
 from .dlrm import (DLRMConfig, DLRMDense, bce_logits_loss,
                    dlrm_tiny_config, synthetic_click_batch)
@@ -17,6 +19,7 @@ __all__ = [
     "bert_large_config", "bert_tiny_config", "mlm_loss",
     "GPTConfig", "GPTLMHeadModel", "gpt2_small_config",
     "gpt2_medium_config", "gpt_tiny_config", "lm_loss", "chunked_lm_loss",
+    "GraniteConfig", "GraniteLMHeadModel", "granite_tiny_config",
     "MnistCNN", "MnistMLP", "cross_entropy_loss",
     "DLRMConfig", "DLRMDense", "bce_logits_loss", "dlrm_tiny_config",
     "synthetic_click_batch",

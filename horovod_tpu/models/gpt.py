@@ -95,9 +95,10 @@ def gpt_tiny_config(**kw) -> GPTConfig:
     return GPTConfig(**defaults)
 
 
-def _flash_causal(q, k, v, sharding: Optional[NamedSharding]):
+def _flash_causal(q, k, v, sharding: Optional[NamedSharding],
+                  scale: Optional[float] = None):
     from ..ops.pallas_attention import flash_attention
-    attend = functools.partial(flash_attention, causal=True)
+    attend = functools.partial(flash_attention, causal=True, scale=scale)
     if sharding is not None and sharding.mesh.size > 1:
         # GSPMD does not partition a Mosaic kernel; attention is
         # independent per sequence and per head, so each chip runs
@@ -289,12 +290,15 @@ def loss_chunks(seq: int) -> Tuple[int, int]:
     return count, -(-seq // count)
 
 
-def _chunk_nll(h, table, targets):
+def _chunk_nll(h, table, targets, scale: float):
     """One chunk: fp32 logits ``[B, C, V]`` of ``h`` ``[B, C, H]``
-    (products on ``h``'s dtype, accumulated in fp32), each position's
-    log-sum-exp and its negative log-likelihood of ``targets``."""
+    (products on ``h``'s dtype, accumulated in fp32) times ``scale``,
+    each position's log-sum-exp and its negative log-likelihood of
+    ``targets``."""
     logits = jnp.einsum("bch,vh->bcv", h, table,
                         preferred_element_type=jnp.float32)
+    if scale != 1.0:
+        logits = logits * scale
     lse = jax.nn.logsumexp(logits, axis=-1)
     at_target = jnp.take_along_axis(
         logits, targets[..., None], axis=-1)[..., 0]
@@ -314,37 +318,41 @@ def _chunked(hidden, targets, weights):
     return split(hidden), split(targets), split(weights)
 
 
-@jax.custom_vjp
-def _weighted_nll(hidden, embedding, targets, weights):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _weighted_nll(hidden, embedding, targets, weights, scale):
     """``sum(weights * nll)`` over ``[B, S]``, a chunk of the sequence
-    at a time."""
+    at a time; the logits are the tied head's times ``scale``."""
     table = embedding.astype(hidden.dtype)
 
     def one(total, chunk_of):
         h, t, w = chunk_of
-        return total + (w * _chunk_nll(h, table, t)[2]).sum(), None
+        return total + (w * _chunk_nll(h, table, t, scale)[2]).sum(), None
     total, _ = jax.lax.scan(one, jnp.zeros((), jnp.float32),
                             _chunked(hidden, targets, weights))
     return total
 
 
-def _weighted_nll_fwd(hidden, embedding, targets, weights):
+def _weighted_nll_fwd(hidden, embedding, targets, weights, scale):
     """The same pass over a chunk's logits gives its gradients too:
-    ``(softmax - onehot) * weights``, cast to the compute dtype as
-    autodiff's transpose of the logits' ``astype`` does, times the
-    embedding (to the hidden states) and times the hidden states (to
-    the embedding, summed over chunks in fp32).  Those two arrays are
+    ``(softmax - onehot) * weights`` (times ``scale``, the logits'
+    own factor), cast to the compute dtype as autodiff's transpose of
+    the logits' ``astype`` does, times the embedding (to the hidden
+    states) and times the hidden states (to the embedding, summed over
+    chunks in fp32).  Those two arrays are
     the residuals; no chunk's logits outlive its step of the scan."""
     table = embedding.astype(hidden.dtype)
 
     def one(carry, chunk_of):
         total, d_table = carry
         h, t, w = chunk_of
-        logits, lse, nll = _chunk_nll(h, table, t)
+        logits, lse, nll = _chunk_nll(h, table, t, scale)
         hit = jax.lax.broadcasted_iota(
             jnp.int32, logits.shape, 2) == t[..., None]
         d_logits = jnp.exp(logits - lse[..., None]) - hit
-        d_logits = (d_logits * w[..., None]).astype(hidden.dtype)
+        d_logits = d_logits * w[..., None]
+        if scale != 1.0:
+            d_logits = d_logits * scale
+        d_logits = d_logits.astype(hidden.dtype)
         d_h = jnp.einsum("bcv,vh->bch", d_logits, table)
         d_table = d_table + jnp.einsum(
             "bcv,bch->vh", d_logits, h,
@@ -364,7 +372,8 @@ def _weighted_nll_fwd(hidden, embedding, targets, weights):
                    whole(nll))
 
 
-def _weighted_nll_bwd(residuals, g):
+def _weighted_nll_bwd(scale, residuals, g):
+    del scale  # the residuals carry it
     d_hidden, d_embedding, nll = residuals
     return ((g * d_hidden).astype(d_hidden.dtype),
             (g * d_embedding).astype(d_embedding.dtype), None, g * nll)
@@ -373,8 +382,10 @@ def _weighted_nll_bwd(residuals, g):
 _weighted_nll.defvjp(_weighted_nll_fwd, _weighted_nll_bwd)
 
 
-def chunked_lm_loss(hidden, embedding, input_ids, mask=None):
-    """``lm_loss`` of the tied head's logits without the logits: from
+def chunked_lm_loss(hidden, embedding, input_ids, mask=None,
+                    logits_scale: float = 1.0):
+    """``lm_loss`` of the tied head's logits (times ``logits_scale``,
+    for a model that scales them) without the logits: from
     the final hidden states ``[B, S, H]`` and the token embedding ``[V,
     H]`` (``GPTLMHeadModel.hidden_and_embedding``), a chunk of at most
     ``LOSS_CHUNK`` positions of the sequence at a time, so that the batch
@@ -389,4 +400,5 @@ def chunked_lm_loss(hidden, embedding, input_ids, mask=None):
     total = counts.sum()
     if mask is not None:
         total = jnp.maximum(total, 1.0)
-    return _weighted_nll(hidden, embedding, targets, counts / total)
+    return _weighted_nll(hidden, embedding, targets, counts / total,
+                         float(logits_scale))

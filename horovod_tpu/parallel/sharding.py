@@ -59,6 +59,33 @@ def gpt_partition_rules(tp: str = "tp",
     return _transformer_partition_rules(tp, fsdp)
 
 
+def granite_partition_rules(tp: str = "tp",
+                            fsdp: Optional[str] = None) -> Rules:
+    """Tensor-parallel sharding for the Granite hybrid family
+    (models/granite.py): attention over its heads (query heads and, in
+    step with them, key-value heads: ``tp`` must divide both), the MLP
+    over its columns, the Mamba-2 mixer over its heads from the
+    recurrence on (per-head vectors, the output projection's rows).
+    ``in_proj`` stays whole across ``tp``: its columns are five unequal
+    runs (z, x, B, C, dt) of which B and C serve every head, so no even
+    split of them follows the heads; the step constrains ``x`` to the
+    heads' sharding after the split.  The tied head inherits the
+    embedding's vocabulary sharding."""
+    f = fsdp
+    return [
+        (r"word_embeddings/embedding$", P(tp, f)),
+        (r"attention/(query|key|value)/kernel$", P(f, tp, None)),
+        (r"attention/out/kernel$", P(tp, None, f)),
+        (r"mlp/(gate|up)/kernel$", P(f, tp)),
+        (r"mlp/out/kernel$", P(tp, f)),
+        (r"mamba/in_proj/kernel$", P(f, None)),
+        (r"mamba/out_proj/kernel$", P(tp, f)),
+        (r"mamba/(A_log|dt_bias|D)$", P(tp)),
+        (r"mamba/norm/scale$", P(tp)),
+        (r".*", P()),  # norms and the convolution replicated
+    ]
+
+
 def resnet_partition_rules(fsdp: Optional[str] = None) -> Rules:
     """ResNet is pure data parallel (conv kernels are small); optionally
     ZeRO-shard the dense head."""

@@ -12,6 +12,7 @@ path that hits peak MXU/ICI utilisation.
 """
 
 import logging
+from functools import partial
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
@@ -312,6 +313,14 @@ def _memory_limit(device) -> Optional[int]:
     return (stats or {}).get("bytes_limit")
 
 
+def _state_and_memory(state, mesh, rules) -> Tuple[int, Optional[int]]:
+    """What a step's ``remat`` decision sees when it is traced: the
+    bytes of ``state`` on one device under ``rules``, and the memory
+    the mesh's device reports."""
+    return (_bytes_on_one_device(state, infer_shardings(state, mesh, rules)),
+            _memory_limit(mesh.devices.flat[0]))
+
+
 _REMAT_KEPT = metrics.gauge(
     "hvd_gpt_remat_kept_bytes",
     "Bytes one device keeps across the GPT step's remat, by the names "
@@ -320,6 +329,71 @@ _LOSS_CHUNKS = metrics.gauge(
     "hvd_gpt_loss_chunks",
     "Chunks of the sequence the GPT step's loss walks (set when the "
     "step is traced)")
+_GRANITE_REMAT_KEPT = metrics.gauge(
+    "hvd_granite_remat_kept_bytes",
+    "Bytes one device keeps across the Granite step's remat, by the "
+    "names kept (set when the step is traced)")
+_SSM_CHUNKS = metrics.gauge(
+    "hvd_ssm_chunks",
+    "Chunks of a sequence the Granite step's state-space recurrence "
+    "walks (set when the step is traced)")
+_SSM_SCAN_BYTES = metrics.gauge(
+    "hvd_ssm_scan_bytes",
+    "Bytes of the arrays the chunked recurrence materialises for one "
+    "layer's forward pass on one device (set when the step is traced)")
+_HYBRID_LAYERS = metrics.gauge(
+    "hvd_hybrid_layers",
+    "Layers of the Granite step's stack, by kind (set when the step is "
+    "traced)")
+
+
+def _make_causal_lm_train_step(mesh, tx, rules: Rules, batch_axis: str,
+                               model, traced_model: Callable,
+                               step_loss: Callable):
+    """The sharded causal-LM step both decoder families build:
+    ``(init_fn, step_fn, batch_sharding)``.
+
+    ``model`` makes the parameters; ``traced_model(state, ids)`` is
+    asked when the step is traced and returns the model of that trace
+    (where a family decides what ``remat`` keeps, from the state it is
+    handed and the batch's shape); ``step_loss(model, params, ids)`` is
+    the loss.  Parameters and optimizer state are laid out by ``rules``
+    and donated, the batch rides ``batch_axis``, XLA inserts the
+    collectives."""
+    batch_sharding = NamedSharding(mesh, P(batch_axis, None))
+
+    def _init(rng, ids):
+        params = model.init(rng, ids)["params"]
+        return params, tx.init(params)
+
+    def init_fn(rng, ids):
+        # Jitted with out_shardings like BERT's: every array is born
+        # on its own shard, so a model that only fits sharded never
+        # passes through one chip.
+        shardings = infer_shardings(
+            jax.eval_shape(_init, rng, ids), mesh, rules)
+        return jax.jit(_init, out_shardings=shardings)(rng, ids)
+
+    @partial(jax.jit, donate_argnums=(0, 1))
+    def step_fn(params, opt_state, ids):
+        loss, grads = jax.value_and_grad(partial(
+            step_loss, traced_model((params, opt_state), ids)))(
+                params, ids)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+        return params, opt_state, loss
+
+    return init_fn, step_fn, batch_sharding
+
+
+def _heads_sharding(mesh, batch_axis: str) -> NamedSharding:
+    """How a step shards ``[B, S, heads, D]``: the batch over its data
+    axis and (the rules' "tp") the heads.  The models read the platform
+    off its mesh: on TPU devices their attention runs the Pallas
+    kernels, each chip on its share."""
+    heads_axis = "tp" if "tp" in mesh.axis_names else None
+    return NamedSharding(mesh, P(batch_axis, None, heads_axis, None))
 
 
 def make_gpt_train_step(config, mesh, learning_rate: float = 1e-2,
@@ -341,34 +415,13 @@ def make_gpt_train_step(config, mesh, learning_rate: float = 1e-2,
     ``models.gpt.remat_names`` chooses when the step is traced, from
     the batch's tokens on one device, the state the step is handed and
     the memory the mesh's device reports."""
-    import optax
-    from functools import partial
-    from jax.sharding import NamedSharding, PartitionSpec as P
     from .models import gpt
-    from .parallel.sharding import gpt_partition_rules, infer_shardings
+    from .parallel.sharding import gpt_partition_rules
 
-    tx = optax.adam(learning_rate)
     batch_axis = fsdp or "dp"
-    batch_sharding = NamedSharding(mesh, P(batch_axis, None))
     rules = gpt_partition_rules(fsdp=fsdp)
-    # The model reads the platform off the mesh: on TPU devices its
-    # attention runs the Pallas kernels, each chip on its share of the
-    # batch and (the rules' "tp") of the heads.
-    heads_axis = "tp" if "tp" in mesh.axis_names else None
-    qkv_sharding = NamedSharding(mesh, P(batch_axis, None, heads_axis, None))
+    qkv_sharding = _heads_sharding(mesh, batch_axis)
     model = gpt.GPTLMHeadModel(config, qkv_sharding=qkv_sharding)
-
-    def _init(rng, ids):
-        params = model.init(rng, ids)["params"]
-        return params, tx.init(params)
-
-    def init_fn(rng, ids):
-        # Jitted with out_shardings like BERT's: every array is born
-        # on its own shard, so a model that only fits sharded never
-        # passes through one chip.
-        shardings = infer_shardings(
-            jax.eval_shape(_init, rng, ids), mesh, rules)
-        return jax.jit(_init, out_shardings=shardings)(rng, ids)
 
     def traced_model(state, ids):
         """The model of this trace: with ``remat``, keeping what fits
@@ -379,27 +432,85 @@ def make_gpt_train_step(config, mesh, learning_rate: float = 1e-2,
         sizes = (ids.size // mesh.shape[batch_axis], config.hidden_size,
                  config.intermediate_size, config.num_heads,
                  config.num_layers, np.dtype(config.dtype).itemsize)
-        names = gpt.remat_names(
-            *sizes,
-            state_bytes=_bytes_on_one_device(
-                state, infer_shardings(state, mesh, rules)),
-            memory_limit=_memory_limit(mesh.devices.flat[0]))
+        names = gpt.remat_names(*sizes,
+                                *_state_and_memory(state, mesh, rules))
         _REMAT_KEPT.set(gpt.remat_bytes(names, *sizes),
                         names="+".join(names))
         return gpt.GPTLMHeadModel(config, qkv_sharding=qkv_sharding,
                                   remat_names=names)
 
-    @partial(jax.jit, donate_argnums=(0, 1))
-    def step_fn(params, opt_state, ids):
-        loss, grads = jax.value_and_grad(partial(
-            gpt_step_loss, traced_model((params, opt_state), ids)))(
-                params, ids)
-        with jax.named_scope("optimizer"):
-            updates, opt_state = tx.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
-        return params, opt_state, loss
+    return _make_causal_lm_train_step(
+        mesh, optax.adam(learning_rate), rules, batch_axis, model,
+        traced_model, gpt_step_loss)
 
-    return init_fn, step_fn, batch_sharding
+
+def granite_step_loss(model, params, ids):
+    """The loss of ``make_granite_train_step``'s step: the hybrid
+    stack's final hidden states, then ``chunked_lm_loss`` with the tied
+    head's logits divided by ``logits_scaling``."""
+    from .models.gpt import chunked_lm_loss
+    from .models.granite import GraniteLMHeadModel
+    hidden, embedding = model.apply(
+        {"params": params}, ids,
+        method=GraniteLMHeadModel.hidden_and_embedding)
+    with jax.named_scope("loss"):
+        return chunked_lm_loss(
+            hidden, embedding, ids,
+            logits_scale=1.0 / model.config.logits_scaling)
+
+
+def make_granite_train_step(config, mesh, learning_rate: float = 1e-4,
+                            weight_decay: float = 0.1,
+                            fsdp: Optional[str] = None):
+    """Sharded causal-LM training step for the Granite hybrid family
+    (``models/granite.py``: Mamba-2 and grouped-query attention layers
+    in one stack), as ``make_gpt_train_step`` and through the same
+    builder.  Returns (init_fn, step_fn, batch_sharding); the rules are
+    ``granite_partition_rules``.  AdamW; the decay leaves the vectors
+    alone (norms, biases, ``A_log``, ``dt_bias``, ``D``), as the
+    published recipes do.  With ``config.remat`` every layer is
+    recomputed in the backward pass but for the flash kernels' output
+    and what ``models.granite.remat_names`` chooses of the widest
+    matmuls' outputs when the step is traced, from the batch's tokens
+    on one device, the state the step is handed and the memory the
+    mesh's device reports."""
+    from .models import granite
+    from .ops import ssd
+    from .parallel.sharding import granite_partition_rules
+
+    batch_axis = fsdp or "dp"
+    tx = optax.adamw(
+        learning_rate, weight_decay=weight_decay,
+        mask=lambda params: jax.tree.map(lambda p: p.ndim >= 2, params))
+    rules = granite_partition_rules(fsdp=fsdp)
+    heads_sharding = _heads_sharding(mesh, batch_axis)
+    model = granite.GraniteLMHeadModel(config, heads_sharding=heads_sharding)
+
+    def traced_model(state, ids):
+        """The model of this trace: with ``remat``, keeping what fits
+        beside ``state`` at ``ids``'s shape."""
+        seq = ids.shape[1]
+        sequences = ids.shape[0] // mesh.shape[batch_axis]  # on one device
+        _SSM_CHUNKS.set(ssd.chunks_of(seq, config.mamba_chunk_size)[0])
+        _SSM_SCAN_BYTES.set(ssd.scan_bytes(
+            sequences, seq,
+            config.mamba_n_heads // mesh.shape.get("tp", 1),
+            config.mamba_d_head, config.mamba_d_state,
+            config.mamba_chunk_size, np.dtype(config.dtype).itemsize))
+        for kind in (granite.MAMBA, granite.ATTENTION):
+            _HYBRID_LAYERS.set(config.layer_types.count(kind), kind=kind)
+        if not config.remat:
+            return model
+        tokens = sequences * seq
+        names = granite.remat_names(
+            tokens, config, *_state_and_memory(state, mesh, rules))
+        _GRANITE_REMAT_KEPT.set(granite.remat_bytes(names, tokens, config),
+                                names="+".join(names))
+        return granite.GraniteLMHeadModel(
+            config, heads_sharding=heads_sharding, remat_names=names)
+
+    return _make_causal_lm_train_step(
+        mesh, tx, rules, batch_axis, model, traced_model, granite_step_loss)
 
 
 def run_gpt_fsdp_dry_run(n_devices: int, batch_size: int = 8,

@@ -1,5 +1,5 @@
-"""The flash-attention kernels, the GPT step that calls them and BERT's
-dropout step compile for the chip, asked without one.
+"""The flash-attention kernels, the GPT and Granite steps that call them
+and BERT's dropout step compile for the chip, asked without one.
 
 The TPU's compiler is installed beside JAX and compiles for a v5e that
 is described, not attached (the ``on-chip-measurement`` guide, section
@@ -31,13 +31,16 @@ from jax.sharding import SingleDeviceSharding
 
 from horovod_tpu.models.bert import bert_tiny_config
 from horovod_tpu.models.gpt import gpt_tiny_config
+from horovod_tpu.models.granite import granite_tiny_config
 from horovod_tpu.ops.pallas_attention import flash_attention
 from horovod_tpu.parallel.mesh import build_mesh
 from horovod_tpu.parallel.sharding import (bert_partition_rules,
                                            gpt_partition_rules,
+                                           granite_partition_rules,
                                            infer_shardings)
 from horovod_tpu.training import (make_bert_pretrain_step,
-                                  make_gpt_train_step)
+                                  make_gpt_train_step,
+                                  make_granite_train_step)
 
 
 @pytest.fixture(scope="module")
@@ -202,3 +205,39 @@ def test_bert_dropout_bits_are_made_shard_by_shard(v5e_2x2, chips):
             2 * cfg.num_layers + 1,
         "%d,%d,%d,%d" % (per_chip, cfg.num_heads, seq, seq):
             cfg.num_layers}, made
+
+
+@pytest.mark.parametrize("axes", [{"dp": 1}, {"dp": 2, "tp": 2}],
+                         ids=["1chip", "dp2xtp2"])
+def test_granite_step_compiles_with_the_kernels_on_grouped_heads(v5e_2x2,
+                                                                 axes):
+    """``make_granite_train_step`` on a mesh of TPU devices: the three
+    flash kernels once in the attention layer and in no Mamba layer
+    (4 query heads over 2 key-value heads, repeated to the kernels'
+    equal counts; under ``tp`` shard by shard), the chunked recurrence
+    compiled as XLA's own operations under ``mamba/ssd``, and with
+    ``remat`` the forward kernel not run a second time."""
+    chips = math.prod(axes.values())
+    batch, seq = 2 * chips, 96   # six chunks of 16
+    cfg = granite_tiny_config(remat=True)
+    mesh = build_mesh(axes, v5e_2x2.devices[:chips])
+    init_fn, step_fn, batch_sharding = make_granite_train_step(cfg, mesh)
+    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
+                               sharding=batch_sharding)
+    state = jax.eval_shape(init_fn, jax.ShapeDtypeStruct((2,), jnp.uint32),
+                           ids)
+    state = jax.tree.map(
+        lambda leaf, sharding: jax.ShapeDtypeStruct(
+            leaf.shape, leaf.dtype, sharding=sharding),
+        state, infer_shardings(state, mesh, granite_partition_rules()))
+    text = step_fn.lower(*state, ids).compile().as_text()
+    kernels = Counter(re.findall(
+        r'custom_call_target="tpu_custom_call".*?'
+        r'(layer_\d+)/attention/[^"]*?/(hvd_flash_\w+)/', text))
+    assert kernels == {("layer_1", name): 1 for name in (
+        "hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv")}, kernels
+    for stage in ("intra_chunk", "chunk_states", "state_scan",
+                  "state_output"):
+        assert re.search(r"layer_0/mamba/ssd/%s" % stage, text), stage
+    assert "rematted_computation/layer_0/mamba" in text
+    assert not re.search(r"(f32|bf16)\[[0-9,]*\b%d,%d\b" % (seq, seq), text)
