@@ -158,6 +158,75 @@ def infer_shardings(tree, mesh: Mesh, rules: Rules):
     return jax.tree_util.tree_map_with_path(leaf_sharding, tree)
 
 
+# A leaf under this many elements keeps its gradient on XLA's combined
+# all-reduce and its optimizer state whole on every chip: a collective
+# costs its latency whatever it carries, and XLA sums a model's vectors
+# (biases, norms: BERT-large has 247 leaves of at most 30522 floats,
+# a thousandth of its parameters) in one operation, where an exchange a
+# leaf would be hundreds.  The smallest matrix there has 524288.
+DATA_AXIS_MIN_ELEMENTS = 1 << 16
+
+
+def shard_over_data_axis(tree, shardings, mesh: Mesh, axis: str = "dp"):
+    """From the parameters' shardings to their gradients' and their
+    optimizer moments': ``shardings`` (``infer_shardings`` of ``tree``,
+    which is the parameters or an optimizer state that mirrors them)
+    with ``axis`` added to each leaf of ``DATA_AXIS_MIN_ELEMENTS`` or
+    more, on its first dimension that the rules left free and the axis'
+    size divides (``_fit_spec``'s test).  A gradient constrained to that
+    sharding is reduce-scattered over ``axis`` where it would have been
+    all-reduced, and the optimizer's update runs on a chip's part of
+    the leaf against its part of the moments (weight-update sharding,
+    Xu et al. 2020, arXiv:2004.13336).  Every other leaf keeps its
+    sharding; on an ``axis`` of one, or a mesh without it,
+    ``shardings`` itself comes back."""
+    shards = mesh.shape.get(axis, 1)
+    if shards == 1:
+        return shardings
+
+    def with_axis(leaf, sharding):
+        if leaf.size < DATA_AXIS_MIN_ELEMENTS:
+            return sharding
+        spec = list(sharding.spec) + [None] * (leaf.ndim - len(sharding.spec))
+        for dim, (n, taken) in enumerate(zip(leaf.shape, spec)):
+            if taken is None and n % shards == 0:
+                spec[dim] = axis
+                return NamedSharding(mesh, P(*spec))
+        return sharding
+
+    return jax.tree.map(with_axis, tree, shardings)
+
+
+def gather_over_data_axis(tree, shardings, mesh: Mesh, axis: str = "dp"):
+    """``tree``, laid out by ``shardings`` (``shard_over_data_axis``'s),
+    whole again over ``axis``: an ``all_gather`` a leaf along the
+    dimension that holds ``axis``, the other leaves as they are.
+
+    Written out under ``shard_map`` (over ``axis`` alone: the mesh's
+    other axes stay GSPMD's) and not left to a sharding constraint: the
+    TPU compiler runs these gathers beside the backward pass's matmuls
+    (141 of BERT-large's 148 as ``async-collective-start`` / ``-done``
+    pairs), where it runs 109 of the gathers the partitioner writes for
+    a constraint one after another: 10.2 ms of a dp4 step for 1.5, the
+    step 148.60 ms for 143.20 (PERF.md, PR 31)."""
+    def only_axis(sharding):
+        return P(*(axis if ax == axis else None for ax in sharding.spec))
+
+    def gather(tree):
+        return jax.tree.map(
+            lambda leaf, sharding: jax.lax.all_gather(
+                leaf, axis, axis=sharding.spec.index(axis), tiled=True)
+            if axis in sharding.spec else leaf, tree, shardings)
+
+    specs = jax.tree.map(only_axis, shardings)
+    # The gathered leaves are the same on every chip by construction,
+    # which check_vma cannot see.
+    return jax.shard_map(
+        gather, mesh=mesh, in_specs=(specs,),
+        out_specs=jax.tree.map(lambda _: P(), specs), axis_names={axis},
+        check_vma=False)(tree)
+
+
 def shard_tree(tree, mesh: Mesh, rules: Rules):
     """Device-put a pytree according to the rules (for seeding initial
     state onto the mesh)."""

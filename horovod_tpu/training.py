@@ -2,10 +2,16 @@
 
 The TPU-native core training path: one jit-compiled step per model whose
 parameters, optimizer state and activations are laid out over a named
-mesh (dp / tp / sp / fsdp axes), with XLA inserting the gradient
-allreduce and tensor-parallel collectives (GSPMD).  This is what
-replaces the reference's DistributedOptimizer+NCCL pipeline at full
-performance (reference: torch/optimizer.py:110-236,
+mesh (dp / tp / sp / fsdp axes), with XLA inserting the collectives
+from the shardings (GSPMD).  The BERT step's gradient exchange over a
+``dp`` larger than one is weight-update sharding: each matrix's
+gradient reduce-scattered over ``dp``, AdamW on the chip's part of it
+against moments that live sharded, the update all-gathered and added
+to the whole parameter (``parallel.sharding.shard_over_data_axis`` says
+which leaves and along which dimension, ``gather_over_data_axis`` is
+the gather); the causal-LM steps still all-reduce.  This is
+what replaces the reference's DistributedOptimizer+NCCL pipeline at
+full performance (reference: torch/optimizer.py:110-236,
 tensorflow/__init__.py:334-381 — gradient hooks feeding allreduce); the
 drop-in per-gradient API also exists (horovod_tpu.jax) but this is the
 path that hits peak MXU/ICI utilisation.
@@ -23,8 +29,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .common import metrics
 from .models.bert import BertConfig, BertForMaskedLM, mlm_loss
-from .parallel.sharding import (bert_partition_rules, infer_shardings,
-                                Rules)
+from .parallel.sharding import (bert_partition_rules,
+                                gather_over_data_axis, infer_shardings,
+                                Rules, shard_over_data_axis)
 
 
 class TrainState(train_state.TrainState):
@@ -37,6 +44,18 @@ class TrainState(train_state.TrainState):
 # generation; libtpu's compiler alone knows it) each chip makes its
 # shard's bits from the key offset by its partition id.
 _RBG_PARTITIONED = {"xla_tpu_spmd_rng_bit_generator_unsafe": True}
+# Where a weight gradient is wanted reduce-scattered, the partitioner
+# by default splits its matmul into one part a chip and passes the
+# partial sums round a ring between the parts ("windowed einsum").  On
+# the dp4 cell that hides the exchange and costs more than it hides:
+# the same step, in one process on four chips, is 156.14 ms with the
+# windowed form and 143.20 without.  Without it the compiler's
+# reduce-scatter fusions run one after another and take 8.8 ms; with
+# it the elementwise fusions (the partial sums' additions) take 10.6
+# ms more, the waits for asynchronous copies 4.5 more and the matmuls
+# 3.1 more (PERF.md, PR 31).
+_PLAIN_REDUCE_SCATTER = {
+    "xla_tpu_enable_windowed_einsum_for_reduce_scatter": False}
 
 
 def factor_mesh_axes(n_devices: int,
@@ -75,6 +94,17 @@ def factor_mesh_axes(n_devices: int,
     return axes
 
 
+_DP_EXCHANGE_BYTES = metrics.gauge(
+    "hvd_dp_exchange_bytes",
+    "Bytes of gradients the BERT step exchanges over dp a step, by the "
+    "form each leaf takes: reduce_scatter (AdamW on a shard, the update "
+    "all-gathered) or all_reduce (set when the step is traced)")
+_DP_EXCHANGE_LEAVES = metrics.gauge(
+    "hvd_dp_exchange_leaves",
+    "Gradient leaves the BERT step exchanges over dp, by the form each "
+    "takes (set when the step is traced)")
+
+
 def make_bert_pretrain_step(
         config: BertConfig, mesh: Mesh,
         learning_rate: float = 1e-4,
@@ -102,8 +132,17 @@ def make_bert_pretrain_step(
       masks of the same distribution.  ``jax_default_prng_impl`` is not
       touched: initial weights come from the key the caller hands
       ``init_fn``, as before;
-    * gradient reduction over dp and the tp/sp collectives are inserted
-      by XLA (GSPMD) — on TPU hardware they ride ICI.
+    * on a ``dp`` larger than one each gradient of
+      ``DATA_AXIS_MIN_ELEMENTS`` or more is reduce-scattered over ``dp``
+      along a dimension the rules left free, AdamW runs on the chip's
+      part of the leaf against moments that live sharded the same way
+      (``state.opt_state``: a ``dp``-th a chip), and the update is
+      all-gathered and added: ``state.params`` stays the whole tree on
+      every chip.  The smaller leaves (biases, norms) keep XLA's
+      combined all-reduce and whole moments.  XLA (GSPMD) writes the
+      reduction and the collectives of tp / sp from the shardings, the
+      gathers are ``gather_over_data_axis``'s; on TPU hardware all of
+      it rides ICI.  On a ``dp`` of one no exchange is traced.
     """
     model = BertForMaskedLM(config)
     tx = optax.adamw(learning_rate, weight_decay=0.01)
@@ -133,16 +172,18 @@ def make_bert_pretrain_step(
         with jax.named_scope("loss"):
             return mlm_loss(logits, batch["labels"], batch["mask"])
 
-    def _step(state, batch):
-        dropout_rng = jax.random.fold_in(
-            jax.random.key(dropout_seed, impl="rbg"), state.step)
-        loss, grads = jax.value_and_grad(_loss_fn)(
-            state.params, batch, dropout_rng)
-        # Named, so that a device trace puts AdamW's fusions under a
-        # path of their own and not under the step's bare name.
-        with jax.named_scope("optimizer"):
-            new_state = state.apply_gradients(grads=grads)
-        return new_state, loss
+    def _count_exchange(grads, exchange):
+        sums = {"reduce_scatter": [0, 0], "all_reduce": [0, 0]}
+        if exchange is not None:
+            for grad, sharding in zip(jax.tree.leaves(grads),
+                                      jax.tree.leaves(exchange)):
+                form = sums["reduce_scatter" if "dp" in sharding.spec
+                            else "all_reduce"]
+                form[0] += grad.size * grad.dtype.itemsize
+                form[1] += 1
+        for form, (nbytes, leaves) in sums.items():
+            _DP_EXCHANGE_BYTES.set(nbytes, form=form)
+            _DP_EXCHANGE_LEAVES.set(leaves, form=form)
 
     # Shapes of the state determine its sharding tree; evaluate
     # abstractly so no host memory is spent.
@@ -150,6 +191,52 @@ def make_bert_pretrain_step(
         rng = jax.random.PRNGKey(0)
         abstract_state = jax.eval_shape(_init, rng, example_batch)
         state_sharding = infer_shardings(abstract_state, mesh, rules)
+        # The shardings the gradients are exchanged to; None where the
+        # step has no exchange (a dp of one).
+        exchange = shard_over_data_axis(
+            abstract_state.params, state_sharding.params, mesh)
+        if exchange is state_sharding.params:
+            exchange = None
+        else:
+            state_sharding = state_sharding.replace(
+                opt_state=shard_over_data_axis(
+                    abstract_state.opt_state, state_sharding.opt_state,
+                    mesh))
+
+        def _step(state, batch):
+            dropout_rng = jax.random.fold_in(
+                jax.random.key(dropout_seed, impl="rbg"), state.step)
+            loss, grads = jax.value_and_grad(_loss_fn)(
+                state.params, batch, dropout_rng)
+            _count_exchange(grads, exchange)
+            # Named, so that a device trace puts AdamW's fusions under a
+            # path of their own and not under the step's bare name.
+            with jax.named_scope("optimizer"):
+                if exchange is None:
+                    return state.apply_gradients(grads=grads), loss
+                # A leaf's gradient is wanted on the chip that holds
+                # that part of its moments, and nowhere else: XLA sums
+                # it as a reduce-scatter and runs AdamW on the part.
+                grads = jax.lax.with_sharding_constraint(grads, exchange)
+                updates, opt_state = tx.update(
+                    grads, state.opt_state,
+                    jax.lax.with_sharding_constraint(state.params, exchange))
+                # The update is gathered, not the new parameter: whole
+                # parameter + update is an elementwise pass XLA runs in
+                # place on the donated buffer, where a gathered
+                # parameter costs a copy of every leaf before the step
+                # (the gather that overwrites it is not ordered after
+                # its readers) and one after: 8 ms of the dp4 step.
+                updates = gather_over_data_axis(updates, exchange, mesh)
+                params = optax.apply_updates(state.params, updates)
+            return state.replace(step=state.step + 1, params=params,
+                                 opt_state=opt_state), loss
+
+        compiler_options = None
+        if on_tpu:
+            compiler_options = dict(_RBG_PARTITIONED)
+            if exchange is not None:
+                compiler_options.update(_PLAIN_REDUCE_SCATTER)
         init_fn = jax.jit(_init, out_shardings=state_sharding)
         step_fn = jax.jit(
             _step,
@@ -158,7 +245,7 @@ def make_bert_pretrain_step(
                                        example_batch)),
             out_shardings=(state_sharding, repl),
             donate_argnums=(0,) if donate else (),
-            compiler_options=_RBG_PARTITIONED if on_tpu else None)
+            compiler_options=compiler_options)
         return init_fn, step_fn
 
     return make_jitted, batch_sharding

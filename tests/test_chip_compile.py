@@ -34,8 +34,7 @@ from horovod_tpu.models.gpt import gpt_tiny_config
 from horovod_tpu.models.granite import granite_tiny_config
 from horovod_tpu.ops.pallas_attention import flash_attention
 from horovod_tpu.parallel.mesh import build_mesh
-from horovod_tpu.parallel.sharding import (bert_partition_rules,
-                                           gpt_partition_rules,
+from horovod_tpu.parallel.sharding import (gpt_partition_rules,
                                            granite_partition_rules,
                                            infer_shardings)
 from horovod_tpu.training import (make_bert_pretrain_step,
@@ -176,6 +175,21 @@ def test_gpt_step_runs_the_kernels_in_every_layer(v5e_2x2, axes, fsdp,
                          text)
 
 
+def _bert_step_text(cfg, mesh, per_chip, seq):
+    """The TPU compiler's text of ``make_bert_pretrain_step``'s step
+    for ``mesh``, its state laid out as ``make_jitted`` lays it out
+    (``eval_shape`` of the jitted ``init_fn`` carries its
+    ``out_shardings``)."""
+    make_jitted, batch_sharding = make_bert_pretrain_step(cfg, mesh)
+    batch = {name: jax.ShapeDtypeStruct((per_chip * mesh.shape["dp"], seq),
+                                        jnp.int32, sharding=batch_sharding)
+             for name in ("input_ids", "labels", "mask")}
+    init_fn, step_fn = make_jitted(batch)
+    state = jax.eval_shape(
+        init_fn, jax.ShapeDtypeStruct((2,), jnp.uint32), batch)
+    return step_fn.lower(state, batch).compile().as_text()
+
+
 @pytest.mark.parametrize("chips", [1, 4])
 def test_bert_dropout_bits_are_made_shard_by_shard(v5e_2x2, chips):
     """The TPU compiler's own text of the tiny BERT step with dropout:
@@ -185,18 +199,7 @@ def test_bert_dropout_bits_are_made_shard_by_shard(v5e_2x2, chips):
     per_chip, seq = 8, 128
     cfg = bert_tiny_config(hidden_dropout=0.1, attention_dropout=0.1)
     mesh = build_mesh({"dp": chips}, v5e_2x2.devices[:chips])
-    make_jitted, batch_sharding = make_bert_pretrain_step(cfg, mesh)
-    batch = {name: jax.ShapeDtypeStruct((per_chip * chips, seq), jnp.int32,
-                                        sharding=batch_sharding)
-             for name in ("input_ids", "labels", "mask")}
-    init_fn, step_fn = make_jitted(batch)
-    state = jax.eval_shape(
-        init_fn, jax.ShapeDtypeStruct((2,), jnp.uint32), batch)
-    state = jax.tree.map(
-        lambda leaf, sharding: jax.ShapeDtypeStruct(
-            leaf.shape, leaf.dtype, sharding=sharding),
-        state, infer_shardings(state, mesh, bert_partition_rules(tp=None)))
-    text = step_fn.lower(state, batch).compile().as_text()
+    text = _bert_step_text(cfg, mesh, per_chip, seq)
 
     made = Counter(re.findall(
         r"= u32\[([0-9,]+)\]\S* rng-bit-generator\(", text))
@@ -205,6 +208,56 @@ def test_bert_dropout_bits_are_made_shard_by_shard(v5e_2x2, chips):
             2 * cfg.num_layers + 1,
         "%d,%d,%d,%d" % (per_chip, cfg.num_heads, seq, seq):
             cfg.num_layers}, made
+
+
+def test_bert_dp_exchange_updates_a_shard_and_gathers(v5e_2x2):
+    """One layer of BERT-large's widths at the dp4 cell's 64 x 128 a
+    chip, compiled for four chips.  AdamW's moments of every matrix
+    arrive a quarter the leaf's size; the FFN matrices' gradients
+    (two thirds of a layer's bytes) are never all-reduced whole: the
+    compiler sums them in its reduce-scatter fusions (an all-reduce of
+    a padded shape inside a custom fusion); every matrix's update comes
+    back by an ``all-gather``, some of them beside a matmul as
+    ``async-collective-start`` / ``-done`` pairs (141 of 148 in the
+    whole model); and no weight-gradient matmul is split into a part a
+    chip with ``collective-permute``s between them, which the step's
+    compiler option turns off.  What is still all-reduced is the
+    vectors and what the partitioner chooses to all-reduce and slice:
+    the attention projections' 2 MB gradients and, at the tiny
+    vocabulary of 512, the embedding's."""
+    cfg = bert_tiny_config(hidden_size=1024, intermediate_size=4096,
+                           num_heads=16, num_layers=1, hidden_dropout=0.1,
+                           attention_dropout=0.1)
+    mesh = build_mesh({"dp": 4}, v5e_2x2.devices[:4])
+    text = _bert_step_text(cfg, mesh, 64, 128)
+
+    def shapes(opcode):
+        """Array shapes in the results of ``opcode``s (an all-reduce
+        XLA has combined has a tuple of them)."""
+        found = Counter()
+        for result in re.findall(
+                r"^\s*(?:ROOT )?%%?[\w.\-]+ = (.*?) %s\(" % opcode, text,
+                re.M):
+            found.update(re.findall(r"\w+\[([0-9,]+)\]", result))
+        return found
+
+    matrices = {"1024,16,64": 3, "16,64,1024": 1, "1024,4096": 1,
+                "4096,1024": 1, "1024,1024": 1}
+    reduced, gathered = shapes("all-reduce"), shapes("all-gather")
+    assert not {"1024,4096", "4096,1024"} & set(reduced), reduced
+    assert all(gathered[shape] >= n for shape, n in matrices.items()), \
+        gathered
+    assert len(re.findall(r"%async-collective-start[.\d]* = ", text)) >= 2
+    entry = text[text.index("\nENTRY "):]
+    quarters = Counter(re.findall(r"= f32\[([0-9,]+)\]\S* parameter\(",
+                                  entry))
+    assert quarters["256,4096"] == 2 and quarters["1024,1024"] == 2 + 1, \
+        quarters   # mu and nu of intermediate / of output, and mlm_transform
+    # A windowed weight gradient moves 256 columns a hop; what is left
+    # is the reduce-scatter fusions' own few rows.
+    assert all(int(shape.split(",")[0]) < 64
+               for shape in shapes("collective-permute-start")), \
+        shapes("collective-permute-start")
 
 
 @pytest.mark.parametrize("axes", [{"dp": 1}, {"dp": 2, "tp": 2}],
