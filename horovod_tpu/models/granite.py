@@ -149,12 +149,13 @@ def _a_log_init(key, shape, dtype=jnp.float32):
 def causal_depthwise_conv(x, kernel, bias):
     """``out_t = bias + sum_k kernel[k] * x_{t - (K - 1) + k}`` per
     channel, zeros before the sequence.  ``x``: ``[B, S, C]``;
-    ``kernel``: ``[K, C]``."""
+    ``kernel``: ``[K, C]``; ``bias``: ``[C]``, or None for none."""
     taps, seq = kernel.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-    out = bias.astype(x.dtype)
+    out = None if bias is None else bias.astype(x.dtype)
     for k in range(taps):
-        out = out + padded[:, k:k + seq] * kernel[k].astype(x.dtype)
+        tap = padded[:, k:k + seq] * kernel[k].astype(x.dtype)
+        out = tap if out is None else out + tap
     return out
 
 

@@ -1,18 +1,43 @@
-"""Expert parallelism: Switch-style top-1 MoE dispatch over a mesh axis.
+"""Expert layers.  Two live here and they share nothing yet
+(ROADMAP.md, Queue 3, D18):
 
-The reference's ``alltoall`` collective exists for exactly this workload
-(SURVEY §2.3 EP row: "alltoall again the relevant primitive"); here the
-full dispatch-compute-combine runs in-graph: capacity-bucketed one-hot
-dispatch → ``lax.all_to_all`` to the expert owners → expert FFN →
-``all_to_all`` back → gate-weighted combine.  One expert per ``ep``-axis
-device; tokens over capacity are dropped (standard Switch semantics).
+* :func:`routed_experts`: top-k routed experts of which this device
+  holds a run, dropless: sigmoid scores over ALL experts, the (token,
+  expert) pairs whose expert is held sorted by expert, gathered, run
+  through grouped matrix products (``jax.lax.ragged_dot``, which the
+  TPU compiler lowers to a Mosaic kernel over the groups' tiles) and
+  added back into their tokens.  No capacity and no exchange: on one
+  chip of an expert-parallel group it computes this chip's part of
+  the layer.
+* :func:`moe_ffn` with :func:`top1_dispatch`: Switch-style top-1
+  dispatch over a mesh axis.  The reference's ``alltoall`` collective
+  exists for exactly this workload (SURVEY §2.3 EP row: "alltoall again
+  the relevant primitive"); here the full dispatch-compute-combine runs
+  in-graph: capacity-bucketed one-hot dispatch → ``lax.all_to_all`` to
+  the expert owners → expert FFN → ``all_to_all`` back → gate-weighted
+  combine.  One expert per ``ep``-axis device; tokens over capacity are
+  dropped (standard Switch semantics).
 """
 
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+# What a recomputed layer may keep of :func:`routed_experts`, by
+# ``checkpoint_name``: the sorted rows (or they are gathered again) and
+# the two first products' outputs (or those products run twice).
+ROWS_NAME, EXPERT_GATE_UP_NAME = "moe_rows", "moe_gate_up"
+# And what it MUST keep: the choice.  A recomputed forward pass is
+# another program than the first (other fusions, other roundings of the
+# router's input), and a near-tie it decides the other way sends the
+# backward pass's cotangents through experts the forward pass never
+# ran: one such token of 128 moved a tiny layer's gradients by 130 %.
+CHOICE_NAME = "moe_chosen"
+# Added to the sum of the chosen gates before they are divided by it.
+GATE_SUM_EPS = 1e-6
 
 
 def top1_dispatch(gate_logits: jax.Array, capacity: int):
@@ -76,3 +101,200 @@ def moe_ffn(x: jax.Array, gate_w: jax.Array, expert_fn: Callable,
     y = jnp.einsum("tec,ecd->td", combine,
                    returned.astype(jnp.float32))
     return y.astype(x.dtype), lax.pmean(aux, axis_name)
+
+
+class Routing(NamedTuple):
+    """What the router decided for ``T`` tokens: the ``top_k`` experts
+    each chose, of ALL experts, and the gate of each."""
+    chosen: jax.Array   # [T, top_k] int32
+    gates: jax.Array    # [T, top_k] float32
+
+
+def sigmoid_top_k(x: jax.Array, router_kernel: jax.Array,
+                  selection_bias: jax.Array, top_k: int,
+                  normalize: bool = True, scale: float = 1.0) -> Routing:
+    """Scores ``s = sigmoid(x @ W_r)`` over all experts; the ``top_k``
+    of ``s + selection_bias`` are chosen (the bias selects and no
+    gradient reaches it); a chosen expert's gate is its ``s``, divided
+    by the chosen gates' sum where ``normalize``, times ``scale``.
+    All of it in float32 whatever ``x``'s type, the product at full
+    precision: it is 64 columns wide, and a choice is a comparison."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router_kernel.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    _, chosen = lax.top_k(scores + lax.stop_gradient(selection_bias), top_k)
+    chosen = checkpoint_name(chosen, CHOICE_NAME)
+    gates = jnp.take_along_axis(scores, chosen, axis=-1)
+    if normalize:
+        gates = gates / (gates.sum(-1, keepdims=True) + GATE_SUM_EPS)
+    return Routing(chosen.astype(jnp.int32), gates * scale)
+
+
+def dispatch_rows(tokens: int, top_k: int, held: int) -> int:
+    """Rows of the sorted buffer one layer walks: as many pairs as the
+    routing can give the experts held, every token choosing them alone.
+    Not a capacity: no routing overflows it."""
+    return tokens * min(top_k, held)
+
+
+def dispatch_bytes(tokens: int, hidden: int, width: int, top_k: int,
+                   held: int, itemsize: int) -> int:
+    """Bytes one layer's forward materialises on one device between the
+    router and the combine: the choice, the gates and each pair's place
+    in the sort [T, k], each row's token and gate [R], the groups'
+    sizes; the sorted rows [R, hidden]; gate, up and their gated
+    product [R, width]; the experts' output [R, hidden]."""
+    rows = dispatch_rows(tokens, top_k, held)
+    indices = tokens * top_k * (4 + 4 + 4) + rows * (4 + 4) + held * 4
+    return indices + itemsize * rows * (2 * hidden + 3 * width)
+
+
+class Plan(NamedTuple):
+    """The dispatch's plan for ``T`` tokens and ``R`` rows
+    (:func:`dispatch_rows`), integers all.  All ``T x top_k`` pairs are
+    sorted by expert, stably (by token within an expert), those whose
+    expert is not held after every pair that is; the first ``R`` places
+    are the row buffer."""
+    token: jax.Array        # [R] int32: the token of a row; 0 past the pairs
+    valid: jax.Array        # [R] bool: the row holds a pair
+    group_sizes: jax.Array  # [held] int32: rows of each expert held
+    place: jax.Array        # [T, top_k] int32: where the sort put a pair
+    is_held: jax.Array      # [T, top_k] bool: its expert is held here
+
+
+def held_pairs(routing: Routing, first_expert: int, held: int):
+    """Of the ``T x top_k`` pairs, those whose expert lies in
+    ``[first_expert, first_expert + held)``, sorted by expert:
+    ``(Plan, row_gate)``, ``row_gate`` ``[R]`` the gate of each row's
+    pair and 0 past the pairs (a gather of ``routing.gates``, through
+    which their gradient comes)."""
+    tokens, top_k = routing.chosen.shape
+    rows = dispatch_rows(tokens, top_k, held)
+    local = routing.chosen.reshape(-1) - first_expert
+    is_held = (local >= 0) & (local < held)
+    key = jnp.where(is_held, local, held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    place = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.size, dtype=jnp.int32), unique_indices=True)
+    order = order[:rows]
+    valid = jnp.take(is_held, order)
+    plan = Plan(
+        token=jnp.where(valid, order // top_k, 0), valid=valid,
+        group_sizes=jnp.bincount(key, length=held + 1)[:held].astype(
+            jnp.int32),
+        place=place.reshape(tokens, top_k),
+        is_held=is_held.reshape(tokens, top_k))
+    row_gate = jnp.where(
+        valid, routing.gates.reshape(-1).at[order].get(unique_indices=True),
+        0.0)
+    return plan, row_gate
+
+
+def _rows_of_pairs(buffer, plan: Plan):
+    """``[T, top_k, D]``: each pair's row of ``buffer`` ``[R, D]``, zeros
+    for a pair that has none.  A gather over places no two pairs share;
+    a pair not held lies past the pairs held, where the buffer holds
+    whatever the products left, or past the buffer."""
+    got = buffer.at[plan.place].get(mode="fill", fill_value=0,
+                                    unique_indices=True)
+    return jnp.where(plan.is_held[..., None], got, 0)
+
+
+def _pairs_of_tokens(per_token, plan: Plan):
+    """``[R, D]``: for each row its token's row of ``per_token`` ``[T,
+    D]``, zeros past the pairs."""
+    return jnp.where(plan.valid[:, None],
+                     jnp.take(per_token, plan.token, axis=0), 0)
+
+
+# Dispatch and combine are each a gather, forward and backward, and
+# each other's transpose: left to autodiff the transposes are
+# scatter-adds in which up to ``top_k`` rows, and every row past the
+# pairs, meet in one token.
+
+@jax.custom_vjp
+def _dispatch(x, plan: Plan):
+    """``rows[r] = x[token[r]]``."""
+    return _pairs_of_tokens(x, plan)
+
+
+def _dispatch_fwd(x, plan):
+    return _dispatch(x, plan), plan
+
+
+def _dispatch_bwd(plan, d_rows):
+    return _combine(d_rows, plan), None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(out, plan: Plan):
+    """``y[t] = sum of out[place] over t's pairs held``, in float32."""
+    return _rows_of_pairs(out, plan).astype(jnp.float32).sum(1).astype(
+        out.dtype)
+
+
+def _combine_fwd(out, plan):
+    return _combine(out, plan), plan
+
+
+def _combine_bwd(plan, d_y):
+    return _dispatch(d_y, plan), None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def routed_experts(x: jax.Array, router_kernel: jax.Array,
+                   selection_bias: jax.Array, gate_kernels: jax.Array,
+                   up_kernels: jax.Array, down_kernels: jax.Array, *,
+                   first_expert: int, top_k: int, normalize: bool = True,
+                   scale: float = 1.0):
+    """Top-k routed SwiGLU experts, the part of the layer that the
+    experts HELD give: ``y_t = sum over the chosen e held of g_te *
+    expert_e(x_t)``, ``expert_e(x) = (silu(x W1_e) * (x W3_e)) W2_e``.
+
+    ``x``: ``[T, D]``.  ``router_kernel`` ``[D, E]`` and
+    ``selection_bias`` ``[E]`` are over ALL ``E`` experts; the stacked
+    ``gate_kernels`` and ``up_kernels`` ``[held, D, F]`` and
+    ``down_kernels`` ``[held, F, D]`` are those of experts
+    ``first_expert .. first_expert + held - 1``.  What an expert held
+    elsewhere would add is left out, and nothing stands in for it.
+
+    No pair is dropped, by construction: the row buffer is as long as
+    the routing can make it (:func:`dispatch_rows`), so its size grows
+    with ``T x top_k`` and not with ``T x E``.  Router, choice and
+    gates are float32; the products take ``x``'s type and accumulate
+    in float32.  A pair's gate multiplies its row BEFORE the last
+    product (the same sum), so that the gates' gradient needs the
+    gated product ``[R, F]``, which the backward pass has, and not the
+    experts' output ``[R, D]``, which it would compute again.
+    Returns ``(y [T, D], Routing)``."""
+    held = gate_kernels.shape[0]
+    dtype = x.dtype
+    with jax.named_scope("router"):
+        routing = sigmoid_top_k(x, router_kernel, selection_bias, top_k,
+                                normalize, scale)
+    with jax.named_scope("dispatch"):
+        plan, row_gate = held_pairs(routing, first_expert, held)
+        rows = checkpoint_name(_dispatch(x, plan), ROWS_NAME)
+    with jax.named_scope("experts"):
+        def grouped(lhs, kernels):
+            return lax.ragged_dot(lhs, kernels.astype(dtype),
+                                  plan.group_sizes,
+                                  preferred_element_type=dtype)
+        a = checkpoint_name(grouped(rows, gate_kernels), EXPERT_GATE_UP_NAME)
+        b = checkpoint_name(grouped(rows, up_kernels), EXPERT_GATE_UP_NAME)
+        # A row past the groups' ends holds whatever the products left
+        # there, which need not be finite: it is masked BEFORE the
+        # nonlinearity, so that neither its value nor its derivative
+        # times a zero cotangent can be nan.
+        keep = plan.valid[:, None]
+        gated = jax.nn.silu(jnp.where(keep, a, 0)) * jnp.where(keep, b, 0)
+        gated = gated.astype(jnp.float32) * row_gate[:, None]
+        out = grouped(gated.astype(dtype), down_kernels)
+    with jax.named_scope("combine"):
+        y = _combine(out, plan)
+    return y, routing

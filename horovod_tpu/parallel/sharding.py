@@ -86,6 +86,34 @@ def granite_partition_rules(tp: str = "tp",
     ]
 
 
+def lfm2_partition_rules(tp: str = "tp", fsdp: Optional[str] = None,
+                         ep: str = "ep") -> Rules:
+    """Sharding for the LFM2-MoE family (models/lfm2.py).  Over ``tp``:
+    attention over its heads (``tp`` must divide the key-value heads),
+    the dense SwiGLU and every expert over their columns, the
+    convolution operator's output projection over its rows; its input
+    projection stays whole (its columns are the three runs B, C, x,
+    which an even split does not follow).  The stacked experts' leading
+    axis lies on ``ep`` where the mesh has one; the router, over all
+    experts, is whole everywhere.  No exchange is written for ``ep``
+    yet: GSPMD gathers what ``routed_experts`` reads (ROADMAP.md,
+    Queue 2).  The tied head inherits the embedding's vocabulary
+    sharding."""
+    f = fsdp
+    return [
+        (r"word_embeddings/embedding$", P(tp, f)),
+        (r"attention/(query|key|value)/kernel$", P(f, tp, None)),
+        (r"attention/out/kernel$", P(tp, None, f)),
+        (r"mlp/(gate|up)/kernel$", P(f, tp)),
+        (r"mlp/out/kernel$", P(tp, f)),
+        (r"conv/in_proj/kernel$", P(f, None)),
+        (r"conv/out_proj/kernel$", P(tp, f)),
+        (r"moe/(gate|up)$", P(ep, f, tp)),
+        (r"moe/down$", P(ep, tp, f)),
+        (r".*", P()),  # norms, taps, the router and its bias replicated
+    ]
+
+
 def resnet_partition_rules(fsdp: Optional[str] = None) -> Rules:
     """ResNet is pure data parallel (conv kernels are small); optionally
     ZeRO-shard the dense head."""

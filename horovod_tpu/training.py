@@ -430,8 +430,32 @@ _SSM_SCAN_BYTES = metrics.gauge(
     "layer's forward pass on one device (set when the step is traced)")
 _HYBRID_LAYERS = metrics.gauge(
     "hvd_hybrid_layers",
-    "Layers of the Granite step's stack, by kind (set when the step is "
+    "Layers of a hybrid step's stack, by kind (set when the step is "
     "traced)")
+_LFM2_REMAT_KEPT = metrics.gauge(
+    "hvd_lfm2_remat_kept_bytes",
+    "Bytes one device keeps across the LFM2 step's remat, by the names "
+    "kept (set when the step is traced)")
+_MOE_EXPERTS = metrics.gauge(
+    "hvd_moe_experts",
+    "Routed experts of a sparse layer: which=total the router's width, "
+    "which=held those one device computes (set when the step is traced)")
+_MOE_TOP_K = metrics.gauge(
+    "hvd_moe_top_k",
+    "Experts a token chooses in a sparse layer (set when the step is "
+    "traced)")
+_MOE_DISPATCH_ROWS = metrics.gauge(
+    "hvd_moe_dispatch_rows",
+    "Rows of the sorted buffer one sparse layer walks on one device "
+    "(set when the step is traced)")
+_MOE_DISPATCH_BYTES = metrics.gauge(
+    "hvd_moe_dispatch_bytes",
+    "Bytes one sparse layer's forward pass materialises on one device "
+    "between the router and the combine (set when the step is traced)")
+MOE_PAIRS_HELD = metrics.gauge(
+    "hvd_moe_pairs_held",
+    "Pairs of token and expert that fell on the experts held, of one "
+    "batch, by layer (set by whoever counts a batch's choices)")
 
 
 def _make_causal_lm_train_step(mesh, tx, rules: Rules, batch_axis: str,
@@ -598,6 +622,72 @@ def make_granite_train_step(config, mesh, learning_rate: float = 1e-4,
 
     return _make_causal_lm_train_step(
         mesh, tx, rules, batch_axis, model, traced_model, granite_step_loss)
+
+
+def lfm2_step_loss(model, params, ids):
+    """The loss of ``make_lfm2_train_step``'s step: the stack's final
+    hidden states, then ``chunked_lm_loss`` over the tied head."""
+    from .models.gpt import chunked_lm_loss
+    from .models.lfm2 import LFM2LMHeadModel
+    hidden, embedding = model.apply(
+        {"params": params}, ids,
+        method=LFM2LMHeadModel.hidden_and_embedding)
+    with jax.named_scope("loss"):
+        return chunked_lm_loss(hidden, embedding, ids)
+
+
+def make_lfm2_train_step(config, mesh, learning_rate: float = 1e-4,
+                         weight_decay: float = 0.1,
+                         fsdp: Optional[str] = None):
+    """Sharded causal-LM training step for the LFM2-MoE family
+    (``models/lfm2.py``: gated short convolutions and rotary
+    grouped-query attention, a dense SwiGLU or top-k routed experts a
+    layer), as ``make_granite_train_step`` and through the same
+    builder.  Returns (init_fn, step_fn, batch_sharding); the rules are
+    ``lfm2_partition_rules``.  AdamW with decay on matrices only (the
+    stacked experts and the convolution's taps among them; the norms
+    and the selection bias are vectors, and the bias gets no gradient,
+    so nothing moves it).
+    With ``config.remat`` every layer is recomputed in the backward pass
+    but for the flash kernels' output and what
+    ``models.lfm2.remat_names`` chooses when the step is traced."""
+    from .models import lfm2
+    from .parallel import moe
+    from .parallel.sharding import lfm2_partition_rules
+
+    batch_axis = fsdp or "dp"
+    tx = optax.adamw(
+        learning_rate, weight_decay=weight_decay,
+        mask=lambda params: jax.tree.map(lambda p: p.ndim >= 2, params))
+    rules = lfm2_partition_rules(fsdp=fsdp)
+    heads_sharding = _heads_sharding(mesh, batch_axis)
+    model = lfm2.LFM2LMHeadModel(config, heads_sharding=heads_sharding)
+
+    def traced_model(state, ids):
+        """The model of this trace: with ``remat``, keeping what fits
+        beside ``state`` at ``ids``'s shape."""
+        tokens = ids.size // mesh.shape[batch_axis]   # on one device
+        held, top_k = config.experts_held, config.num_experts_per_tok
+        _MOE_EXPERTS.set(config.num_experts, which="total")
+        _MOE_EXPERTS.set(held, which="held")
+        _MOE_TOP_K.set(top_k)
+        _MOE_DISPATCH_ROWS.set(moe.dispatch_rows(tokens, top_k, held))
+        _MOE_DISPATCH_BYTES.set(moe.dispatch_bytes(
+            tokens, config.hidden_size, config.moe_intermediate_size, top_k,
+            held, np.dtype(config.dtype).itemsize))
+        for kind in (lfm2.CONV, lfm2.ATTENTION):
+            _HYBRID_LAYERS.set(config.layer_types.count(kind), kind=kind)
+        if not config.remat:
+            return model
+        names = lfm2.remat_names(
+            tokens, config, *_state_and_memory(state, mesh, rules))
+        _LFM2_REMAT_KEPT.set(lfm2.remat_bytes(names, tokens, config),
+                             names="+".join(names))
+        return lfm2.LFM2LMHeadModel(
+            config, heads_sharding=heads_sharding, remat_names=names)
+
+    return _make_causal_lm_train_step(
+        mesh, tx, rules, batch_axis, model, traced_model, lfm2_step_loss)
 
 
 def run_gpt_fsdp_dry_run(n_devices: int, batch_size: int = 8,

@@ -32,14 +32,17 @@ from jax.sharding import SingleDeviceSharding
 from horovod_tpu.models.bert import bert_tiny_config
 from horovod_tpu.models.gpt import gpt_tiny_config
 from horovod_tpu.models.granite import granite_tiny_config
+from horovod_tpu.models.lfm2 import lfm2_tiny_config
 from horovod_tpu.ops.pallas_attention import flash_attention
 from horovod_tpu.parallel.mesh import build_mesh
 from horovod_tpu.parallel.sharding import (gpt_partition_rules,
                                            granite_partition_rules,
-                                           infer_shardings)
+                                           infer_shardings,
+                                           lfm2_partition_rules)
 from horovod_tpu.training import (make_bert_pretrain_step,
                                   make_gpt_train_step,
-                                  make_granite_train_step)
+                                  make_granite_train_step,
+                                  make_lfm2_train_step)
 
 
 @pytest.fixture(scope="module")
@@ -294,3 +297,49 @@ def test_granite_step_compiles_with_the_kernels_on_grouped_heads(v5e_2x2,
         assert re.search(r"layer_0/mamba/ssd/%s" % stage, text), stage
     assert "rematted_computation/layer_0/mamba" in text
     assert not re.search(r"(f32|bf16)\[[0-9,]*\b%d,%d\b" % (seq, seq), text)
+
+
+@pytest.mark.parametrize("axes", [{"dp": 1}, {"dp": 2, "tp": 2}],
+                         ids=["1chip", "dp2xtp2"])
+def test_lfm2_step_compiles_with_grouped_products_and_the_kernels(v5e_2x2,
+                                                                  axes):
+    """``make_lfm2_train_step`` on a mesh of TPU devices: the three
+    flash kernels once, in the attention layer, on normed and rotated
+    heads; the experts' products compiled from ``ragged_dot`` under
+    ``moe/experts`` in both sparse layers, forward and backward; the
+    routers' ``top_k`` once a sparse layer, not again in the recomputed
+    pass; no one-hot ``[T, E, C]`` array."""
+    chips = math.prod(axes.values())
+    batch, seq = 2 * chips, 128
+    cfg = lfm2_tiny_config(remat=True)
+    mesh = build_mesh(axes, v5e_2x2.devices[:chips])
+    init_fn, step_fn, batch_sharding = make_lfm2_train_step(cfg, mesh)
+    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
+                               sharding=batch_sharding)
+    state = jax.eval_shape(init_fn, jax.ShapeDtypeStruct((2,), jnp.uint32),
+                           ids)
+    state = jax.tree.map(
+        lambda leaf, sharding: jax.ShapeDtypeStruct(
+            leaf.shape, leaf.dtype, sharding=sharding),
+        state, infer_shardings(state, mesh, lfm2_partition_rules()))
+    text = step_fn.lower(*state, ids).compile().as_text()
+    kernels = Counter(re.findall(
+        r'custom_call_target="tpu_custom_call".*?'
+        r'(layer_\d+)/attention/[^"]*?/(hvd_flash_\w+)/', text))
+    assert kernels == {("layer_1", name): 1 for name in (
+        "hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv")}, kernels
+    # On one chip each grouped product is the compiler's own Mosaic
+    # kernel (which names itself and drops the module path): in each
+    # sparse layer three forward and six backward, none a second time.
+    # Under ``tp`` GSPMD partitions ``ragged_dot`` first and the
+    # products keep their path.
+    grouped = text.count('op_name="ragged-dot-none"')
+    if chips == 1:
+        assert grouped == 2 * 9, grouped
+    else:
+        assert grouped or re.search(r"layer_1/moe/experts/[^\"]*ragged_dot",
+                                    text)
+    assert len(set(re.findall(r"(layer_\d)/moe/router/[^\"]*top_k",
+                              text))) == 2
+    assert not re.search(r"rematted_computation/[^\"]*moe/router/[^\"]*top_k",
+                         text)

@@ -185,8 +185,8 @@ def test_gauges_show_in_the_metrics_snapshot():
     # one device's share: 2 of 4 sequences, 4 of 8 heads
     assert gauges["hvd_ssm_scan_bytes"] == ssd.scan_bytes(
         2, 64, 4, cfg.mamba_d_head, cfg.mamba_d_state, 16, 4)
-    assert gauges["hvd_hybrid_layers"] == {"kind=mamba": 2.0,
-                                           "kind=attention": 1.0}
+    layers = gauges["hvd_hybrid_layers"]   # other families set other kinds
+    assert (layers["kind=mamba"], layers["kind=attention"]) == (2.0, 1.0)
 
 
 # The benchmark's cell: 8192 tokens, one period at the published
