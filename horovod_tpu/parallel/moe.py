@@ -8,7 +8,13 @@
   TPU compiler lowers to a Mosaic kernel over the groups' tiles) and
   added back into their tokens.  No capacity and no exchange: on one
   chip of an expert-parallel group it computes this chip's part of
-  the layer.
+  the layer.  The sorted buffer is as long as the routing can fill it;
+  the pairs held are a prefix of it, its length known on the device
+  when the plan is made, and that is what says which rows hold a pair
+  and how far the rows' gates are gathered (:func:`_gates_of_rows`).  The wide
+  passes (the rows' gather, the gated product, their backward) walk
+  the whole buffer: cut into chunks inside a loop they cost on the v5e
+  what they saved (``PERF.md``, PR 33).
 * :func:`moe_ffn` with :func:`top1_dispatch`: Switch-style top-1
   dispatch over a mesh axis.  The reference's ``alltoall`` collective
   exists for exactly this workload (SURVEY §2.3 EP row: "alltoall again
@@ -38,6 +44,10 @@ ROWS_NAME, EXPERT_GATE_UP_NAME = "moe_rows", "moe_gate_up"
 CHOICE_NAME = "moe_chosen"
 # Added to the sum of the chosen gates before they are divided by it.
 GATE_SUM_EPS = 1e-6
+# Rows of the sorted buffer a walk (:func:`_gates_of_rows`) takes at a
+# time: it stops at the first chunk that starts past the pairs.  A
+# multiple of the grouped kernel's 512-row tile.
+WALK_CHUNK_ROWS = 2048
 
 
 def top1_dispatch(gate_logits: jax.Array, capacity: int):
@@ -131,10 +141,18 @@ def sigmoid_top_k(x: jax.Array, router_kernel: jax.Array,
 
 
 def dispatch_rows(tokens: int, top_k: int, held: int) -> int:
-    """Rows of the sorted buffer one layer walks: as many pairs as the
-    routing can give the experts held, every token choosing them alone.
-    Not a capacity: no routing overflows it."""
+    """Length of one layer's sorted buffer: as many pairs as the routing
+    can give the experts held, every token choosing them alone.  Not a
+    capacity: no routing overflows it; and not what a walk touches
+    (:func:`rows_walked`)."""
     return tokens * min(top_k, held)
+
+
+def rows_walked(pairs: int, rows: int) -> int:
+    """Rows of a buffer of ``rows`` that a walk touches where
+    ``pairs`` of them hold a pair: the chunks of ``WALK_CHUNK_ROWS``
+    that start before the pairs end."""
+    return min(rows, -(-pairs // WALK_CHUNK_ROWS) * WALK_CHUNK_ROWS)
 
 
 def dispatch_bytes(tokens: int, hidden: int, width: int, top_k: int,
@@ -166,8 +184,8 @@ def held_pairs(routing: Routing, first_expert: int, held: int):
     """Of the ``T x top_k`` pairs, those whose expert lies in
     ``[first_expert, first_expert + held)``, sorted by expert:
     ``(Plan, row_gate)``, ``row_gate`` ``[R]`` the gate of each row's
-    pair and 0 past the pairs (a gather of ``routing.gates``, through
-    which their gradient comes)."""
+    pair and 0 past the pairs (a gather of ``routing.gates`` as far as
+    the pairs go, through which their gradient comes)."""
     tokens, top_k = routing.chosen.shape
     rows = dispatch_rows(tokens, top_k, held)
     local = routing.chosen.reshape(-1) - first_expert
@@ -177,27 +195,31 @@ def held_pairs(routing: Routing, first_expert: int, held: int):
     place = jnp.zeros_like(order).at[order].set(
         jnp.arange(order.size, dtype=jnp.int32), unique_indices=True)
     order = order[:rows]
-    valid = jnp.take(is_held, order)
+    group_sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    # The pairs held are sorted first: they are the rows before their
+    # count, and no gather has to ask.
+    valid = jnp.arange(rows) < group_sizes.sum()
     plan = Plan(
         token=jnp.where(valid, order // top_k, 0), valid=valid,
-        group_sizes=jnp.bincount(key, length=held + 1)[:held].astype(
-            jnp.int32),
-        place=place.reshape(tokens, top_k),
+        group_sizes=group_sizes, place=place.reshape(tokens, top_k),
         is_held=is_held.reshape(tokens, top_k))
-    row_gate = jnp.where(
-        valid, routing.gates.reshape(-1).at[order].get(unique_indices=True),
-        0.0)
-    return plan, row_gate
+    return plan, _gates_of_rows(routing.gates, order, plan)
 
 
 def _rows_of_pairs(buffer, plan: Plan):
-    """``[T, top_k, D]``: each pair's row of ``buffer`` ``[R, D]``, zeros
-    for a pair that has none.  A gather over places no two pairs share;
-    a pair not held lies past the pairs held, where the buffer holds
-    whatever the products left, or past the buffer."""
-    got = buffer.at[plan.place].get(mode="fill", fill_value=0,
-                                    unique_indices=True)
-    return jnp.where(plan.is_held[..., None], got, 0)
+    """``[top_k, T, ...]``: each pair's row of ``buffer`` ``[R, ...]``,
+    zeros for a pair that has none.  A gather over places no two pairs
+    share; a pair not held lies past the pairs held, where the buffer
+    holds whatever the products left, or past the buffer.  The pairs of
+    one token lie ``T`` rows apart, so that a sum over them runs over
+    the major axis: as ``[T, top_k, D]`` the compiler lays ``top_k``
+    into the tiles' eight sublanes and copies the gather's output to
+    get it there (0.45 ms a gather of 32768 rows on the v5e)."""
+    got = buffer.at[plan.place.T].get(mode="fill", fill_value=0,
+                                      unique_indices=True)
+    is_held = plan.is_held.T
+    return jnp.where(is_held.reshape(is_held.shape + (1,) * (got.ndim - 2)),
+                     got, 0)
 
 
 def _pairs_of_tokens(per_token, plan: Plan):
@@ -205,6 +227,52 @@ def _pairs_of_tokens(per_token, plan: Plan):
     D]``, zeros past the pairs."""
     return jnp.where(plan.valid[:, None],
                      jnp.take(per_token, plan.token, axis=0), 0)
+
+
+@jax.custom_vjp
+@jax.jit
+def _gates_of_rows(gates, order, plan: Plan):
+    """``[R]``: the gate of each row's pair, ``gates`` ``[T, top_k]`` at
+    ``order`` (the pair a row holds), 0 past the pairs; its transpose a
+    gather as well, of each pair's row.
+
+    A walk: the rows are taken ``WALK_CHUNK_ROWS`` at a time in a loop
+    that stops at the first chunk that starts past the pairs, and
+    leaves zeros from there on.  The pairs' count is read on the
+    device, so the loop makes as many trips as the routing filled
+    chunks, and the traced program holds its body once.  (A gather of
+    single numbers costs the v5e as much a row as a gather of
+    2048-wide rows: 0.23 ms for 32768.)  JAX differentiates no loop of
+    such a length, hence the custom VJP.  Where the buffer is no whole
+    number of chunks the last one starts early and writes some rows a
+    second time, the same values.  What a walk costs beside its chunks
+    is the buffer's zeros and a copy of each chunk into it: nothing for
+    this vector, as much as it saves for the ``[R, D]`` buffers, which
+    is why they are not walked (``PERF.md``, PR 33).  Under
+    ``jax.jit``, so that the layers of a step, and the programs of a
+    process, trace the loop once between them."""
+    flat, rows = gates.reshape(-1), order.size
+    size = min(WALK_CHUNK_ROWS, rows)
+
+    def trip(c, row_gate):
+        start = jnp.minimum(c * size, rows - size)
+        pair, valid = (lax.dynamic_slice_in_dim(of_row, start, size)
+                       for of_row in (order, plan.valid))
+        return lax.dynamic_update_slice_in_dim(
+            row_gate, jnp.where(valid, jnp.take(flat, pair), 0), start, 0)
+    return lax.fori_loop(0, (plan.group_sizes.sum() + size - 1) // size,
+                         trip, jnp.zeros(rows, flat.dtype))
+
+
+def _gates_of_rows_fwd(gates, order, plan):
+    return _gates_of_rows(gates, order, plan), plan
+
+
+def _gates_of_rows_bwd(plan, d_row_gate):
+    return _rows_of_pairs(d_row_gate, plan).T, None, None
+
+
+_gates_of_rows.defvjp(_gates_of_rows_fwd, _gates_of_rows_bwd)
 
 
 # Dispatch and combine are each a gather, forward and backward, and
@@ -232,7 +300,7 @@ _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 @jax.custom_vjp
 def _combine(out, plan: Plan):
     """``y[t] = sum of out[place] over t's pairs held``, in float32."""
-    return _rows_of_pairs(out, plan).astype(jnp.float32).sum(1).astype(
+    return _rows_of_pairs(out, plan).astype(jnp.float32).sum(0).astype(
         out.dtype)
 
 
@@ -265,7 +333,13 @@ def routed_experts(x: jax.Array, router_kernel: jax.Array,
 
     No pair is dropped, by construction: the row buffer is as long as
     the routing can make it (:func:`dispatch_rows`), so its size grows
-    with ``T x top_k`` and not with ``T x E``.  Router, choice and
+    with ``T x top_k`` and not with ``T x E``.  What grows with the
+    pairs really sent is the grouped products' work, which skip the
+    rows past the groups' ends, and the gather of the rows' gates,
+    which stops at the first chunk that starts past them
+    (:func:`_gates_of_rows`); the extent is read from the plan, on the device,
+    each step, and no setting stands between a router that sends every
+    pair here and one that sends none.  Router, choice and
     gates are float32; the products take ``x``'s type and accumulate
     in float32.  A pair's gate multiplies its row BEFORE the last
     product (the same sum), so that the gates' gradient needs the

@@ -446,8 +446,14 @@ _MOE_TOP_K = metrics.gauge(
     "traced)")
 _MOE_DISPATCH_ROWS = metrics.gauge(
     "hvd_moe_dispatch_rows",
-    "Rows of the sorted buffer one sparse layer walks on one device "
-    "(set when the step is traced)")
+    "Length of the sorted buffer of one sparse layer on one device: the "
+    "pairs the routing can at most send there (set when the step is "
+    "traced)")
+_MOE_WALK_CHUNK_ROWS = metrics.gauge(
+    "hvd_moe_walk_chunk_rows",
+    "Rows a walk over a sparse layer's sorted buffer takes at a time (the "
+    "gather of the rows' gates); it stops at the first chunk that starts "
+    "past the pairs held (set when the step is traced)")
 _MOE_DISPATCH_BYTES = metrics.gauge(
     "hvd_moe_dispatch_bytes",
     "Bytes one sparse layer's forward pass materialises on one device "
@@ -672,6 +678,7 @@ def make_lfm2_train_step(config, mesh, learning_rate: float = 1e-4,
         _MOE_EXPERTS.set(held, which="held")
         _MOE_TOP_K.set(top_k)
         _MOE_DISPATCH_ROWS.set(moe.dispatch_rows(tokens, top_k, held))
+        _MOE_WALK_CHUNK_ROWS.set(moe.WALK_CHUNK_ROWS)
         _MOE_DISPATCH_BYTES.set(moe.dispatch_bytes(
             tokens, config.hidden_size, config.moe_intermediate_size, top_k,
             held, np.dtype(config.dtype).itemsize))

@@ -35,22 +35,31 @@ def dense_form(x, router, bias, gate, up, down, first_expert, top_k):
     return y
 
 
-def layer_inputs(seed=0, bias=None):
+def layer_inputs(seed=0, bias=None, experts=EXPERTS):
     keys = jax.random.split(jax.random.PRNGKey(seed), 5)
     stacked = lambda key, a, b: 0.3 * jax.random.normal(key, (HELD, a, b))
     return (jax.random.normal(keys[0], (TOKENS, HIDDEN)),
-            jax.random.normal(keys[1], (HIDDEN, EXPERTS)),
-            jnp.zeros(EXPERTS) if bias is None else bias,
+            jax.random.normal(keys[1], (HIDDEN, experts)),
+            jnp.zeros(experts) if bias is None else bias,
             stacked(keys[2], HIDDEN, WIDTH), stacked(keys[3], HIDDEN, WIDTH),
             stacked(keys[4], WIDTH, HIDDEN))
 
 
-def both_forms(args, first_expert, top_k):
+def both_forms(args, first_expert, top_k, kept=None):
     """Value and gradients (tokens, router, the three stacks) of the
-    squared output, the program's layer and the dense form."""
+    squared output, the program's layer and the dense form; with
+    ``kept`` the program's layer is recomputed in its backward pass but
+    for those names."""
+    def layer(*a):
+        return moe.routed_experts(*a, first_expert=first_expert,
+                                  top_k=top_k)[0]
+    if kept is not None:
+        layer = jax.checkpoint(
+            layer, policy=jax.checkpoint_policies.save_only_these_names(
+                *kept))
+
     def program(*a):
-        return (moe.routed_experts(*a, first_expert=first_expert,
-                                   top_k=top_k)[0] ** 2).sum()
+        return (layer(*a) ** 2).sum()
 
     def dense(*a):
         return (dense_form(*a, first_expert, top_k) ** 2).sum()
@@ -130,6 +139,139 @@ def test_no_pair_is_dropped(seed, skew):
     assert sorted(place[is_held]) == list(range(sizes.sum()))
     assert (np.asarray(plan.token)[place[is_held]]
             == np.nonzero(is_held)[0]).all()
+
+
+# Sixteen experts of which four are held, top 2 of 64 tokens: a buffer
+# of 128 rows of which an even router fills 32.
+WALK_EXPERTS, WALK_FIRST, WALK_TOP_K = 16, 4, 2
+WALK_ROWS, WALK_EXPECTED = TOKENS * WALK_TOP_K, TOKENS * WALK_TOP_K * HELD // 16
+ROUTINGS = ["nothing-held", "all-held", "ends-on-a-chunks-boundary",
+            "ends-one-row-past-it", "three-times-the-expectation"]
+
+
+def walked_routing(monkeypatch, routing):
+    """The layer's inputs for one of ``ROUTINGS`` and the pairs it
+    sends the experts held, with the walk's chunk set so that the
+    buffer is several chunks long and the pairs end where the case
+    says.  The chunk follows the routing, not the other way round: the
+    router decides what is held, and a boundary is wherever it ends."""
+    here = ((jnp.arange(WALK_EXPERTS) >= WALK_FIRST)
+            & (jnp.arange(WALK_EXPERTS) < WALK_FIRST + HELD))
+    bias = {"nothing-held": jnp.where(here, -10.0, 0.0),
+            "all-held": jnp.where(here, 10.0, 0.0),
+            "three-times-the-expectation": jnp.where(here, 0.72, 0.0)}.get(
+                routing)
+    args = layer_inputs(seed=3, bias=bias, experts=WALK_EXPERTS)
+    plan, _ = moe.held_pairs(moe.sigmoid_top_k(*args[:3], WALK_TOP_K),
+                             WALK_FIRST, HELD)
+    pairs = int(plan.group_sizes.sum())
+    chunk = 24
+    if routing in ROUTINGS[2:4]:
+        ends = pairs - (routing == "ends-one-row-past-it")
+        chunk = max(d for d in range(2, WALK_ROWS // 4) if ends % d == 0)
+    monkeypatch.setattr(moe, "WALK_CHUNK_ROWS", chunk)
+    return args, pairs, chunk
+
+
+@pytest.mark.parametrize("kept", [
+    None, (moe.CHOICE_NAME, moe.ROWS_NAME, moe.EXPERT_GATE_UP_NAME),
+    (moe.CHOICE_NAME,)], ids=["plain", "remat-kept", "remat-dropped"])
+@pytest.mark.parametrize("routing", ROUTINGS)
+def test_walk_stops_where_the_pairs_end_and_drops_none(monkeypatch, routing,
+                                                       kept):
+    """Wherever the pairs end in the buffer (nowhere, at its last row,
+    on a chunk's last row of the walk, one row into the next chunk, at
+    three times what an even router sends) the layer and every gradient
+    equal the dense form: plain, and recomputed with the sorted rows and
+    the first products' outputs kept or made again."""
+    args, pairs, chunk = walked_routing(monkeypatch, routing)
+    assert WALK_ROWS > 4 * chunk
+    if routing == "three-times-the-expectation":
+        assert 3 * WALK_EXPECTED <= pairs < WALK_ROWS
+    elif routing in ROUTINGS[:2]:
+        assert pairs == {"nothing-held": 0, "all-held": WALK_ROWS}[routing]
+    else:
+        assert chunk < pairs < WALK_ROWS - chunk
+        assert pairs % chunk == (routing == "ends-one-row-past-it")
+    got, dense = both_forms(args, WALK_FIRST, WALK_TOP_K, kept)
+    assert_same(got, dense)
+    if pairs == 0:
+        assert float(got[0]) == 0.0
+        assert all(float(jnp.abs(g).max()) == 0.0 for g in got[1])
+
+
+@pytest.mark.parametrize("routing", ROUTINGS[2:])
+def test_rows_past_the_pairs_may_hold_anything(monkeypatch, routing):
+    """The chip's grouped kernel writes nothing past the groups' ends,
+    so there its output, and the cotangent it hands its left operand,
+    hold what the memory held.  With nan there, forward and backward,
+    every gradient is finite and the dense form's."""
+    args, pairs, chunk = walked_routing(monkeypatch, routing)
+    product = jax.lax.ragged_dot
+
+    @jax.custom_vjp
+    def spoil_past(rows, sizes):
+        row = jnp.arange(rows.shape[0])[:, None]
+        return jnp.where(row < sizes.sum(), rows, jnp.nan)
+    spoil_past.defvjp(lambda rows, sizes: (spoil_past(rows, sizes), None),
+                      lambda _, d: (d, None))
+
+    @jax.custom_vjp
+    def spoil_back(rows, sizes):
+        return rows
+    spoil_back.defvjp(lambda rows, sizes: (rows, sizes),
+                      lambda sizes, d: (spoil_past(d, sizes), None))
+
+    def spoiled(lhs, rhs, group_sizes, **options):
+        return spoil_past(product(spoil_back(lhs, group_sizes), rhs,
+                                  group_sizes, **options), group_sizes)
+    plan, _ = moe.held_pairs(moe.sigmoid_top_k(*args[:3], WALK_TOP_K),
+                             WALK_FIRST, HELD)
+    probe = spoiled(jnp.ones((WALK_ROWS, HIDDEN)), args[3], plan.group_sizes)
+    assert bool(jnp.isnan(probe[pairs:]).all()) and pairs < WALK_ROWS
+    monkeypatch.setattr(jax.lax, "ragged_dot", spoiled)
+    got, dense = both_forms(args, WALK_FIRST, WALK_TOP_K)
+    assert all(bool(jnp.isfinite(g).all()) for g in got[1])
+    assert_same(got, dense)
+
+
+def test_the_traced_program_holds_the_chunks_as_one_loop(monkeypatch):
+    """Sixteen chunks or two: three grouped products, one loop (the
+    rows' gates) and as many gathers, forward and backward."""
+    args = layer_inputs(seed=3, experts=WALK_EXPERTS)
+
+    def counts(chunk):
+        monkeypatch.setattr(moe, "WALK_CHUNK_ROWS", chunk)
+        layer = lambda *a: moe.routed_experts(
+            *a, first_expert=WALK_FIRST, top_k=WALK_TOP_K)[0]
+        grads = jax.grad(lambda *a: (layer(*a) ** 2).sum(),
+                         argnums=(0, 1, 3, 4, 5))
+        return [(text.count("= ragged_dot"), text.count(" gather["),
+                 text.count(" while["))
+                for text in (str(jax.make_jaxpr(layer)(*args)),
+                             str(jax.make_jaxpr(grads)(*args)))]
+    sixteen, two = counts(WALK_ROWS // 16), counts(WALK_ROWS // 2)
+    assert sixteen == two
+    (products, _, loops), (all_products, _, all_loops) = sixteen
+    assert (products, all_products) == (3, 9)
+    assert loops == all_loops == 1
+
+
+def test_rows_walked_is_the_chunks_that_start_before_the_pairs_end():
+    chunk = moe.WALK_CHUNK_ROWS
+    assert chunk % 512 == 0          # whole tiles of the grouped kernel
+    rows = 16 * chunk
+    assert moe.rows_walked(0, rows) == 0
+    assert moe.rows_walked(1, rows) == chunk
+    assert moe.rows_walked(4 * chunk, rows) == 4 * chunk
+    assert moe.rows_walked(4 * chunk + 1, rows) == 5 * chunk
+    assert moe.rows_walked(rows, rows) == rows
+    # the cell: 8012 to 8478 pairs a layer of 32768 rows
+    assert moe.rows_walked(8012, 32768) == 4 * 2048
+    assert moe.rows_walked(8478, 32768) == 5 * 2048
+    # a buffer shorter than a chunk, or no whole number of them
+    assert moe.rows_walked(3, 100) == 100
+    assert moe.rows_walked(chunk + 1, chunk + 100) == chunk + 100
 
 
 def test_dispatch_bytes_counts_the_arrays_by_hand():
@@ -261,6 +403,10 @@ def test_gauges_show_in_the_metrics_snapshot():
     assert gauges["hvd_moe_top_k"] == 2
     # one device's share of the batch: 2 of 4 sequences of 64
     assert gauges["hvd_moe_dispatch_rows"] == 128 * 2
+    assert gauges["hvd_moe_walk_chunk_rows"] == moe.WALK_CHUNK_ROWS
+    # a layer's walked share, as docs/observability.md reads it: a
+    # buffer shorter than a chunk is walked whole once a pair is held
+    assert moe.rows_walked(1, int(gauges["hvd_moe_dispatch_rows"])) == 256
     assert gauges["hvd_moe_dispatch_bytes"] == moe.dispatch_bytes(
         128, cfg.hidden_size, cfg.moe_intermediate_size, 2, 4, 4)
     layers = gauges["hvd_hybrid_layers"]
