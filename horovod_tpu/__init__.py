@@ -77,7 +77,8 @@ __all__ = [
 
 from .common import timeline as _timeline
 
-# hvd/import: the first to the last line of this file (the imports of
-# jax, flax and optax among them), a cold span like hvd/init.
-_timeline.record("import", _import_start, _time.time())
+# hvd/import: the first to the last line of this file (the import of jax
+# among them), a cold span like hvd/init.  A module that this file does
+# not import (horovod_tpu.training, horovod_tpu.models) records its own.
+_timeline.record("import", _import_start, _time.time(), module=__name__)
 del _time, _timeline, _import_start

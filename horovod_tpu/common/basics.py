@@ -192,7 +192,7 @@ def init(comm=None, process_sets=None):
 
 
 def _init_locked(state: HorovodTpuState, comm, process_sets):
-    # The four children cover init between them, so that what the
+    # The five children cover init between them, so that what the
     # parent holds beyond their sum is the `with` statements alone.
     span = timeline_mod.span
     with span("init/rendezvous", cold=True):
@@ -254,6 +254,17 @@ def _init_locked(state: HorovodTpuState, comm, process_sets):
         with span("init/distributed", cold=True):
             state.distributed_client_owned = _maybe_init_jax_distributed(
                 state.rank_info)
+
+    # The process's first jax.devices(): the device client's start (on a
+    # TPU, seconds).  In a world of one nothing below needs the devices,
+    # and the client would start at whatever line of the user's script
+    # touches JAX first, inside no span; so every world starts it here
+    # and init() returns with the chips held.
+    with span("init/device_client", cold=True) as client:
+        import jax
+        devices = jax.devices()
+        client.args.update(platform=devices[0].platform,
+                           devices=len(devices))
 
     with span("init/backend", cold=True):
         # Failpoint rank= predicates resolve against the final rank of
@@ -537,9 +548,12 @@ def metrics_snapshot() -> dict:
 
 
 def spans() -> List[dict]:
-    """This process's cold spans in the order they ended: start-up (``hvd/import``,
-    ``hvd/init`` and its children), every compilation phase
-    (``hvd/compile/<phase>`` with ``program=``) and ``hvd/shutdown``,
+    """This process's cold spans in the order they ended: start-up
+    (``hvd/import`` with ``module=``, ``hvd/init`` and its children), a
+    step's layout (``hvd/step/shardings``), the first call of each
+    program of ``horovod_tpu.training`` (``hvd/program/first_call``),
+    every compilation phase (``hvd/compile/<phase>`` with ``program=``)
+    and ``hvd/shutdown``,
     each ``{"name", "start", "end", "thread", "parent", "args"}`` with
     wall-clock seconds.  The end of ``hvd/init`` is the instant
     ``hvd.init()`` returned.  Hot spans keep only count and seconds:

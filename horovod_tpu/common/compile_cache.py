@@ -19,6 +19,13 @@ of that one's span), ``lower`` (to MLIR), then either ``cache_load``
 too quick or too small to keep).  This is what says which program
 compiled in the middle of a run, and what to watch for a step that
 retraces.
+
+``first_call(jitted, kind)`` is what the step builders hand out: the
+jitted function, whose first call is the cold span
+``hvd/program/first_call`` from before the call until its outputs are
+ready.  The request's ``hvd/compile/*`` spans are its children; what it
+holds beyond them is the executable's load onto the device and its
+first run.
 """
 
 import os
@@ -131,3 +138,35 @@ def _subscribe():
         monitoring.register_event_time_span_listener(_on_time_span)
         monitoring.register_scalar_listener(_on_scalar)
         _subscribed = True
+
+
+# ---------------------------------------------------------------------------
+# A program's first call
+# ---------------------------------------------------------------------------
+
+class first_call:
+    """``jitted`` with its first call inside a cold span
+    ``hvd/program/first_call`` (``program=`` the function's name,
+    ``kind=`` "init" or "step"), which ends when that call's outputs
+    are ready.  Every later call tests one flag and goes to ``jitted``;
+    ``.lower``, ``.trace`` and whatever else a jitted function has are
+    ``jitted``'s own."""
+
+    def __init__(self, jitted, kind: str):
+        self._jitted = jitted
+        self._kind = kind
+        self._called = False
+
+    def __call__(self, *args, **kwargs):
+        if self._called:
+            return self._jitted(*args, **kwargs)
+        self._called = True
+        import jax
+        with timeline.span("program/first_call", cold=True,
+                           program=self._jitted.__name__, kind=self._kind):
+            return jax.block_until_ready(self._jitted(*args, **kwargs))
+
+    def __getattr__(self, name):
+        # Not self._jitted: on an instance that has none yet (a copy
+        # being made) that would come back here for ever.
+        return getattr(object.__getattribute__(self, "_jitted"), name)

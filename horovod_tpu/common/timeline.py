@@ -45,25 +45,6 @@ from . import metrics
 
 logger = logging.getLogger("horovod_tpu.timeline")
 
-# Activity names, matching the reference span vocabulary (common.h:32-62).
-NEGOTIATE_ALLREDUCE = "NEGOTIATE_ALLREDUCE"
-NEGOTIATE_ALLGATHER = "NEGOTIATE_ALLGATHER"
-NEGOTIATE_BROADCAST = "NEGOTIATE_BROADCAST"
-NEGOTIATE_ALLTOALL = "NEGOTIATE_ALLTOALL"
-WAIT_FOR_DATA = "WAIT_FOR_DATA"
-WAIT_FOR_OTHER_TENSOR_DATA = "WAIT_FOR_OTHER_TENSOR_DATA"
-FUSE_BUFFER = "MEMCPY_IN_FUSION_BUFFER"
-UNFUSE_BUFFER = "MEMCPY_OUT_FUSION_BUFFER"
-XLA_ALLREDUCE = "XLA_ALLREDUCE"
-XLA_ALLGATHER = "XLA_ALLGATHER"
-XLA_BROADCAST = "XLA_BROADCAST"
-XLA_ALLTOALL = "XLA_ALLTOALL"
-XLA_REDUCESCATTER = "XLA_REDUCESCATTER"
-XLA_COMPILE = "XLA_COMPILE"
-ADASUM_VHDD = "ADASUM_VHDD"
-QUEUE = "QUEUE"
-
-
 
 # ---------------------------------------------------------------------------
 # The clock, and spans

@@ -1,3 +1,7 @@
+import time as _time
+
+_import_start = _time.time()
+
 from .resnet import (ResNet, ResNet18, ResNet34, ResNet50, ResNet101,
                      ResNet152)
 from .bert import (BertConfig, BertEncoder, BertForMaskedLM,
@@ -26,3 +30,10 @@ __all__ = [
     "DLRMConfig", "DLRMDense", "bce_logits_loss", "dlrm_tiny_config",
     "synthetic_click_batch",
 ]
+
+from ..common import timeline as _timeline
+
+# hvd/import: the first to the last line of this file (flax and the
+# kernels the models call come with it), as the package records its own.
+_timeline.record("import", _import_start, _time.time(), module=__name__)
+del _time, _timeline, _import_start

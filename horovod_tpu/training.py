@@ -17,6 +17,10 @@ drop-in per-gradient API also exists (horovod_tpu.jax) but this is the
 path that hits peak MXU/ICI utilisation.
 """
 
+import time
+
+_import_start = time.time()
+
 import logging
 from functools import partial
 from typing import Callable, Dict, Optional, Tuple
@@ -27,7 +31,8 @@ import optax
 from flax.training import train_state
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .common import metrics
+from .common import metrics, timeline
+from .common.compile_cache import first_call
 from .models.bert import BertConfig, BertForMaskedLM, mlm_loss
 from .parallel.sharding import (bert_partition_rules,
                                 gather_over_data_axis, infer_shardings,
@@ -189,19 +194,20 @@ def make_bert_pretrain_step(
     # abstractly so no host memory is spent.
     def make_jitted(example_batch):
         rng = jax.random.PRNGKey(0)
-        abstract_state = jax.eval_shape(_init, rng, example_batch)
-        state_sharding = infer_shardings(abstract_state, mesh, rules)
-        # The shardings the gradients are exchanged to; None where the
-        # step has no exchange (a dp of one).
-        exchange = shard_over_data_axis(
-            abstract_state.params, state_sharding.params, mesh)
-        if exchange is state_sharding.params:
-            exchange = None
-        else:
-            state_sharding = state_sharding.replace(
-                opt_state=shard_over_data_axis(
-                    abstract_state.opt_state, state_sharding.opt_state,
-                    mesh))
+        with timeline.span("step/shardings", cold=True, program="_init"):
+            abstract_state = jax.eval_shape(_init, rng, example_batch)
+            state_sharding = infer_shardings(abstract_state, mesh, rules)
+            # The shardings the gradients are exchanged to; None where
+            # the step has no exchange (a dp of one).
+            exchange = shard_over_data_axis(
+                abstract_state.params, state_sharding.params, mesh)
+            if exchange is state_sharding.params:
+                exchange = None
+            else:
+                state_sharding = state_sharding.replace(
+                    opt_state=shard_over_data_axis(
+                        abstract_state.opt_state,
+                        state_sharding.opt_state, mesh))
 
         def _step(state, batch):
             dropout_rng = jax.random.fold_in(
@@ -246,7 +252,7 @@ def make_bert_pretrain_step(
             out_shardings=(state_sharding, repl),
             donate_argnums=(0,) if donate else (),
             compiler_options=compiler_options)
-        return init_fn, step_fn
+        return first_call(init_fn, "init"), first_call(step_fn, "step")
 
     return make_jitted, batch_sharding
 
@@ -487,9 +493,11 @@ def _make_causal_lm_train_step(mesh, tx, rules: Rules, batch_axis: str,
         # Jitted with out_shardings like BERT's: every array is born
         # on its own shard, so a model that only fits sharded never
         # passes through one chip.
-        shardings = infer_shardings(
-            jax.eval_shape(_init, rng, ids), mesh, rules)
-        return jax.jit(_init, out_shardings=shardings)(rng, ids)
+        with timeline.span("step/shardings", cold=True, program="_init"):
+            shardings = infer_shardings(
+                jax.eval_shape(_init, rng, ids), mesh, rules)
+        return first_call(jax.jit(_init, out_shardings=shardings),
+                          "init")(rng, ids)
 
     @partial(jax.jit, donate_argnums=(0, 1))
     def step_fn(params, opt_state, ids):
@@ -501,7 +509,7 @@ def _make_causal_lm_train_step(mesh, tx, rules: Rules, batch_axis: str,
             params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
-    return init_fn, step_fn, batch_sharding
+    return init_fn, first_call(step_fn, "step"), batch_sharding
 
 
 def _heads_sharding(mesh, batch_axis: str) -> NamedSharding:
@@ -744,3 +752,8 @@ def run_gpt_dry_run(n_devices: int, batch_size: int = 8,
     params, opt_state, loss = step_fn(params, opt_state, ids)
     jax.block_until_ready(loss)
     return float(loss), mesh
+
+
+# hvd/import: the first to the last line of this file, which the package
+# does not import (flax, optax, the models and their kernels come with it).
+timeline.record("import", _import_start, time.time(), module=__name__)

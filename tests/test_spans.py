@@ -203,8 +203,10 @@ def test_init_children_add_up_and_the_eager_path_is_clocked_once():
     init = [s for s in spans if s["name"] == "hvd/init"][-1]
     children = [s for s in spans if s["parent"] == "hvd/init"
                 and s["start"] >= init["start"]]
+    # A world of one has no init/distributed; the other four are there.
     assert [c["name"] for c in children] == [
-        "hvd/init/rendezvous", "hvd/init/backend", "hvd/init/runtime"]
+        "hvd/init/rendezvous", "hvd/init/device_client", "hvd/init/backend",
+        "hvd/init/runtime"]
     total = sum(c["end"] - c["start"] for c in children)
     whole = init["end"] - init["start"]
     # Within 5 %; where a warm init takes under a millisecond, within
@@ -349,3 +351,160 @@ def test_jitted_steps_name_their_optimizer_and_loss():
     text = step_fn.lower(params, opt_state, ids).as_text(debug_info=True)
     assert "jit(step_fn)/optimizer/" in text
     assert "jvp(loss)/" in text and "transpose(jvp(loss))/" in text
+
+
+def _init_and_its_children():
+    spans = tl.spans()
+    init = [s for s in spans if s["name"] == "hvd/init"][-1]
+    return init, [s for s in spans if s["parent"] == "hvd/init"
+                  and s["start"] >= init["start"]]
+
+
+def test_init_starts_the_device_client_in_a_world_of_one_and_again():
+    """``hvd.init()`` returns with the devices held whatever the world's
+    size, and says what it found; a second incarnation records the span
+    again."""
+    import jax
+
+    import horovod_tpu as hvd
+    seen = []
+    for _ in range(2):
+        hvd.init()
+        hvd.shutdown()
+        init, children = _init_and_its_children()
+        client, = [c for c in children
+                   if c["name"] == "hvd/init/device_client"]
+        assert client["args"] == {"platform": jax.devices()[0].platform,
+                                  "devices": len(jax.devices())}
+        assert init["start"] <= client["start"] <= client["end"] \
+            <= init["end"]
+        order = [c["name"] for c in children]
+        assert order.index("hvd/init/rendezvous") \
+            < order.index("hvd/init/device_client") \
+            < order.index("hvd/init/backend")
+        seen.append(client["start"])
+    assert seen[1] > seen[0]
+
+
+def test_modules_the_package_does_not_import_record_their_own_import():
+    import horovod_tpu.training  # noqa: F401
+    imports = {s["args"].get("module"): s for s in tl.spans()
+               if s["name"] == "hvd/import"}
+    assert {"horovod_tpu", "horovod_tpu.models",
+            "horovod_tpu.training"} <= set(imports)
+    for span in imports.values():
+        assert span["end"] > span["start"]
+    # The package's own import ended before a late module's began.
+    assert imports["horovod_tpu"]["end"] \
+        <= imports["horovod_tpu.training"]["start"]
+
+
+def _tiny_gpt():
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models.gpt import GPTConfig
+    from horovod_tpu.parallel import build_mesh
+    from horovod_tpu.training import make_gpt_train_step
+    mesh = build_mesh({"dp": 1}, jax.devices()[:1])
+    config = GPTConfig(vocab_size=64, hidden_size=16, num_layers=1,
+                       num_heads=2, intermediate_size=32,
+                       max_position_embeddings=16)
+    init_fn, step_fn, _ = make_gpt_train_step(config, mesh)
+    ids = jnp.arange(32, dtype=jnp.int32).reshape(2, 16) % 64
+
+    def step(fn, state):
+        *state, loss = fn(*state, ids)
+        return tuple(state), loss
+    return (lambda: init_fn(jax.random.PRNGKey(0), ids)), step_fn, step, \
+        lambda state: (*state, ids)
+
+
+def _tiny_bert():
+    import jax
+
+    from horovod_tpu.models.bert import bert_tiny_config
+    from horovod_tpu.parallel import build_mesh
+    from horovod_tpu.training import (make_bert_batch,
+                                      make_bert_pretrain_step)
+    mesh = build_mesh({"dp": 1}, jax.devices()[:1])
+    config = bert_tiny_config()
+    make_jitted, _ = make_bert_pretrain_step(config, mesh)
+    batch = make_bert_batch(2, 16, config.vocab_size)
+    init_fn, step_fn = make_jitted(batch)
+    return (lambda: init_fn(jax.random.PRNGKey(0), batch)), step_fn, \
+        (lambda fn, state: fn(state, batch)), lambda state: (state, batch)
+
+
+@pytest.mark.parametrize("family", ["gpt", "bert"])
+def test_a_programs_first_call_is_one_span_around_its_compile_spans(family):
+    """Each program ``training.py`` hands out records its first call
+    once, from before the call until its outputs are ready, with the
+    request's compile spans inside; what it hands out is still the
+    jitted function, and computes what the bare one computes."""
+    import jax
+    import numpy as np
+
+    from horovod_tpu.common import compile_cache
+    compile_cache._subscribe()
+    since = tl.wall(time.perf_counter())
+    init, step_fn, step, step_args = {"gpt": _tiny_gpt,
+                                      "bert": _tiny_bert}[family]()
+    assert isinstance(step_fn, compile_cache.first_call)
+    state, bare_state = init(), init()
+    losses, bare_losses = [], []
+    for _ in range(4):
+        state, loss = step(step_fn, state)
+        bare_state, bare_loss = step(step_fn._jitted, bare_state)
+        losses.append(np.asarray(loss))
+        bare_losses.append(np.asarray(bare_loss))
+    assert [v.tobytes() for v in losses] == \
+        [v.tobytes() for v in bare_losses]
+    for mine, bare in zip(jax.tree.leaves(state),
+                          jax.tree.leaves(bare_state)):
+        assert np.asarray(mine).tobytes() == np.asarray(bare).tobytes()
+
+    # Still a jitted function to its callers.
+    assert "optimizer" in step_fn.lower(*step_args(state)).as_text(
+        debug_info=True)
+    assert step_fn.trace(*step_args(state)).jaxpr.eqns
+
+    mine = [s for s in tl.spans() if s["start"] >= since]
+    calls = [s for s in mine if s["name"] == "hvd/program/first_call"]
+    # init() ran twice: the causal-LM builder jits a new init program a
+    # call, BERT's is one program.  The step: once, whoever called it.
+    kinds = collections.Counter(s["args"]["kind"] for s in calls)
+    assert kinds == {"init": 2 if family == "gpt" else 1, "step": 1}
+    for kind in ("init", "step"):
+        # The first of its kind compiled (JAX keeps the executable for
+        # the causal-LM builder's second, equal, init program).
+        call = [s for s in calls if s["args"]["kind"] == kind][0]
+        program = call["args"]["program"]
+        inside = [s for s in mine if s["parent"] == call["name"]
+                  and call["start"] <= s["start"] and s["end"] <= call["end"]]
+        assert all(s["name"].startswith("hvd/compile/") for s in inside)
+        closing = [s for s in inside
+                   if s["args"]["program"] == "jit(%s)" % program]
+        # (Where another test turned the persistent cache on in this
+        # process, the request may end in a load.)
+        assert [s["name"] for s in closing] in (
+            ["hvd/compile/lower", "hvd/compile/backend_compile"],
+            ["hvd/compile/lower", "hvd/compile/cache_load"])
+        assert call["end"] >= closing[-1]["end"]
+        assert any(s["name"] == "hvd/compile/trace"
+                   and s["args"]["program"] == program for s in inside)
+    # The state is laid out before the init program is first called.
+    layouts = [s for s in mine if s["name"] == "hvd/step/shardings"]
+    first_init = min(s["start"] for s in calls
+                     if s["args"]["kind"] == "init")
+    assert layouts and layouts[0]["end"] <= first_init
+    assert layouts[0]["args"] == {"program": "_init"}
+    # No compile span of the later calls hangs under a first call, and
+    # hundreds of steps add none.
+    before = len(tl.spans())
+    for _ in range(200):
+        state, loss = step(step_fn, state)
+    assert len([s for s in tl.spans()
+                if s["name"] == "hvd/program/first_call"
+                and s["start"] >= since]) == len(calls)
+    assert len(tl.spans()) == before
