@@ -354,12 +354,15 @@ def _gpt_step_loss_check(config, batch_size, seq_len):
     gradient leaves, on one device: against the benchmark's plain
     float32 reference and against the same model's logits through
     ``lm_loss`` (bf16's rounding of the logits apart, the same
-    arithmetic), both within the benchmark's tolerances."""
+    arithmetic), both within the benchmark's tolerances.  On the chip
+    the batch is sized to walk more than one chunk of the loss (4 x
+    1024 tokens are two chunks of 512 positions; the info line says how
+    many)."""
     import jax
     import numpy as np
     from benchmarks.reference import common as reference
     from benchmarks.reference import gpt as reference_gpt
-    from horovod_tpu.models.gpt import GPTLMHeadModel, lm_loss
+    from horovod_tpu.models.gpt import GPTLMHeadModel, lm_loss, loss_chunks
     from horovod_tpu.training import gpt_step_loss
 
     model = GPTLMHeadModel(config)
@@ -390,10 +393,12 @@ def _gpt_step_loss_check(config, batch_size, seq_len):
         leaf_err = {n: float(np.linalg.norm(got[n] - want[n])
                              / np.linalg.norm(want[n])) for n in want}
         _info("sharded: gpt step loss %.6f, hidden %d, %d layers, "
-              "vocabulary %d, batch %d x %d, against the %s, %.6f: "
+              "vocabulary %d, batch %d x %d in %d chunks of %d positions, "
+              "against the %s, %.6f: "
               "relative error %.2e (limit %.0e), leaves %s (limit %.0e)"
               % (got_loss, config.hidden_size, config.num_layers,
-                 config.vocab_size, batch_size, seq_len, other, want_loss,
+                 config.vocab_size, batch_size, seq_len,
+                 *loss_chunks(seq_len, batch_size), other, want_loss,
                  loss_err, reference.LOSS_RTOL, json.dumps(leaf_err),
                  reference.GRAD_REL_L2))
         assert loss_err <= reference.LOSS_RTOL, other
@@ -402,7 +407,7 @@ def _gpt_step_loss_check(config, batch_size, seq_len):
 
 def phase_sharded(bert_config=None, gpt_config=None,
                   bert_batch=(64, 128), gpt_batch=(8, 512),
-                  gpt_check_config=None, gpt_check_batch=(2, 1024),
+                  gpt_check_config=None, gpt_check_batch=(4, 1024),
                   steps: int = 3, platform: str = "tpu"):
     import jax
     import numpy as np

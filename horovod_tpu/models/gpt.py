@@ -43,11 +43,14 @@ from jax.sharding import NamedSharding
 FLASH_NAMES = ("flash_out", "flash_lse")
 MATMUL_NAMES = ("query", "key", "value", "attention_out", "intermediate")
 REMAT_NAMES = FLASH_NAMES + MATMUL_NAMES
-# Positions of the sequence in one chunk of ``chunked_lm_loss``: 16 x
-# 128 tokens x 50257 fp32 logits are 0.41 GB where all 1024 positions
-# were 3.29, and a chunk still feeds the MXU (chosen on the v5e, PERF.md
-# PR 26).
-LOSS_CHUNK = 128
+# Tokens one device holds in one chunk of ``chunked_lm_loss``.  2048 is
+# 16 sequences x 128 positions, chosen on the v5e at 16 x 1024 (PERF.md
+# PR 26): 2048 x 50257 fp32 logits are 0.41 GB where all 1024 positions
+# were 3.29, and a chunk's products still feed the MXU.  Counted in
+# tokens and not in positions since PR 35: every chunk reads and writes
+# the whole fp32 gradient of the embedding, so two sequences of 4096
+# walk 4 chunks of 1024 positions, not 32 of 128.
+LOSS_CHUNK_TOKENS = 2048
 
 
 @dataclasses.dataclass(frozen=True)
@@ -282,11 +285,15 @@ def lm_loss(logits, input_ids, mask=None):
     return -(ll * m).sum() / jnp.maximum(m.sum(), 1.0)
 
 
-def loss_chunks(seq: int) -> Tuple[int, int]:
-    """``(count, length)`` of the chunks ``chunked_lm_loss`` walks: at
-    most ``LOSS_CHUNK`` positions each, one chunk for a short sequence,
-    and as even as a length that the count does not divide allows."""
-    count = -(-seq // LOSS_CHUNK)
+def loss_chunks(seq: int, sequences: int) -> Tuple[int, int]:
+    """``(count, length)`` of the chunks ``chunked_lm_loss`` walks over
+    ``seq`` positions with ``sequences`` of the batch on one device:
+    the fewest chunks of at most ``LOSS_CHUNK_TOKENS`` tokens a device
+    (``sequences * length``), one chunk for a short batch, a whole
+    position where the sequences alone are more than that, and as even
+    as a length that the count does not divide allows."""
+    longest = max(1, LOSS_CHUNK_TOKENS // sequences)
+    count = -(-seq // longest)
     return count, -(-seq // count)
 
 
@@ -305,10 +312,11 @@ def _chunk_nll(h, table, targets, scale: float):
     return logits, lse, lse - at_target
 
 
-def _chunked(hidden, targets, weights):
+def _chunked(hidden, targets, weights, chunks: Tuple[int, int]):
     """``[B, S, ...]`` as ``[count, B, length, ...]`` for a scan over
-    chunks of the sequence; the padding weighs nothing."""
-    count, length = loss_chunks(hidden.shape[1])
+    ``chunks`` (``loss_chunks``'s count and length) of the sequence; the
+    padding weighs nothing."""
+    count, length = chunks
     pad = count * length - hidden.shape[1]
 
     def split(a):
@@ -318,21 +326,21 @@ def _chunked(hidden, targets, weights):
     return split(hidden), split(targets), split(weights)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _weighted_nll(hidden, embedding, targets, weights, scale):
-    """``sum(weights * nll)`` over ``[B, S]``, a chunk of the sequence
-    at a time; the logits are the tied head's times ``scale``."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _weighted_nll(hidden, embedding, targets, weights, scale, chunks):
+    """``sum(weights * nll)`` over ``[B, S]``, one of ``chunks`` of the
+    sequence at a time; the logits are the tied head's times ``scale``."""
     table = embedding.astype(hidden.dtype)
 
     def one(total, chunk_of):
         h, t, w = chunk_of
         return total + (w * _chunk_nll(h, table, t, scale)[2]).sum(), None
     total, _ = jax.lax.scan(one, jnp.zeros((), jnp.float32),
-                            _chunked(hidden, targets, weights))
+                            _chunked(hidden, targets, weights, chunks))
     return total
 
 
-def _weighted_nll_fwd(hidden, embedding, targets, weights, scale):
+def _weighted_nll_fwd(hidden, embedding, targets, weights, scale, chunks):
     """The same pass over a chunk's logits gives its gradients too:
     ``(softmax - onehot) * weights`` (times ``scale``, the logits'
     own factor), cast to the compute dtype as autodiff's transpose of
@@ -362,7 +370,7 @@ def _weighted_nll_fwd(hidden, embedding, targets, weights, scale):
     (total, d_table), (d_hidden, nll) = jax.lax.scan(
         one, (jnp.zeros((), jnp.float32),
               jnp.zeros(embedding.shape, jnp.float32)),
-        _chunked(hidden, targets, weights))
+        _chunked(hidden, targets, weights, chunks))
 
     def whole(a):  # [count, B, length, ...] back to [B, S, ...]
         a = jnp.moveaxis(a, 0, 1)
@@ -372,8 +380,8 @@ def _weighted_nll_fwd(hidden, embedding, targets, weights, scale):
                    whole(nll))
 
 
-def _weighted_nll_bwd(scale, residuals, g):
-    del scale  # the residuals carry it
+def _weighted_nll_bwd(scale, chunks, residuals, g):
+    del scale, chunks  # the residuals carry both
     d_hidden, d_embedding, nll = residuals
     return ((g * d_hidden).astype(d_hidden.dtype),
             (g * d_embedding).astype(d_embedding.dtype), None, g * nll)
@@ -383,15 +391,19 @@ _weighted_nll.defvjp(_weighted_nll_fwd, _weighted_nll_bwd)
 
 
 def chunked_lm_loss(hidden, embedding, input_ids, mask=None,
-                    logits_scale: float = 1.0):
+                    logits_scale: float = 1.0,
+                    sequences: Optional[int] = None):
     """``lm_loss`` of the tied head's logits (times ``logits_scale``,
     for a model that scales them) without the logits: from
     the final hidden states ``[B, S, H]`` and the token embedding ``[V,
-    H]`` (``GPTLMHeadModel.hidden_and_embedding``), a chunk of at most
-    ``LOSS_CHUNK`` positions of the sequence at a time, so that the batch
-    stays sharded as it is and the vocabulary stays whole.  A chunk's
-    fp32 logits are made once, where a gradient is asked for too (a
-    custom VJP)."""
+    H]`` (``GPTLMHeadModel.hidden_and_embedding``), a chunk of the
+    sequence at a time, so that the batch stays sharded as it is and the
+    vocabulary stays whole.  A chunk holds at most ``LOSS_CHUNK_TOKENS``
+    tokens of one device (``loss_chunks``): under GSPMD ``B`` is the
+    global batch, so a sharded step says how many ``sequences`` of it
+    one device holds (the step builders read that off their mesh); all
+    ``B`` where nothing is said.  A chunk's fp32 logits are made once,
+    where a gradient is asked for too (a custom VJP)."""
     targets = jnp.roll(input_ids, -1, axis=1)
     # Position t is weighed by its TARGET's mask; the last has none.
     counts = (jnp.ones(input_ids.shape, jnp.float32) if mask is None
@@ -400,5 +412,7 @@ def chunked_lm_loss(hidden, embedding, input_ids, mask=None,
     total = counts.sum()
     if mask is not None:
         total = jnp.maximum(total, 1.0)
+    batch, seq = input_ids.shape
     return _weighted_nll(hidden, embedding, targets, counts / total,
-                         float(logits_scale))
+                         float(logits_scale),
+                         loss_chunks(seq, sequences or batch))

@@ -380,12 +380,35 @@ def gpt_step_loss(model, params, ids):
     (``models/gpt.py`` ``chunked_lm_loss``): the value and gradients of
     ``lm_loss(model.apply(...), ids)`` without the ``[B, S, V]``
     logits.  No dropout: ``deterministic`` stays at its default."""
-    from .models.gpt import GPTLMHeadModel, chunked_lm_loss
+    from .models.gpt import GPTLMHeadModel
     hidden, embedding = model.apply(
         {"params": params}, ids,
         method=GPTLMHeadModel.hidden_and_embedding)
+    return _tied_head_loss(model.qkv_sharding, hidden, embedding, ids)
+
+
+def _sequences_on_one_device(sharding: Optional[NamedSharding],
+                             batch: int) -> int:
+    """Sequences of a global ``batch`` that one device holds under
+    ``sharding``, whose first dimension is the batch's (the batch's
+    own, or a model's of ``[B, S, heads, D]``); all of them for a model
+    that is applied directly."""
+    if sharding is None:
+        return batch
+    over_batch = NamedSharding(sharding.mesh, P(sharding.spec[0]))
+    return over_batch.shard_shape((batch,))[0]
+
+
+def _tied_head_loss(sharding: Optional[NamedSharding], hidden, embedding,
+                    ids, logits_scale: float = 1.0):
+    """``chunked_lm_loss`` as every causal-LM step calls it: under the
+    scope ``loss``, its chunks sized by the sequences one device holds
+    under the model's ``sharding``."""
+    from .models.gpt import chunked_lm_loss
     with jax.named_scope("loss"):
-        return chunked_lm_loss(hidden, embedding, ids)
+        return chunked_lm_loss(
+            hidden, embedding, ids, logits_scale=logits_scale,
+            sequences=_sequences_on_one_device(sharding, ids.shape[0]))
 
 
 def _bytes_on_one_device(tree, shardings) -> int:
@@ -419,9 +442,13 @@ _REMAT_KEPT = metrics.gauge(
     "Bytes one device keeps across the GPT step's remat, by the names "
     "kept (set when the step is traced)")
 _LOSS_CHUNKS = metrics.gauge(
-    "hvd_gpt_loss_chunks",
-    "Chunks of the sequence the GPT step's loss walks (set when the "
+    "hvd_lm_loss_chunks",
+    "Chunks of the sequence a causal-LM step's loss walks (set when the "
     "step is traced)")
+_LOSS_CHUNK_TOKENS = metrics.gauge(
+    "hvd_lm_loss_chunk_tokens",
+    "Tokens one device holds in one chunk of a causal-LM step's loss "
+    "(set when the step is traced)")
 _GRANITE_REMAT_KEPT = metrics.gauge(
     "hvd_granite_remat_kept_bytes",
     "Bytes one device keeps across the Granite step's remat, by the "
@@ -482,7 +509,9 @@ def _make_causal_lm_train_step(mesh, tx, rules: Rules, batch_axis: str,
     handed and the batch's shape); ``step_loss(model, params, ids)`` is
     the loss.  Parameters and optimizer state are laid out by ``rules``
     and donated, the batch rides ``batch_axis``, XLA inserts the
-    collectives."""
+    collectives.  Every ``step_loss`` ends in ``chunked_lm_loss``, so
+    the chunks it walks are put on record here."""
+    from .models.gpt import loss_chunks
     batch_sharding = NamedSharding(mesh, P(batch_axis, None))
 
     def _init(rng, ids):
@@ -501,6 +530,10 @@ def _make_causal_lm_train_step(mesh, tx, rules: Rules, batch_axis: str,
 
     @partial(jax.jit, donate_argnums=(0, 1))
     def step_fn(params, opt_state, ids):
+        sequences = _sequences_on_one_device(batch_sharding, ids.shape[0])
+        count, length = loss_chunks(ids.shape[1], sequences)
+        _LOSS_CHUNKS.set(count)
+        _LOSS_CHUNK_TOKENS.set(sequences * length)
         loss, grads = jax.value_and_grad(partial(
             step_loss, traced_model((params, opt_state), ids)))(
                 params, ids)
@@ -551,7 +584,6 @@ def make_gpt_train_step(config, mesh, learning_rate: float = 1e-2,
     def traced_model(state, ids):
         """The model of this trace: with ``remat``, keeping what fits
         beside ``state`` at ``ids``'s shape."""
-        _LOSS_CHUNKS.set(gpt.loss_chunks(ids.shape[1])[0])
         if not config.remat:
             return model
         sizes = (ids.size // mesh.shape[batch_axis], config.hidden_size,
@@ -573,15 +605,12 @@ def granite_step_loss(model, params, ids):
     """The loss of ``make_granite_train_step``'s step: the hybrid
     stack's final hidden states, then ``chunked_lm_loss`` with the tied
     head's logits divided by ``logits_scaling``."""
-    from .models.gpt import chunked_lm_loss
     from .models.granite import GraniteLMHeadModel
     hidden, embedding = model.apply(
         {"params": params}, ids,
         method=GraniteLMHeadModel.hidden_and_embedding)
-    with jax.named_scope("loss"):
-        return chunked_lm_loss(
-            hidden, embedding, ids,
-            logits_scale=1.0 / model.config.logits_scaling)
+    return _tied_head_loss(model.heads_sharding, hidden, embedding, ids,
+                           logits_scale=1.0 / model.config.logits_scaling)
 
 
 def make_granite_train_step(config, mesh, learning_rate: float = 1e-4,
@@ -641,13 +670,11 @@ def make_granite_train_step(config, mesh, learning_rate: float = 1e-4,
 def lfm2_step_loss(model, params, ids):
     """The loss of ``make_lfm2_train_step``'s step: the stack's final
     hidden states, then ``chunked_lm_loss`` over the tied head."""
-    from .models.gpt import chunked_lm_loss
     from .models.lfm2 import LFM2LMHeadModel
     hidden, embedding = model.apply(
         {"params": params}, ids,
         method=LFM2LMHeadModel.hidden_and_embedding)
-    with jax.named_scope("loss"):
-        return chunked_lm_loss(hidden, embedding, ids)
+    return _tied_head_loss(model.heads_sharding, hidden, embedding, ids)
 
 
 def make_lfm2_train_step(config, mesh, learning_rate: float = 1e-4,

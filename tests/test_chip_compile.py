@@ -29,6 +29,7 @@ from jax.experimental import topologies
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
+import horovod_tpu as hvd
 from horovod_tpu.models.bert import bert_tiny_config
 from horovod_tpu.models.gpt import gpt_tiny_config
 from horovod_tpu.models.granite import granite_tiny_config
@@ -120,6 +121,15 @@ def test_flash_backward_compiles_for_v5e(v5e_chip, shape, dtype, causal):
     assert largest * 4 <= batch * heads * seq * seq, largest
 
 
+def _one_loss_chunk_of(tokens: int):
+    """What the step just traced put on record of its loss's walk: one
+    chunk (these batches are short) of the ``tokens`` ONE described
+    chip holds, not of the global batch."""
+    gauges = hvd.metrics_snapshot()["gauges"]
+    assert gauges["hvd_lm_loss_chunks"] == 1
+    assert gauges["hvd_lm_loss_chunk_tokens"] == tokens
+
+
 @pytest.mark.parametrize("axes,fsdp,remat", [
     ({"dp": 1}, None, False), ({"dp": 2, "tp": 2}, None, False),
     ({"dp": 2, "fsdp": 2}, "fsdp", False), ({"dp": 1}, None, True)],
@@ -151,6 +161,7 @@ def test_gpt_step_runs_the_kernels_in_every_layer(v5e_2x2, axes, fsdp,
             leaf.shape, leaf.dtype, sharding=sharding),
         state, infer_shardings(state, mesh, gpt_partition_rules(fsdp=fsdp)))
     text = step_fn.lower(*state, ids).compile().as_text()
+    _one_loss_chunk_of(batch // mesh.shape[fsdp or "dp"] * seq)
 
     def recomputed_matmuls(text):
         return [line for line in text.splitlines()
@@ -287,6 +298,7 @@ def test_granite_step_compiles_with_the_kernels_on_grouped_heads(v5e_2x2,
             leaf.shape, leaf.dtype, sharding=sharding),
         state, infer_shardings(state, mesh, granite_partition_rules()))
     text = step_fn.lower(*state, ids).compile().as_text()
+    _one_loss_chunk_of(batch // mesh.shape["dp"] * seq)
     kernels = Counter(re.findall(
         r'custom_call_target="tpu_custom_call".*?'
         r'(layer_\d+)/attention/[^"]*?/(hvd_flash_\w+)/', text))
@@ -323,6 +335,7 @@ def test_lfm2_step_compiles_with_grouped_products_and_the_kernels(v5e_2x2,
             leaf.shape, leaf.dtype, sharding=sharding),
         state, infer_shardings(state, mesh, lfm2_partition_rules()))
     text = step_fn.lower(*state, ids).compile().as_text()
+    _one_loss_chunk_of(batch // mesh.shape["dp"] * seq)
     kernels = Counter(re.findall(
         r'custom_call_target="tpu_custom_call".*?'
         r'(layer_\d+)/attention/[^"]*?/(hvd_flash_\w+)/', text))

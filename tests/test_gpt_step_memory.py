@@ -12,7 +12,8 @@ import numpy as np
 import pytest
 
 import horovod_tpu as hvd
-from horovod_tpu.models import gpt
+from horovod_tpu import training
+from horovod_tpu.models import gpt, granite, lfm2
 from horovod_tpu.parallel.mesh import build_mesh
 from horovod_tpu.training import gpt_step_loss, make_gpt_train_step
 from test_pallas_attention import _shapes
@@ -27,12 +28,45 @@ def _rel_l2(got, want):
     return float(np.linalg.norm(got - want) / np.linalg.norm(want))
 
 
-@pytest.mark.parametrize("remat", [False, True], ids=["", "remat"])
-@pytest.mark.parametrize("seq", [256, 301], ids=["s256", "s301-ragged"])
+@pytest.mark.parametrize("sequences,dp,seq,want", [
+    (16, 1, 1024, (8, 128)),     # the GPT cell: 2048 tokens, as chosen
+    (2, 1, 4096, (4, 1024)),     # the Granite and LFM2 cells
+    (64, 4, 1024, (8, 128)),     # 16 of the 64 on a device, not 64
+    (1, 1, 8192, (4, 2048)),
+    (16, 1, 301, (3, 101)),      # ragged: 2 positions of padding
+    (2, 1, 1000, (1, 1000)),     # under 2048 tokens: one chunk
+    (4096, 1, 16, (16, 1)),      # more sequences than that: a position
+], ids=["16x1024", "2x4096", "64x1024-dp4", "1x8192", "16x301-ragged",
+        "2x1000-short", "4096x16"])
+def test_loss_chunks_follow_the_tokens_on_one_device(sequences, dp, seq,
+                                                     want):
+    """The rule on integers: the fewest chunks of at most
+    ``LOSS_CHUNK_TOKENS`` tokens of ONE device, as even as the count
+    allows; the global batch is divided by what the mesh shards it
+    over before the rule sees it."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    mesh = build_mesh({"dp": dp}, jax.devices()[:dp])
+    on_one = training._sequences_on_one_device(
+        NamedSharding(mesh, P("dp", None)), sequences)
+    assert on_one == sequences // dp
+    count, length = gpt.loss_chunks(seq, on_one)
+    assert (count, length) == want
+    assert count * length >= seq > (count - 1) * length
+    assert on_one * length <= gpt.LOSS_CHUNK_TOKENS or length == 1
+    # A model that is applied directly holds the whole batch.
+    assert training._sequences_on_one_device(None, sequences) == sequences
+
+
+# 32 sequences: 64 positions a chunk.
+@pytest.mark.parametrize("seq,chunks,remat", [
+    (60, (1, 60), False), (127, (2, 64), True),
+    (151, (3, 51), False), (151, (3, 51), True)],
+    ids=["s60-one", "s127-ragged-remat", "s151-ragged", "s151-ragged-remat"])
 @pytest.mark.parametrize("masked", [False, True], ids=["", "mask"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-def test_chunked_loss_is_lm_loss_of_the_logits(dtype, masked, seq, remat):
+def test_chunked_loss_is_lm_loss_of_the_logits(dtype, masked, seq, chunks,
+                                               remat):
     """Value and the gradient of EVERY parameter leaf, against
     ``lm_loss(model(ids), ids)`` without ``remat``: float32 to 1e-5;
     bf16 no further from the float32 answer than the logits path is
@@ -43,13 +77,14 @@ def test_chunked_loss_is_lm_loss_of_the_logits(dtype, masked, seq, remat):
                                 max_position_embeddings=seq)
     cfg = dataclasses.replace(plain, remat=remat)
     rng = np.random.RandomState(seq)
-    ids = jnp.asarray(rng.randint(0, cfg.vocab_size, (2, seq),
+    batch = 32
+    ids = jnp.asarray(rng.randint(0, cfg.vocab_size, (batch, seq),
                                   dtype=np.int32))
-    mask = jnp.asarray(rng.rand(2, seq) < 0.7) if masked else None
+    mask = jnp.asarray(rng.rand(batch, seq) < 0.7) if masked else None
     params = gpt.GPTLMHeadModel(plain).init(
         jax.random.PRNGKey(0), ids)["params"]
-    # Two whole chunks; three of 101 positions, the last two padding.
-    assert gpt.loss_chunks(seq) == ((2, 128) if seq == 256 else (3, 101))
+    # One whole chunk; two and three with a position and two of padding.
+    assert gpt.loss_chunks(seq, batch) == chunks
 
     def of_logits(config):
         model = gpt.GPTLMHeadModel(config)
@@ -104,9 +139,10 @@ def test_chunked_loss_is_lm_loss_of_the_logits(dtype, masked, seq, remat):
                 rtol=tol, atol=tol * scale, err_msg=str(path))
 
 
-def _tiny_step(remat: bool, batch=4, seq=2 * gpt.LOSS_CHUNK):
-    # Sized so that a chunk's logits are the step's largest array (the
-    # CPU's attention holds [B, heads, S, S] scores).
+def _tiny_step(remat: bool, batch=16, seq=256):
+    # Sized so that a chunk's logits (128 positions of 16 sequences) are
+    # the step's largest array (the CPU's attention holds [B, heads, S,
+    # S] scores).
     cfg = gpt.gpt_tiny_config(remat=remat, max_position_embeddings=seq,
                               vocab_size=2048, num_heads=2)
     mesh = build_mesh({"dp": 1}, jax.devices()[:1])
@@ -124,7 +160,8 @@ def test_gpt_step_holds_no_array_of_the_whole_logits():
     batch, seq = args[2].shape
     whole = batch * seq * cfg.vocab_size
     shapes = _shapes(jax.make_jaxpr(step_fn)(*args).jaxpr, set())
-    assert (batch, gpt.LOSS_CHUNK, cfg.vocab_size) in shapes, shapes
+    assert gpt.loss_chunks(seq, batch) == (2, 128)
+    assert (batch, 128, cfg.vocab_size) in shapes, shapes
     assert max(map(math.prod, shapes)) * 2 <= whole, shapes
 
     model = gpt.GPTLMHeadModel(cfg)
@@ -186,17 +223,46 @@ def test_the_step_decides_by_its_device_memory(monkeypatch):
 
 def test_gauges_show_in_the_metrics_snapshot():
     """Tracing a tiny step sets the bytes kept across ``remat`` (by the
-    names kept) and the loss's chunk count."""
-    cfg, step_fn, args = _tiny_step(remat=True, seq=3 * gpt.LOSS_CHUNK + 5)
+    names kept), the loss's chunk count and a chunk's tokens."""
+    cfg, step_fn, args = _tiny_step(remat=True, seq=3 * 128 + 5)
     step_fn.lower(*args)
     gauges = hvd.metrics_snapshot()["gauges"]
-    assert gauges["hvd_gpt_loss_chunks"] == 4
+    assert "hvd_gpt_loss_chunks" not in gauges
+    assert gauges["hvd_lm_loss_chunks"] == 4
+    assert gauges["hvd_lm_loss_chunk_tokens"] == 16 * 98   # ceil(389 / 4)
     batch, seq = args[2].shape
     per_token = 2 * cfg.num_layers * (
         5 * cfg.hidden_size + cfg.intermediate_size) \
         + 4 * cfg.num_layers * cfg.num_heads
     assert gauges["hvd_gpt_remat_kept_bytes"][
         "names=" + "+".join(gpt.REMAT_NAMES)] == batch * seq * per_token
+
+
+@pytest.mark.parametrize("family", ["gpt", "granite", "lfm2"])
+def test_every_builder_records_the_chunks_of_one_device(family):
+    """The three causal-LM builders set both gauges when their step is
+    traced, from the sequences ONE device holds: 64 sequences of 128
+    positions over dp2 x tp2 are 32 a device, two chunks of 64
+    positions; the global batch would walk four of 32."""
+    config, make = {
+        "gpt": (gpt.gpt_tiny_config(), make_gpt_train_step),
+        "granite": (granite.granite_tiny_config(),
+                    training.make_granite_train_step),
+        "lfm2": (lfm2.lfm2_tiny_config(), training.make_lfm2_train_step),
+    }[family]
+    mesh = build_mesh({"dp": 2, "tp": 2}, jax.devices()[:4])
+    init_fn, step_fn, batch_sharding = make(config, mesh)
+    ids = jax.ShapeDtypeStruct((64, 128), jnp.int32, sharding=batch_sharding)
+    state = jax.eval_shape(init_fn, jax.random.PRNGKey(0), ids)
+    for gauge in (training._LOSS_CHUNKS, training._LOSS_CHUNK_TOKENS):
+        gauge.set(0)   # whatever an earlier test's trace left
+    text = step_fn.lower(*state, ids).as_text()
+    gauges = hvd.metrics_snapshot()["gauges"]
+    assert gauges["hvd_lm_loss_chunks"] == 2
+    assert gauges["hvd_lm_loss_chunk_tokens"] == 32 * 64 == 2048
+    # The walk itself: the global batch, 64 positions at a time.
+    assert "tensor<64x64x%dxf32>" % config.vocab_size in text
+    assert "tensor<64x32x%dxf32>" % config.vocab_size not in text
 
 
 def test_step_loss_is_the_logits_loss_on_a_mesh():

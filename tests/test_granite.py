@@ -93,7 +93,9 @@ def test_chunked_loss_scales_the_logits(scale):
     """``chunked_lm_loss(..., logits_scale=s)`` is ``lm_loss`` of the
     tied head's logits times ``s``, in value and both gradients."""
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
-    hidden = jax.random.normal(keys[0], (2, 2 * gpt.LOSS_CHUNK + 7, 32))
+    # 32 sequences walk 64 positions a chunk: three ragged chunks here.
+    hidden = jax.random.normal(keys[0], (32, 2 * 64 + 7, 32))
+    assert gpt.loss_chunks(seq=135, sequences=32) == (3, 45)
     table = jax.random.normal(keys[1], (96, 32))
     ids = jax.random.randint(keys[2], hidden.shape[:2], 0, 96)
 
