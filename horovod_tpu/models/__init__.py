@@ -13,6 +13,8 @@ from .gpt import (GPTConfig, GPTLMHeadModel, chunked_lm_loss,
 from .granite import (GraniteConfig, GraniteLMHeadModel,
                       granite_tiny_config)
 from .lfm2 import LFM2Config, LFM2LMHeadModel, lfm2_tiny_config
+from .deepseek_v3 import (DeepseekV3Config, DeepseekV3LMHeadModel,
+                          deepseek_v3_tiny_config)
 from .mnist import MnistCNN, MnistMLP, cross_entropy_loss
 from .dlrm import (DLRMConfig, DLRMDense, bce_logits_loss,
                    dlrm_tiny_config, synthetic_click_batch)
@@ -26,6 +28,7 @@ __all__ = [
     "gpt2_medium_config", "gpt_tiny_config", "lm_loss", "chunked_lm_loss",
     "GraniteConfig", "GraniteLMHeadModel", "granite_tiny_config",
     "LFM2Config", "LFM2LMHeadModel", "lfm2_tiny_config",
+    "DeepseekV3Config", "DeepseekV3LMHeadModel", "deepseek_v3_tiny_config",
     "MnistCNN", "MnistMLP", "cross_entropy_loss",
     "DLRMConfig", "DLRMDense", "bce_logits_loss", "dlrm_tiny_config",
     "synthetic_click_batch",

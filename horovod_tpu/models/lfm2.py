@@ -300,15 +300,24 @@ class LFM2LMHeadModel(nn.Module):
                           preferred_element_type=jnp.float32)
 
 
+def sown_choices(model: nn.Module, params, input_ids):
+    """``{layer index: [T, top_k] int32}``: what the ``moe`` module of
+    every sparse ``layer_<i>`` of ``model`` sowed as ``chosen`` on
+    ``input_ids`` (the stacks of this family and of the families built
+    on its routed experts)."""
+    _, state = model.apply(
+        {"params": params}, input_ids, mutable=["intermediates"],
+        method="hidden_and_embedding")
+    return {int(name.split("_")[1]): layer["moe"]["chosen"][0]
+            for name, layer in state["intermediates"].items()}
+
+
 def expert_choices(config: LFM2Config, params, input_ids):
     """``{layer index: [T, top_k] int32}``: the experts, of all
     ``num_experts``, that each token chose in every sparse layer."""
-    model = LFM2LMHeadModel(dataclasses.replace(config, remat=False))
-    _, state = model.apply(
-        {"params": params}, input_ids, mutable=["intermediates"],
-        method=LFM2LMHeadModel.hidden_and_embedding)
-    return {int(name.split("_")[1]): layer["moe"]["chosen"][0]
-            for name, layer in state["intermediates"].items()}
+    return sown_choices(
+        LFM2LMHeadModel(dataclasses.replace(config, remat=False)), params,
+        input_ids)
 
 
 def counts_by_expert(chosen, num_experts: int):

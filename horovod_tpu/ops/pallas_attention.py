@@ -20,6 +20,14 @@ seen as ``[B, S, H * D]``: a block holds as many heads as fill the 128
 lanes (two of GPT-2's), each head a lane slice of it, so XLA moves
 nothing around the kernels and every store is lane-dense.
 
+Two head sizes: queries and keys share one (``D``), values and the
+output may have another (``Dv``; latent attention's keys carry a
+positional part that its values lack).  A block then holds the same
+heads of both widths, as few as make both whole lanes (two of 192 and
+128); the forward's accumulator, dV and ``di`` are ``Dv`` wide, dQ and
+dK ``D`` wide, and nothing is padded.  Where the two are equal the
+kernels are the ones they were.
+
 Tiles wholly above the causal diagonal are skipped, tiles on it are
 masked, tiles below it carry no mask arithmetic.  Both products of a
 tile take their operands in the dtype they arrive in (bf16 in the
@@ -147,8 +155,14 @@ def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
                                preferred_element_type=jnp.float32)
 
 
+def _head_lanes(h: int, d: int, dv: int):
+    """Head ``h``'s lanes of a block of queries or keys, and of a block
+    of values (or of anything as wide as they)."""
+    return slice(h * d, (h + 1) * d), slice(h * dv, (h + 1) * dv)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
-                vt_ref, *, causal, nq, nk, heads, d, bq, bk, sq, sk, skv):
+                vt_ref, *, causal, nq, nk, heads, d, dv, bq, bk, sq, sk, skv):
     """Scores, statistics and accumulator all transposed ([keys,
     queries], [1, queries], [D, queries]): the running max and sum of
     a query then lie along the lanes, a few registers a tile, and
@@ -162,16 +176,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
     _when(j == 0, init)
-    # The values once a grid step as [heads * D, keys], for the
+    # The values once a grid step as [heads * Dv, keys], for the
     # transposed accumulator: a head's are then a sublane slice.
     vt_ref[:] = v_ref[0].T
 
     offsets = _offsets(causal, (sk, sq), 1)
     for h in range(heads):
-        lanes = slice(h * d, (h + 1) * d)
+        lanes, vlanes = _head_lanes(h, d, dv)
 
-        def tile(masked, rows, cols, q0, k0, h=h, lanes=lanes):
-            vt = vt_ref[lanes, cols]                        # [D, sk]
+        def tile(masked, rows, cols, q0, k0, h=h, lanes=lanes,
+                 vlanes=vlanes):
+            vt = vt_ref[vlanes, cols]                       # [Dv, sk]
             st = _dot(k_ref[0, cols, lanes], q_ref[0, rows, lanes],
                       _NT)                          # q arrives scaled
             if masked:
@@ -183,7 +198,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
             l_ref[h, :, rows] = l_ref[h, :, rows] * corr + jnp.sum(
                 pt, axis=0, keepdims=True)
             acc_ref[h, :, rows] = acc_ref[h, :, rows] * corr + _dot(
-                vt, pt.astype(vt.dtype))                    # [D, sq]
+                vt, pt.astype(vt.dtype))                    # [Dv, sq]
             m_ref[h, :, rows] = m_new
 
         _tiles(tile, i, j, causal=causal, bq=bq, bk=bk, sq=sq, sk=sk,
@@ -192,15 +207,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
     def finalize():
         l = l_ref[:]                                        # [heads, 1, bq]
         l = jnp.where(l == 0.0, 1.0, l)               # fully-masked rows
-        out_t = (acc_ref[:] / l).reshape(heads * d, bq)
-        o_ref[0] = out_t.T.astype(o_ref.dtype)              # [bq, heads * D]
+        out_t = (acc_ref[:] / l).reshape(heads * dv, bq)
+        o_ref[0] = out_t.T.astype(o_ref.dtype)              # [bq, heads * Dv]
         lse_ref[0] = m_ref[:] + jnp.log(l)
     _when(j == nk - 1, finalize)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc,
-                *, causal, nq, nk, heads, d, bq, bk, sq, sk, skv):
+                *, causal, nq, nk, heads, d, dv, bq, bk, sq, sk, skv):
     """Scores transposed, as in the forward: ``lse`` and ``di`` come
     as rows and broadcast down the sublanes."""
     i, j = _block_ids(nq, nk, 3)
@@ -212,16 +227,17 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
 
     offsets = _offsets(causal, (sk, sq), 1)
     for h in range(heads):
-        lanes = slice(h * d, (h + 1) * d)
+        lanes, vlanes = _head_lanes(h, d, dv)
 
-        def tile(masked, rows, cols, q0, k0, h=h, lanes=lanes):
-            q, do = q_ref[0, rows, lanes], do_ref[0, rows, lanes]
+        def tile(masked, rows, cols, q0, k0, h=h, lanes=lanes,
+                 vlanes=vlanes):
+            q, do = q_ref[0, rows, lanes], do_ref[0, rows, vlanes]
             st = _dot(k_ref[0, cols, lanes], q, _NT)        # [sk, sq]
             if masked:
                 st = _mask(st, offsets, q0, k0, 1, skv)
             pt = jnp.exp(st - lse_ref[0, h, :, rows])       # rows: [1, sq]
-            dv_acc[cols, lanes] += _dot(pt.astype(do.dtype), do)
-            dpt = _dot(v_ref[0, cols, lanes], do, _NT)
+            dv_acc[cols, vlanes] += _dot(pt.astype(do.dtype), do)
+            dpt = _dot(v_ref[0, cols, vlanes], do, _NT)
             dst = pt * (dpt - di_ref[0, h, :, rows])
             dk_acc[cols, lanes] += _dot(dst.astype(q.dtype), q)  # q scaled
 
@@ -235,7 +251,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dq_acc,
-               *, scale, causal, nq, nk, heads, d, bq, bk, sq, sk, skv):
+               *, scale, causal, nq, nk, heads, d, dv, bq, bk, sq, sk, skv):
     """Scores as [queries, keys], so that dQ = dS K is a plain
     product; the row statistics are turned from lanes to sublanes."""
     i, j = _block_ids(nq, nk, 2)
@@ -246,15 +262,16 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dq_acc,
 
     offsets = _offsets(causal, (sq, sk), 0)
     for h in range(heads):
-        lanes = slice(h * d, (h + 1) * d)
+        lanes, vlanes = _head_lanes(h, d, dv)
 
-        def tile(masked, rows, cols, q0, k0, h=h, lanes=lanes):
+        def tile(masked, rows, cols, q0, k0, h=h, lanes=lanes,
+                 vlanes=vlanes):
             k = k_ref[0, cols, lanes]
             s = _dot(q_ref[0, rows, lanes], k, _NT)         # [sq, sk]
             if masked:
                 s = _mask(s, offsets, q0, k0, 0, skv)
             p = jnp.exp(s - lse_ref[0, h, 0, rows][:, None])
-            dp = _dot(do_ref[0, rows, lanes], v_ref[0, cols, lanes], _NT)
+            dp = _dot(do_ref[0, rows, vlanes], v_ref[0, cols, vlanes], _NT)
             ds = p * (dp - di_ref[0, h, 0, rows][:, None])
             dq_acc[rows, lanes] += _dot(ds.astype(k.dtype), k)
 
@@ -277,12 +294,14 @@ def _blocking(seq: int, tile: int, seq_block: int):
     return tile, block, -(-seq // block) * block
 
 
-def _heads_per_block(heads: int, d: int) -> int:
-    """As few heads as make a block's last dimension whole lanes; all
-    of them where no such count divides ``heads`` (a block as wide as
-    the array is always allowed)."""
+def _heads_per_block(heads: int, d: int, dv: int) -> int:
+    """As few heads as make a block's last dimension whole lanes, at
+    the queries' and keys' width and at the values'; all of them where
+    no such count divides ``heads`` (a block as wide as the array is
+    always allowed)."""
     for g in range(1, heads):
-        if heads % g == 0 and (g * d) % LANES == 0:
+        if heads % g == 0 and (g * d) % LANES == 0 \
+                and (g * dv) % LANES == 0:
             return g
     return heads
 
@@ -294,15 +313,15 @@ def _pad_seq(x, rows: int):
 
 class _Plan:
     """Blocks, grid and index maps of one kernel call on ``[B, S, H *
-    D]`` arrays.  The grid is (batch, head groups, x, y), y sequential:
-    x the q blocks and y the kv blocks (``q_axis`` 2), or the other
-    way round (``q_axis`` 3)."""
+    D]`` queries and keys and ``[B, S, H * Dv]`` values.  The grid is
+    (batch, head groups, x, y), y sequential: x the q blocks and y the
+    kv blocks (``q_axis`` 2), or the other way round (``q_axis`` 3)."""
 
-    def __init__(self, q, k, heads, tile, seq_block, q_axis):
+    def __init__(self, q, k, v, heads, tile, seq_block, q_axis):
         self.batch, self.sq_len, width = q.shape
         self.skv_len = k.shape[1]
-        self.d = width // heads
-        self.g = _heads_per_block(heads, self.d)
+        self.d, self.dv = width // heads, v.shape[2] // heads
+        self.g = _heads_per_block(heads, self.d, self.dv)
         self.sq, self.bq, self.sq_pad = _blocking(self.sq_len, tile[0],
                                                   seq_block)
         self.sk, self.bk, self.skv_pad = _blocking(self.skv_len, tile[1],
@@ -316,20 +335,29 @@ class _Plan:
         # padded, for the kernels to hide them: a padded key scores 0,
         # not nothing.
         self.sizes = dict(nq=self.nq, nk=self.nk, heads=self.g, d=self.d,
-                          bq=self.bq, bk=self.bk, sq=self.sq, sk=self.sk,
+                          dv=self.dv, bq=self.bq, bk=self.bk, sq=self.sq,
+                          sk=self.sk,
                           skv=(self.skv_len if self.skv_pad != self.skv_len
                                else None))
 
-    def _rows(self, block, at):
-        return pl.BlockSpec((1, block, self.g * self.d),
+    def _rows(self, block, at, d):
+        return pl.BlockSpec((1, block, self.g * d),
                             lambda *ids: (ids[0], ids[at], ids[1]))
 
     def q_rows(self):
-        """A block of queries (or of anything shaped like them)."""
-        return self._rows(self.bq, self.q_at)
+        """A block of queries (or of dQ)."""
+        return self._rows(self.bq, self.q_at, self.d)
+
+    def o_rows(self):
+        """A block of the output (or of its cotangent): the queries'
+        rows at the values' width."""
+        return self._rows(self.bq, self.q_at, self.dv)
 
     def k_rows(self):
-        return self._rows(self.bk, self.k_at)
+        return self._rows(self.bk, self.k_at, self.d)
+
+    def v_rows(self):
+        return self._rows(self.bk, self.k_at, self.dv)
 
     def q_stats(self):
         """Row statistics, [B, H, 1, Sq]: a row of floats a head."""
@@ -349,20 +377,20 @@ class _Plan:
 @functools.partial(jax.jit, static_argnames=(
     "heads", "causal", "tile", "seq_block", "interpret"))
 def _fwd_call(q, k, v, *, heads, causal, tile, seq_block, interpret):
-    """``q`` (scaled), ``k``, ``v``: [B, S, H * D].  Returns the output
-    [B, Sq, H * D] and the log-sum-exp of every (padded) row,
-    [B, H, 1, Sq padded]."""
-    p = _Plan(q, k, heads, tile, seq_block, q_axis=2)
+    """``q`` (scaled), ``k``: [B, S, H * D]; ``v``: [B, S, H * Dv].
+    Returns the output [B, Sq, H * Dv] and the log-sum-exp of every
+    (padded) row, [B, H, 1, Sq padded]."""
+    p = _Plan(q, k, v, heads, tile, seq_block, q_axis=2)
     out, lse = p.call(
         functools.partial(_fwd_kernel, causal=causal, **p.sizes),
         "hvd_flash_fwd",
-        [p.q_rows(), p.k_rows(), p.k_rows()], [p.q_rows(), p.q_stats()],
-        [jax.ShapeDtypeStruct((p.batch, p.sq_pad, heads * p.d), q.dtype),
+        [p.q_rows(), p.k_rows(), p.v_rows()], [p.o_rows(), p.q_stats()],
+        [jax.ShapeDtypeStruct((p.batch, p.sq_pad, heads * p.dv), q.dtype),
          jax.ShapeDtypeStruct((p.batch, heads, 1, p.sq_pad), jnp.float32)],
         [pltpu.VMEM((p.g, 1, p.bq), jnp.float32),
          pltpu.VMEM((p.g, 1, p.bq), jnp.float32),
-         pltpu.VMEM((p.g, p.d, p.bq), jnp.float32),
-         pltpu.VMEM((p.g * p.d, p.bk), v.dtype)],
+         pltpu.VMEM((p.g, p.dv, p.bq), jnp.float32),
+         pltpu.VMEM((p.g * p.dv, p.bk), v.dtype)],
         interpret,
     )(_pad_seq(q, p.sq_pad), _pad_seq(k, p.skv_pad), _pad_seq(v, p.skv_pad))
     return out[:, :p.sq_len], lse
@@ -378,7 +406,7 @@ def _bwd_operands(p: _Plan, q, k, v, lse, do, di):
              for x in (lse, di)]
     args = (_pad_seq(q, p.sq_pad), _pad_seq(k, p.skv_pad),
             _pad_seq(v, p.skv_pad), _pad_seq(do, p.sq_pad), *stats)
-    specs = [p.q_rows(), p.k_rows(), p.k_rows(), p.q_rows(),
+    specs = [p.q_rows(), p.k_rows(), p.v_rows(), p.o_rows(),
              p.q_stats(), p.q_stats()]
     return args, specs
 
@@ -389,7 +417,7 @@ def _bwd_call(q, k, v, lse, do, di, *, heads, scale, causal, tile,
               seq_block, interpret):
     """dQ, dK, dV of ``_fwd_call`` (``q`` scaled; dQ is for the
     unscaled one).  ``di`` is ``sum(o * do)`` a row, [B, H, 1, Sq]."""
-    p = _Plan(q, k, heads, tile, seq_block, q_axis=2)
+    p = _Plan(q, k, v, heads, tile, seq_block, q_axis=2)
     args, specs = _bwd_operands(p, q, k, v, lse, do, di)
     dq = p.call(
         functools.partial(_dq_kernel, scale=scale, causal=causal, **p.sizes),
@@ -397,14 +425,15 @@ def _bwd_call(q, k, v, lse, do, di, *, heads, scale, causal, tile,
         jax.ShapeDtypeStruct(args[0].shape, q.dtype),
         [pltpu.VMEM((p.bq, p.g * p.d), jnp.float32)], interpret)(*args)
 
-    p = _Plan(q, k, heads, tile, seq_block, q_axis=3)
+    p = _Plan(q, k, v, heads, tile, seq_block, q_axis=3)
     args, specs = _bwd_operands(p, q, k, v, lse, do, di)
-    like_k = jax.ShapeDtypeStruct(args[1].shape, k.dtype)
     dk, dv = p.call(
         functools.partial(_dkv_kernel, causal=causal, **p.sizes),
-        "hvd_flash_bwd_dkv", specs, [p.k_rows(), p.k_rows()],
-        [like_k, like_k],
-        [pltpu.VMEM((p.bk, p.g * p.d), jnp.float32)] * 2, interpret)(*args)
+        "hvd_flash_bwd_dkv", specs, [p.k_rows(), p.v_rows()],
+        [jax.ShapeDtypeStruct(args[1].shape, k.dtype),
+         jax.ShapeDtypeStruct(args[2].shape, v.dtype)],
+        [pltpu.VMEM((p.bk, p.g * p.d), jnp.float32),
+         pltpu.VMEM((p.bk, p.g * p.dv), jnp.float32)], interpret)(*args)
     return dq[:, :p.sq_len], dk[:, :p.skv_len], dv[:, :p.skv_len]
 
 
@@ -418,31 +447,32 @@ def _flash(q, k, v, scale, causal, tile, seq_block, interpret):
 
 def _flash_vjp_fwd(q, k, v, scale, causal, tile, seq_block, interpret):
     B, Sq, H, D = q.shape
+    Dv = v.shape[-1]
     # [B, S, H, D] is [B, S, H * D] for free.  The scale rides on q,
     # in q's dtype (exact for a head size that is a power of four):
     # the kernels then spend nothing on it per score, and dK = dS^T
     # (scale q) comes out scaled by itself.
     q, k, v = ((q * scale).reshape(B, Sq, H * D),
-               k.reshape(B, -1, H * D), v.reshape(B, -1, H * D))
+               k.reshape(B, -1, H * D), v.reshape(B, -1, H * Dv))
     out, lse = _fwd_call(q, k, v, heads=H, causal=causal, tile=tile,
                          seq_block=seq_block, interpret=interpret)
     # Named for a caller's checkpoint policy: a model that recomputes
     # its layers in the backward pass can keep these two and spare the
     # forward kernel's second run.
-    out = checkpoint_name(out.reshape(B, Sq, H, D), "flash_out")
+    out = checkpoint_name(out.reshape(B, Sq, H, Dv), "flash_out")
     lse = checkpoint_name(lse, "flash_lse")
     return out, (q, k, v, out, lse)
 
 
 def _flash_vjp_bwd(scale, causal, tile, seq_block, interpret, res, do):
     q, k, v, out, lse = res
-    B, Sq, H, D = do.shape
+    B, Sq, H, Dv = do.shape
     di = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
     di = di.transpose(0, 2, 1)[:, :, None, :]               # [B, H, 1, Sq]
-    grads = _bwd_call(q, k, v, lse, do.reshape(B, Sq, H * D), di, heads=H,
+    grads = _bwd_call(q, k, v, lse, do.reshape(B, Sq, H * Dv), di, heads=H,
                       scale=scale, causal=causal, tile=tile,
                       seq_block=seq_block, interpret=interpret)
-    return tuple(g.reshape(B, -1, H, D) for g in grads)
+    return tuple(g.reshape(B, g.shape[1], H, -1) for g in grads)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -454,7 +484,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: bool = False) -> jax.Array:
-    """Flash attention on ``[B, S, H, D]`` tensors, differentiable.
+    """Flash attention on ``[B, S, H, D]`` queries and keys and ``[B,
+    S, H, Dv]`` values, differentiable; the output is ``Dv`` wide, and
+    ``scale`` defaults to ``1 / sqrt(D)``.
 
     ``block_q`` x ``block_k`` is the tile of scores the kernels compute
     at a time (by default ``TILE``, picked on the chip); a grid step

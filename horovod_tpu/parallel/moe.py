@@ -25,7 +25,7 @@
   dropped (standard Switch semantics).
 """
 
-from typing import Callable, NamedTuple
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -42,7 +42,8 @@ ROWS_NAME, EXPERT_GATE_UP_NAME = "moe_rows", "moe_gate_up"
 # backward pass's cotangents through experts the forward pass never
 # ran: one such token of 128 moved a tiny layer's gradients by 130 %.
 CHOICE_NAME = "moe_chosen"
-# Added to the sum of the chosen gates before they are divided by it.
+# Added to the sum of the chosen gates before they are divided by it,
+# where the caller names no other (a family's own: ``gate_sum_eps``).
 GATE_SUM_EPS = 1e-6
 # Rows of the sorted buffer a walk (:func:`_gates_of_rows`) takes at a
 # time: it stops at the first chunk that starts past the pairs.  A
@@ -122,21 +123,29 @@ class Routing(NamedTuple):
 
 def sigmoid_top_k(x: jax.Array, router_kernel: jax.Array,
                   selection_bias: jax.Array, top_k: int,
-                  normalize: bool = True, scale: float = 1.0) -> Routing:
+                  normalize: bool = True, scale: float = 1.0,
+                  gate_sum_eps: float = GATE_SUM_EPS,
+                  chosen: Optional[jax.Array] = None) -> Routing:
     """Scores ``s = sigmoid(x @ W_r)`` over all experts; the ``top_k``
     of ``s + selection_bias`` are chosen (the bias selects and no
     gradient reaches it); a chosen expert's gate is its ``s``, divided
-    by the chosen gates' sum where ``normalize``, times ``scale``.
-    All of it in float32 whatever ``x``'s type, the product at full
-    precision: it is 64 columns wide, and a choice is a comparison."""
+    by the chosen gates' sum plus ``gate_sum_eps`` where ``normalize``,
+    times ``scale``.  All of it in float32 whatever ``x``'s type, the
+    product at full precision: it is as many columns wide as there are
+    experts, and a choice is a comparison.  ``chosen`` ``[T, top_k]``
+    hands the choice over (a comparison that holds two computations to
+    ONE discrete choice: two compiles of one forward pass decide a few
+    near-ties differently); scores and gates are still this router's."""
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), router_kernel.astype(jnp.float32),
         precision=lax.Precision.HIGHEST))
-    _, chosen = lax.top_k(scores + lax.stop_gradient(selection_bias), top_k)
+    if chosen is None:
+        _, chosen = lax.top_k(scores + lax.stop_gradient(selection_bias),
+                              top_k)
     chosen = checkpoint_name(chosen, CHOICE_NAME)
     gates = jnp.take_along_axis(scores, chosen, axis=-1)
     if normalize:
-        gates = gates / (gates.sum(-1, keepdims=True) + GATE_SUM_EPS)
+        gates = gates / (gates.sum(-1, keepdims=True) + gate_sum_eps)
     return Routing(chosen.astype(jnp.int32), gates * scale)
 
 
@@ -319,7 +328,8 @@ def routed_experts(x: jax.Array, router_kernel: jax.Array,
                    selection_bias: jax.Array, gate_kernels: jax.Array,
                    up_kernels: jax.Array, down_kernels: jax.Array, *,
                    first_expert: int, top_k: int, normalize: bool = True,
-                   scale: float = 1.0):
+                   scale: float = 1.0, gate_sum_eps: float = GATE_SUM_EPS,
+                   chosen: Optional[jax.Array] = None):
     """Top-k routed SwiGLU experts, the part of the layer that the
     experts HELD give: ``y_t = sum over the chosen e held of g_te *
     expert_e(x_t)``, ``expert_e(x) = (silu(x W1_e) * (x W3_e)) W2_e``.
@@ -350,7 +360,7 @@ def routed_experts(x: jax.Array, router_kernel: jax.Array,
     dtype = x.dtype
     with jax.named_scope("router"):
         routing = sigmoid_top_k(x, router_kernel, selection_bias, top_k,
-                                normalize, scale)
+                                normalize, scale, gate_sum_eps, chosen)
     with jax.named_scope("dispatch"):
         plan, row_gate = held_pairs(routing, first_expert, held)
         rows = checkpoint_name(_dispatch(x, plan), ROWS_NAME)

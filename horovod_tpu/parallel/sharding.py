@@ -114,6 +114,33 @@ def lfm2_partition_rules(tp: str = "tp", fsdp: Optional[str] = None,
     ]
 
 
+def deepseek_v3_partition_rules(tp: str = "tp", fsdp: Optional[str] = None,
+                                ep: str = "ep") -> Rules:
+    """Sharding for the DeepSeek-V3 family (models/deepseek_v3.py).
+    Over ``tp``: latent attention over its heads (the query, the
+    key-value up-projection and the output projection; the narrow
+    down-projection and the latent's norm, which every head reads, stay
+    whole), the dense SwiGLU, the shared expert and every routed expert
+    over their columns.  The stacked experts' leading axis lies on
+    ``ep`` where the mesh has one, the router whole everywhere, and no
+    exchange is written for ``ep`` yet, as for
+    :func:`lfm2_partition_rules`.  The embedding and the head, two
+    matrices, both by rows of the vocabulary."""
+    f = fsdp
+    return [
+        (r"word_embeddings/embedding$", P(tp, f)),
+        (r"lm_head$", P(tp, f)),
+        (r"attention/(query|kv_up)/kernel$", P(f, tp, None)),
+        (r"attention/kv_down/kernel$", P(f, None)),
+        (r"attention/out/kernel$", P(tp, None, f)),
+        (r"(mlp|moe/shared)/(gate|up)/kernel$", P(f, tp)),
+        (r"(mlp|moe/shared)/out/kernel$", P(tp, f)),
+        (r"moe/(gate|up)$", P(ep, f, tp)),
+        (r"moe/down$", P(ep, tp, f)),
+        (r".*", P()),  # norms, the router and its bias replicated
+    ]
+
+
 def resnet_partition_rules(fsdp: Optional[str] = None) -> Rules:
     """ResNet is pure data parallel (conv kernels are small); optionally
     ZeRO-shard the dense head."""

@@ -463,8 +463,9 @@ _SSM_SCAN_BYTES = metrics.gauge(
     "layer's forward pass on one device (set when the step is traced)")
 _HYBRID_LAYERS = metrics.gauge(
     "hvd_hybrid_layers",
-    "Layers of a hybrid step's stack, by kind (set when the step is "
-    "traced)")
+    "Layers of a step's stack whose layers differ, by kind: the mixer's "
+    "(mamba, attention; conv, full_attention) or the feed-forward's "
+    "(dense, sparse) (set when the step is traced)")
 _LFM2_REMAT_KEPT = metrics.gauge(
     "hvd_lfm2_remat_kept_bytes",
     "Bytes one device keeps across the LFM2 step's remat, by the names "
@@ -491,6 +492,28 @@ _MOE_DISPATCH_BYTES = metrics.gauge(
     "hvd_moe_dispatch_bytes",
     "Bytes one sparse layer's forward pass materialises on one device "
     "between the router and the combine (set when the step is traced)")
+_MOE_SHARED_WIDTH = metrics.gauge(
+    "hvd_moe_shared_width",
+    "Width of the always-on shared expert beside a sparse layer's routed "
+    "ones; 0 where there is none (set when the step is traced)")
+_DEEPSEEK_V3_REMAT_KEPT = metrics.gauge(
+    "hvd_deepseek_v3_remat_kept_bytes",
+    "Bytes one device keeps across the DeepSeek-V3 step's remat, by the "
+    "names kept (set when the step is traced)")
+_MLA_HEADS = metrics.gauge(
+    "hvd_mla_heads",
+    "Heads of latent attention one device computes (set when the step is "
+    "traced)")
+_MLA_HEAD_DIMS = metrics.gauge(
+    "hvd_mla_head_dims",
+    "Widths of latent attention: which=qk a query's and a key's, which=v "
+    "a value's, which=rope the rotary part of qk, which=latent the "
+    "compressed key-value (set when the step is traced)")
+_MLA_EXPAND_BYTES = metrics.gauge(
+    "hvd_mla_expand_bytes",
+    "Bytes one layer's forward pass writes on one device for the keys and "
+    "values expanded for every head, the one rotated key repeated among "
+    "them (set when the step is traced)")
 MOE_PAIRS_HELD = metrics.gauge(
     "hvd_moe_pairs_held",
     "Pairs of token and expert that fell on the experts held, of one "
@@ -667,6 +690,20 @@ def make_granite_train_step(config, mesh, learning_rate: float = 1e-4,
         mesh, tx, rules, batch_axis, model, traced_model, granite_step_loss)
 
 
+def _set_moe_gauges(tokens: int, hidden: int, width: int, total: int,
+                    held: int, top_k: int, itemsize: int):
+    """What a step with ``routed_experts`` layers puts on record when
+    it is traced, for ``tokens`` of the batch on one device."""
+    from .parallel import moe
+    _MOE_EXPERTS.set(total, which="total")
+    _MOE_EXPERTS.set(held, which="held")
+    _MOE_TOP_K.set(top_k)
+    _MOE_DISPATCH_ROWS.set(moe.dispatch_rows(tokens, top_k, held))
+    _MOE_WALK_CHUNK_ROWS.set(moe.WALK_CHUNK_ROWS)
+    _MOE_DISPATCH_BYTES.set(moe.dispatch_bytes(
+        tokens, hidden, width, top_k, held, itemsize))
+
+
 def lfm2_step_loss(model, params, ids):
     """The loss of ``make_lfm2_train_step``'s step: the stack's final
     hidden states, then ``chunked_lm_loss`` over the tied head."""
@@ -693,7 +730,6 @@ def make_lfm2_train_step(config, mesh, learning_rate: float = 1e-4,
     but for the flash kernels' output and what
     ``models.lfm2.remat_names`` chooses when the step is traced."""
     from .models import lfm2
-    from .parallel import moe
     from .parallel.sharding import lfm2_partition_rules
 
     batch_axis = fsdp or "dp"
@@ -708,15 +744,10 @@ def make_lfm2_train_step(config, mesh, learning_rate: float = 1e-4,
         """The model of this trace: with ``remat``, keeping what fits
         beside ``state`` at ``ids``'s shape."""
         tokens = ids.size // mesh.shape[batch_axis]   # on one device
-        held, top_k = config.experts_held, config.num_experts_per_tok
-        _MOE_EXPERTS.set(config.num_experts, which="total")
-        _MOE_EXPERTS.set(held, which="held")
-        _MOE_TOP_K.set(top_k)
-        _MOE_DISPATCH_ROWS.set(moe.dispatch_rows(tokens, top_k, held))
-        _MOE_WALK_CHUNK_ROWS.set(moe.WALK_CHUNK_ROWS)
-        _MOE_DISPATCH_BYTES.set(moe.dispatch_bytes(
-            tokens, config.hidden_size, config.moe_intermediate_size, top_k,
-            held, np.dtype(config.dtype).itemsize))
+        _set_moe_gauges(tokens, config.hidden_size,
+                        config.moe_intermediate_size, config.num_experts,
+                        config.experts_held, config.num_experts_per_tok,
+                        np.dtype(config.dtype).itemsize)
         for kind in (lfm2.CONV, lfm2.ATTENTION):
             _HYBRID_LAYERS.set(config.layer_types.count(kind), kind=kind)
         if not config.remat:
@@ -730,6 +761,82 @@ def make_lfm2_train_step(config, mesh, learning_rate: float = 1e-4,
 
     return _make_causal_lm_train_step(
         mesh, tx, rules, batch_axis, model, traced_model, lfm2_step_loss)
+
+
+def deepseek_v3_step_loss(model, params, ids, chosen=None):
+    """The loss of ``make_deepseek_v3_train_step``'s step: the stack's
+    final hidden states, then ``chunked_lm_loss`` over the head, which
+    is a matrix of its own and not the embedding.  ``chosen``
+    (``models.deepseek_v3.expert_choices``'s) hands the sparse layers
+    their choice of experts; the step itself hands none."""
+    from .models.deepseek_v3 import DeepseekV3LMHeadModel, given_choices
+    hidden, head = model.apply(
+        {"params": params, **(given_choices(chosen) if chosen else {})}, ids,
+        method=DeepseekV3LMHeadModel.hidden_and_embedding)
+    return _tied_head_loss(model.heads_sharding, hidden, head, ids)
+
+
+def make_deepseek_v3_train_step(config, mesh, learning_rate: float = 1e-4,
+                                weight_decay: float = 0.1,
+                                fsdp: Optional[str] = None):
+    """Sharded causal-LM training step for the DeepSeek-V3 family
+    (``models/deepseek_v3.py``: latent attention in every layer, a
+    dense SwiGLU or routed experts beside a shared expert), as
+    ``make_lfm2_train_step`` and through the same builder.  Returns
+    (init_fn, step_fn, batch_sharding); the rules are
+    ``deepseek_v3_partition_rules``.  AdamW with decay on matrices only
+    (the stacked experts, the embedding and the head among them; the
+    norms and the selection bias are vectors, and the bias gets no
+    gradient, so nothing moves it).  With ``config.remat`` every layer
+    is recomputed in the backward pass but for the flash kernels'
+    output, the routers' choice and what
+    ``models.deepseek_v3.remat_names`` chooses when the step is
+    traced."""
+    from .models import deepseek_v3
+    from .parallel.sharding import deepseek_v3_partition_rules
+
+    batch_axis = fsdp or "dp"
+    tx = optax.adamw(
+        learning_rate, weight_decay=weight_decay,
+        mask=lambda params: jax.tree.map(lambda p: p.ndim >= 2, params))
+    rules = deepseek_v3_partition_rules(fsdp=fsdp)
+    heads_sharding = _heads_sharding(mesh, batch_axis)
+    model = deepseek_v3.DeepseekV3LMHeadModel(
+        config, heads_sharding=heads_sharding)
+
+    def traced_model(state, ids):
+        """The model of this trace: with ``remat``, keeping what fits
+        beside ``state`` at ``ids``'s shape."""
+        tokens = ids.size // mesh.shape[batch_axis]   # on one device
+        heads = config.num_attention_heads // mesh.shape.get("tp", 1)
+        _MLA_HEADS.set(heads)
+        for which, width in (("qk", config.qk_head_dim),
+                             ("v", config.v_head_dim),
+                             ("rope", config.qk_rope_head_dim),
+                             ("latent", config.kv_lora_rank)):
+            _MLA_HEAD_DIMS.set(width, which=which)
+        _MLA_EXPAND_BYTES.set(deepseek_v3.expand_bytes(tokens, config, heads))
+        _set_moe_gauges(tokens, config.hidden_size,
+                        config.moe_intermediate_size,
+                        config.n_routed_experts, config.experts_held,
+                        config.num_experts_per_tok,
+                        np.dtype(config.dtype).itemsize)
+        _MOE_SHARED_WIDTH.set(config.shared_width)
+        for kind in (deepseek_v3.DENSE, deepseek_v3.SPARSE):
+            _HYBRID_LAYERS.set(config.ffn_types.count(kind), kind=kind)
+        if not config.remat:
+            return model
+        names = deepseek_v3.remat_names(
+            tokens, config, *_state_and_memory(state, mesh, rules))
+        _DEEPSEEK_V3_REMAT_KEPT.set(
+            deepseek_v3.remat_bytes(names, tokens, config),
+            names="+".join(names))
+        return deepseek_v3.DeepseekV3LMHeadModel(
+            config, heads_sharding=heads_sharding, remat_names=names)
+
+    return _make_causal_lm_train_step(
+        mesh, tx, rules, batch_axis, model, traced_model,
+        deepseek_v3_step_loss)
 
 
 def run_gpt_fsdp_dry_run(n_devices: int, batch_size: int = 8,

@@ -31,16 +31,19 @@ from jax.sharding import SingleDeviceSharding
 
 import horovod_tpu as hvd
 from horovod_tpu.models.bert import bert_tiny_config
+from horovod_tpu.models.deepseek_v3 import deepseek_v3_tiny_config
 from horovod_tpu.models.gpt import gpt_tiny_config
 from horovod_tpu.models.granite import granite_tiny_config
 from horovod_tpu.models.lfm2 import lfm2_tiny_config
 from horovod_tpu.ops.pallas_attention import flash_attention
 from horovod_tpu.parallel.mesh import build_mesh
-from horovod_tpu.parallel.sharding import (gpt_partition_rules,
+from horovod_tpu.parallel.sharding import (deepseek_v3_partition_rules,
+                                           gpt_partition_rules,
                                            granite_partition_rules,
                                            infer_shardings,
                                            lfm2_partition_rules)
 from horovod_tpu.training import (make_bert_pretrain_step,
+                                  make_deepseek_v3_train_step,
                                   make_gpt_train_step,
                                   make_granite_train_step,
                                   make_lfm2_train_step)
@@ -356,3 +359,84 @@ def test_lfm2_step_compiles_with_grouped_products_and_the_kernels(v5e_2x2,
                               text))) == 2
     assert not re.search(r"rematted_computation/[^\"]*moe/router/[^\"]*top_k",
                          text)
+
+
+@pytest.mark.parametrize("shape,dv", [
+    ((1, 2048, 4, 192), 128),    # latent attention's widths, two blocks
+    ((2, 1000, 4, 192), 128),    # a ragged length
+    ((2, 512, 8, 64), 128),      # values wider than keys
+], ids=["192-128-s2048", "192-128-ragged-s1000", "64-128"])
+def test_flash_kernels_compile_with_two_head_sizes(v5e_chip, shape, dv):
+    """Values of another width than queries and keys: the three Mosaic
+    kernels with a block of two heads at both widths (384 and 256
+    lanes; a head's lanes start at 192, off the 128-lane tiles), the
+    output and dV at the values' width, and no score square left for
+    XLA to hold."""
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=v5e_chip)
+    v = jax.ShapeDtypeStruct(shape[:3] + (dv,), jnp.bfloat16,
+                             sharding=v5e_chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True)
+        assert out.shape == v.shape
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, v).compile()
+    text = compiled.as_text()
+    kernels = Counter(re.findall(
+        r'custom_call_target="tpu_custom_call".*?/(hvd_flash_\w+)/', text))
+    assert kernels == {"hvd_flash_fwd": 1, "hvd_flash_bwd_dq": 1,
+                       "hvd_flash_bwd_dkv": 1}, kernels
+    batch, seq, heads, _ = shape
+    largest = max(math.prod(int(n) for n in dims.split(","))
+                  for dims in re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", text))
+    assert largest * 4 <= batch * heads * seq * seq, largest
+
+
+@pytest.mark.parametrize("axes", [{"dp": 1}, {"dp": 2, "tp": 2}],
+                         ids=["1chip", "dp2xtp2"])
+def test_deepseek_v3_step_compiles_with_the_kernels_in_every_layer(v5e_2x2,
+                                                                   axes):
+    """``make_deepseek_v3_train_step`` on a mesh of TPU devices: the
+    three flash kernels once in every layer, on heads of 24 for queries
+    and keys and 16 for values (under ``tp`` shard by shard); the
+    experts' products compiled from ``ragged_dot`` in both sparse layers
+    and the shared expert beside them; the routers' ``top_k`` once a
+    sparse layer, not again in the recomputed pass; no S x S array."""
+    chips = math.prod(axes.values())
+    batch, seq = 2 * chips, 160   # no width of the model's is 160
+    cfg = deepseek_v3_tiny_config(remat=True)
+    mesh = build_mesh(axes, v5e_2x2.devices[:chips])
+    init_fn, step_fn, batch_sharding = make_deepseek_v3_train_step(cfg, mesh)
+    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
+                               sharding=batch_sharding)
+    state = jax.eval_shape(init_fn, jax.ShapeDtypeStruct((2,), jnp.uint32),
+                           ids)
+    state = jax.tree.map(
+        lambda leaf, sharding: jax.ShapeDtypeStruct(
+            leaf.shape, leaf.dtype, sharding=sharding),
+        state, infer_shardings(state, mesh, deepseek_v3_partition_rules()))
+    text = step_fn.lower(*state, ids).compile().as_text()
+    _one_loss_chunk_of(batch // mesh.shape["dp"] * seq)
+    kernels = Counter(re.findall(
+        r'custom_call_target="tpu_custom_call".*?'
+        r'(layer_\d+)/attention/[^"]*?/(hvd_flash_\w+)/', text))
+    assert kernels == {
+        ("layer_%d" % i, name): 1 for i in range(cfg.num_hidden_layers)
+        for name in ("hvd_flash_fwd", "hvd_flash_bwd_dq",
+                     "hvd_flash_bwd_dkv")}, kernels
+    grouped = text.count('op_name="ragged-dot-none"')
+    if chips == 1:
+        assert grouped == 2 * 9, grouped
+    else:
+        assert grouped or re.search(r"layer_1/moe/experts/[^\"]*ragged_dot",
+                                    text)
+    for layer in (1, 2):
+        assert re.search(r"layer_%d/moe/shared/" % layer, text)
+        assert re.search(r"layer_%d/attention/rotary/" % layer, text)
+    assert len(set(re.findall(r"(layer_\d)/moe/router/[^\"]*top_k",
+                              text))) == 2
+    assert not re.search(
+        r"rematted_computation/[^\"]*moe/router/[^\"]*top_k", text)
+    assert not re.search(r"(f32|bf16)\[[0-9,]*\b%d,%d\b" % (seq, seq), text)

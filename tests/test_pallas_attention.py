@@ -290,3 +290,60 @@ def test_flash_compiled_is_refused_off_tpu(qkv):
     q, k, v = qkv
     with pytest.raises(ValueError, match="interpret mode"):
         flash_attention(q, k, v)
+
+
+# Values narrower than keys (latent attention: a positional part rides
+# on queries and keys alone).  Neither case has a count of heads under
+# all of them that fills whole lanes at both widths, so a block takes
+# them all: 4 heads of 24 and 16, 6 heads of 64 and 32.
+@pytest.mark.parametrize("heads,d,dv", [(4, 24, 16), (6, 64, 32)],
+                         ids=["24-16", "64-32"])
+@pytest.mark.parametrize("seq,seq_block", [(64, 64), (50, 32)],
+                         ids=["one-block", "ragged-blocks"])
+def test_flash_values_narrower_than_keys(heads, d, dv, seq, seq_block):
+    """Forward and the three gradients against the einsum, causal,
+    jitted: the output and dV take the values' width, dQ and dK the
+    queries', and the scale comes from the queries' width."""
+    rng = np.random.RandomState(3)
+    q, k = (jnp.asarray(rng.randn(2, seq, heads, d).astype(np.float32))
+            for _ in range(2))
+    v = jnp.asarray(rng.randn(2, seq, heads, dv).astype(np.float32))
+
+    def attend(q, k, v):
+        return pallas_attention._flash(q, k, v, d ** -0.5, True, (16, 16),
+                                       seq_block, True)
+    ref = functools.partial(reference_attention, causal=True)
+    got = jax.jit(attend)(q, k, v)
+    assert got.shape == (2, seq, heads, dv)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+    got_g = jax.jit(functools.partial(_grads, attend))(q, k, v)
+    for name, a, b in zip("qkv", got_g, _grads(ref, q, k, v)):
+        assert a.shape == b.shape, name
+        b = np.asarray(b)
+        np.testing.assert_allclose(np.asarray(a), b, rtol=2e-4,
+                                   atol=2e-5 * float(np.abs(b).max()),
+                                   err_msg="d" + name)
+
+
+def test_flash_default_scale_is_the_queries_width():
+    rng = np.random.RandomState(4)
+    q, k = (jnp.asarray(rng.randn(1, 32, 2, 24).astype(np.float32))
+            for _ in range(2))
+    v = jnp.asarray(rng.randn(1, 32, 2, 16).astype(np.float32))
+    attend = jax.jit(functools.partial(flash_attention, causal=True,
+                                       block_q=16, block_k=16,
+                                       interpret=True))
+    np.testing.assert_allclose(
+        np.asarray(attend(q, k, v)),
+        np.asarray(reference_attention(q, k, v, causal=True,
+                                       scale=24 ** -0.5)),
+        atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("heads,d,dv,g", [
+    (16, 64, 64, 2), (32, 192, 128, 2), (16, 128, 128, 1), (4, 24, 16, 4),
+    (12, 64, 64, 2), (32, 64, 128, 2)],
+    ids=["gpt2", "latent", "d128", "toy", "gpt2-small", "wider-values"])
+def test_a_block_holds_the_same_heads_of_both_widths(heads, d, dv, g):
+    assert pallas_attention._heads_per_block(heads, d, dv) == g
