@@ -7,7 +7,7 @@ once through the entry points a user would call:
   against a float32 ``jax.numpy`` softmax attention at BERT-large's
   heads (8, 512, 16, 64), GPT-2's (4, 1024, 12, 64, causal) and the
   GPT cell's step (16, 1024, 16, 64, causal), all bf16: the forward's
-  output, and dQ, dK, dV from the two backward kernels against the
+  output, and dQ, dK, dV from the backward kernel against the
   reference's gradients;
 * ``trainer`` - a trainer started by the launcher
   (``python -m horovod_tpu.runner.launch -np 1``) that calls

@@ -9,11 +9,20 @@ in tiles of scores that live and die in VMEM.
 * ``hvd_flash_fwd``: online softmax over the tiles of a query block;
   writes the output and each row's log-sum-exp (one float32 a row,
   rows along the lanes).
-* ``hvd_flash_bwd_dkv``: grid over kv blocks, q blocks the sequential
-  last dimension; recomputes ``p = exp(s - lse)`` tile by tile and
-  accumulates dK and dV in float32 scratch.
-* ``hvd_flash_bwd_dq``: grid over q blocks, kv blocks sequential; the
-  same recomputation, accumulates dQ.
+* ``hvd_flash_bwd``: grid over kv blocks and, last, q blocks, both
+  sequential; recomputes ``p = exp(s - lse)`` tile by tile, once, and
+  accumulates all three gradients in float32 scratch: dK and dV of the
+  kv block over the q blocks, dQ of the block's heads for the WHOLE
+  (padded) query sequence over the kv blocks, written when it is whole.
+  Five products and one ``exp`` a tile.
+
+Where that dQ does not fit ``FUSED_DQ_BYTES`` of VMEM (a rule on static
+shapes: past 21845 rows at two heads of 192, 65536 at 128 lanes) the
+backward is the two kernels it was before PR 38, seven products and two
+``exp`` a tile: ``hvd_flash_bwd_dkv``, the same body without dQ, and
+``hvd_flash_bwd_dq``, grid over q blocks, kv blocks sequential.  Gauges
+``hvd_flash_bwd_lowerings{form}`` and ``hvd_flash_bwd_dq_vmem_bytes``
+say which a process lowered.
 
 The kernels read and write the models' own ``[B, S, H, D]`` arrays,
 seen as ``[B, S, H * D]``: a block holds as many heads as fill the 128
@@ -60,6 +69,8 @@ from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
 
+from ..common import metrics
+
 NEG_INF = -1e30
 
 # The tile of scores (queries x keys) the kernels compute at a time,
@@ -76,6 +87,11 @@ NEG_INF = -1e30
 TILE = (512, 512)
 SEQ_BLOCK = 1024
 LANES = 128
+# The backward pass is one kernel where a float32 dQ of a block's heads
+# for the whole (padded) query sequence fits this much of the 128 MiB of
+# VMEM: 21845 rows at two heads of 192, 65536 at 128 lanes.
+FUSED_DQ_BYTES = 32 << 20
+DEFAULT_VMEM_BYTES = 16 << 20      # Mosaic's scoped limit where none is set
 
 _NT = (((1,), (1,)), ((), ()))     # a @ b.T
 
@@ -213,17 +229,39 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
     _when(j == nk - 1, finalize)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
-                dk_ref, dv_ref, dk_acc, dv_acc,
-                *, causal, nq, nk, heads, d, dv, bq, bk, sq, sk, skv):
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
+                dk_ref, dv_ref, *rest,
+                scale, causal, nq, nk, heads, d, dv, bq, bk, sq, sk, skv):
     """Scores transposed, as in the forward: ``lse`` and ``di`` come
-    as rows and broadcast down the sublanes."""
+    as rows and broadcast down the sublanes.  dK and dV are summed over
+    the q blocks, the grid's last dimension.
+
+    ``rest`` is their two accumulators and, before them, what the fused
+    form adds: dQ's output block, every row of the group's queries.
+    After them its accumulator, carried across the kv blocks as well and
+    held transposed, ``[q block, heads * D, queries]``, so that with the
+    keys turned once a kv block (``kt_ref``, as the forward turns its
+    values) ``dQ^T += K^T dS^T`` is a plain product of the transposed
+    ``ds`` this kernel has; a q block is turned back and written, times
+    ``scale``, when the last kv block has been added.  Keys ascend for
+    every query row, the order ``_dq_kernel`` adds in."""
     i, j = _block_ids(nq, nk, 3)
+    fused = len(rest) > 2
+    if fused:
+        dq_ref, dk_acc, dv_acc, dqt_acc, kt_ref = rest
+    else:
+        dk_acc, dv_acc = rest
 
     def init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
+        if fused:
+            kt_ref[:] = k_ref[0].T
     _when(i == 0, init)
+    if fused:
+        def init_dq():
+            dqt_acc[i] = jnp.zeros(dqt_acc.shape[1:], dqt_acc.dtype)
+        _when(j == 0, init_dq)
 
     offsets = _offsets(causal, (sk, sq), 1)
     for h in range(heads):
@@ -238,8 +276,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
             pt = jnp.exp(st - lse_ref[0, h, :, rows])       # rows: [1, sq]
             dv_acc[cols, vlanes] += _dot(pt.astype(do.dtype), do)
             dpt = _dot(v_ref[0, cols, vlanes], do, _NT)
-            dst = pt * (dpt - di_ref[0, h, :, rows])
-            dk_acc[cols, lanes] += _dot(dst.astype(q.dtype), q)  # q scaled
+            dst = (pt * (dpt - di_ref[0, h, :, rows])).astype(q.dtype)
+            dk_acc[cols, lanes] += _dot(dst, q)             # q scaled
+            if fused:
+                dqt_acc[i, lanes, rows] += _dot(kt_ref[lanes, cols], dst)
 
         _tiles(tile, i, j, causal=causal, bq=bq, bk=bk, sq=sq, sk=sk,
                skv=skv, keys_outer=True)
@@ -248,6 +288,11 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
     _when(i == nq - 1, finalize)
+    if fused:
+        def write_dq():
+            dq_ref[0, pl.ds(i * bq, bq), :] = (dqt_acc[i].T * scale).astype(
+                dq_ref.dtype)
+        _when(j == nk - 1, write_dq)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dq_acc,
@@ -364,13 +409,27 @@ class _Plan:
         return pl.BlockSpec((1, self.g, 1, self.bq),
                             lambda *ids: (ids[0], ids[1], 0, ids[self.q_at]))
 
+    def q_whole(self):
+        """Every (padded) row of the group's queries, whichever block
+        the grid is at: the fused backward's dQ, written back once."""
+        return pl.BlockSpec((1, self.sq_pad, self.g * self.d),
+                            lambda *ids: (ids[0], 0, ids[1]))
+
+    def dq_bytes(self):
+        """A float32 accumulator of dQ for one group's whole sequence."""
+        return self.sq_pad * self.g * self.d * 4
+
     def call(self, kernel, name, in_specs, out_specs, out_shape, scratch,
-             interpret):
+             interpret, carried="parallel", vmem_limit=None):
+        """``carried``: what the grid's third dimension is to Mosaic,
+        ``"arbitrary"`` where an accumulator is carried across it."""
         return pl.pallas_call(
             kernel, grid=self.grid, in_specs=in_specs, out_specs=out_specs,
             out_shape=out_shape, scratch_shapes=scratch,
-            compiler_params=pltpu.CompilerParams(dimension_semantics=(
-                "parallel", "parallel", "parallel", "arbitrary")),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", carried,
+                                     "arbitrary"),
+                vmem_limit_bytes=vmem_limit),
             interpret=interpret, name=name)
 
 
@@ -411,29 +470,66 @@ def _bwd_operands(p: _Plan, q, k, v, lse, do, di):
     return args, specs
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "heads", "scale", "causal", "tile", "seq_block", "interpret"))
-def _bwd_call(q, k, v, lse, do, di, *, heads, scale, causal, tile,
-              seq_block, interpret):
-    """dQ, dK, dV of ``_fwd_call`` (``q`` scaled; dQ is for the
-    unscaled one).  ``di`` is ``sum(o * do)`` a row, [B, H, 1, Sq]."""
-    p = _Plan(q, k, v, heads, tile, seq_block, q_axis=2)
-    args, specs = _bwd_operands(p, q, k, v, lse, do, di)
-    dq = p.call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal, **p.sizes),
-        "hvd_flash_bwd_dq", specs, p.q_rows(),
-        jax.ShapeDtypeStruct(args[0].shape, q.dtype),
-        [pltpu.VMEM((p.bq, p.g * p.d), jnp.float32)], interpret)(*args)
+_LOWERINGS = metrics.gauge(
+    "hvd_flash_bwd_lowerings",
+    "Lowerings of the flash kernels' backward pass this process made, by "
+    "form: fused (one kernel) or split (dK/dV and dQ apart); one a "
+    "distinct shape and trace context, as inside and outside a shard_map "
+    "(set when the backward is traced)")
+_DQ_VMEM_BYTES = metrics.gauge(
+    "hvd_flash_bwd_dq_vmem_bytes",
+    "Bytes of the float32 dQ accumulator the last fused backward lowering "
+    "holds in VMEM (set when the backward is traced)")
 
+
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "scale", "causal", "tile", "seq_block", "interpret",
+    "dq_budget"))
+def _bwd_call(q, k, v, lse, do, di, *, heads, scale, causal, tile,
+              seq_block, interpret, dq_budget):
+    """dQ, dK, dV of ``_fwd_call`` (``q`` scaled; dQ is for the
+    unscaled one).  ``di`` is ``sum(o * do)`` a row, [B, H, 1, Sq].
+    One kernel where dQ's accumulator fits ``dq_budget`` bytes, the
+    dK/dV kernel and then the dQ kernel where it does not."""
     p = _Plan(q, k, v, heads, tile, seq_block, q_axis=3)
+    fused = p.dq_bytes() <= dq_budget
+    _LOWERINGS.inc(1, form="fused" if fused else "split")
+    _LOWERINGS.inc(0, form="split" if fused else "fused")   # reads 0, not absent
     args, specs = _bwd_operands(p, q, k, v, lse, do, di)
-    dk, dv = p.call(
-        functools.partial(_dkv_kernel, causal=causal, **p.sizes),
-        "hvd_flash_bwd_dkv", specs, [p.k_rows(), p.v_rows()],
-        [jax.ShapeDtypeStruct(args[1].shape, k.dtype),
-         jax.ShapeDtypeStruct(args[2].shape, v.dtype)],
-        [pltpu.VMEM((p.bk, p.g * p.d), jnp.float32),
-         pltpu.VMEM((p.bk, p.g * p.dv), jnp.float32)], interpret)(*args)
+    out_specs = [p.k_rows(), p.v_rows()]
+    out_shape = [jax.ShapeDtypeStruct(args[1].shape, k.dtype),
+                 jax.ShapeDtypeStruct(args[2].shape, v.dtype)]
+    scratch = [pltpu.VMEM((p.bk, p.g * p.d), jnp.float32),
+               pltpu.VMEM((p.bk, p.g * p.dv), jnp.float32)]
+    vmem_limit = None
+    if fused:
+        _DQ_VMEM_BYTES.set(p.dq_bytes())
+        out_specs.append(p.q_whole())
+        out_shape.append(jax.ShapeDtypeStruct(args[0].shape, q.dtype))
+        scratch += [pltpu.VMEM((p.nq, p.g * p.d, p.bq), jnp.float32),
+                    pltpu.VMEM((p.g * p.d, p.bk), k.dtype)]
+        # The accumulator, dQ's block twice (Pallas double-buffers an
+        # output) and what the scoped default allows for the rest.
+        vmem_limit = (p.dq_bytes() + 2 * p.sq_pad * p.g * p.d
+                      * q.dtype.itemsize + DEFAULT_VMEM_BYTES)
+    dk, dv, *dq = p.call(
+        functools.partial(_bwd_kernel, causal=causal, scale=scale,
+                          **p.sizes),
+        "hvd_flash_bwd" if fused else "hvd_flash_bwd_dkv", specs, out_specs,
+        out_shape, scratch, interpret,
+        carried="arbitrary" if fused else "parallel",
+        vmem_limit=vmem_limit)(*args)
+    if fused:
+        dq, = dq
+    else:
+        p = _Plan(q, k, v, heads, tile, seq_block, q_axis=2)
+        args, specs = _bwd_operands(p, q, k, v, lse, do, di)
+        dq = p.call(
+            functools.partial(_dq_kernel, scale=scale, causal=causal,
+                              **p.sizes),
+            "hvd_flash_bwd_dq", specs, p.q_rows(),
+            jax.ShapeDtypeStruct(args[0].shape, q.dtype),
+            [pltpu.VMEM((p.bq, p.g * p.d), jnp.float32)], interpret)(*args)
     return dq[:, :p.sq_len], dk[:, :p.skv_len], dv[:, :p.skv_len]
 
 
@@ -471,7 +567,8 @@ def _flash_vjp_bwd(scale, causal, tile, seq_block, interpret, res, do):
     di = di.transpose(0, 2, 1)[:, :, None, :]               # [B, H, 1, Sq]
     grads = _bwd_call(q, k, v, lse, do.reshape(B, Sq, H * Dv), di, heads=H,
                       scale=scale, causal=causal, tile=tile,
-                      seq_block=seq_block, interpret=interpret)
+                      seq_block=seq_block, interpret=interpret,
+                      dq_budget=FUSED_DQ_BYTES)
     return tuple(g.reshape(B, g.shape[1], H, -1) for g in grads)
 
 

@@ -35,6 +35,7 @@ from horovod_tpu.models.deepseek_v3 import deepseek_v3_tiny_config
 from horovod_tpu.models.gpt import gpt_tiny_config
 from horovod_tpu.models.granite import granite_tiny_config
 from horovod_tpu.models.lfm2 import lfm2_tiny_config
+from horovod_tpu.ops import pallas_attention
 from horovod_tpu.ops.pallas_attention import flash_attention
 from horovod_tpu.parallel.mesh import build_mesh
 from horovod_tpu.parallel.sharding import (deepseek_v3_partition_rules,
@@ -99,26 +100,43 @@ def test_flash_forward_compiles_for_v5e(v5e_chip, shape, dtype, causal):
     # decisions from program ids, both variants of a tile emitted.
     ((4, 4096, 16, 64), jnp.bfloat16, True),
     ((2, 1536, 16, 64), jnp.bfloat16, False),   # and a padded block
+    # The kanana cell's step: 8 x 8 blocks of two heads, 192 | 128 wide,
+    # a float32 dQ of 8192 x 384 (12 MiB) in VMEM beside dK's and dV's.
+    ((2, 8192, 32, 192, 128), jnp.bfloat16, True),
+    # dQ of 65536 rows at 128 lanes fills the budget to the byte.
+    ((1, 65536, 2, 64), jnp.bfloat16, True),
+    # Past the budget (66560 rows at 128 lanes): the two kernels.
+    ((1, 66560, 2, 64), jnp.bfloat16, True),
 ], ids=["gpt2-16x1024-causal", "bert-s512", "ragged-s500-f32",
-        "4x4096-causal", "s1536-padded-block"])
+        "4x4096-causal", "s1536-padded-block", "kanana-2x8192-causal",
+        "s65536-at-the-budget", "s66560-past-the-budget"])
 def test_flash_backward_compiles_for_v5e(v5e_chip, shape, dtype, causal):
-    """dQ and dK/dV are Mosaic kernels beside the forward's, and no
+    """The backward is ONE Mosaic kernel beside the forward's wherever
+    dQ's accumulator fits its VMEM budget (every cell's shape; Mosaic
+    takes the plan's VMEM), dK/dV's and dQ's kernels past it; and no
     score square is left for XLA to hold."""
-    x = jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+    batch, seq, heads = shape[:3]
+    q = jax.ShapeDtypeStruct(shape[:4], dtype, sharding=v5e_chip)
+    v = jax.ShapeDtypeStruct(shape[:3] + shape[-1:], dtype,
+                             sharding=v5e_chip)
 
     def loss(q, k, v):
         out = flash_attention(q, k, v, causal=causal)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        x, x, x).compile().as_text()
+        q, q, v).compile().as_text()
     kernels = Counter(re.findall(
         r'custom_call_target="tpu_custom_call".*?/(hvd_flash_\w+)/', text))
-    assert kernels == {"hvd_flash_fwd": 1, "hvd_flash_bwd_dq": 1,
-                       "hvd_flash_bwd_dkv": 1}, kernels
+    # dQ's accumulator: the padded rows x a block's lanes x 4 bytes.
+    # The long shapes here have blocks of 128 lanes; kanana's 8192 rows
+    # of 384 are 12 MiB.
+    fused = seq * 128 * 4 <= pallas_attention.FUSED_DQ_BYTES
+    assert kernels == ({"hvd_flash_fwd": 1, "hvd_flash_bwd": 1} if fused
+                       else {"hvd_flash_fwd": 1, "hvd_flash_bwd_dq": 1,
+                             "hvd_flash_bwd_dkv": 1}), kernels
     # No array anywhere near the size of the scores (H * D may equal
     # S, as in this cell, so sizes are compared and not dimensions).
-    batch, seq, heads, _ = shape
     largest = max(math.prod(int(n) for n in dims.split(","))
                   for dims in re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", text))
     assert largest * 4 <= batch * heads * seq * seq, largest
@@ -140,7 +158,7 @@ def _one_loss_chunk_of(tokens: int):
 def test_gpt_step_runs_the_kernels_in_every_layer(v5e_2x2, axes, fsdp,
                                                   remat, monkeypatch):
     """``make_gpt_train_step`` on a mesh of TPU devices, nothing else
-    said: forward, dQ and dK/dV kernels in every layer, once each (the
+    said: the forward and the backward kernel in every layer, once each (the
     forward's output is kept where the layers are recomputed; shard by
     shard where the mesh has several chips, which GSPMD alone refuses,
     the batch over the axis the step builder shards it by), and no S x
@@ -186,8 +204,7 @@ def test_gpt_step_runs_the_kernels_in_every_layer(v5e_2x2, axes, fsdp,
         r'(layer_\d+)/attention/[^"]*?/(hvd_flash_\w+)/', text))
     assert kernels == {
         ("layer_%d" % i, name): 1 for i in range(cfg.num_layers)
-        for name in ("hvd_flash_fwd", "hvd_flash_bwd_dq",
-                     "hvd_flash_bwd_dkv")}, kernels
+        for name in ("hvd_flash_fwd", "hvd_flash_bwd")}, kernels
     assert not re.search(r"(f32|bf16)\[[0-9,]*\b%d,%d\b" % (seq, seq),
                          text)
 
@@ -281,7 +298,7 @@ def test_bert_dp_exchange_updates_a_shard_and_gathers(v5e_2x2):
                          ids=["1chip", "dp2xtp2"])
 def test_granite_step_compiles_with_the_kernels_on_grouped_heads(v5e_2x2,
                                                                  axes):
-    """``make_granite_train_step`` on a mesh of TPU devices: the three
+    """``make_granite_train_step`` on a mesh of TPU devices: the two
     flash kernels once in the attention layer and in no Mamba layer
     (4 query heads over 2 key-value heads, repeated to the kernels'
     equal counts; under ``tp`` shard by shard), the chunked recurrence
@@ -306,7 +323,7 @@ def test_granite_step_compiles_with_the_kernels_on_grouped_heads(v5e_2x2,
         r'custom_call_target="tpu_custom_call".*?'
         r'(layer_\d+)/attention/[^"]*?/(hvd_flash_\w+)/', text))
     assert kernels == {("layer_1", name): 1 for name in (
-        "hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv")}, kernels
+        "hvd_flash_fwd", "hvd_flash_bwd")}, kernels
     for stage in ("intra_chunk", "chunk_states", "state_scan",
                   "state_output"):
         assert re.search(r"layer_0/mamba/ssd/%s" % stage, text), stage
@@ -318,7 +335,7 @@ def test_granite_step_compiles_with_the_kernels_on_grouped_heads(v5e_2x2,
                          ids=["1chip", "dp2xtp2"])
 def test_lfm2_step_compiles_with_grouped_products_and_the_kernels(v5e_2x2,
                                                                   axes):
-    """``make_lfm2_train_step`` on a mesh of TPU devices: the three
+    """``make_lfm2_train_step`` on a mesh of TPU devices: the two
     flash kernels once, in the attention layer, on normed and rotated
     heads; the experts' products compiled from ``ragged_dot`` under
     ``moe/experts`` in both sparse layers, forward and backward; the
@@ -343,7 +360,7 @@ def test_lfm2_step_compiles_with_grouped_products_and_the_kernels(v5e_2x2,
         r'custom_call_target="tpu_custom_call".*?'
         r'(layer_\d+)/attention/[^"]*?/(hvd_flash_\w+)/', text))
     assert kernels == {("layer_1", name): 1 for name in (
-        "hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv")}, kernels
+        "hvd_flash_fwd", "hvd_flash_bwd")}, kernels
     # On one chip each grouped product is the compiler's own Mosaic
     # kernel (which names itself and drops the module path): in each
     # sparse layer three forward and six backward, none a second time.
@@ -367,7 +384,7 @@ def test_lfm2_step_compiles_with_grouped_products_and_the_kernels(v5e_2x2,
     ((2, 512, 8, 64), 128),      # values wider than keys
 ], ids=["192-128-s2048", "192-128-ragged-s1000", "64-128"])
 def test_flash_kernels_compile_with_two_head_sizes(v5e_chip, shape, dv):
-    """Values of another width than queries and keys: the three Mosaic
+    """Values of another width than queries and keys: the two Mosaic
     kernels with a block of two heads at both widths (384 and 256
     lanes; a head's lanes start at 192, off the 128-lane tiles), the
     output and dV at the values' width, and no score square left for
@@ -386,8 +403,7 @@ def test_flash_kernels_compile_with_two_head_sizes(v5e_chip, shape, dv):
     text = compiled.as_text()
     kernels = Counter(re.findall(
         r'custom_call_target="tpu_custom_call".*?/(hvd_flash_\w+)/', text))
-    assert kernels == {"hvd_flash_fwd": 1, "hvd_flash_bwd_dq": 1,
-                       "hvd_flash_bwd_dkv": 1}, kernels
+    assert kernels == {"hvd_flash_fwd": 1, "hvd_flash_bwd": 1}, kernels
     batch, seq, heads, _ = shape
     largest = max(math.prod(int(n) for n in dims.split(","))
                   for dims in re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", text))
@@ -399,7 +415,7 @@ def test_flash_kernels_compile_with_two_head_sizes(v5e_chip, shape, dv):
 def test_deepseek_v3_step_compiles_with_the_kernels_in_every_layer(v5e_2x2,
                                                                    axes):
     """``make_deepseek_v3_train_step`` on a mesh of TPU devices: the
-    three flash kernels once in every layer, on heads of 24 for queries
+    two flash kernels once in every layer, on heads of 24 for queries
     and keys and 16 for values (under ``tp`` shard by shard); the
     experts' products compiled from ``ragged_dot`` in both sparse layers
     and the shared expert beside them; the routers' ``top_k`` once a
@@ -424,8 +440,7 @@ def test_deepseek_v3_step_compiles_with_the_kernels_in_every_layer(v5e_2x2,
         r'(layer_\d+)/attention/[^"]*?/(hvd_flash_\w+)/', text))
     assert kernels == {
         ("layer_%d" % i, name): 1 for i in range(cfg.num_hidden_layers)
-        for name in ("hvd_flash_fwd", "hvd_flash_bwd_dq",
-                     "hvd_flash_bwd_dkv")}, kernels
+        for name in ("hvd_flash_fwd", "hvd_flash_bwd")}, kernels
     grouped = text.count('op_name="ragged-dot-none"')
     if chips == 1:
         assert grouped == 2 * 9, grouped

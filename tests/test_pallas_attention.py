@@ -3,6 +3,7 @@ the same kernel code compiles via Mosaic on TPU)."""
 
 import dataclasses
 import functools
+import re
 
 import numpy as np
 import pytest
@@ -12,6 +13,7 @@ import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from horovod_tpu.common import metrics
 from horovod_tpu.ops import pallas_attention
 from horovod_tpu.ops.pallas_attention import flash_attention
 from horovod_tpu.parallel.attention import reference_attention
@@ -44,6 +46,19 @@ def test_flash_uneven_blocks(qkv):
     np.testing.assert_allclose(got, exp, atol=2e-5, rtol=2e-5)
 
 
+@pytest.fixture(params=["fused", "split"])
+def form(request, monkeypatch):
+    """Both forms of the backward pass on one shape: these shapes select
+    the fused kernel, and with no budget for dQ's accumulator they take
+    the dK/dV kernel and the dQ kernel."""
+    if request.param == "split":
+        monkeypatch.setattr(pallas_attention, "FUSED_DQ_BYTES", 0)
+    pallas_attention._bwd_call.clear_cache()   # whatever ran before: trace
+    lowered = pallas_attention._LOWERINGS.value(form=request.param)
+    yield request.param
+    assert pallas_attention._LOWERINGS.value(form=request.param) > lowered
+
+
 def _grads(attend, q, k, v):
     def loss(q, k, v):
         return jnp.mean(attend(q, k, v).astype(jnp.float32) ** 2)
@@ -60,9 +75,9 @@ def _grads(attend, q, k, v):
 @pytest.mark.parametrize("causal", [True, False],
                          ids=["causal", "full"])
 def test_flash_backward_kernels_match_reference(qkv, causal, seq, blocks,
-                                                dtype, tol):
-    """dQ, dK, dV from the two backward kernels (interpret mode)
-    against the gradients of the plain reference."""
+                                                dtype, tol, form):
+    """dQ, dK, dV from the backward kernels (interpret mode; fused, and
+    as two) against the gradients of the plain reference."""
     q, k, v = (t[:, :seq].astype(dtype) for t in qkv)
     got = _grads(functools.partial(
         flash_attention, causal=causal, block_q=blocks[0],
@@ -82,11 +97,13 @@ def test_flash_backward_kernels_match_reference(qkv, causal, seq, blocks,
 @pytest.mark.parametrize("seq,tile", [(50, (16, 8)), (48, (16, 16))],
                          ids=["ragged-tile", "whole-tiles-ragged-block"])
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
-def test_flash_blocks_accumulate_across_grid_steps(qkv, causal, seq, tile):
+def test_flash_blocks_accumulate_across_grid_steps(qkv, causal, seq, tile,
+                                                   form):
     """Sequences longer than ``seq_block``: the online softmax and the
-    gradient accumulators carry over the grid's sequential dimension,
-    the causal decisions are made from program ids, and keys that pad
-    the last block count for nothing."""
+    gradient accumulators carry over the grid's sequential dimension
+    (fused, dQ over the kv blocks as dK and dV over the q blocks), the
+    causal decisions are made from program ids, and keys that pad the
+    last block count for nothing."""
     q, k, v = (t[:, :seq] for t in qkv)
 
     def attend(q, k, v):
@@ -174,9 +191,49 @@ def test_checkpoint_policy_spares_the_forward_kernel(qkv):
     (kept, kept_text), (redone, redone_text) = grads(keep), grads(None)
     assert kept_text.count("name=hvd_flash_fwd") == 1
     assert redone_text.count("name=hvd_flash_fwd") == 2
-    assert kept_text.count("name=hvd_flash_bwd_dq") == 1
+    assert kept_text.count("name=hvd_flash_bwd") == 1
     for a, b in zip(kept, redone):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _backward_kernels(budget=None, seq=64):
+    """The names of the kernels in the backward pass of a [1, seq, 2,
+    16] float32 attention in blocks of 32 rows (dQ's accumulator: seq x
+    128 bytes), lowered with ``budget`` bytes for it."""
+    x = jnp.ones((1, seq, 2 * 16), jnp.float32)
+    stats = jnp.ones((1, 2, 1, seq), jnp.float32)
+    budget = pallas_attention.FUSED_DQ_BYTES if budget is None else budget
+    text = str(jax.make_jaxpr(functools.partial(
+        pallas_attention._bwd_call, heads=2, scale=0.25, causal=True,
+        tile=(16, 16), seq_block=32, interpret=True, dq_budget=budget))(
+            x, x, x, stats, x, stats))
+    return sorted(set(re.findall(r"name=(hvd_flash_\w+)", text)))
+
+
+def test_backward_is_one_kernel_where_dq_fits_the_budget():
+    """The rule reads static shapes alone: the bytes of a float32 dQ
+    for a block's heads over the whole padded sequence against one
+    constant.  Under it the backward lowers ``hvd_flash_bwd``, past it
+    the two kernels; the gauges say which, and what the accumulator
+    holds."""
+    lowerings = pallas_attention._LOWERINGS
+    was = {f: lowerings.value(form=f) for f in ("fused", "split")}
+    assert _backward_kernels(seq=64) == ["hvd_flash_bwd"]
+    assert _backward_kernels(budget=64 * 128) == ["hvd_flash_bwd"]
+    gauges = metrics.snapshot()["gauges"]
+    assert gauges["hvd_flash_bwd_dq_vmem_bytes"] == 64 * 2 * 16 * 4
+    assert gauges["hvd_flash_bwd_lowerings"] == {
+        "form=fused": was["fused"] + 2, "form=split": was["split"]}
+    # One row more pads to a third block of 32: 96 x 128 bytes.
+    assert _backward_kernels(budget=64 * 128, seq=65) == [
+        "hvd_flash_bwd_dkv", "hvd_flash_bwd_dq"]
+    assert _backward_kernels(budget=96 * 128, seq=65) == ["hvd_flash_bwd"]
+    assert metrics.gauge("hvd_flash_bwd_dq_vmem_bytes").value() == 96 * 128
+    assert lowerings.value(form="split") == was["split"] + 1
+    assert lowerings.value(form="fused") == was["fused"] + 3
+    # Every shape a cell runs fits, and R3's 32k-token local blocks.
+    for rows, lanes in ((8192, 384), (1024, 128), (4096, 128), (32768, 128)):
+        assert rows * lanes * 4 <= pallas_attention.FUSED_DQ_BYTES
 
 
 def test_flash_bf16(qkv):
@@ -300,7 +357,7 @@ def test_flash_compiled_is_refused_off_tpu(qkv):
                          ids=["24-16", "64-32"])
 @pytest.mark.parametrize("seq,seq_block", [(64, 64), (50, 32)],
                          ids=["one-block", "ragged-blocks"])
-def test_flash_values_narrower_than_keys(heads, d, dv, seq, seq_block):
+def test_flash_values_narrower_than_keys(heads, d, dv, seq, seq_block, form):
     """Forward and the three gradients against the einsum, causal,
     jitted: the output and dV take the values' width, dQ and dK the
     queries', and the scale comes from the queries' width."""
