@@ -2,6 +2,7 @@
 recurrence itself, one position after another, in float32 at full
 precision: the output and all five gradients."""
 
+import functools
 import os
 import sys
 
@@ -174,3 +175,132 @@ def test_scan_bytes_counts_the_arrays_by_hand():
     # a ragged sequence counts its padded chunk
     assert gated_delta.scan_bytes(1, 100, 1, 4, 4, 32, 4) == \
         gated_delta.scan_bytes(1, 128, 1, 4, 4, 32, 4)
+
+
+def cast_twice(t, dtype):
+    """``t`` rounded to ``dtype`` as the operator's body rounds it."""
+    return t.astype(dtype).astype(jnp.float32)
+
+
+def straight_through(t, dtype):
+    """``t`` rounded to ``dtype``, the derivative the identity."""
+    return t + jax.lax.stop_gradient(cast_twice(t, dtype) - t)
+
+
+def plain_walk(w, u, k_to_end, chunk_decay, rounded=cast_twice):
+    """The walk over the chunks as one ``lax.scan`` that autodiff
+    differentiates: the body ``_walk``'s forward rule runs, written
+    out again here for its written-out backward to be held to."""
+    dtype = w.dtype
+
+    def step(carried, of_chunk):
+        w_c, u_c, k_c, decay = of_chunk
+        v_new = u_c - jnp.einsum("blhk,bhkv->blhv", rounded(w_c, dtype),
+                                 rounded(carried, dtype))
+        left = carried * decay[..., None, None] + jnp.einsum(
+            "blhk,blhv->bhkv", rounded(k_c, dtype), rounded(v_new, dtype))
+        return left, (carried, v_new)
+    _, batch, _, heads, d_k = w.shape
+    _, outs = jax.lax.scan(
+        step, jnp.zeros((batch, heads, d_k, u.shape[-1]), jnp.float32),
+        (w, u, k_to_end, chunk_decay))
+    return outs
+
+
+def walk_operands(seq, chunk, dtype, batch=2, heads=3, d_k=16, d_v=8):
+    """Operands as the operator hands them to its walk, the chunks
+    first: corrections and keys of a size that keeps the state near 1,
+    decays between 0.25 and 1, and the padded positions of a last chunk
+    that ``chunk`` does not fill zero in ``w``, ``u`` and ``k_to_end``.
+    With them a cotangent for each of the walk's two outputs."""
+    count, length = gated_delta.chunks_of(seq, chunk)
+    keys = jax.random.split(jax.random.PRNGKey(seq + chunk), 6)
+    real = (jnp.arange(count * length) < seq).reshape(
+        count, 1, length, 1, 1)
+    rows = lambda key, width: real * jax.random.normal(
+        key, (count, batch, length, heads, width)) / length ** .5
+    w, u, k_to_end = rows(keys[0], d_k), rows(keys[1], d_v), rows(keys[2],
+                                                                 d_k)
+    decay = jax.random.uniform(keys[3], (count, batch, heads), minval=.25)
+    cotangents = (
+        jax.random.normal(keys[4], (count, batch, heads, d_k, d_v)),
+        jax.random.normal(keys[5], u.shape))
+    return (w.astype(dtype), u, k_to_end.astype(dtype), decay), cotangents
+
+
+def walk_gradients(walk, operands, cotangents):
+    return jax.jit(lambda: jax.vjp(walk, *operands)[1](cotangents))()
+
+
+WALKS = {**CASES, "two-chunks-ragged": (33, 32)}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("seq,chunk", WALKS.values(), ids=WALKS.keys())
+def test_written_out_backward_equals_autodiff_of_the_plain_scan(seq, chunk,
+                                                                dtype):
+    """All four operands' gradients, with a cotangent on the entered
+    states AND on ``v_new``: to 1e-5 of the gradient's largest entry,
+    and one rounding where a gradient comes back in bfloat16.  The
+    roundings of the plain scan's operands are straight-through here,
+    as the written-out rule takes them; in float32 they are nothing."""
+    operands, cotangents = walk_operands(seq, chunk, dtype)
+    outs, want_outs = (jax.jit(walk)(*operands)
+                       for walk in (gated_delta._walk, plain_walk))
+    for a, b in zip(outs, want_outs):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    got = walk_gradients(gated_delta._walk, operands, cotangents)
+    want = walk_gradients(
+        functools.partial(plain_walk, rounded=straight_through), operands,
+        cotangents)
+    for name, a, b, operand in zip("w u k_to_end chunk_decay".split(), got,
+                                   want, operands):
+        assert a.dtype == b.dtype == operand.dtype, name
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        # The last chunk leaves its state to nobody and the first is
+        # entered with zeros: under three chunks some gradients are 0.
+        assert np.abs(b).max() > 0 or len(operand) < 3, name
+        # a step of bfloat16 is at most 2^-7 of the number
+        rounding = 2.0 ** -7 if operand.dtype == jnp.bfloat16 else 0.0
+        np.testing.assert_allclose(
+            a, b, rtol=rounding, atol=1e-5 * float(np.abs(b).max()),
+            err_msg=name)
+
+
+@pytest.mark.parametrize("seq,chunk", WALKS.values(), ids=WALKS.keys())
+def test_written_out_backward_rounds_no_cotangent(seq, chunk):
+    """Autodiff of ``t.astype(bfloat16).astype(float32)`` rounds the
+    COTANGENT to bfloat16 on its way back, once a product and trip; the
+    written-out rule does not.  So against autodiff of the body as it
+    is written the bfloat16 gradients lie within one rounding a trip
+    of the largest entry (in float32 the two bodies are one)."""
+    operands, cotangents = walk_operands(seq, chunk, jnp.bfloat16)
+    got = walk_gradients(gated_delta._walk, operands, cotangents)
+    want = walk_gradients(plain_walk, operands, cotangents)
+    trips = len(operands[0])
+    for name, a, b in zip("w u k_to_end chunk_decay".split(), got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        np.testing.assert_allclose(
+            a, b, rtol=0, err_msg=name,
+            atol=(1e-5 + 2.0 ** -8 * trips) * float(np.abs(b).max()))
+
+
+def _scans_in_the_gradient(*kept):
+    """Scans in the jaxpr of the gradient of one operator call that is
+    recomputed but for the names ``kept``."""
+    loss = lambda *a: jnp.sum(gated_delta_chunked(*a, 32))
+    policy = jax.checkpoint_policies.save_only_these_names(*kept)
+    gradient = jax.grad(jax.checkpoint(loss, policy=policy),
+                        argnums=(0, 1, 2, 3, 4))
+    return str(jax.make_jaxpr(gradient)(*inputs(96))).count(" scan[")
+
+
+def test_kept_states_leave_no_walk_to_recompute():
+    """With the walk's outputs kept across ``remat`` its backward reads
+    them as they are: a forward walk and a backward walk.  Dropped,
+    the forward walk is made once more, and nothing else."""
+    assert _scans_in_the_gradient(gated_delta.WY_NAME,
+                                  gated_delta.STATES_NAME) == 2
+    assert _scans_in_the_gradient(gated_delta.WY_NAME) == 3
+    assert _scans_in_the_gradient() == 3

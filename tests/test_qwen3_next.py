@@ -406,6 +406,24 @@ def test_gauges_show_in_the_metrics_snapshot():
         qwen3_next.REMAT_NAMES, 2, 96, cfg)
 
 
+def test_the_step_differentiates_through_the_written_out_walk():
+    """``hvd_gdn_walk_traces{pass}``: a traced step traces the walk's
+    backward rule once a delta-rule layer, so its gradient is the
+    written-out reverse scan and not autodiff's of the forward one, and
+    its forward rule at least as often (``remat`` traces a layer's
+    forward pass more than once)."""
+    walks = gated_delta._WALK_TRACES
+    was = {p: walks.value(**{"pass": p}) for p in ("forward", "backward")}
+    cfg, mesh, init_fn, step_fn, ids = _tiny_step({"dp": 1}, remat=True)
+    state = jax.eval_shape(init_fn, jax.random.PRNGKey(0), ids)
+    step_fn.lower(*state, ids)
+    traced = hvd.metrics_snapshot()["gauges"]["hvd_gdn_walk_traces"]
+    layers = cfg.layer_types.count(qwen3_next.LINEAR)
+    assert traced["pass=backward"] == was["backward"] + layers == \
+        walks.value(**{"pass": "backward"})
+    assert traced["pass=forward"] >= was["forward"] + layers
+
+
 def test_remat_bytes_by_hand_at_the_published_widths():
     """One sequence of 8192 at the published widths, one period, 32 of
     512 experts held; and what a chip of 16.9 GB that holds 7.5 GB of
