@@ -52,12 +52,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
-from jax.sharding import NamedSharding
+from jax.sharding import Mesh, NamedSharding
 
 from ..parallel import moe
 from .gpt import FLASH_NAMES, _flash_causal, attention_impl
 from .granite import GatedMLP, RMSNorm
-from .lfm2 import rotary_tables, sown_choices
+from .lfm2 import mesh_of, rotary_tables, sown_choices
 
 DENSE, SPARSE = "dense", "sparse"
 # The collection of variables by which a caller hands the sparse layers
@@ -238,8 +238,13 @@ class SparseFFN(nn.Module):
     """The routed experts held (the parameters of
     ``parallel.moe.routed_experts``: a router over all experts, the
     stacked matrices of those held) and beside them the shared expert,
-    one gated MLP named ``shared`` (the module's name is its scope)."""
+    one gated MLP named ``shared`` (the module's name is its scope).
+    The routed passes are Pallas kernels where LFM2's are."""
     config: DeepseekV3Config
+    # The mesh the step this model is traced in lays its arrays on (the
+    # step builder says, through ``heads_sharding``); None where the
+    # model is applied directly.
+    mesh: Optional[Mesh] = None
 
     @nn.compact
     def __call__(self, x):
@@ -264,7 +269,8 @@ class SparseFFN(nn.Module):
             stacked("down", width, hidden),
             first_expert=cfg.first_expert, top_k=cfg.num_experts_per_tok,
             normalize=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor,
-            gate_sum_eps=GATE_SUM_EPS, chosen=given)
+            gate_sum_eps=GATE_SUM_EPS, chosen=given,
+            kernels=moe.on_one_tpu(self.mesh) and not self.is_initializing())
         self.sow("intermediates", "chosen", routing.chosen)
         shared = GatedMLP(
             dataclasses.replace(cfg, intermediate_size=cfg.shared_width),
@@ -286,7 +292,8 @@ class DeepseekV3Layer(nn.Module):
         u = norm("ffn_norm")(x)
         if self.ffn == DENSE:
             return x + GatedMLP(cfg, name="mlp")(u)
-        return x + SparseFFN(cfg, name="moe")(u)
+        return x + SparseFFN(cfg, mesh_of(self.heads_sharding),
+                             name="moe")(u)
 
 
 class DeepseekV3LMHeadModel(nn.Module):

@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
-from jax.sharding import NamedSharding
+from jax.sharding import Mesh, NamedSharding
 
 from ..parallel import moe
 from .gpt import FLASH_NAMES, _flash_causal, attention_impl
@@ -211,10 +211,23 @@ class RotaryAttention(nn.Module):
                                param_dtype=jnp.float32, name="out")(ctx)
 
 
+def mesh_of(sharding: Optional[NamedSharding]) -> Optional[Mesh]:
+    """The mesh a step builder's sharding lies on; None where the model
+    is applied directly."""
+    return None if sharding is None else sharding.mesh
+
+
 class RoutedExperts(nn.Module):
     """The parameters of ``parallel.moe.routed_experts``: a router over
-    all experts, and the stacked matrices of the experts held."""
+    all experts, and the stacked matrices of the experts held.  On one
+    TPU device (the platform read as the attention's is) the layer's
+    wide passes are Pallas kernels; ``init`` wants the parameters'
+    shapes and nothing of the layer, so no kernel is traced for it."""
     config: LFM2Config
+    # The mesh the step this model is traced in lays its arrays on (the
+    # step builder says, through ``heads_sharding``); None where the
+    # model is applied directly.
+    mesh: Optional[Mesh] = None
 
     @nn.compact
     def __call__(self, x):
@@ -234,7 +247,8 @@ class RoutedExperts(nn.Module):
             stacked("gate", hidden, width), stacked("up", hidden, width),
             stacked("down", width, hidden),
             first_expert=cfg.first_expert, top_k=cfg.num_experts_per_tok,
-            normalize=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor)
+            normalize=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor,
+            kernels=moe.on_one_tpu(self.mesh) and not self.is_initializing())
         self.sow("intermediates", "chosen", routing.chosen)
         return y.reshape(x.shape)
 
@@ -258,7 +272,8 @@ class LFM2Layer(nn.Module):
         u = norm("ffn_norm")(x)
         if self.ffn == DENSE:
             return x + GatedMLP(cfg, name="mlp")(u)
-        return x + RoutedExperts(cfg, name="moe")(u)
+        return x + RoutedExperts(cfg, mesh_of(self.heads_sharding),
+                                 name="moe")(u)
 
 
 class LFM2LMHeadModel(nn.Module):

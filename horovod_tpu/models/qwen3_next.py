@@ -58,13 +58,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
-from jax.sharding import NamedSharding
+from jax.sharding import Mesh, NamedSharding
 
 from ..ops import gated_delta
 from ..parallel import moe
 from .gpt import FLASH_NAMES, _flash_causal, attention_impl
 from .granite import GatedMLP, causal_depthwise_conv
-from .lfm2 import rotary_tables, rotate, sown_choices
+from .lfm2 import mesh_of, rotary_tables, rotate, sown_choices
 
 LINEAR, FULL = "linear_attention", "full_attention"
 # The collection of variables by which a caller hands the sparse layers
@@ -348,8 +348,13 @@ class SparseFFN(nn.Module):
     ``parallel.moe.routed_experts``: a softmax router over all experts,
     the stacked matrices of those held) and beside them the shared
     expert, one gated MLP times a sigmoid of the token, under the scope
-    ``shared``."""
+    ``shared``.  The routed passes are Pallas kernels where LFM2's
+    are."""
     config: Qwen3NextConfig
+    # The mesh the step this model is traced in lays its arrays on (the
+    # step builder says, through ``heads_sharding``); None where the
+    # model is applied directly.
+    mesh: Optional[Mesh] = None
 
     @nn.compact
     def __call__(self, x):
@@ -370,7 +375,8 @@ class SparseFFN(nn.Module):
             stacked("down", width, hidden),
             first_expert=cfg.first_expert, top_k=cfg.num_experts_per_tok,
             normalize=cfg.norm_topk_prob, chosen=given,
-            router=moe.softmax_top_k)
+            router=moe.softmax_top_k,
+            kernels=moe.on_one_tpu(self.mesh) and not self.is_initializing())
         self.sow("intermediates", "chosen", routing.chosen)
         shared = GatedMLP(cfg, name="shared")(x)
         with jax.named_scope("shared"):
@@ -396,7 +402,8 @@ class Qwen3NextLayer(nn.Module):
         else:
             x = x + GatedAttention(cfg, self.heads_sharding,
                                    name="attention")(u, cos, sin)
-        return x + SparseFFN(cfg, name="moe")(norm("ffn_norm")(x))
+        return x + SparseFFN(cfg, mesh_of(self.heads_sharding), name="moe")(
+            norm("ffn_norm")(x))
 
 
 class Qwen3NextLMHeadModel(nn.Module):

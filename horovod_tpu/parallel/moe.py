@@ -14,9 +14,17 @@
   the pairs held are a prefix of it, its length known on the device
   when the plan is made, and that is what says which rows hold a pair
   and how far the rows' gates are gathered (:func:`_gates_of_rows`).  The wide
-  passes (the rows' gather, the gated product, their backward) walk
-  the whole buffer: cut into chunks inside a loop they cost on the v5e
-  what they saved (``PERF.md``, PR 33).
+  passes (the rows' gathers, tokens to rows and rows to tokens, the sum
+  of the rows' two cotangents, the gated product and its backward) stop
+  there too where they are Pallas kernels (``ops/pallas_moe.py``: the
+  pairs' count a prefetched scalar, no row past it fetched, computed or
+  written): on one TPU device, where a caller says so
+  (:func:`on_one_tpu`) and the kernels' tiles divide the shapes
+  (:func:`kernels_fit`).  Elsewhere (the CPU, a mesh of several devices,
+  since GSPMD does not partition a Mosaic call, ``init``) they are
+  XLA's gathers and fusions, which walk the whole buffer whatever it
+  holds: cut into chunks inside an XLA loop they cost on the v5e what
+  they saved (``PERF.md``, PR 33).
 * :func:`moe_ffn` with :func:`top1_dispatch`: Switch-style top-1
   dispatch over a mesh axis.  The reference's ``alltoall`` collective
   exists for exactly this workload (SURVEY §2.3 EP row: "alltoall again
@@ -27,6 +35,7 @@
   dropped (standard Switch semantics).
 """
 
+import functools
 from typing import Callable, NamedTuple, Optional
 
 import jax
@@ -187,6 +196,16 @@ def rows_walked(pairs: int, rows: int) -> int:
     return min(rows, -(-pairs // WALK_CHUNK_ROWS) * WALK_CHUNK_ROWS)
 
 
+def blocks_walked(pairs: int, rows: int, block: Optional[int] = None) -> int:
+    """Grid steps of a kernel over a buffer of ``rows`` that do work
+    where ``pairs`` of them hold a pair: the blocks of ``block`` rows
+    (the kernels' ``ROW_BLOCK``, ``hvd_moe_kernel_block_rows``) that
+    start before the pairs end.  Over the grid's length,
+    ``-(-rows // block)``, the share of blocks that did work."""
+    block = min(block or _kernels().ROW_BLOCK, rows)
+    return min(-(-rows // block), -(-pairs // block))
+
+
 def dispatch_bytes(tokens: int, hidden: int, width: int, top_k: int,
                    held: int, itemsize: int) -> int:
     """Bytes one layer's forward materialises on one device between the
@@ -307,44 +326,158 @@ def _gates_of_rows_bwd(plan, d_row_gate):
 _gates_of_rows.defvjp(_gates_of_rows_fwd, _gates_of_rows_bwd)
 
 
+def _kernels():
+    """``ops/pallas_moe.py``, imported where a layer is traced as
+    kernels, as the models import the flash kernels: Pallas takes a
+    second to import, and a process that runs no kernel does not pay
+    it."""
+    from ..ops import pallas_moe
+    return pallas_moe
+
+
+def _kernel(name: str, *args):
+    """One of ``ops/pallas_moe.py``'s jitted functions, called in the
+    one tracing context they share (a jaxpr is kept by it too)."""
+    pallas_moe = _kernels()
+    with pallas_moe.one_trace_context():
+        return getattr(pallas_moe, name)(*args)
+
+
+def on_one_tpu(mesh) -> bool:
+    """Whether arrays laid out on ``mesh`` (None: a model applied
+    directly, on the default backend) lie on ONE TPU device: where the
+    kernels can run.  Read where the models read the platform for their
+    attention (``models/gpt.py`` ``attention_impl``); GSPMD does not
+    partition a Mosaic call, so a mesh of several devices keeps XLA's
+    passes."""
+    if mesh is None:
+        return jax.default_backend() == "tpu"
+    return mesh.devices.flat[0].platform == "tpu" and mesh.size == 1
+
+
+def kernels_fit(tokens: int, top_k: int, held: int, hidden: int, width: int,
+                dtype) -> bool:
+    """Whether the kernels' tiles divide a layer's static shapes: a row
+    of ``hidden`` whole (8, 128) tiles of 32-bit words (2048 bfloat16,
+    1024 float32), the experts' ``width`` whole lanes, buffer and
+    tokens whole blocks.  The three sparse cells' do; the tests' tiny
+    models' do not, and take XLA's passes anywhere."""
+    pallas_moe = _kernels()
+    rows = dispatch_rows(tokens, top_k, held)
+    return (pallas_moe.row_sublanes(hidden, dtype) > 0
+            and width % pallas_moe.LANES == 0
+            and rows % min(rows, pallas_moe.ROW_BLOCK) == 0
+            and tokens % min(tokens, pallas_moe.TOKEN_BLOCK) == 0
+            and min(rows, tokens) % pallas_moe.SUBLANES == 0)
+
+
+def _pairs_held_first(plan: Plan):
+    """``([T, top_k] int32, [T] int32)``: the rows of each token's pairs
+    held, moved to the front of its ``top_k`` in their order, and how
+    many they are: a pass over them then asks no pair whether it is
+    held.  Elementwise over ``[T, top_k, top_k]``: no sort and no
+    gather of single numbers, which cost the v5e what a gather of rows
+    does."""
+    top_k = plan.place.shape[1]
+    rank = jnp.cumsum(plan.is_held, axis=1) - 1
+    to_slot = plan.is_held[:, :, None] & (
+        rank[:, :, None] == jnp.arange(top_k))
+    return (jnp.where(to_slot, plan.place[:, :, None], 0).sum(1),
+            plan.is_held.sum(1).astype(jnp.int32))
+
+
 # Dispatch and combine are each a gather, forward and backward, and
 # each other's transpose: left to autodiff the transposes are
 # scatter-adds in which up to ``top_k`` rows, and every row past the
-# pairs, meet in one token.
+# pairs, meet in one token.  ``kernels`` says how a gather is made
+# (static: a custom VJP's rule calls the kernels' jitted functions, it
+# is not inside them); as a kernel it leaves the rows past the pairs as
+# the memory held them.  Who guarantees what about those rows: the
+# grouped products read and write rows by group, so neither a buffer's
+# tail nor a cotangent's reaches them; these rules read a buffer by the
+# pairs' places and count alone; and the gated product's rule does as
+# much.  Nothing else is handed a buffer.
 
-@jax.custom_vjp
-def _dispatch(x, plan: Plan):
+def _rows_of_tokens(x, plan: Plan, kernels: bool):
     """``rows[r] = x[token[r]]``."""
+    if kernels:
+        return _kernel("rows_of_tokens", x, plan.token,
+                       plan.group_sizes.sum())
     return _pairs_of_tokens(x, plan)
 
 
-def _dispatch_fwd(x, plan):
-    return _dispatch(x, plan), plan
+def _tokens_of_rows(outs, plan: Plan, kernels: bool):
+    """``y[t] = sum of out[place] over t's pairs held``, in float32,
+    ``out`` the sum of ``outs``."""
+    if kernels:
+        pairs = plan.group_sizes.sum()
+        # Two cotangents are added a row that holds a pair at a time,
+        # the sum written over the first.
+        out = outs[0] if len(outs) == 1 else _kernel("add_rows", *outs, pairs)
+        return _kernel("tokens_of_rows", out, *_pairs_held_first(plan),
+                       pairs)
+    out = sum(outs[1:], outs[0])
+    return _rows_of_pairs(out, plan).astype(jnp.float32).sum(0).astype(
+        out.dtype)
 
 
-def _dispatch_bwd(plan, d_rows):
-    return _combine(d_rows, plan), None
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _dispatch(x, plan: Plan, kernels: bool):
+    """The rows of tokens, handed out twice, once for each of the two
+    products that read them: their cotangents then come back apart and
+    are added where the rows are read, which as a kernel is a row that
+    holds a pair at a time (autodiff's ``add_any`` is a pass over the
+    whole buffer: 1.5 ms a layer at 81920 rows)."""
+    rows = _rows_of_tokens(x, plan, kernels)
+    return rows, rows
+
+
+def _dispatch_fwd(x, plan, kernels):
+    return _dispatch(x, plan, kernels), plan
+
+
+def _dispatch_bwd(kernels, plan, d_rows):
+    return _tokens_of_rows(d_rows, plan, kernels), None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@jax.custom_vjp
-def _combine(out, plan: Plan):
-    """``y[t] = sum of out[place] over t's pairs held``, in float32."""
-    return _rows_of_pairs(out, plan).astype(jnp.float32).sum(0).astype(
-        out.dtype)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _combine(out, plan: Plan, kernels: bool):
+    """The tokens of rows."""
+    return _tokens_of_rows((out,), plan, kernels)
 
 
-def _combine_fwd(out, plan):
-    return _combine(out, plan), plan
+def _combine_fwd(out, plan, kernels):
+    return _combine(out, plan, kernels), plan
 
 
-def _combine_bwd(plan, d_y):
-    return _dispatch(d_y, plan), None
+def _combine_bwd(kernels, plan, d_y):
+    return _rows_of_tokens(d_y, plan, kernels), None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+@jax.custom_vjp
+def _gated(a, b, row_gate, pairs):
+    """``silu(a) * b * row_gate`` over the rows that hold a pair, as
+    kernels forward and backward: float32 throughout and rounded once;
+    past the pairs, values and cotangents are what the memory held."""
+    return _kernel("gated", a, b, row_gate, pairs)
+
+
+def _gated_fwd(a, b, row_gate, pairs):
+    return _gated(a, b, row_gate, pairs), (a, b, row_gate, pairs)
+
+
+def _gated_bwd(kept, d_gated):
+    a, b, row_gate, pairs = kept
+    return (*_kernel("gated_bwd", a, b, row_gate, d_gated, pairs), None)
+
+
+_gated.defvjp(_gated_fwd, _gated_bwd)
 
 
 def routed_experts(x: jax.Array, router_kernel: jax.Array,
@@ -353,7 +486,7 @@ def routed_experts(x: jax.Array, router_kernel: jax.Array,
                    first_expert: int, top_k: int, normalize: bool = True,
                    scale: float = 1.0, gate_sum_eps: float = GATE_SUM_EPS,
                    chosen: Optional[jax.Array] = None,
-                   router: Optional[Callable] = None):
+                   router: Optional[Callable] = None, kernels: bool = False):
     """Top-k routed SwiGLU experts, the part of the layer that the
     experts HELD give: ``y_t = sum over the chosen e held of g_te *
     expert_e(x_t)``, ``expert_e(x) = (silu(x W1_e) * (x W3_e)) W2_e``.
@@ -362,7 +495,12 @@ def routed_experts(x: jax.Array, router_kernel: jax.Array,
     :func:`sigmoid_top_k` with ``selection_bias``, ``scale`` and
     ``gate_sum_eps``; another (:func:`softmax_top_k`) is called as
     ``router(x, router_kernel, top_k, normalize, chosen)`` and those
-    three are not read (``selection_bias`` may be None).
+    three are not read (``selection_bias`` may be None).  ``kernels``:
+    the caller found the layer's tokens on one TPU device
+    (:func:`on_one_tpu`; the models ask outside ``init``, which wants
+    the parameters' shapes and nothing of the layer), so the wide
+    passes are Pallas kernels wherever their tiles divide the shapes
+    (:func:`kernels_fit`); the results are the same to a rounding.
 
     ``x``: ``[T, D]``.  ``router_kernel`` ``[D, E]`` and
     ``selection_bias`` ``[E]`` are over ALL ``E`` experts; the stacked
@@ -375,11 +513,17 @@ def routed_experts(x: jax.Array, router_kernel: jax.Array,
     the routing can make it (:func:`dispatch_rows`), so its size grows
     with ``T x top_k`` and not with ``T x E``.  What grows with the
     pairs really sent is the grouped products' work, which skip the
-    rows past the groups' ends, and the gather of the rows' gates,
-    which stops at the first chunk that starts past them
-    (:func:`_gates_of_rows`); the extent is read from the plan, on the device,
-    each step, and no setting stands between a router that sends every
-    pair here and one that sends none.  Router, choice and
+    rows past the groups' ends, the gather of the rows' gates, which
+    stops at the first chunk that starts past them
+    (:func:`_gates_of_rows`), and, as kernels, the rows' gathers both
+    ways, the sum of the rows' two cotangents and the gated product
+    with its backward: a block of rows that starts past the pairs is
+    neither fetched nor computed nor written, and holds what the memory
+    held (every reader masks by the count or the places before a
+    nonlinearity or a sum).  As XLA's passes those walk the whole
+    buffer and leave zeros there.  The extent is read from the plan, on
+    the device, each step, and no setting stands between a router that
+    sends every pair here and one that sends none.  Router, choice and
     gates are float32; the products take ``x``'s type and accumulate
     in float32.  A pair's gate multiplies its row BEFORE the last
     product (the same sum), so that the gates' gradient needs the
@@ -388,6 +532,8 @@ def routed_experts(x: jax.Array, router_kernel: jax.Array,
     Returns ``(y [T, D], Routing)``."""
     held = gate_kernels.shape[0]
     dtype = x.dtype
+    kernels = kernels and kernels_fit(x.shape[0], top_k, held, x.shape[1],
+                                      gate_kernels.shape[2], dtype)
     with jax.named_scope("router"):
         if router is None:
             routing = sigmoid_top_k(x, router_kernel, selection_bias, top_k,
@@ -396,22 +542,29 @@ def routed_experts(x: jax.Array, router_kernel: jax.Array,
             routing = router(x, router_kernel, top_k, normalize, chosen)
     with jax.named_scope("dispatch"):
         plan, row_gate = held_pairs(routing, first_expert, held)
-        rows = checkpoint_name(_dispatch(x, plan), ROWS_NAME)
+        rows, rows_again = (checkpoint_name(rows, ROWS_NAME)
+                            for rows in _dispatch(x, plan, kernels))
     with jax.named_scope("experts"):
         def grouped(lhs, kernels):
             return lax.ragged_dot(lhs, kernels.astype(dtype),
                                   plan.group_sizes,
                                   preferred_element_type=dtype)
         a = checkpoint_name(grouped(rows, gate_kernels), EXPERT_GATE_UP_NAME)
-        b = checkpoint_name(grouped(rows, up_kernels), EXPERT_GATE_UP_NAME)
-        # A row past the groups' ends holds whatever the products left
-        # there, which need not be finite: it is masked BEFORE the
-        # nonlinearity, so that neither its value nor its derivative
-        # times a zero cotangent can be nan.
-        keep = plan.valid[:, None]
-        gated = jax.nn.silu(jnp.where(keep, a, 0)) * jnp.where(keep, b, 0)
-        gated = gated.astype(jnp.float32) * row_gate[:, None]
-        out = grouped(gated.astype(dtype), down_kernels)
+        b = checkpoint_name(grouped(rows_again, up_kernels),
+                            EXPERT_GATE_UP_NAME)
+        if kernels:
+            gated = _gated(a, b, row_gate, plan.group_sizes.sum())
+        else:
+            # A row past the groups' ends holds whatever the products
+            # left there, which need not be finite: it is masked BEFORE
+            # the nonlinearity, so that neither its value nor its
+            # derivative times a zero cotangent can be nan.
+            keep = plan.valid[:, None]
+            gated = (jax.nn.silu(jnp.where(keep, a, 0))
+                     * jnp.where(keep, b, 0))
+            gated = (gated.astype(jnp.float32)
+                     * row_gate[:, None]).astype(dtype)
+        out = grouped(gated, down_kernels)
     with jax.named_scope("combine"):
-        y = _combine(out, plan)
+        y = _combine(out, plan, kernels)
     return y, routing

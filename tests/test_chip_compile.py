@@ -37,8 +37,9 @@ from horovod_tpu.models.gpt import gpt_tiny_config
 from horovod_tpu.models.granite import granite_tiny_config
 from horovod_tpu.models.lfm2 import lfm2_tiny_config
 from horovod_tpu.models.qwen3_next import qwen3_next_tiny_config
-from horovod_tpu.ops import pallas_attention
+from horovod_tpu.ops import pallas_attention, pallas_moe
 from horovod_tpu.ops.pallas_attention import flash_attention
+from horovod_tpu.parallel import moe
 from horovod_tpu.parallel.mesh import build_mesh
 from horovod_tpu.parallel.sharding import (deepseek_v3_partition_rules,
                                            gpt_partition_rules,
@@ -569,3 +570,114 @@ def _copies_in_while_bodies(text):
         if copied and computation in bodies:
             copies.append([int(n) for n in copied.group(1).split(",") if n])
     return copies
+
+
+# The three sparse cells: tokens on the chip, top k, experts held,
+# hidden and the experts' width.
+SPARSE_CELLS = {"qwen3-next-1x8192": (8192, 10, 32, 2048, 512),
+                "kanana-2x8192": (16384, 6, 16, 2048, 768),
+                "lfm2-2x4096": (8192, 4, 16, 2048, 1536)}
+
+
+@pytest.mark.parametrize("which", ["rows_of_tokens", "tokens_of_rows",
+                                   "add_rows", "gated", "gated_bwd"])
+@pytest.mark.parametrize("cell", list(SPARSE_CELLS))
+def test_moe_kernels_compile_at_the_cells_shapes(v5e_2x2, v5e_chip, cell,
+                                                 which):
+    """Each kernel of ``ops/pallas_moe.py`` at a sparse cell's buffer
+    (81920, 98304 and 32768 rows of 2048 bfloat16) inside the scoped
+    VMEM a kernel has where it asks for no more (none of them asks),
+    and the shapes are ones the kernels' tiles divide."""
+    tokens, top_k, held, hidden, width = SPARSE_CELLS[cell]
+    rows = moe.dispatch_rows(tokens, top_k, held)
+    assert rows == tokens * top_k
+    of = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=v5e_chip)
+    n, row_gate = of((), jnp.int32), of((rows,), jnp.float32)
+    wide, buffer = of((rows, width)), of((rows, hidden))
+    args = {
+        "rows_of_tokens": (of((tokens, hidden)), of((rows,), jnp.int32), n),
+        "tokens_of_rows": (buffer, of((tokens, top_k), jnp.int32),
+                           of((tokens,), jnp.int32), n),
+        "add_rows": (buffer, buffer, n),
+        "gated": (wide, wide, row_gate, n),
+        "gated_bwd": (wide, wide, row_gate, wide, n)}[which]
+    text = getattr(pallas_moe, which).lower(*args).compile().as_text()
+    assert "hvd_moe_%s" % which in text
+    assert "vmem_limit" not in text
+    assert moe.kernels_fit(tokens, top_k, held, hidden, width, jnp.bfloat16)
+    assert moe.on_one_tpu(build_mesh({"dp": 1}, v5e_2x2.devices[:1]))
+    assert not moe.on_one_tpu(build_mesh({"dp": 2, "tp": 2},
+                                         v5e_2x2.devices))
+
+
+@pytest.mark.parametrize("axes", [{"dp": 1}, {"dp": 2, "tp": 2}],
+                         ids=["1chip", "dp2xtp2"])
+@pytest.mark.parametrize("family", ["lfm2", "deepseek_v3", "qwen3_next"])
+def test_sparse_steps_run_the_wide_passes_as_kernels_on_one_device(
+        v5e_2x2, family, axes):
+    """The three sparse steps at a hidden size of whole tiles a row
+    and experts of whole lanes (2048 and 128 bfloat16; the tiny models'
+    64 and 32 take XLA's passes anywhere), four experts held of 32:
+    on one described chip every sparse layer holds the ``hvd_moe_``
+    custom calls under its ``moe/dispatch`` and ``moe/combine`` scopes
+    (rows of tokens and tokens of rows, each forward and as the
+    other's transpose) and the gated product's under ``moe/experts``,
+    and neither a gather nor a ``select`` of the buffer's full ``[R,
+    D]`` shape is left under ``moe/dispatch`` or ``moe/combine``; on a
+    2 x 2 mesh no kernel, because GSPMD does not partition a Mosaic
+    call."""
+    tiny, make, rules, sparse_layers, experts = {
+        "lfm2": (lfm2_tiny_config, make_lfm2_train_step,
+                 lfm2_partition_rules, 2, "num_experts"),
+        "deepseek_v3": (deepseek_v3_tiny_config,
+                        make_deepseek_v3_train_step,
+                        deepseek_v3_partition_rules, 2, "n_routed_experts"),
+        "qwen3_next": (qwen3_next_tiny_config, make_qwen3_next_train_step,
+                       qwen3_next_partition_rules, 4, "num_experts")}[family]
+    chips = math.prod(axes.values())
+    cfg = tiny(remat=True, hidden_size=2048, moe_intermediate_size=128,
+               **{experts: 32})
+    assert cfg.experts_held == 4
+    mesh = build_mesh(axes, v5e_2x2.devices[:chips])
+    init_fn, step_fn, batch_sharding = make(cfg, mesh)
+    # 512 tokens a chip: buffers of 1024 and (top 3) 1536 rows, whole
+    # blocks of 512
+    tokens = 512
+    ids = jax.ShapeDtypeStruct((2 * chips, tokens // 2), jnp.int32,
+                               sharding=batch_sharding)
+    state = jax.eval_shape(init_fn, jax.ShapeDtypeStruct((2,), jnp.uint32),
+                           ids)
+    state = jax.tree.map(
+        lambda leaf, sharding: jax.ShapeDtypeStruct(
+            leaf.shape, leaf.dtype, sharding=sharding),
+        state, infer_shardings(state, mesh, rules()))
+    text = step_fn.lower(*state, ids).compile().as_text()
+    calls = Counter(re.findall(
+        r'custom_call_target="tpu_custom_call".*?moe/(\w+)/[^"]*?'
+        r'/(hvd_moe_\w+)/pallas_call', text))
+    if chips > 1:
+        assert "hvd_moe_" not in text
+        return
+    # a layer: rows of tokens forward (dispatch) and backward (combine),
+    # tokens of rows forward (combine) and backward (dispatch); no name
+    # of the layer is dropped, so nothing runs a second time but the
+    # gated product, which nothing keeps
+    per_layer = {("dispatch", "hvd_moe_rows_of_tokens"): 1,
+                 ("combine", "hvd_moe_rows_of_tokens"): 1,
+                 ("combine", "hvd_moe_tokens_of_rows"): 1,
+                 ("dispatch", "hvd_moe_tokens_of_rows"): 1,
+                 ("dispatch", "hvd_moe_add_rows"): 1}
+    for key, count in per_layer.items():
+        assert calls[key] == count * sparse_layers, (key, calls)
+    assert calls[("experts", "hvd_moe_gated")] >= sparse_layers
+    assert calls[("experts", "hvd_moe_gated_bwd")] >= sparse_layers
+    assert text.count('op_name="ragged-dot-none"') == 9 * sparse_layers
+    # and XLA holds no pass over a whole buffer under the two scopes
+    top_k = cfg.num_experts_per_tok
+    buffer = r"(bf16|f32)\[%d,2048\]" % (tokens * min(top_k, 4))
+    left = [line for line in text.splitlines()
+            if re.search(r"moe/(dispatch|combine)/", line)
+            and re.search(r"= %s\S* (gather|select|fusion)\(" % buffer, line)
+            and "custom_call_target" not in line]
+    assert not left, left[:3]
