@@ -362,7 +362,8 @@ def _gpt_step_loss_check(config, batch_size, seq_len):
     import numpy as np
     from benchmarks.reference import common as reference
     from benchmarks.reference import gpt as reference_gpt
-    from horovod_tpu.models.gpt import GPTLMHeadModel, lm_loss, loss_chunks
+    from horovod_tpu.models.gpt import GPTLMHeadModel, lm_loss
+    from horovod_tpu.models.layers import loss_chunks
     from horovod_tpu.training import gpt_step_loss
 
     model = GPTLMHeadModel(config)

@@ -7,9 +7,9 @@ from .resnet import (ResNet, ResNet18, ResNet34, ResNet50, ResNet101,
 from .bert import (BertConfig, BertEncoder, BertForMaskedLM,
                    bert_base_config, bert_large_config, bert_tiny_config,
                    mlm_loss)
-from .gpt import (GPTConfig, GPTLMHeadModel, chunked_lm_loss,
-                  gpt2_medium_config, gpt2_small_config, gpt_tiny_config,
-                  lm_loss)
+from .layers import chunked_lm_loss
+from .gpt import (GPTConfig, GPTLMHeadModel, gpt2_medium_config,
+                  gpt2_small_config, gpt_tiny_config, lm_loss)
 from .granite import (GraniteConfig, GraniteLMHeadModel,
                       granite_tiny_config)
 from .lfm2 import LFM2Config, LFM2LMHeadModel, lfm2_tiny_config

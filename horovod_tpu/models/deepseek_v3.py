@@ -39,12 +39,13 @@ head, the one rotated key laid out once a head (``hvd_mla_expand_bytes``
 says what that writes); elsewhere it is einsums, which read the one
 rotated key as it is.  In training nothing caches the latent.  With
 ``remat`` a layer is recomputed in the backward pass but for the flash
-kernels' output, the routers' choice and what :func:`remat_names` finds
-room for.  Parameter names are matched by
+kernels' output, the routers' choice and what the device has room for
+(``REMAT_CANDIDATES``).  Parameter names are matched by
 :func:`horovod_tpu.parallel.sharding.deepseek_v3_partition_rules`.
 """
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -52,17 +53,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import NamedSharding
 
 from ..parallel import moe
-from .gpt import FLASH_NAMES, _flash_causal, attention_impl
-from .granite import GatedMLP, RMSNorm
-from .lfm2 import mesh_of, rotary_tables, sown_choices
+from . import layers
+from .layers import (FLASH_NAMES, GatedMLP, RMSNorm, SparseFFN, _flash_causal,
+                     attention_impl, mesh_of, prefixes, recomputed,
+                     rotary_tables)
+from .layers import given_choices  # noqa: F401  (the benchmark's name)
 
 DENSE, SPARSE = "dense", "sparse"
-# The collection of variables by which a caller hands the sparse layers
-# a choice of experts (``given_choices``).
-GIVEN = "given"
 # The class's own addend to the sum of the chosen gates.
 GATE_SUM_EPS = 1e-20
 # The expanded keys and values, by ``checkpoint_name``: recomputing
@@ -73,16 +73,17 @@ EXPANDED_KV_NAME = "mla_kv"
 # the dense SwiGLU and of the shared expert, the expanded keys and
 # values, the routed experts' gate and up (two grouped products over a
 # buffer of which an eighth holds a pair), the sorted rows (a gather).
-# ``remat_names`` keeps as many as fit the device.  (On the v5e at 2 x
-# 8192 the first two fit, and the step that keeps the expanded keys and
-# values is also a quarter of the compiled size of one that makes them
-# again: PERF.md, PR 37.)
+# As many as fit the device are kept (``layers.kept_across_remat``; on
+# the v5e at 2 x 8192 the first two fit, and the step that keeps the
+# expanded keys and values is also a quarter of the compiled size of
+# one that makes them again: PERF.md, PR 37).
 MATMUL_NAMES = ("gate_up", EXPANDED_KV_NAME, moe.EXPERT_GATE_UP_NAME,
                 moe.ROWS_NAME)
 # Always kept: the kernels' output, and the routers' choice, which a
 # recomputed pass must not make again (``parallel/moe.py``).
 KEPT_NAMES = FLASH_NAMES + (moe.CHOICE_NAME,)
 REMAT_NAMES = KEPT_NAMES + MATMUL_NAMES
+REMAT_CANDIDATES = prefixes(REMAT_NAMES, len(KEPT_NAMES))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -206,8 +207,7 @@ class LatentAttention(nn.Module):
             q_rope = rotate_pairs(q_rope, cos, sin)
             k_rope = rotate_pairs(k_rope[:, :, None, :], cos, sin)
         scale = cfg.qk_head_dim ** -0.5
-        mesh = (None if self.heads_sharding is None
-                else self.heads_sharding.mesh)
+        mesh = mesh_of(self.heads_sharding)
         if attention_impl(cfg, mesh, not self.is_initializing()) == "flash":
             with jax.named_scope("rotary"):
                 # The kernels take a key a head: the one rotated key is
@@ -234,48 +234,16 @@ class LatentAttention(nn.Module):
                                param_dtype=jnp.float32, name="out")(ctx)
 
 
-class SparseFFN(nn.Module):
-    """The routed experts held (the parameters of
-    ``parallel.moe.routed_experts``: a router over all experts, the
-    stacked matrices of those held) and beside them the shared expert,
-    one gated MLP named ``shared`` (the module's name is its scope).
-    The routed passes are Pallas kernels where LFM2's are."""
-    config: DeepseekV3Config
-    # The mesh the step this model is traced in lays its arrays on (the
-    # step builder says, through ``heads_sharding``); None where the
-    # model is applied directly.
-    mesh: Optional[Mesh] = None
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.config
-        hidden, width = cfg.hidden_size, cfg.moe_intermediate_size
-        stacked = lambda name, fan_in, fan_out: self.param(
-            name, nn.initializers.lecun_normal(batch_axis=(0,)),
-            (cfg.experts_held, fan_in, fan_out), jnp.float32)
-        router = self.param("router", nn.initializers.lecun_normal(),
-                            (hidden, cfg.n_routed_experts), jnp.float32)
-        # A buffer in the published model: it selects, no gradient
-        # reaches it, and no rule here moves it.
-        bias = self.param("expert_bias", nn.initializers.zeros,
-                          (cfg.n_routed_experts,), jnp.float32)
-        # A caller may hand the choice over, as the variable ``chosen``
-        # of the collection ``given`` (``given_choices``).
-        given = (self.get_variable(GIVEN, "chosen")
-                 if self.has_variable(GIVEN, "chosen") else None)
-        y, routing = moe.routed_experts(
-            x.reshape(-1, hidden), router, bias,
-            stacked("gate", hidden, width), stacked("up", hidden, width),
-            stacked("down", width, hidden),
-            first_expert=cfg.first_expert, top_k=cfg.num_experts_per_tok,
-            normalize=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor,
-            gate_sum_eps=GATE_SUM_EPS, chosen=given,
-            kernels=moe.on_one_tpu(self.mesh) and not self.is_initializing())
-        self.sow("intermediates", "chosen", routing.chosen)
-        shared = GatedMLP(
-            dataclasses.replace(cfg, intermediate_size=cfg.shared_width),
-            name="shared")
-        return y.reshape(x.shape) + shared(x)
+def sparse_ffn(config: DeepseekV3Config, mesh) -> SparseFFN:
+    """The routed experts of a sparse layer (sigmoid scores plus a
+    selection bias) and beside them the shared expert, with no gate."""
+    return SparseFFN(
+        experts=config.n_routed_experts, held=config.experts_held,
+        first_expert=config.first_expert, top_k=config.num_experts_per_tok,
+        width=config.moe_intermediate_size, normalize=config.norm_topk_prob,
+        dtype=config.dtype, scale=config.routed_scaling_factor,
+        gate_sum_eps=GATE_SUM_EPS, shared=config.shared_width, mesh=mesh,
+        name="moe")
 
 
 class DeepseekV3Layer(nn.Module):
@@ -291,9 +259,9 @@ class DeepseekV3Layer(nn.Module):
             norm("attention_norm")(x), cos, sin)
         u = norm("ffn_norm")(x)
         if self.ffn == DENSE:
-            return x + GatedMLP(cfg, name="mlp")(u)
-        return x + SparseFFN(cfg, mesh_of(self.heads_sharding),
-                             name="moe")(u)
+            return x + GatedMLP(cfg.intermediate_size, cfg.dtype,
+                                name="mlp")(u)
+        return x + sparse_ffn(cfg, mesh_of(self.heads_sharding))(u)
 
 
 class DeepseekV3LMHeadModel(nn.Module):
@@ -301,7 +269,7 @@ class DeepseekV3LMHeadModel(nn.Module):
     config: DeepseekV3Config
     heads_sharding: Optional[NamedSharding] = None
     # What a recomputed layer keeps (``config.remat``); the step
-    # builder hands over what ``remat_names`` chose for its shapes.
+    # builder hands over what fits its shapes and its device.
     remat_names: Tuple[str, ...] = REMAT_NAMES
 
     @nn.compact
@@ -319,12 +287,7 @@ class DeepseekV3LMHeadModel(nn.Module):
         with jax.named_scope("rotary_tables"):   # once a step
             cos, sin = rotary_tables(input_ids.shape[1],
                                      cfg.qk_rope_head_dim, cfg.rope_theta)
-        layer = DeepseekV3Layer
-        if cfg.remat:
-            layer = nn.remat(
-                DeepseekV3Layer,
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    *self.remat_names))
+        layer = recomputed(DeepseekV3Layer, cfg.remat, self.remat_names)
         for i, ffn in enumerate(cfg.ffn_types):
             x = layer(cfg, ffn, self.heads_sharding,
                       name=f"layer_{i}")(x, cos, sin)
@@ -337,27 +300,15 @@ class DeepseekV3LMHeadModel(nn.Module):
                           preferred_element_type=jnp.float32)
 
 
-def expert_choices(config: DeepseekV3Config, params, input_ids):
-    """``{layer index: [T, top_k] int32}``: the experts, of all
-    ``n_routed_experts``, that each token chose in every sparse layer."""
-    return sown_choices(
-        DeepseekV3LMHeadModel(dataclasses.replace(config, remat=False)),
-        params, input_ids)
+expert_choices = functools.partial(layers.expert_choices,
+                                   DeepseekV3LMHeadModel)
 
 
-def given_choices(chosen) -> dict:
-    """``expert_choices``'s ``{layer index: [T, top_k]}`` as the
-    variables that make every sparse layer take that choice and not its
-    own: ``model.apply({"params": params, **given_choices(chosen)},
-    ...)``."""
-    return {GIVEN: {"layer_%d" % i: {"moe": {"chosen": c}}
-                    for i, c in chosen.items()}}
-
-
-def remat_bytes(names, tokens: int, config: DeepseekV3Config) -> int:
+def remat_bytes(names, sequences: int, seq: int,
+                config: DeepseekV3Config) -> int:
     """Bytes one device keeps across ``remat`` for ``names``, with
-    ``tokens`` of the batch on it.  Tensor parallelism is not counted:
-    the figure errs high."""
+    ``sequences`` sequences of ``seq`` on it.  Tensor parallelism is not
+    counted: the figure errs high."""
     itemsize = np.dtype(config.dtype).itemsize
     layers, heads = config.num_hidden_layers, config.num_attention_heads
     dense, sparse = (config.ffn_types.count(kind)
@@ -374,21 +325,4 @@ def remat_bytes(names, tokens: int, config: DeepseekV3Config) -> int:
         * config.moe_intermediate_size * itemsize,
         EXPANDED_KV_NAME: layers * expand_bytes(1, config, heads),
         moe.ROWS_NAME: sparse * rows * config.hidden_size * itemsize}
-    return tokens * sum(per_token[name] for name in names)
-
-
-def remat_names(tokens: int, config: DeepseekV3Config, state_bytes: int,
-                memory_limit: Optional[int]) -> Tuple[str, ...]:
-    """As ``models.lfm2.remat_names``: the kernels' names, the choice
-    and as many of ``MATMUL_NAMES``, in their order, as fit one device's
-    ``memory_limit`` bytes beside the state the step is handed and a
-    margin of a quarter of the memory; every name where the device
-    reports no limit."""
-    if memory_limit is None:
-        return REMAT_NAMES
-    for count in range(len(REMAT_NAMES), len(KEPT_NAMES), -1):
-        names = REMAT_NAMES[:count]
-        if (remat_bytes(names, tokens, config) + state_bytes
-                + memory_limit // 4 <= memory_limit):
-            return names
-    return KEPT_NAMES
+    return sequences * seq * sum(per_token[name] for name in names)

@@ -29,7 +29,8 @@ attention through the Pallas flash kernels on a TPU, with keys and
 values repeated to the query heads (the kernels take equal head
 counts), and as grouped einsums elsewhere; with ``remat`` a layer is
 recomputed in the backward pass but for the kernels' output and what
-:func:`remat_names` finds room for of the widest matmuls' outputs.
+the device has room for of the widest matmuls' outputs
+(``REMAT_CANDIDATES``).
 Parameter names are matched by
 :func:`horovod_tpu.parallel.sharding.granite_partition_rules`.
 """
@@ -46,17 +47,19 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import NamedSharding
 
 from ..ops.ssd import ssd_chunked
-from .gpt import FLASH_NAMES, _flash_causal, attention_impl
+from .layers import (FLASH_NAMES, GatedMLP, RMSNorm, causal_depthwise_conv,
+                     grouped_causal_attention, prefixes, recomputed)
 
 MAMBA, ATTENTION = "mamba", "attention"
 # What a recomputed layer may keep from its forward pass, by
 # ``checkpoint_name``: what the flash kernels name, and the outputs of
 # the two widest matmuls (the MLP's gate and up, the Mamba mixer's
 # input projection), dearest to recompute first: 34.5 and 13.6 ms of a
-# 441 ms step at 2 x 4096 on a v5e (PERF.md, PR 29).  ``remat_names``
-# keeps as many of the latter as fit the device.
+# 441 ms step at 2 x 4096 on a v5e (PERF.md, PR 29).  As many of the
+# latter as fit the device are kept (``layers.kept_across_remat``).
 MATMUL_NAMES = ("gate_up", "in_proj")
 REMAT_NAMES = FLASH_NAMES + MATMUL_NAMES
+REMAT_CANDIDATES = prefixes(REMAT_NAMES, len(FLASH_NAMES))
 # Mamba-2's published initialisation of the step size: log-uniform in
 # [DT_MIN, DT_MAX], never under DT_FLOOR; and of the decay rate:
 # uniform in A_RANGE.
@@ -84,8 +87,8 @@ class GraniteConfig:
     rms_norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     # Recompute every layer in the backward pass but for what it names
-    # (REMAT_NAMES); the step builder asks ``remat_names`` which of the
-    # matmuls' outputs fit the device.
+    # (REMAT_NAMES); the step builder finds which of the matmuls'
+    # outputs fit the device.
     remat: bool = False
     # As ``GPTConfig.attention_impl``.
     attention_impl: str = "auto"
@@ -120,20 +123,6 @@ def granite_tiny_config(**kw) -> GraniteConfig:
     return GraniteConfig(**defaults)
 
 
-class RMSNorm(nn.Module):
-    eps: float
-    dtype: Any
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           jnp.float32)
-        x = x.astype(jnp.float32)
-        x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
-                              + self.eps)
-        return (x * scale).astype(self.dtype)
-
-
 def _dt_bias_init(key, shape, dtype=jnp.float32):
     """The inverse softplus of a step size drawn log-uniformly."""
     dt = jnp.exp(jax.random.uniform(key, shape, dtype)
@@ -144,19 +133,6 @@ def _dt_bias_init(key, shape, dtype=jnp.float32):
 
 def _a_log_init(key, shape, dtype=jnp.float32):
     return jnp.log(jax.random.uniform(key, shape, dtype, *A_RANGE))
-
-
-def causal_depthwise_conv(x, kernel, bias):
-    """``out_t = bias + sum_k kernel[k] * x_{t - (K - 1) + k}`` per
-    channel, zeros before the sequence.  ``x``: ``[B, S, C]``;
-    ``kernel``: ``[K, C]``; ``bias``: ``[C]``, or None for none."""
-    taps, seq = kernel.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-    out = None if bias is None else bias.astype(x.dtype)
-    for k in range(taps):
-        tap = padded[:, k:k + seq] * kernel[k].astype(x.dtype)
-        out = tap if out is None else out + tap
-    return out
 
 
 class Mamba2Mixer(nn.Module):
@@ -212,60 +188,18 @@ class GroupedQueryAttention(nn.Module):
     def __call__(self, x):
         cfg = self.config
         q_heads, kv_heads = cfg.num_attention_heads, cfg.num_key_value_heads
-        group, head_dim = q_heads // kv_heads, cfg.head_dim
         dense = lambda heads, name: nn.DenseGeneral(
-            features=(heads, head_dim), axis=-1, use_bias=False,
+            features=(heads, cfg.head_dim), axis=-1, use_bias=False,
             dtype=cfg.dtype, param_dtype=jnp.float32, name=name)
         q = dense(q_heads, "query")(x)
         k = dense(kv_heads, "key")(x)
         v = dense(kv_heads, "value")(x)
-        mesh = (None if self.heads_sharding is None
-                else self.heads_sharding.mesh)
-        # ``init`` wants the parameters' shapes and nothing of the
-        # attention, so no kernel is traced and lowered for it.
-        if attention_impl(cfg, mesh, not self.is_initializing()) == "flash":
-            # The kernels take as many key-value heads as query heads:
-            # each is laid out once for every query head it serves.
-            ctx = _flash_causal(q, jnp.repeat(k, group, axis=2),
-                                jnp.repeat(v, group, axis=2),
-                                self.heads_sharding,
-                                scale=cfg.attention_multiplier)
-            ctx = ctx.astype(cfg.dtype)
-        else:
-            seq = x.shape[1]
-            q = q.reshape(*q.shape[:2], kv_heads, group, head_dim)
-            scores = jnp.einsum("bqkgd,bskd->bkgqs", q, k)
-            scores = scores * cfg.attention_multiplier
-            causal = jnp.tril(jnp.ones((seq, seq), bool))
-            scores = jnp.where(causal, scores, jnp.finfo(cfg.dtype).min)
-            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-            ctx = jnp.einsum("bkgqs,bskd->bqkgd", probs.astype(cfg.dtype), v)
-            ctx = ctx.reshape(*ctx.shape[:2], q_heads, head_dim)
+        ctx = grouped_causal_attention(
+            q, k, v, cfg.attention_multiplier, cfg, self.heads_sharding,
+            self.is_initializing())
         return nn.DenseGeneral(features=cfg.hidden_size, axis=(-2, -1),
                                use_bias=False, dtype=cfg.dtype,
                                param_dtype=jnp.float32, name="out")(ctx)
-
-
-class GatedMLP(nn.Module):
-    config: GraniteConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.config
-        # ``W_in``'s two halves as two matrices: a tensor-parallel axis
-        # splits both alike, and each is a plain matmul (as one
-        # parameter of [hidden, 2, intermediate] or [2, hidden,
-        # intermediate] XLA wrote the weight's gradient and both of
-        # AdamW's moments in another layout and copied them back, 18
-        # ms a step at the published widths).
-        dense = lambda features, name: nn.Dense(
-            features, use_bias=False, dtype=cfg.dtype,
-            param_dtype=jnp.float32, name=name)
-        gate = checkpoint_name(dense(cfg.intermediate_size, "gate")(x),
-                               "gate_up")
-        up = checkpoint_name(dense(cfg.intermediate_size, "up")(x),
-                             "gate_up")
-        return dense(cfg.hidden_size, "out")(nn.silu(gate) * up)
 
 
 class GraniteLayer(nn.Module):
@@ -281,7 +215,8 @@ class GraniteLayer(nn.Module):
                  else GroupedQueryAttention)(cfg, self.heads_sharding,
                                              name=self.kind)
         x = x + cfg.residual_multiplier * mixer(norm("mixer_norm")(x))
-        m = GatedMLP(cfg, name="mlp")(norm("mlp_norm")(x))
+        m = GatedMLP(cfg.intermediate_size, cfg.dtype, name="mlp")(
+            norm("mlp_norm")(x))
         return x + cfg.residual_multiplier * m
 
 
@@ -290,7 +225,7 @@ class GraniteLMHeadModel(nn.Module):
     config: GraniteConfig
     heads_sharding: Optional[NamedSharding] = None
     # What a recomputed layer keeps (``config.remat``); the step
-    # builder hands over what ``remat_names`` chose for its shapes.
+    # builder hands over what fits its shapes and its device.
     remat_names: Tuple[str, ...] = REMAT_NAMES
 
     @nn.compact
@@ -301,12 +236,7 @@ class GraniteLMHeadModel(nn.Module):
         wte = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                        param_dtype=jnp.float32, name="word_embeddings")
         x = wte(input_ids) * cfg.embedding_multiplier
-        layer = GraniteLayer
-        if cfg.remat:
-            layer = nn.remat(
-                GraniteLayer,
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    *self.remat_names))
+        layer = recomputed(GraniteLayer, cfg.remat, self.remat_names)
         for i, kind in enumerate(cfg.layer_types):
             x = layer(cfg, kind, self.heads_sharding, name=f"layer_{i}")(x)
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
@@ -320,11 +250,12 @@ class GraniteLMHeadModel(nn.Module):
         return logits / self.config.logits_scaling
 
 
-def remat_bytes(names, tokens: int, config: GraniteConfig) -> int:
+def remat_bytes(names, sequences: int, seq: int,
+                config: GraniteConfig) -> int:
     """Bytes one device keeps across ``remat`` for ``names``, with
-    ``tokens`` of the batch on it: the kernels' output and fp32 row
-    statistics in every attention layer, gate and up in every layer,
-    the input projection's output in every Mamba layer.  Tensor
+    ``sequences`` sequences of ``seq`` on it: the kernels' output and
+    fp32 row statistics in every attention layer, gate and up in every
+    layer, the input projection's output in every Mamba layer.  Tensor
     parallelism (which splits them all) is not counted: the figure
     errs high."""
     itemsize = np.dtype(config.dtype).itemsize
@@ -336,21 +267,4 @@ def remat_bytes(names, tokens: int, config: GraniteConfig) -> int:
         "in_proj": kinds.count(MAMBA) * itemsize * (
             2 * config.mamba_d_inner + 2 * config.mamba_d_state
             + config.mamba_n_heads)}
-    return tokens * sum(per_token[name] for name in names)
-
-
-def remat_names(tokens: int, config: GraniteConfig, state_bytes: int,
-                memory_limit: Optional[int]) -> Tuple[str, ...]:
-    """The names a recomputed layer keeps: the kernels' and as many of
-    ``MATMUL_NAMES``, in their order, as fit one device's
-    ``memory_limit`` bytes beside the state the step is handed and a
-    margin of a quarter of the memory (``models.gpt.remat_names``'s
-    rule); every name where the device reports no limit."""
-    if memory_limit is None:
-        return REMAT_NAMES
-    for count in range(len(REMAT_NAMES), len(FLASH_NAMES), -1):
-        names = REMAT_NAMES[:count]
-        if (remat_bytes(names, tokens, config) + state_bytes
-                + memory_limit // 4 <= memory_limit):
-            return names
-    return FLASH_NAMES
+    return sequences * seq * sum(per_token[name] for name in names)

@@ -24,16 +24,18 @@ anywhere.
   ``experts_held`` of the experts from ``first_expert`` on and computes
   their part of the layer, for every token that chose them.
 
-TPU-first like ``granite.py``, whose norm, gated MLP, convolution and
-flash dispatch it imports: matmuls in ``dtype`` (bfloat16) from float32
-parameters; the router, the norms and the rotation in float32; with
-``remat`` a layer is recomputed in the backward pass but for the flash
-kernels' output, the routers' choice and what :func:`remat_names` finds
-room for.  Parameter names are matched by
+TPU-first like ``granite.py`` (the norm, the gated MLP, the convolution,
+the flash dispatch and the sparse feed-forward are ``layers.py``'s):
+matmuls in ``dtype`` (bfloat16) from float32 parameters; the router, the
+norms and the rotation in float32; with ``remat`` a layer is recomputed
+in the backward pass but for the flash kernels' output, the routers'
+choice and what the device has room for (``REMAT_CANDIDATES``).
+Parameter names are matched by
 :func:`horovod_tpu.parallel.sharding.lfm2_partition_rules`.
 """
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -41,11 +43,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import NamedSharding
 
 from ..parallel import moe
-from .gpt import FLASH_NAMES, _flash_causal, attention_impl
-from .granite import GatedMLP, RMSNorm, causal_depthwise_conv
+from . import layers
+from .layers import (FLASH_NAMES, GatedMLP, RMSNorm, SparseFFN,
+                     causal_depthwise_conv, counts_by_expert,
+                     grouped_causal_attention, mesh_of, prefixes, recomputed,
+                     rotary_tables, rotate)
 
 CONV, ATTENTION = "conv", "full_attention"
 DENSE, SPARSE = "dense", "sparse"
@@ -53,12 +58,13 @@ DENSE, SPARSE = "dense", "sparse"
 # flash kernels name, dearest to recompute first: the dense SwiGLU's gate
 # and up, the experts' gate and up (two grouped products), the
 # convolution operator's input projection, the sorted rows (a gather).
-# ``remat_names`` keeps as many as fit the device.
+# As many as fit the device are kept (``layers.kept_across_remat``).
 MATMUL_NAMES = ("gate_up", moe.EXPERT_GATE_UP_NAME, "in_proj", moe.ROWS_NAME)
 # Always kept: the kernels' output, and the routers' choice, which a
 # recomputed pass must not make again (``parallel/moe.py``).
 KEPT_NAMES = FLASH_NAMES + (moe.CHOICE_NAME,)
 REMAT_NAMES = KEPT_NAMES + MATMUL_NAMES
+REMAT_CANDIDATES = prefixes(REMAT_NAMES, len(KEPT_NAMES))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -126,25 +132,6 @@ def lfm2_tiny_config(**kw) -> LFM2Config:
     return LFM2Config(**defaults)
 
 
-def rotary_tables(seq: int, head_dim: int, theta: float):
-    """``cos`` and ``sin`` of ``position x theta^(-2i / head_dim)``,
-    each ``[seq, head_dim / 2]`` in float32."""
-    inverse = 1.0 / theta ** (
-        jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
-    angles = jnp.arange(seq, dtype=jnp.float32)[:, None] * inverse
-    return jnp.cos(angles), jnp.sin(angles)
-
-
-def rotate(x, cos, sin):
-    """Rotary positions in the rotate-half pairing: channel ``i`` of a
-    head turns with channel ``i + head_dim / 2``.  ``x``: ``[B, S,
-    heads, head_dim]``; float32 inside, ``x``'s type out."""
-    first, second = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    cos, sin = cos[:, None, :], sin[:, None, :]
-    return jnp.concatenate([first * cos - second * sin,
-                            second * cos + first * sin], -1).astype(x.dtype)
-
-
 class ShortConv(nn.Module):
     """``C * conv(B * x)`` between two projections."""
     config: LFM2Config
@@ -176,7 +163,7 @@ class RotaryAttention(nn.Module):
     def __call__(self, x, cos, sin):
         cfg = self.config
         q_heads, kv_heads = cfg.num_attention_heads, cfg.num_key_value_heads
-        group, head_dim = q_heads // kv_heads, cfg.head_dim
+        head_dim = cfg.head_dim
         dense = lambda heads, name: nn.DenseGeneral(
             features=(heads, head_dim), axis=-1, use_bias=False,
             dtype=cfg.dtype, param_dtype=jnp.float32, name=name)
@@ -187,70 +174,23 @@ class RotaryAttention(nn.Module):
         with jax.named_scope("rotary"):
             q = rotate(norm("query_norm")(q), cos, sin)
             k = rotate(norm("key_norm")(k), cos, sin)
-        scale = head_dim ** -0.5
-        mesh = (None if self.heads_sharding is None
-                else self.heads_sharding.mesh)
-        if attention_impl(cfg, mesh, not self.is_initializing()) == "flash":
-            # As Granite's: each key-value head laid out once for every
-            # query head it serves.
-            ctx = _flash_causal(q, jnp.repeat(k, group, axis=2),
-                                jnp.repeat(v, group, axis=2),
-                                self.heads_sharding, scale=scale)
-            ctx = ctx.astype(cfg.dtype)
-        else:
-            seq = x.shape[1]
-            q = q.reshape(*q.shape[:2], kv_heads, group, head_dim)
-            scores = jnp.einsum("bqkgd,bskd->bkgqs", q, k) * scale
-            causal = jnp.tril(jnp.ones((seq, seq), bool))
-            scores = jnp.where(causal, scores, jnp.finfo(cfg.dtype).min)
-            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-            ctx = jnp.einsum("bkgqs,bskd->bqkgd", probs.astype(cfg.dtype), v)
-            ctx = ctx.reshape(*ctx.shape[:2], q_heads, head_dim)
+        ctx = grouped_causal_attention(
+            q, k, v, head_dim ** -0.5, cfg, self.heads_sharding,
+            self.is_initializing())
         return nn.DenseGeneral(features=cfg.hidden_size, axis=(-2, -1),
                                use_bias=False, dtype=cfg.dtype,
                                param_dtype=jnp.float32, name="out")(ctx)
 
 
-def mesh_of(sharding: Optional[NamedSharding]) -> Optional[Mesh]:
-    """The mesh a step builder's sharding lies on; None where the model
-    is applied directly."""
-    return None if sharding is None else sharding.mesh
-
-
-class RoutedExperts(nn.Module):
-    """The parameters of ``parallel.moe.routed_experts``: a router over
-    all experts, and the stacked matrices of the experts held.  On one
-    TPU device (the platform read as the attention's is) the layer's
-    wide passes are Pallas kernels; ``init`` wants the parameters'
-    shapes and nothing of the layer, so no kernel is traced for it."""
-    config: LFM2Config
-    # The mesh the step this model is traced in lays its arrays on (the
-    # step builder says, through ``heads_sharding``); None where the
-    # model is applied directly.
-    mesh: Optional[Mesh] = None
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.config
-        hidden, width = cfg.hidden_size, cfg.moe_intermediate_size
-        stacked = lambda name, fan_in, fan_out: self.param(
-            name, nn.initializers.lecun_normal(batch_axis=(0,)),
-            (cfg.experts_held, fan_in, fan_out), jnp.float32)
-        router = self.param("router", nn.initializers.lecun_normal(),
-                            (hidden, cfg.num_experts), jnp.float32)
-        # A buffer in the published model: it selects, no gradient
-        # reaches it, and no rule here moves it.
-        bias = self.param("expert_bias", nn.initializers.zeros,
-                          (cfg.num_experts,), jnp.float32)
-        y, routing = moe.routed_experts(
-            x.reshape(-1, hidden), router, bias,
-            stacked("gate", hidden, width), stacked("up", hidden, width),
-            stacked("down", width, hidden),
-            first_expert=cfg.first_expert, top_k=cfg.num_experts_per_tok,
-            normalize=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor,
-            kernels=moe.on_one_tpu(self.mesh) and not self.is_initializing())
-        self.sow("intermediates", "chosen", routing.chosen)
-        return y.reshape(x.shape)
+def sparse_ffn(config: LFM2Config, mesh) -> SparseFFN:
+    """The routed experts of a sparse layer: sigmoid scores plus a
+    selection bias, no shared expert."""
+    return SparseFFN(
+        experts=config.num_experts, held=config.experts_held,
+        first_expert=config.first_expert, top_k=config.num_experts_per_tok,
+        width=config.moe_intermediate_size, normalize=config.norm_topk_prob,
+        dtype=config.dtype, scale=config.routed_scaling_factor, mesh=mesh,
+        name="moe")
 
 
 class LFM2Layer(nn.Module):
@@ -271,9 +211,9 @@ class LFM2Layer(nn.Module):
                                     name="attention")(u, cos, sin)
         u = norm("ffn_norm")(x)
         if self.ffn == DENSE:
-            return x + GatedMLP(cfg, name="mlp")(u)
-        return x + RoutedExperts(cfg, mesh_of(self.heads_sharding),
-                                 name="moe")(u)
+            return x + GatedMLP(cfg.intermediate_size, cfg.dtype,
+                                name="mlp")(u)
+        return x + sparse_ffn(cfg, mesh_of(self.heads_sharding))(u)
 
 
 class LFM2LMHeadModel(nn.Module):
@@ -281,7 +221,7 @@ class LFM2LMHeadModel(nn.Module):
     config: LFM2Config
     heads_sharding: Optional[NamedSharding] = None
     # What a recomputed layer keeps (``config.remat``); the step
-    # builder hands over what ``remat_names`` chose for its shapes.
+    # builder hands over what fits its shapes and its device.
     remat_names: Tuple[str, ...] = REMAT_NAMES
 
     @nn.compact
@@ -295,12 +235,7 @@ class LFM2LMHeadModel(nn.Module):
         with jax.named_scope("rotary_tables"):   # once a step
             cos, sin = rotary_tables(input_ids.shape[1], cfg.head_dim,
                                      cfg.rope_theta)
-        layer = LFM2Layer
-        if cfg.remat:
-            layer = nn.remat(
-                LFM2Layer,
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    *self.remat_names))
+        layer = recomputed(LFM2Layer, cfg.remat, self.remat_names)
         for i, (operator, ffn) in enumerate(zip(cfg.layer_types,
                                                 cfg.ffn_types)):
             x = layer(cfg, operator, ffn, self.heads_sharding,
@@ -315,30 +250,7 @@ class LFM2LMHeadModel(nn.Module):
                           preferred_element_type=jnp.float32)
 
 
-def sown_choices(model: nn.Module, params, input_ids):
-    """``{layer index: [T, top_k] int32}``: what the ``moe`` module of
-    every sparse ``layer_<i>`` of ``model`` sowed as ``chosen`` on
-    ``input_ids`` (the stacks of this family and of the families built
-    on its routed experts)."""
-    _, state = model.apply(
-        {"params": params}, input_ids, mutable=["intermediates"],
-        method="hidden_and_embedding")
-    return {int(name.split("_")[1]): layer["moe"]["chosen"][0]
-            for name, layer in state["intermediates"].items()}
-
-
-def expert_choices(config: LFM2Config, params, input_ids):
-    """``{layer index: [T, top_k] int32}``: the experts, of all
-    ``num_experts``, that each token chose in every sparse layer."""
-    return sown_choices(
-        LFM2LMHeadModel(dataclasses.replace(config, remat=False)), params,
-        input_ids)
-
-
-def counts_by_expert(chosen, num_experts: int):
-    """``[num_experts] int32``: how many of the ``T x top_k`` choices
-    ``chosen`` fell on each expert."""
-    return jnp.bincount(chosen.reshape(-1), length=num_experts)
+expert_choices = functools.partial(layers.expert_choices, LFM2LMHeadModel)
 
 
 def choice_counts(config: LFM2Config, params, input_ids):
@@ -349,10 +261,10 @@ def choice_counts(config: LFM2Config, params, input_ids):
                                             input_ids).items()}
 
 
-def remat_bytes(names, tokens: int, config: LFM2Config) -> int:
+def remat_bytes(names, sequences: int, seq: int, config: LFM2Config) -> int:
     """Bytes one device keeps across ``remat`` for ``names``, with
-    ``tokens`` of the batch on it.  Tensor parallelism is not counted:
-    the figure errs high."""
+    ``sequences`` sequences of ``seq`` on it.  Tensor parallelism is not
+    counted: the figure errs high."""
     itemsize = np.dtype(config.dtype).itemsize
     operators, ffns = config.layer_types, config.ffn_types
     rows = moe.dispatch_rows(1, config.num_experts_per_tok,
@@ -370,21 +282,4 @@ def remat_bytes(names, tokens: int, config: LFM2Config) -> int:
         "in_proj": operators.count(CONV) * 3 * config.hidden_size * itemsize,
         moe.ROWS_NAME: ffns.count(SPARSE) * rows * config.hidden_size
         * itemsize}
-    return tokens * sum(per_token[name] for name in names)
-
-
-def remat_names(tokens: int, config: LFM2Config, state_bytes: int,
-                memory_limit: Optional[int]) -> Tuple[str, ...]:
-    """As ``models.granite.remat_names``: the kernels' names and as many
-    of ``MATMUL_NAMES``, in their order, as fit one device's
-    ``memory_limit`` bytes beside the state the step is handed and a
-    margin of a quarter of the memory; every name where the device
-    reports no limit."""
-    if memory_limit is None:
-        return REMAT_NAMES
-    for count in range(len(REMAT_NAMES), len(KEPT_NAMES), -1):
-        names = REMAT_NAMES[:count]
-        if (remat_bytes(names, tokens, config) + state_bytes
-                + memory_limit // 4 <= memory_limit):
-            return names
-    return KEPT_NAMES
+    return sequences * seq * sum(per_token[name] for name in names)

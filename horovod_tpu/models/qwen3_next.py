@@ -45,12 +45,13 @@ float32 parameters; the router, the norms, the rotation, ``g`` and
 Pallas flash kernels with keys and values repeated to the query heads;
 elsewhere it is grouped einsums.  With ``remat`` a layer is recomputed
 in the backward pass but for the flash kernels' output, the routers'
-choice and what :func:`remat_names` finds room for.  Parameter names
-are matched by
+choice and what the device has room for (``REMAT_CANDIDATES``).
+Parameter names are matched by
 :func:`horovod_tpu.parallel.sharding.qwen3_next_partition_rules`.
 """
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -58,18 +59,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import NamedSharding
 
 from ..ops import gated_delta
 from ..parallel import moe
-from .gpt import FLASH_NAMES, _flash_causal, attention_impl
-from .granite import GatedMLP, causal_depthwise_conv
-from .lfm2 import mesh_of, rotary_tables, rotate, sown_choices
+from . import layers
+from .layers import (FLASH_NAMES, SparseFFN, causal_depthwise_conv,
+                     grouped_causal_attention, mesh_of, prefixes, recomputed,
+                     rotary_tables, rotate)
+from .layers import given_choices  # noqa: F401  (the benchmark's name)
 
 LINEAR, FULL = "linear_attention", "full_attention"
-# The collection of variables by which a caller hands the sparse layers
-# a choice of experts (``given_choices``).
-GIVEN = "given"
 # Added under the root of a head's sum of squares (queries and keys of
 # the delta rule), the class's own.
 L2_NORM_EPS = 1e-6
@@ -82,13 +82,15 @@ A_RANGE = (0.0, 16.0)
 # after another) and ``T``'s products; the delta-rule layers' input
 # projection; the routed experts' gate and up (two grouped products
 # over a buffer of which a sixteenth holds a pair), the sorted rows (a
-# gather).  ``remat_names`` keeps as many as fit the device.
+# gather).  As many as fit the device are kept
+# (``layers.kept_across_remat``).
 MATMUL_NAMES = ("gate_up", gated_delta.STATES_NAME, gated_delta.WY_NAME,
                 "in_proj", moe.EXPERT_GATE_UP_NAME, moe.ROWS_NAME)
 # Always kept: the kernels' output, and the routers' choice, which a
 # recomputed pass must not make again (``parallel/moe.py``).
 KEPT_NAMES = FLASH_NAMES + (moe.CHOICE_NAME,)
 REMAT_NAMES = KEPT_NAMES + MATMUL_NAMES
+REMAT_CANDIDATES = prefixes(REMAT_NAMES, len(KEPT_NAMES))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -150,13 +152,6 @@ class Qwen3NextConfig:
         return tuple(
             FULL if (i + 1) % self.full_attention_interval == 0 else LINEAR
             for i in range(self.num_hidden_layers))
-
-    @property
-    def intermediate_size(self) -> int:
-        """What ``granite.GatedMLP`` reads for its width: the one dense
-        SwiGLU of this model is the shared expert (the published key of
-        this name is read by no layer)."""
-        return self.shared_expert_intermediate_size
 
     @property
     def rotary_dim(self) -> int:
@@ -285,7 +280,7 @@ class GatedDeltaNet(nn.Module):
 
 def rotate_part(x, cos, sin):
     """Rotary positions on a head's first ``2 x cos.shape[-1]`` channels
-    (``models.lfm2.rotate``'s pairing over that part); the others pass."""
+    (``layers.rotate``'s pairing over that part); the others pass."""
     part = 2 * cos.shape[-1]
     return jnp.concatenate([rotate(x[..., :part], cos, sin), x[..., part:]],
                            axis=-1)
@@ -302,7 +297,7 @@ class GatedAttention(nn.Module):
     def __call__(self, x, cos, sin):
         cfg = self.config
         q_heads, kv_heads = cfg.num_attention_heads, cfg.num_key_value_heads
-        group, head_dim = q_heads // kv_heads, cfg.head_dim
+        head_dim = cfg.head_dim
         dense = lambda heads, width, name: nn.DenseGeneral(
             features=(heads, width), axis=-1, use_bias=False,
             dtype=cfg.dtype, param_dtype=jnp.float32, name=name)
@@ -316,25 +311,9 @@ class GatedAttention(nn.Module):
         with jax.named_scope("rotary"):
             q = rotate_part(norm("query_norm")(q), cos, sin)
             k = rotate_part(norm("key_norm")(k), cos, sin)
-        scale = head_dim ** -0.5
-        mesh = (None if self.heads_sharding is None
-                else self.heads_sharding.mesh)
-        if attention_impl(cfg, mesh, not self.is_initializing()) == "flash":
-            # As Granite's: each key-value head laid out once for every
-            # query head it serves.
-            ctx = _flash_causal(q, jnp.repeat(k, group, axis=2),
-                                jnp.repeat(v, group, axis=2),
-                                self.heads_sharding, scale=scale)
-            ctx = ctx.astype(cfg.dtype)
-        else:
-            seq = x.shape[1]
-            q = q.reshape(*q.shape[:2], kv_heads, group, head_dim)
-            scores = jnp.einsum("bqkgd,bskd->bkgqs", q, k) * scale
-            causal = jnp.tril(jnp.ones((seq, seq), bool))
-            scores = jnp.where(causal, scores, jnp.finfo(cfg.dtype).min)
-            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-            ctx = jnp.einsum("bkgqs,bskd->bqkgd", probs.astype(cfg.dtype), v)
-            ctx = ctx.reshape(*ctx.shape[:2], q_heads, head_dim)
+        ctx = grouped_causal_attention(
+            q, k, v, head_dim ** -0.5, cfg, self.heads_sharding,
+            self.is_initializing())
         with jax.named_scope("output_gate"):
             ctx = ctx * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
                 cfg.dtype)
@@ -343,47 +322,16 @@ class GatedAttention(nn.Module):
                                param_dtype=jnp.float32, name="out")(ctx)
 
 
-class SparseFFN(nn.Module):
-    """The routed experts held (the parameters of
-    ``parallel.moe.routed_experts``: a softmax router over all experts,
-    the stacked matrices of those held) and beside them the shared
-    expert, one gated MLP times a sigmoid of the token, under the scope
-    ``shared``.  The routed passes are Pallas kernels where LFM2's
-    are."""
-    config: Qwen3NextConfig
-    # The mesh the step this model is traced in lays its arrays on (the
-    # step builder says, through ``heads_sharding``); None where the
-    # model is applied directly.
-    mesh: Optional[Mesh] = None
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.config
-        hidden, width = cfg.hidden_size, cfg.moe_intermediate_size
-        stacked = lambda name, fan_in, fan_out: self.param(
-            name, nn.initializers.lecun_normal(batch_axis=(0,)),
-            (cfg.experts_held, fan_in, fan_out), jnp.float32)
-        router = self.param("router", nn.initializers.lecun_normal(),
-                            (hidden, cfg.num_experts), jnp.float32)
-        # A caller may hand the choice over, as the variable ``chosen``
-        # of the collection ``given`` (``given_choices``).
-        given = (self.get_variable(GIVEN, "chosen")
-                 if self.has_variable(GIVEN, "chosen") else None)
-        y, routing = moe.routed_experts(
-            x.reshape(-1, hidden), router, None,
-            stacked("gate", hidden, width), stacked("up", hidden, width),
-            stacked("down", width, hidden),
-            first_expert=cfg.first_expert, top_k=cfg.num_experts_per_tok,
-            normalize=cfg.norm_topk_prob, chosen=given,
-            router=moe.softmax_top_k,
-            kernels=moe.on_one_tpu(self.mesh) and not self.is_initializing())
-        self.sow("intermediates", "chosen", routing.chosen)
-        shared = GatedMLP(cfg, name="shared")(x)
-        with jax.named_scope("shared"):
-            open_ = nn.Dense(1, use_bias=False, dtype=jnp.float32,
-                             param_dtype=jnp.float32, name="shared_gate")(x)
-            shared = shared * jax.nn.sigmoid(open_).astype(cfg.dtype)
-        return y.reshape(x.shape) + shared
+def sparse_ffn(config: Qwen3NextConfig, mesh) -> SparseFFN:
+    """The routed experts of a layer under a softmax router, and beside
+    them the shared expert times a sigmoid of the token."""
+    return SparseFFN(
+        experts=config.num_experts, held=config.experts_held,
+        first_expert=config.first_expert, top_k=config.num_experts_per_tok,
+        width=config.moe_intermediate_size, normalize=config.norm_topk_prob,
+        dtype=config.dtype, router=moe.softmax_top_k,
+        shared=config.shared_expert_intermediate_size, shared_gate=True,
+        mesh=mesh, name="moe")
 
 
 class Qwen3NextLayer(nn.Module):
@@ -402,7 +350,7 @@ class Qwen3NextLayer(nn.Module):
         else:
             x = x + GatedAttention(cfg, self.heads_sharding,
                                    name="attention")(u, cos, sin)
-        return x + SparseFFN(cfg, mesh_of(self.heads_sharding), name="moe")(
+        return x + sparse_ffn(cfg, mesh_of(self.heads_sharding))(
             norm("ffn_norm")(x))
 
 
@@ -411,7 +359,7 @@ class Qwen3NextLMHeadModel(nn.Module):
     config: Qwen3NextConfig
     heads_sharding: Optional[NamedSharding] = None
     # What a recomputed layer keeps (``config.remat``); the step
-    # builder hands over what ``remat_names`` chose for its shapes.
+    # builder hands over what fits its shapes and its device.
     remat_names: Tuple[str, ...] = REMAT_NAMES
 
     @nn.compact
@@ -429,12 +377,7 @@ class Qwen3NextLMHeadModel(nn.Module):
         with jax.named_scope("rotary_tables"):   # once a step
             cos, sin = rotary_tables(input_ids.shape[1], cfg.rotary_dim,
                                      cfg.rope_theta)
-        layer = Qwen3NextLayer
-        if cfg.remat:
-            layer = nn.remat(
-                Qwen3NextLayer,
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    *self.remat_names))
+        layer = recomputed(Qwen3NextLayer, cfg.remat, self.remat_names)
         for i, kind in enumerate(cfg.layer_types):
             x = layer(cfg, kind, self.heads_sharding,
                       name=f"layer_{i}")(x, cos, sin)
@@ -448,21 +391,8 @@ class Qwen3NextLMHeadModel(nn.Module):
                           preferred_element_type=jnp.float32)
 
 
-def expert_choices(config: Qwen3NextConfig, params, input_ids):
-    """``{layer index: [T, top_k] int32}``: the experts, of all
-    ``num_experts``, that each token chose in every layer."""
-    return sown_choices(
-        Qwen3NextLMHeadModel(dataclasses.replace(config, remat=False)),
-        params, input_ids)
-
-
-def given_choices(chosen) -> dict:
-    """``expert_choices``'s ``{layer index: [T, top_k]}`` as the
-    variables that make every sparse layer take that choice and not its
-    own: ``model.apply({"params": params, **given_choices(chosen)},
-    ...)``."""
-    return {GIVEN: {"layer_%d" % i: {"moe": {"chosen": c}}
-                    for i, c in chosen.items()}}
+expert_choices = functools.partial(layers.expert_choices,
+                                   Qwen3NextLMHeadModel)
 
 
 def remat_bytes(names, sequences: int, seq: int,
@@ -500,21 +430,3 @@ def remat_bytes(names, sequences: int, seq: int,
         length * d_v + d_k * d_v)
     return sum(states if name == gated_delta.STATES_NAME
                else sequences * seq * per_token[name] for name in names)
-
-
-def remat_names(sequences: int, seq: int, config: Qwen3NextConfig,
-                state_bytes: int,
-                memory_limit: Optional[int]) -> Tuple[str, ...]:
-    """As ``models.deepseek_v3.remat_names``: the kernels' names, the
-    choice and as many of ``MATMUL_NAMES``, in their order, as fit one
-    device's ``memory_limit`` bytes beside the state the step is handed
-    and a margin of a quarter of the memory; every name where the
-    device reports no limit."""
-    if memory_limit is None:
-        return REMAT_NAMES
-    for count in range(len(REMAT_NAMES), len(KEPT_NAMES), -1):
-        names = REMAT_NAMES[:count]
-        if (remat_bytes(names, sequences, seq, config) + state_bytes
-                + memory_limit // 4 <= memory_limit):
-            return names
-    return KEPT_NAMES

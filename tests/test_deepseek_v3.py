@@ -148,8 +148,8 @@ def test_expert_shares_and_one_shared_expert_add_up_to_the_uncut_layer():
         for first in (0, 4):
             of_share = lambda name: p[name][first:first + 4]
             share = {**p, **{n: of_share(n) for n in ("gate", "up", "down")}}
-            module = deepseek_v3.SparseFFN(
-                dataclasses.replace(cfg, first_expert=first))
+            module = deepseek_v3.sparse_ffn(
+                dataclasses.replace(cfg, first_expert=first), None)
             got = jax.jit(module.apply)({"params": share}, x)
             alone, _ = reference.sparse_ffn(
                 x, share, dict(uncut, first_expert=first))
@@ -329,27 +329,15 @@ def test_gauges_show_in_the_metrics_snapshot():
     assert gauges["hvd_moe_shared_width"] == 2 * cfg.moe_intermediate_size
     layers = gauges["hvd_hybrid_layers"]
     assert (layers["kind=dense"], layers["kind=sparse"]) == (1.0, 2.0)
-    # the CPU reports no memory: every name is kept
-    kept = gauges["hvd_deepseek_v3_remat_kept_bytes"]
-    names = "names=" + "+".join(deepseek_v3.REMAT_NAMES)
-    assert kept[names] == deepseek_v3.remat_bytes(
-        deepseek_v3.REMAT_NAMES, 128, cfg)
 
 
-def test_remat_keeps_what_fits_in_the_order_of_its_names():
-    """At the published widths with 16384 tokens on a chip of 16.9 GB
-    that holds 8.25 GB of state: the kernels' output, the choice, gate
-    and up of the dense and shared parts and the expanded keys and
-    values (2.0 GB over six layers) fit; the routed experts' gate and up
-    (1.5 GB) and the sorted rows do not.  On a smaller device only what
-    must be kept is."""
+def test_remat_bytes_by_hand_at_the_published_widths():
+    """A token's bytes a name at the published widths, six layers, 16
+    of 128 experts held (what the device keeps of them is
+    ``test_causal_lm_families.py``'s)."""
     cfg = deepseek_v3.DeepseekV3Config(
         vocab_size=16032, num_hidden_layers=6, experts_held=16)
-    tokens, state, chip = 16384, 8_250_000_000, 16_900_000_000
-    names = deepseek_v3.remat_names(tokens, cfg, state, chip)
-    assert names == deepseek_v3.KEPT_NAMES + (
-        "gate_up", deepseek_v3.EXPANDED_KV_NAME)
-    per_token = lambda name: deepseek_v3.remat_bytes((name,), 1, cfg)
+    per_token = lambda name: deepseek_v3.remat_bytes((name,), 1, 1, cfg)
     assert per_token("flash_out") == 6 * 32 * 128 * 2
     assert per_token("flash_lse") == 6 * 32 * 4
     assert per_token(moe.CHOICE_NAME) == 5 * 6 * 4
@@ -357,10 +345,9 @@ def test_remat_keeps_what_fits_in_the_order_of_its_names():
     assert per_token(moe.EXPERT_GATE_UP_NAME) == 5 * 6 * 2 * 768 * 2
     assert per_token(deepseek_v3.EXPANDED_KV_NAME) == 6 * 32 * 320 * 2
     assert per_token(moe.ROWS_NAME) == 5 * 6 * 2048 * 2
-    assert deepseek_v3.remat_names(tokens, cfg, state, None) == \
-        deepseek_v3.REMAT_NAMES
-    assert deepseek_v3.remat_names(tokens, cfg, state, 12_000_000_000) == \
-        deepseek_v3.KEPT_NAMES
+    # sequences and positions count alike
+    assert deepseek_v3.remat_bytes(deepseek_v3.REMAT_NAMES, 2, 8192, cfg) \
+        == 16384 * sum(map(per_token, deepseek_v3.REMAT_NAMES))
 
 
 def test_expert_choices_of_a_batch():

@@ -13,7 +13,7 @@ import pytest
 
 import horovod_tpu as hvd
 from horovod_tpu import training
-from horovod_tpu.models import gpt, granite, lfm2
+from horovod_tpu.models import gpt, granite, layers, lfm2
 from horovod_tpu.parallel.mesh import build_mesh
 from horovod_tpu.training import gpt_step_loss, make_gpt_train_step
 from test_pallas_attention import _shapes
@@ -49,10 +49,10 @@ def test_loss_chunks_follow_the_tokens_on_one_device(sequences, dp, seq,
     on_one = training._sequences_on_one_device(
         NamedSharding(mesh, P("dp", None)), sequences)
     assert on_one == sequences // dp
-    count, length = gpt.loss_chunks(seq, on_one)
+    count, length = layers.loss_chunks(seq, on_one)
     assert (count, length) == want
     assert count * length >= seq > (count - 1) * length
-    assert on_one * length <= gpt.LOSS_CHUNK_TOKENS or length == 1
+    assert on_one * length <= layers.LOSS_CHUNK_TOKENS or length == 1
     # A model that is applied directly holds the whole batch.
     assert training._sequences_on_one_device(None, sequences) == sequences
 
@@ -84,7 +84,7 @@ def test_chunked_loss_is_lm_loss_of_the_logits(dtype, masked, seq, chunks,
     params = gpt.GPTLMHeadModel(plain).init(
         jax.random.PRNGKey(0), ids)["params"]
     # One whole chunk; two and three with a position and two of padding.
-    assert gpt.loss_chunks(seq, batch) == chunks
+    assert layers.loss_chunks(seq, batch) == chunks
 
     def of_logits(config):
         model = gpt.GPTLMHeadModel(config)
@@ -98,7 +98,7 @@ def test_chunked_loss_is_lm_loss_of_the_logits(dtype, masked, seq, chunks,
             hidden, embedding = model.apply(
                 {"params": p}, ids,
                 method=gpt.GPTLMHeadModel.hidden_and_embedding)
-            return gpt.chunked_lm_loss(hidden, embedding, ids, mask)
+            return layers.chunked_lm_loss(hidden, embedding, ids, mask)
         return loss
 
     want_loss, want = _value_and_grad(of_logits(plain), params)
@@ -160,7 +160,7 @@ def test_gpt_step_holds_no_array_of_the_whole_logits():
     batch, seq = args[2].shape
     whole = batch * seq * cfg.vocab_size
     shapes = _shapes(jax.make_jaxpr(step_fn)(*args).jaxpr, set())
-    assert gpt.loss_chunks(seq, batch) == (2, 128)
+    assert layers.loss_chunks(seq, batch) == (2, 128)
     assert (batch, 128, cfg.vocab_size) in shapes, shapes
     assert max(map(math.prod, shapes)) * 2 <= whole, shapes
 
@@ -170,72 +170,27 @@ def test_gpt_step_holds_no_array_of_the_whole_logits():
     assert (batch, seq, cfg.vocab_size) in _shapes(plain.jaxpr, set())
 
 
-# gpt2-medium at 16 x 1024 on one v5e: what the benchmark's cell asks.
-CELL = dict(tokens=16 * 1024, hidden=1024, intermediate=4096, heads=16,
-            layers=24, itemsize=2)
-CELL_STATE = 3 * 4 * 354_823_168      # fp32 parameters and Adam's two
-V5E = 16_911_433_728                   # memory_stats()["bytes_limit"]
-
-
-def test_remat_keeps_what_fits_the_device():
-    """The rule on integers: the cell's shapes keep every name on a v5e
-    (268 MB of matmul outputs a layer), the same shapes on a sixteenth
-    of the memory keep the kernels' names alone, and a device that
-    reports no limit keeps every name."""
-    sizes = tuple(CELL.values())
-    matmuls = gpt.remat_bytes(gpt.MATMUL_NAMES, *sizes)
-    assert matmuls == 24 * 268_435_456
-    assert gpt.remat_bytes(gpt.FLASH_NAMES, *sizes) == \
+def test_remat_bytes_by_hand_at_the_published_widths():
+    """gpt2-medium at 16 x 1024, what the benchmark's cell asks: 268 MB
+    of matmul outputs a layer (what the device keeps of them is
+    ``test_causal_lm_families.py``'s)."""
+    cfg = gpt.gpt2_medium_config()
+    assert gpt.remat_bytes(gpt.MATMUL_NAMES, 16, 1024, cfg) == \
+        24 * 268_435_456
+    assert gpt.remat_bytes(gpt.FLASH_NAMES, 16, 1024, cfg) == \
         24 * 16384 * (1024 * 2 + 16 * 4)
-    assert gpt.remat_names(**CELL, state_bytes=CELL_STATE,
-                           memory_limit=V5E) == gpt.REMAT_NAMES
-    assert gpt.remat_names(**CELL, state_bytes=CELL_STATE,
-                           memory_limit=V5E // 16) == gpt.FLASH_NAMES
-    assert gpt.remat_names(**CELL, state_bytes=CELL_STATE,
-                           memory_limit=None) == gpt.REMAT_NAMES
-    # Twice the batch no longer fits beside the state and the margin.
-    assert gpt.remat_names(**dict(CELL, tokens=32 * 1024),
-                           state_bytes=CELL_STATE,
-                           memory_limit=V5E) == gpt.FLASH_NAMES
-
-
-def test_the_step_decides_by_its_device_memory(monkeypatch):
-    """``make_gpt_train_step`` hands the rule what it can see when the
-    step is traced: a device that reports little memory keeps the
-    kernels' names, and the compiled step's loss is the same."""
-    cfg, step_fn, args = _tiny_step(remat=True)
-    text = str(jax.make_jaxpr(step_fn)(*args))
-    kept = hvd.metrics_snapshot()["gauges"]["hvd_gpt_remat_kept_bytes"]
-    assert "names=" + "+".join(gpt.REMAT_NAMES) in kept
-
-    monkeypatch.setattr("horovod_tpu.training._memory_limit",
-                        lambda device: 1 << 20)
-    _, small_step, _ = _tiny_step(remat=True)
-    small_text = str(jax.make_jaxpr(small_step)(*args))
-    kept = hvd.metrics_snapshot()["gauges"]["hvd_gpt_remat_kept_bytes"]
-    batch, seq = args[2].shape
-    assert kept["names=flash_out+flash_lse"] == gpt.remat_bytes(
-        gpt.FLASH_NAMES, batch * seq, cfg.hidden_size,
-        cfg.intermediate_size, cfg.num_heads, cfg.num_layers, 2)
-    # The matmuls whose outputs went are traced a second time.
-    assert small_text.count("dot_general") > text.count("dot_general")
 
 
 def test_gauges_show_in_the_metrics_snapshot():
-    """Tracing a tiny step sets the bytes kept across ``remat`` (by the
-    names kept), the loss's chunk count and a chunk's tokens."""
+    """Tracing a tiny step sets the loss's chunk count and a chunk's
+    tokens (the bytes kept across ``remat`` are
+    ``test_causal_lm_families.py``'s)."""
     cfg, step_fn, args = _tiny_step(remat=True, seq=3 * 128 + 5)
     step_fn.lower(*args)
     gauges = hvd.metrics_snapshot()["gauges"]
     assert "hvd_gpt_loss_chunks" not in gauges
     assert gauges["hvd_lm_loss_chunks"] == 4
     assert gauges["hvd_lm_loss_chunk_tokens"] == 16 * 98   # ceil(389 / 4)
-    batch, seq = args[2].shape
-    per_token = 2 * cfg.num_layers * (
-        5 * cfg.hidden_size + cfg.intermediate_size) \
-        + 4 * cfg.num_layers * cfg.num_heads
-    assert gauges["hvd_gpt_remat_kept_bytes"][
-        "names=" + "+".join(gpt.REMAT_NAMES)] == batch * seq * per_token
 
 
 @pytest.mark.parametrize("family", ["gpt", "granite", "lfm2"])

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 import horovod_tpu as hvd
-from horovod_tpu.models import gpt, granite
+from horovod_tpu.models import gpt, granite, layers
 from horovod_tpu.ops import ssd
 from horovod_tpu.parallel.mesh import build_mesh
 from horovod_tpu.training import granite_step_loss, make_granite_train_step
@@ -95,12 +95,12 @@ def test_chunked_loss_scales_the_logits(scale):
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
     # 32 sequences walk 64 positions a chunk: three ragged chunks here.
     hidden = jax.random.normal(keys[0], (32, 2 * 64 + 7, 32))
-    assert gpt.loss_chunks(seq=135, sequences=32) == (3, 45)
+    assert layers.loss_chunks(seq=135, sequences=32) == (3, 45)
     table = jax.random.normal(keys[1], (96, 32))
     ids = jax.random.randint(keys[2], hidden.shape[:2], 0, 96)
 
     def chunked(h, e):
-        return gpt.chunked_lm_loss(h, e, ids, logits_scale=scale)
+        return layers.chunked_lm_loss(h, e, ids, logits_scale=scale)
 
     def plain(h, e):
         return gpt.lm_loss(jnp.einsum("bsh,vh->bsv", h, e) * scale, ids)
@@ -193,51 +193,21 @@ def test_gauges_show_in_the_metrics_snapshot():
 
 # The benchmark's cell: 8192 tokens, one period at the published
 # widths, 9.27 GB of parameters and AdamW's moments, a v5e's memory.
-CELL_TOKENS, CELL_STATE, V5E = 2 * 4096, 772_160_448 * 12, 16_860_000_000
+CELL_TOKENS = 2 * 4096
 
 
-def test_remat_names_keep_what_fits_the_device():
-    """A function of integers: the kernels' names always; gate and up
-    (2.68 GB at the cell's size), then the input projection's output
-    (1.26 GB), while they fit beside the state and a quarter of the
-    memory; every name where the device reports none."""
+def test_remat_bytes_by_hand_at_the_published_widths():
+    """A token's bytes a name: gate and up are 2.68 GB at the cell's
+    size, the input projection's output 1.26 GB (what the device keeps
+    of them is ``test_causal_lm_families.py``'s)."""
     cfg = granite.GraniteConfig(vocab_size=12544)
     per_token = {"flash_out": 2048 * 2, "flash_lse": 32 * 4,
                  "gate_up": 10 * 2 * 8192 * 2, "in_proj": 9 * 8512 * 2}
     for name, width in per_token.items():
-        assert granite.remat_bytes((name,), CELL_TOKENS, cfg) == \
+        assert granite.remat_bytes((name,), 2, 4096, cfg) == \
             CELL_TOKENS * width
-    assert granite.remat_bytes(granite.REMAT_NAMES, CELL_TOKENS, cfg) == \
+    assert granite.remat_bytes(granite.REMAT_NAMES, 2, 4096, cfg) == \
         CELL_TOKENS * sum(per_token.values())
-    names = lambda limit, tokens=CELL_TOKENS: granite.remat_names(
-        tokens, cfg, CELL_STATE, limit)
-    assert names(None) == granite.REMAT_NAMES
-    assert names(V5E) == granite.FLASH_NAMES + ("gate_up",)
-    assert names(2 * V5E) == granite.REMAT_NAMES
-    assert names(V5E, tokens=4 * CELL_TOKENS) == granite.FLASH_NAMES
-    assert names(1 << 20) == granite.FLASH_NAMES
-
-
-def test_the_step_decides_by_its_device_memory(monkeypatch):
-    """``make_granite_train_step`` hands the rule what it sees when the
-    step is traced; on a device that reports little memory the matmuls
-    whose outputs went are traced a second time, and the gauge says
-    which names stayed."""
-    cfg, mesh, init_fn, step_fn, ids = _tiny_step({"dp": 1}, remat=True)
-    state = jax.eval_shape(init_fn, jax.random.PRNGKey(0), ids)
-    text = str(jax.make_jaxpr(step_fn)(*state, ids))
-    kept = hvd.metrics_snapshot()["gauges"]["hvd_granite_remat_kept_bytes"]
-    assert kept["names=" + "+".join(granite.REMAT_NAMES)] == \
-        granite.remat_bytes(granite.REMAT_NAMES, ids.size, cfg)
-
-    monkeypatch.setattr("horovod_tpu.training._memory_limit",
-                        lambda device: 1 << 20)
-    small_step = _tiny_step({"dp": 1}, remat=True)[3]
-    small_text = str(jax.make_jaxpr(small_step)(*state, ids))
-    kept = hvd.metrics_snapshot()["gauges"]["hvd_granite_remat_kept_bytes"]
-    assert kept["names=flash_out+flash_lse"] == granite.remat_bytes(
-        granite.FLASH_NAMES, ids.size, cfg)
-    assert small_text.count("dot_general") > text.count("dot_general")
 
 
 def test_flash_path_equals_the_einsum_path_on_grouped_heads():

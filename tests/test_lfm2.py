@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 import horovod_tpu as hvd
-from horovod_tpu.models import lfm2
+from horovod_tpu.models import layers, lfm2
 from horovod_tpu.parallel import moe
 from horovod_tpu.parallel.mesh import build_mesh
 from horovod_tpu.training import lfm2_step_loss, make_lfm2_train_step
@@ -292,7 +292,7 @@ def test_rotation_equals_the_complex_form():
     imaginary part of a number turned by ``t * theta^(-2i / d)``."""
     seq, heads, d, theta = 24, 3, 16, 1e6
     x = jax.random.normal(jax.random.PRNGKey(0), (2, seq, heads, d))
-    got = lfm2.rotate(x, *lfm2.rotary_tables(seq, d, theta))
+    got = layers.rotate(x, *layers.rotary_tables(seq, d, theta))
     x64 = np.asarray(x, np.float64)
     z = x64[..., :d // 2] + 1j * x64[..., d // 2:]
     angle = (np.arange(seq)[:, None, None]
@@ -313,7 +313,7 @@ def test_queries_and_keys_are_normed_over_their_head(which):
     cfg = lfm2.lfm2_tiny_config(dtype=jnp.float32)
     attention = lfm2.RotaryAttention(cfg)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 32, cfg.hidden_size))
-    tables = lfm2.rotary_tables(32, cfg.head_dim, cfg.rope_theta)
+    tables = layers.rotary_tables(32, cfg.head_dim, cfg.rope_theta)
     params = attention.init(jax.random.PRNGKey(1), x, *tables)["params"]
     assert params[which + "_norm"]["scale"].shape == (cfg.head_dim,)
     larger = dict(params, **{which: {"kernel": 3.0 * params[which]["kernel"]}})
@@ -432,17 +432,14 @@ def test_choice_counts_of_a_batch():
 # The benchmark's cell: 8192 tokens, the dense layer and one period at
 # the published widths, 9.46 GB of parameters and AdamW's moments, a
 # v5e's memory.
-CELL_TOKENS, CELL_STATE, V5E = 2 * 4096, 788_052_352 * 12, 16_860_000_000
 CELL = dict(vocab_size=16384,
             layer_types=("conv", "full_attention", "conv", "conv", "conv"),
             ffn_types=("dense",) + ("sparse",) * 4, experts_held=16)
 
 
-def test_remat_names_keep_what_fits_the_device():
-    """A function of integers: the kernels' names and the routers'
-    choice always; then the dense gate and up, the experts' gate and
-    up, the convolutions' input projection and the sorted rows while
-    they fit beside the state and a quarter of the memory."""
+def test_remat_bytes_by_hand_at_the_published_widths():
+    """A token's bytes a name at the cell's widths (what the device
+    keeps of them is ``test_causal_lm_families.py``'s)."""
     cfg = lfm2.LFM2Config(**CELL)
     rows = 4 * 4   # of one token: four sparse layers, top 4, 16 held
     per_token = {"flash_out": 2048 * 2, "flash_lse": 32 * 4,
@@ -451,14 +448,7 @@ def test_remat_names_keep_what_fits_the_device():
                  "in_proj": 4 * 3 * 2048 * 2, "moe_rows": rows * 2048 * 2}
     assert tuple(per_token) == lfm2.REMAT_NAMES
     for name, width in per_token.items():
-        assert lfm2.remat_bytes((name,), CELL_TOKENS, cfg) == \
-            CELL_TOKENS * width
-    names = lambda limit, tokens=CELL_TOKENS: lfm2.remat_names(
-        tokens, cfg, CELL_STATE, limit)
-    assert names(None) == lfm2.REMAT_NAMES
-    assert names(V5E) == lfm2.REMAT_NAMES      # 2.17 GB beside 9.46 + 4.2
-    assert names(V5E, tokens=2 * CELL_TOKENS) == lfm2.REMAT_NAMES[:-2]
-    assert names(1 << 20) == lfm2.KEPT_NAMES
+        assert lfm2.remat_bytes((name,), 2, 4096, cfg) == 8192 * width
 
 
 def test_the_choice_is_kept_across_remat():
@@ -479,8 +469,8 @@ def test_the_choice_is_kept_across_remat():
         small = text(True)
     assert small.count(" top_k[") == 2
     assert small.count("ragged_dot") > kept.count("ragged_dot")
-    names = hvd.metrics_snapshot()["gauges"]["hvd_lfm2_remat_kept_bytes"]
-    assert "names=" + "+".join(lfm2.KEPT_NAMES) in names
+    names = hvd.metrics_snapshot()["gauges"]["hvd_remat_kept_bytes"]
+    assert "family=lfm2,names=" + "+".join(lfm2.KEPT_NAMES) in names
 
 
 def test_flash_path_equals_the_einsum_path_with_rotary_heads():

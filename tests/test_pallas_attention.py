@@ -290,7 +290,7 @@ def test_gpt_flash_kernels_match_einsum(axes):
     ids = jnp.asarray(np.random.RandomState(0).randint(
         0, cfg_e.vocab_size, (4, 48), dtype=np.int32))
     m_e, m_f = GPTLMHeadModel(cfg_e), GPTLMHeadModel(
-        cfg_f, qkv_sharding=sharding)
+        cfg_f, heads_sharding=sharding)
     params = m_e.init(jax.random.PRNGKey(0), ids)["params"]
 
     def value_and_grad(model):
