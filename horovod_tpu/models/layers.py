@@ -4,8 +4,8 @@ positions, the sparse feed-forward, the rule for what a recomputed
 layer keeps and the loss over chunks of the sequence.
 
 A family's module (``gpt.py``, ``granite.py``, ``lfm2.py``,
-``deepseek_v3.py``, ``qwen3_next.py``) imports from here, from ``ops/``
-and from ``parallel/``, never from another family's: a layer two
+``deepseek_v3.py``, ``qwen3_next.py``, ``afmoe.py``) imports from here,
+from ``ops/`` and from ``parallel/``, never from another family's: a layer two
 families need lives here from the day the second one needs it.  Every
 module below is built with an explicit ``name=`` by its caller, so a
 parameter's path says nothing of this file.
@@ -46,9 +46,11 @@ def mesh_of(sharding: Optional[NamedSharding]) -> Optional[Mesh]:
 
 
 def _flash_causal(q, k, v, sharding: Optional[NamedSharding],
-                  scale: Optional[float] = None):
+                  scale: Optional[float] = None,
+                  window: Optional[int] = None):
     from ..ops.pallas_attention import flash_attention
-    attend = functools.partial(flash_attention, causal=True, scale=scale)
+    attend = functools.partial(flash_attention, causal=True, scale=scale,
+                               window=window)
     if sharding is not None and sharding.mesh.size > 1:
         # GSPMD does not partition a Mosaic kernel; attention is
         # independent per sequence and per head, so each chip runs
@@ -70,27 +72,43 @@ def attention_impl(config, mesh, kernels_apply: bool) -> str:
     return "flash" if platform == "tpu" and kernels_apply else "einsum"
 
 
+def visible_keys(seq: int, window: Optional[int] = None):
+    """``[seq, seq]`` bool, queries down and keys across: key ``j`` is
+    visible to query ``i`` where ``0 <= i - j`` and, with a ``window``,
+    ``i - j < window`` (the query's own position counts as one of the
+    window's keys).  The einsum paths' mask; the kernels decide the same
+    tile by tile (``ops/pallas_attention.py``)."""
+    keep = jnp.tril(jnp.ones((seq, seq), bool))
+    if window is not None:
+        keep = keep & jnp.triu(jnp.ones((seq, seq), bool), 1 - window)
+    return keep
+
+
 def grouped_causal_attention(q, k, v, scale: float, config,
                              heads_sharding: Optional[NamedSharding],
-                             initializing: bool):
+                             initializing: bool,
+                             window: Optional[int] = None):
     """Causal ``softmax(q k^T scale) v`` where each key-value head
-    serves a run of consecutive query heads.  ``q``: ``[B, S, heads,
-    D]``; ``k``, ``v``: ``[B, S, kv_heads, D]``.  The flash kernels
-    where ``attention_impl`` says so (never for ``init``, which wants
-    the parameters' shapes and nothing of the attention): they take as
-    many key-value heads as query heads, so each is laid out once for
-    every query head it serves.  Grouped einsums elsewhere."""
+    serves a run of consecutive query heads; with a ``window`` over the
+    last ``window`` keys up to the query's own (``visible_keys``).
+    ``q``: ``[B, S, heads, D]``; ``k``, ``v``: ``[B, S, kv_heads, D]``.
+    The flash kernels where ``attention_impl`` says so (never for
+    ``init``, which wants the parameters' shapes and nothing of the
+    attention): they take as many key-value heads as query heads, so
+    each is laid out once for every query head it serves, and they walk
+    a window's band and nothing left of it.  Grouped einsums under the
+    mask elsewhere."""
     group = q.shape[2] // k.shape[2]
     if attention_impl(config, mesh_of(heads_sharding),
                       not initializing) == "flash":
         ctx = _flash_causal(q, jnp.repeat(k, group, axis=2),
                             jnp.repeat(v, group, axis=2), heads_sharding,
-                            scale=scale)
+                            scale=scale, window=window)
         return ctx.astype(config.dtype)
     seq, q_heads, head_dim = q.shape[1:]
     q = q.reshape(*q.shape[:2], k.shape[2], group, head_dim)
     scores = jnp.einsum("bqkgd,bskd->bkgqs", q, k) * scale
-    causal = jnp.tril(jnp.ones((seq, seq), bool))
+    causal = visible_keys(seq, window)
     scores = jnp.where(causal, scores, jnp.finfo(config.dtype).min)
     probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
     ctx = jnp.einsum("bkgqs,bskd->bqkgd", probs.astype(config.dtype), v)
@@ -216,7 +234,10 @@ class SparseFFN(nn.Module):
         router = self.param("router", nn.initializers.lecun_normal(),
                             (hidden, self.experts), jnp.float32)
         # A buffer in the published models: it selects, no gradient
-        # reaches it, and no rule here moves it.
+        # reaches it, and AdamW's update of a zero gradient is zero (no
+        # decay on a vector).  What moves it, in a family whose row says
+        # so, is the step's own rule after the optimizer's update
+        # (``moe.moved_bias`` on the counts of the choice sown below).
         bias = None if self.router else self.param(
             "expert_bias", nn.initializers.zeros, (self.experts,),
             jnp.float32)
@@ -247,15 +268,20 @@ class SparseFFN(nn.Module):
         return y.reshape(x.shape) + shared
 
 
+def choices_of(state) -> dict:
+    """``{layer index: [T, top_k] int32}`` from what an ``apply`` with
+    ``mutable=["intermediates"]`` returned beside its value: what the
+    ``moe`` module of every sparse ``layer_<i>`` sowed as ``chosen``."""
+    return {int(name.split("_")[1]): layer["moe"]["chosen"][0]
+            for name, layer in state["intermediates"].items()}
+
+
 def sown_choices(model: nn.Module, params, input_ids):
-    """``{layer index: [T, top_k] int32}``: what the ``moe`` module of
-    every sparse ``layer_<i>`` of ``model`` sowed as ``chosen`` on
-    ``input_ids``."""
+    """``choices_of`` what ``model`` sowed on ``input_ids``."""
     _, state = model.apply(
         {"params": params}, input_ids, mutable=["intermediates"],
         method="hidden_and_embedding")
-    return {int(name.split("_")[1]): layer["moe"]["chosen"][0]
-            for name, layer in state["intermediates"].items()}
+    return choices_of(state)
 
 
 def expert_choices(model_class, config, params, input_ids):

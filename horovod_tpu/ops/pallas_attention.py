@@ -38,7 +38,17 @@ dK ``D`` wide, and nothing is padded.  Where the two are equal the
 kernels are the ones they were.
 
 Tiles wholly above the causal diagonal are skipped, tiles on it are
-masked, tiles below it carry no mask arithmetic.  Both products of a
+masked, tiles below it carry no mask arithmetic.  A causal call may name
+a ``window``: query ``i`` then sees the keys ``j`` with ``0 <= i - j <
+window``, a band under the diagonal.  Tiles wholly left of the band are
+skipped too, tiles that straddle its left edge are masked, tiles inside
+it carry no mask arithmetic; and the grid's sequential dimension does
+not walk the whole sequence but the ``ceil((window - 1) / block) + 1``
+blocks the band reaches from the other dimension's block (three at a
+window of 2048 over blocks of 1024), placed by the index maps: clamped
+at the sequence's end, a clamped step skipped, so that no block the band
+cannot reach is fetched.  The window calls are named
+``hvd_flash_fwd_window`` and ``hvd_flash_bwd_window``.  Both products of a
 tile take their operands in the dtype they arrive in (bf16 in the
 models) and accumulate in float32; ``p`` and ``ds`` are cast to the
 operand dtype before the second product, as the einsum path casts its
@@ -89,7 +99,9 @@ SEQ_BLOCK = 1024
 LANES = 128
 # The backward pass is one kernel where a float32 dQ of a block's heads
 # for the whole (padded) query sequence fits this much of the 128 MiB of
-# VMEM: 21845 rows at two heads of 192, 65536 at 128 lanes.
+# VMEM: 21845 rows at two heads of 192, 65536 at 128 lanes (a sequence of
+# 16384 at 128 lanes takes 8 MiB of it, a window call as a full one: the
+# band bounds the blocks a kv block meets, not the rows dQ has).
 FUSED_DQ_BYTES = 32 << 20
 DEFAULT_VMEM_BYTES = 16 << 20      # Mosaic's scoped limit where none is set
 
@@ -114,13 +126,95 @@ def _block_ids(nq: int, nk: int, q_axis: int):
     return i, j
 
 
+def band_steps(window: int, block: int, blocks: int) -> int:
+    """Blocks of ``block`` rows that a band of ``window`` keys reaches
+    from one block of the other dimension, of the ``blocks`` there are:
+    the block on the diagonal and those the ``window - 1`` keys before
+    a block's first query lie in."""
+    return min(blocks, -(-(window - 1) // block) + 1)
+
+
+class _Walk:
+    """Where a grid step stands.  The grid's last dimension is walked
+    in sequence under the other block's accumulators: all its blocks
+    (``steps`` None; then this is ``_block_ids`` and the tests the
+    kernels made of it), or, in a window call, the ``steps`` blocks the
+    band reaches: kv blocks ``i - steps + 1`` to ``i`` under q block
+    ``i`` (``q_axis`` 2), q blocks ``j`` to ``j + steps - 1`` over kv
+    block ``j`` (``q_axis`` 3).  A step that falls off the sequence is
+    not ``valid``: its index map is clamped to a block the walk meets
+    anyway, nothing is fetched for it and nothing computed.  ``first``
+    and ``last`` are made where they are asked for."""
+
+    def __init__(self, nq: int, nk: int, q_axis: int, steps):
+        self.nq, self.nk, self.q_axis, self.steps = nq, nk, q_axis, steps
+        if steps is None:
+            self.i, self.j = _block_ids(nq, nk, q_axis)
+            self.at = self.j if q_axis == 2 else self.i
+            self.valid = True
+            return
+        outer = pl.program_id(2) if max(nq, nk) > 1 else 0
+        self.at = pl.program_id(3) if steps > 1 else 0
+        if q_axis == 2:
+            self.i, self.j = outer, outer - (steps - 1) + self.at
+            self.valid = self.j >= 0
+        else:
+            self.j, self.i = outer, outer + self.at
+            self.valid = self.i <= nq - 1
+
+    def first(self):
+        return self.at == 0
+
+    def last(self):
+        if self.steps is None:
+            return self.at == (self.nk if self.q_axis == 2 else self.nq) - 1
+        return self.at == self.steps - 1
+
+    def first_of_q(self):
+        """At the first kv block q block ``i`` meets (fused backward:
+        where its dQ starts at zero)."""
+        if self.steps is None:
+            return self.j == 0
+        return ((self.j == 0) | (self.at == self.steps - 1)) & self.valid
+
+    def last_of_q(self):
+        """The last kv block q block ``i`` meets: the one on the
+        diagonal in a window call."""
+        if self.steps is None:
+            return self.j == self.nk - 1
+        return self.at == 0
+
+
+def _tile_state(q0, k0, *, causal, window, sq, sk, skv):
+    """``(run, masked)`` of the tile of ``sq`` queries from ``q0`` and
+    ``sk`` keys from ``k0``: whether any of its scores counts, and
+    whether some do not (it straddles the diagonal, the band's left
+    edge or the end of the keys).  Plain booleans where the positions
+    are plain numbers."""
+    run, masked = True, False
+    if causal:
+        run = k0 <= q0 + (sq - 1)
+        masked = k0 + (sk - 1) > q0
+    if window is not None:
+        # The last key against the first query, the first key against
+        # the last: the band holds the keys less than ``window`` back.
+        run = run & (k0 + (sk - 1) > q0 - window)
+        masked = masked | (k0 <= q0 + (sq - 1) - window)
+    if skv is not None:
+        run = run & (k0 < skv)
+        masked = masked | (k0 + sk > skv)
+    return run, masked
+
+
 def _tiles(fn, i, j, *, causal, bq, bk, sq, sk, skv=None,
-           keys_outer=False):
+           keys_outer=False, window=None, valid=True):
     """Walk block (i, j) of the scores tile by tile: ``fn(masked, rows,
     cols, q0, k0)`` for every tile that is neither wholly above the
-    causal diagonal nor wholly padding; ``masked`` says whether the
-    tile straddles the diagonal or the end of the keys (``skv``: the
-    true number of keys, given where the keys are padded at all)."""
+    causal diagonal, nor wholly left of the ``window``'s band, nor
+    wholly padding; ``masked`` says whether the tile straddles one of
+    the three (``skv``: the true number of keys, given where the keys
+    are padded at all).  Nothing where the grid step is not ``valid``
+    (``_Walk``)."""
     pairs = [(a, c) for a in range(bq // sq) for c in range(bk // sk)]
     if keys_outer:
         pairs.sort(key=lambda ac: (ac[1], ac[0]))
@@ -129,13 +223,10 @@ def _tiles(fn, i, j, *, causal, bq, bk, sq, sk, skv=None,
         tile = functools.partial(
             fn, rows=slice(a * sq, (a + 1) * sq),
             cols=slice(c * sk, (c + 1) * sk), q0=q0, k0=k0)
-        run, masked = True, False
-        if causal:
-            run = k0 <= q0 + (sq - 1)
-            masked = k0 + (sk - 1) > q0
-        if skv is not None:
-            run = run & (k0 < skv)
-            masked = masked | (k0 + sk > skv)
+        run, masked = _tile_state(q0, k0, causal=causal, window=window,
+                                  sq=sq, sk=sk, skv=skv)
+        if valid is not True:
+            run = run & valid
         if isinstance(masked, bool):
             _when(run, functools.partial(tile, masked))
         else:
@@ -155,11 +246,14 @@ def _offsets(causal: bool, shape, q_dim: int):
             - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim))
 
 
-def _mask(s, offsets, q0, k0, q_dim: int, skv=None):
+def _mask(s, offsets, q0, k0, q_dim: int, skv=None, window=None):
     """The scores of a masked tile with those that do not count at
-    ``NEG_INF``: keys after the query (``offsets`` of a causal kernel)
-    and, where ``skv`` is given, keys past the end of the sequence."""
+    ``NEG_INF``: keys after the query (``offsets`` of a causal kernel),
+    keys ``window`` or more before it and, where ``skv`` is given, keys
+    past the end of the sequence."""
     keep = None if offsets is None else offsets >= k0 - q0
+    if window is not None:
+        keep = keep & (offsets < window + k0 - q0)
     if skv is not None:
         kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_dim)
         keep = (kpos < skv) if keep is None else keep & (kpos < skv)
@@ -178,23 +272,28 @@ def _head_lanes(h: int, d: int, dv: int):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
-                vt_ref, *, causal, nq, nk, heads, d, dv, bq, bk, sq, sk, skv):
+                vt_ref, *, causal, nq, nk, heads, d, dv, bq, bk, sq, sk, skv,
+                window, steps):
     """Scores, statistics and accumulator all transposed ([keys,
     queries], [1, queries], [D, queries]): the running max and sum of
     a query then lie along the lanes, a few registers a tile, and
     reducing over keys is elementwise between registers.  With queries
     down the sublanes every statistic costs a register per eight
     rows, more than the tile's own arithmetic at 512 keys."""
-    i, j = _block_ids(nq, nk, 2)
+    walk = _Walk(nq, nk, 2, steps)
+    i, j = walk.i, walk.j
 
     def init():
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
-    _when(j == 0, init)
+    _when(walk.first(), init)
+
     # The values once a grid step as [heads * Dv, keys], for the
     # transposed accumulator: a head's are then a sublane slice.
-    vt_ref[:] = v_ref[0].T
+    def turn_values():
+        vt_ref[:] = v_ref[0].T
+    _when(walk.valid, turn_values)
 
     offsets = _offsets(causal, (sk, sq), 1)
     for h in range(heads):
@@ -206,7 +305,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
             st = _dot(k_ref[0, cols, lanes], q_ref[0, rows, lanes],
                       _NT)                          # q arrives scaled
             if masked:
-                st = _mask(st, offsets, q0, k0, 1, skv)     # [sk, sq]
+                st = _mask(st, offsets, q0, k0, 1, skv, window)  # [sk, sq]
             m_prev = m_ref[h, :, rows]                      # [1, sq]
             m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
             pt = jnp.exp(st - m_new)
@@ -218,7 +317,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
             m_ref[h, :, rows] = m_new
 
         _tiles(tile, i, j, causal=causal, bq=bq, bk=bk, sq=sq, sk=sk,
-               skv=skv, keys_outer=True)
+               skv=skv, keys_outer=True, window=window, valid=walk.valid)
 
     def finalize():
         l = l_ref[:]                                        # [heads, 1, bq]
@@ -226,12 +325,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         out_t = (acc_ref[:] / l).reshape(heads * dv, bq)
         o_ref[0] = out_t.T.astype(o_ref.dtype)              # [bq, heads * Dv]
         lse_ref[0] = m_ref[:] + jnp.log(l)
-    _when(j == nk - 1, finalize)
+    _when(walk.last(), finalize)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
                 dk_ref, dv_ref, *rest,
-                scale, causal, nq, nk, heads, d, dv, bq, bk, sq, sk, skv):
+                scale, causal, nq, nk, heads, d, dv, bq, bk, sq, sk, skv,
+                window, steps):
     """Scores transposed, as in the forward: ``lse`` and ``di`` come
     as rows and broadcast down the sublanes.  dK and dV are summed over
     the q blocks, the grid's last dimension.
@@ -244,8 +344,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
     values) ``dQ^T += K^T dS^T`` is a plain product of the transposed
     ``ds`` this kernel has; a q block is turned back and written, times
     ``scale``, when the last kv block has been added.  Keys ascend for
-    every query row, the order ``_dq_kernel`` adds in."""
-    i, j = _block_ids(nq, nk, 3)
+    every query row, the order ``_dq_kernel`` adds in.  In a window
+    call a kv block meets the ``steps`` q blocks from its own on, and a
+    q block's dQ starts at the first kv block of its band and is whole
+    at the block on the diagonal."""
+    walk = _Walk(nq, nk, 3, steps)
+    i, j = walk.i, walk.j
     fused = len(rest) > 2
     if fused:
         dq_ref, dk_acc, dv_acc, dqt_acc, kt_ref = rest
@@ -257,11 +361,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
         dv_acc[:] = jnp.zeros_like(dv_acc)
         if fused:
             kt_ref[:] = k_ref[0].T
-    _when(i == 0, init)
+    _when(walk.first(), init)
     if fused:
         def init_dq():
             dqt_acc[i] = jnp.zeros(dqt_acc.shape[1:], dqt_acc.dtype)
-        _when(j == 0, init_dq)
+        _when(walk.first_of_q(), init_dq)
 
     offsets = _offsets(causal, (sk, sq), 1)
     for h in range(heads):
@@ -272,7 +376,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
             q, do = q_ref[0, rows, lanes], do_ref[0, rows, vlanes]
             st = _dot(k_ref[0, cols, lanes], q, _NT)        # [sk, sq]
             if masked:
-                st = _mask(st, offsets, q0, k0, 1, skv)
+                st = _mask(st, offsets, q0, k0, 1, skv, window)
             pt = jnp.exp(st - lse_ref[0, h, :, rows])       # rows: [1, sq]
             dv_acc[cols, vlanes] += _dot(pt.astype(do.dtype), do)
             dpt = _dot(v_ref[0, cols, vlanes], do, _NT)
@@ -282,28 +386,30 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
                 dqt_acc[i, lanes, rows] += _dot(kt_ref[lanes, cols], dst)
 
         _tiles(tile, i, j, causal=causal, bq=bq, bk=bk, sq=sq, sk=sk,
-               skv=skv, keys_outer=True)
+               skv=skv, keys_outer=True, window=window, valid=walk.valid)
 
     def finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
-    _when(i == nq - 1, finalize)
+    _when(walk.last(), finalize)
     if fused:
         def write_dq():
             dq_ref[0, pl.ds(i * bq, bq), :] = (dqt_acc[i].T * scale).astype(
                 dq_ref.dtype)
-        _when(j == nk - 1, write_dq)
+        _when(walk.last_of_q(), write_dq)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dq_acc,
-               *, scale, causal, nq, nk, heads, d, dv, bq, bk, sq, sk, skv):
+               *, scale, causal, nq, nk, heads, d, dv, bq, bk, sq, sk, skv,
+               window, steps):
     """Scores as [queries, keys], so that dQ = dS K is a plain
     product; the row statistics are turned from lanes to sublanes."""
-    i, j = _block_ids(nq, nk, 2)
+    walk = _Walk(nq, nk, 2, steps)
+    i, j = walk.i, walk.j
 
     def init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
-    _when(j == 0, init)
+    _when(walk.first(), init)
 
     offsets = _offsets(causal, (sq, sk), 0)
     for h in range(heads):
@@ -314,18 +420,18 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dq_acc,
             k = k_ref[0, cols, lanes]
             s = _dot(q_ref[0, rows, lanes], k, _NT)         # [sq, sk]
             if masked:
-                s = _mask(s, offsets, q0, k0, 0, skv)
+                s = _mask(s, offsets, q0, k0, 0, skv, window)
             p = jnp.exp(s - lse_ref[0, h, 0, rows][:, None])
             dp = _dot(do_ref[0, rows, vlanes], v_ref[0, cols, vlanes], _NT)
             ds = p * (dp - di_ref[0, h, 0, rows][:, None])
             dq_acc[rows, lanes] += _dot(ds.astype(k.dtype), k)
 
         _tiles(tile, i, j, causal=causal, bq=bq, bk=bk, sq=sq, sk=sk,
-               skv=skv)
+               skv=skv, window=window, valid=walk.valid)
 
     def finalize():
         dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
-    _when(j == nk - 1, finalize)
+    _when(walk.last(), finalize)
 
 
 def _blocking(seq: int, tile: int, seq_block: int):
@@ -337,6 +443,34 @@ def _blocking(seq: int, tile: int, seq_block: int):
     n_tiles = -(-seq // tile)
     block = tile * min(n_tiles, max(1, seq_block // tile))
     return tile, block, -(-seq // block) * block
+
+
+def band_tiles(seq: int, window: int, tile=TILE,
+               seq_block: int = SEQ_BLOCK) -> dict:
+    """What a causal window call does with one head's square of
+    ``seq`` queries and keys, from the static shapes and by the
+    kernels' own rule (``_tile_state``): tiles ``walked`` (computed),
+    of them ``masked`` (they straddle the diagonal, the band's left
+    edge or the padding), tiles on or under the diagonal ``skipped``
+    because the band does not reach them, and ``fill``, the scores
+    inside the band over the scores of the tiles walked."""
+    sq, bq, padded = _blocking(seq, tile[0], seq_block)
+    sk = _blocking(seq, tile[1], seq_block)[0]
+    skv = seq if padded != seq else None
+    walked = masked = skipped = 0
+    for q0 in range(0, padded, sq):
+        for k0 in range(0, padded, sk):
+            run, mask = _tile_state(q0, k0, causal=True, window=window,
+                                    sq=sq, sk=sk, skv=skv)
+            causal_run = _tile_state(q0, k0, causal=True, window=None,
+                                     sq=sq, sk=sk, skv=skv)[0]
+            walked += run
+            masked += run and mask
+            skipped += causal_run and not run
+    inside = min(window, seq)
+    scores = inside * (inside + 1) // 2 + (seq - inside) * inside
+    return {"walked": walked, "masked": masked, "skipped": skipped,
+            "fill": scores / (walked * sq * sk)}
 
 
 def _heads_per_block(heads: int, d: int, dv: int) -> int:
@@ -360,9 +494,11 @@ class _Plan:
     """Blocks, grid and index maps of one kernel call on ``[B, S, H *
     D]`` queries and keys and ``[B, S, H * Dv]`` values.  The grid is
     (batch, head groups, x, y), y sequential: x the q blocks and y the
-    kv blocks (``q_axis`` 2), or the other way round (``q_axis`` 3)."""
+    kv blocks (``q_axis`` 2), or the other way round (``q_axis`` 3).
+    With a ``window`` y is the band's ``steps`` blocks (``_Walk``), and
+    the index maps of what y walks place them from x's block."""
 
-    def __init__(self, q, k, v, heads, tile, seq_block, q_axis):
+    def __init__(self, q, k, v, heads, tile, seq_block, q_axis, window=None):
         self.batch, self.sq_len, width = q.shape
         self.skv_len = k.shape[1]
         self.d, self.dv = width // heads, v.shape[2] // heads
@@ -374,6 +510,16 @@ class _Plan:
         self.nq = self.sq_pad // self.bq
         self.nk = self.skv_pad // self.bk
         blocks = (self.nq, self.nk) if q_axis == 2 else (self.nk, self.nq)
+        self.steps = None
+        if window is not None:
+            if (self.sq_len, self.bq) != (self.skv_len, self.bk):
+                raise ValueError(
+                    "a window is a band of one sequence's own keys: as "
+                    "many keys as queries, in blocks of one size (got "
+                    "%d queries in blocks of %d, %d keys in blocks of %d)"
+                    % (self.sq_len, self.bq, self.skv_len, self.bk))
+            self.steps = band_steps(window, self.bk, self.nk)
+            blocks = (blocks[0], self.steps)
         self.grid = (self.batch, heads // self.g) + blocks
         self.q_at, self.k_at = q_axis, 5 - q_axis
         # What every kernel is told; ``skv`` only where keys are
@@ -383,11 +529,25 @@ class _Plan:
                           dv=self.dv, bq=self.bq, bk=self.bk, sq=self.sq,
                           sk=self.sk,
                           skv=(self.skv_len if self.skv_pad != self.skv_len
-                               else None))
+                               else None),
+                          window=window, steps=self.steps)
+
+    def _block(self, at):
+        """``ids -> `` the block of the grid's dimension ``at`` (2 or
+        3) that a step reads: its own index, or in a window call, for
+        the dimension the band is walked in, the block ``_Walk`` names,
+        clamped into the sequence."""
+        if self.steps is None or at == 2:
+            return lambda ids: ids[at]
+        if self.q_at == 2:      # kv blocks up to q block ids[2]'s own
+            return lambda ids: jax.lax.max(
+                ids[2] - (self.steps - 1) + ids[3], 0)
+        return lambda ids: jax.lax.min(ids[2] + ids[3], self.nq - 1)
 
     def _rows(self, block, at, d):
+        of = self._block(at)
         return pl.BlockSpec((1, block, self.g * d),
-                            lambda *ids: (ids[0], ids[at], ids[1]))
+                            lambda *ids: (ids[0], of(ids), ids[1]))
 
     def q_rows(self):
         """A block of queries (or of dQ)."""
@@ -406,8 +566,9 @@ class _Plan:
 
     def q_stats(self):
         """Row statistics, [B, H, 1, Sq]: a row of floats a head."""
+        of = self._block(self.q_at)
         return pl.BlockSpec((1, self.g, 1, self.bq),
-                            lambda *ids: (ids[0], ids[1], 0, ids[self.q_at]))
+                            lambda *ids: (ids[0], ids[1], 0, of(ids)))
 
     def q_whole(self):
         """Every (padded) row of the group's queries, whichever block
@@ -433,16 +594,23 @@ class _Plan:
             interpret=interpret, name=name)
 
 
+def _named(kernel: str, window) -> str:
+    """A window call's kernels under names of their own, so that a
+    trace tells a band's walk from a triangle's."""
+    return kernel if window is None else kernel + "_window"
+
+
 @functools.partial(jax.jit, static_argnames=(
-    "heads", "causal", "tile", "seq_block", "interpret"))
-def _fwd_call(q, k, v, *, heads, causal, tile, seq_block, interpret):
+    "heads", "causal", "tile", "seq_block", "interpret", "window"))
+def _fwd_call(q, k, v, *, heads, causal, tile, seq_block, interpret,
+              window=None):
     """``q`` (scaled), ``k``: [B, S, H * D]; ``v``: [B, S, H * Dv].
     Returns the output [B, Sq, H * Dv] and the log-sum-exp of every
     (padded) row, [B, H, 1, Sq padded]."""
-    p = _Plan(q, k, v, heads, tile, seq_block, q_axis=2)
+    p = _Plan(q, k, v, heads, tile, seq_block, 2, window)
     out, lse = p.call(
         functools.partial(_fwd_kernel, causal=causal, **p.sizes),
-        "hvd_flash_fwd",
+        _named("hvd_flash_fwd", window),
         [p.q_rows(), p.k_rows(), p.v_rows()], [p.o_rows(), p.q_stats()],
         [jax.ShapeDtypeStruct((p.batch, p.sq_pad, heads * p.dv), q.dtype),
          jax.ShapeDtypeStruct((p.batch, heads, 1, p.sq_pad), jnp.float32)],
@@ -484,14 +652,14 @@ _DQ_VMEM_BYTES = metrics.gauge(
 
 @functools.partial(jax.jit, static_argnames=(
     "heads", "scale", "causal", "tile", "seq_block", "interpret",
-    "dq_budget"))
+    "dq_budget", "window"))
 def _bwd_call(q, k, v, lse, do, di, *, heads, scale, causal, tile,
-              seq_block, interpret, dq_budget):
+              seq_block, interpret, dq_budget, window=None):
     """dQ, dK, dV of ``_fwd_call`` (``q`` scaled; dQ is for the
     unscaled one).  ``di`` is ``sum(o * do)`` a row, [B, H, 1, Sq].
     One kernel where dQ's accumulator fits ``dq_budget`` bytes, the
     dK/dV kernel and then the dQ kernel where it does not."""
-    p = _Plan(q, k, v, heads, tile, seq_block, q_axis=3)
+    p = _Plan(q, k, v, heads, tile, seq_block, 3, window)
     fused = p.dq_bytes() <= dq_budget
     _LOWERINGS.inc(1, form="fused" if fused else "split")
     _LOWERINGS.inc(0, form="split" if fused else "fused")   # reads 0, not absent
@@ -515,33 +683,34 @@ def _bwd_call(q, k, v, lse, do, di, *, heads, scale, causal, tile,
     dk, dv, *dq = p.call(
         functools.partial(_bwd_kernel, causal=causal, scale=scale,
                           **p.sizes),
-        "hvd_flash_bwd" if fused else "hvd_flash_bwd_dkv", specs, out_specs,
-        out_shape, scratch, interpret,
+        _named("hvd_flash_bwd" if fused else "hvd_flash_bwd_dkv", window),
+        specs, out_specs, out_shape, scratch, interpret,
         carried="arbitrary" if fused else "parallel",
         vmem_limit=vmem_limit)(*args)
     if fused:
         dq, = dq
     else:
-        p = _Plan(q, k, v, heads, tile, seq_block, q_axis=2)
+        p = _Plan(q, k, v, heads, tile, seq_block, 2, window)
         args, specs = _bwd_operands(p, q, k, v, lse, do, di)
         dq = p.call(
             functools.partial(_dq_kernel, scale=scale, causal=causal,
                               **p.sizes),
-            "hvd_flash_bwd_dq", specs, p.q_rows(),
+            _named("hvd_flash_bwd_dq", window), specs, p.q_rows(),
             jax.ShapeDtypeStruct(args[0].shape, q.dtype),
             [pltpu.VMEM((p.bq, p.g * p.d), jnp.float32)], interpret)(*args)
     return dq[:, :p.sq_len], dk[:, :p.skv_len], dv[:, :p.skv_len]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, scale, causal, tile, seq_block, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, scale, causal, tile, seq_block, interpret, window=None):
     """``flash_attention`` with every choice spelt out; ``seq_block``
     is here for the tests, which cannot hold ``SEQ_BLOCK`` rows."""
     return _flash_vjp_fwd(q, k, v, scale, causal, tile, seq_block,
-                          interpret)[0]
+                          interpret, window)[0]
 
 
-def _flash_vjp_fwd(q, k, v, scale, causal, tile, seq_block, interpret):
+def _flash_vjp_fwd(q, k, v, scale, causal, tile, seq_block, interpret,
+                   window):
     B, Sq, H, D = q.shape
     Dv = v.shape[-1]
     # [B, S, H, D] is [B, S, H * D] for free.  The scale rides on q,
@@ -551,7 +720,8 @@ def _flash_vjp_fwd(q, k, v, scale, causal, tile, seq_block, interpret):
     q, k, v = ((q * scale).reshape(B, Sq, H * D),
                k.reshape(B, -1, H * D), v.reshape(B, -1, H * Dv))
     out, lse = _fwd_call(q, k, v, heads=H, causal=causal, tile=tile,
-                         seq_block=seq_block, interpret=interpret)
+                         seq_block=seq_block, interpret=interpret,
+                         window=window)
     # Named for a caller's checkpoint policy: a model that recomputes
     # its layers in the backward pass can keep these two and spare the
     # forward kernel's second run.
@@ -560,7 +730,8 @@ def _flash_vjp_fwd(q, k, v, scale, causal, tile, seq_block, interpret):
     return out, (q, k, v, out, lse)
 
 
-def _flash_vjp_bwd(scale, causal, tile, seq_block, interpret, res, do):
+def _flash_vjp_bwd(scale, causal, tile, seq_block, interpret, window, res,
+                   do):
     q, k, v, out, lse = res
     B, Sq, H, Dv = do.shape
     di = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
@@ -568,7 +739,7 @@ def _flash_vjp_bwd(scale, causal, tile, seq_block, interpret, res, do):
     grads = _bwd_call(q, k, v, lse, do.reshape(B, Sq, H * Dv), di, heads=H,
                       scale=scale, causal=causal, tile=tile,
                       seq_block=seq_block, interpret=interpret,
-                      dq_budget=FUSED_DQ_BYTES)
+                      dq_budget=FUSED_DQ_BYTES, window=window)
     return tuple(g.reshape(B, g.shape[1], H, -1) for g in grads)
 
 
@@ -580,10 +751,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: bool = False) -> jax.Array:
+                    interpret: bool = False,
+                    window: Optional[int] = None) -> jax.Array:
     """Flash attention on ``[B, S, H, D]`` queries and keys and ``[B,
     S, H, Dv]`` values, differentiable; the output is ``Dv`` wide, and
-    ``scale`` defaults to ``1 / sqrt(D)``.
+    ``scale`` defaults to ``1 / sqrt(D)``.  ``window`` (causal calls
+    only, as many keys as queries): query ``i`` sees the keys ``j`` with
+    ``0 <= i - j < window``, its own position counted, and the kernels
+    walk that band and fetch nothing left of it.
 
     ``block_q`` x ``block_k`` is the tile of scores the kernels compute
     at a time (by default ``TILE``, picked on the chip); a grid step
@@ -595,5 +770,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     tile = (int(block_q or TILE[0]), int(block_k or TILE[1]))
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError("a window of %r keys needs a causal call and "
+                             "at least the query's own position" % (window,))
+        window = int(window)
     return _flash(q, k, v, float(scale), bool(causal), tile, SEQ_BLOCK,
-                  bool(interpret))
+                  bool(interpret), window)

@@ -37,14 +37,23 @@ def _block_scores(q, k, scale):
 
 def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    axis_name: str = "sp", causal: bool = False,
-                   scale: Optional[float] = None) -> jax.Array:
+                   scale: Optional[float] = None,
+                   window: Optional[int] = None) -> jax.Array:
     """Exact attention over a sequence sharded on ``axis_name``.
 
     Shapes (per shard): q/k/v ``[B, S_local, H, D]``; returns
     ``[B, S_local, H, D]``.  K/V rotate around the ring; softmax is
     accumulated online with the running-max trick, so the result is
-    exact (not approximate) regardless of ring size.
+    exact (not approximate) regardless of ring size.  A ``window`` is
+    refused: the ring passes every shard of keys by every shard of
+    queries and has no band yet (``ops/pallas_attention.py`` has, on
+    one chip's sequence).
     """
+    if window is not None:
+        raise NotImplementedError(
+            "ring_attention has no window: a band of %r keys across "
+            "shards of the sequence is not written (ROADMAP.md, Queue 2)"
+            % (window,))
     n = lax.psum(1, axis_name)
     my_idx = lax.axis_index(axis_name)
     if scale is None:

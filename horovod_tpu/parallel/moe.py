@@ -181,6 +181,26 @@ def softmax_top_k(x: jax.Array, router_kernel: jax.Array, top_k: int,
     return Routing(chosen.astype(jnp.int32), gates)
 
 
+def moved_bias(bias: jax.Array, counts: jax.Array, step: float) -> jax.Array:
+    """The selection bias of :func:`sigmoid_top_k` after one step of
+    the rule that balances the experts' loads without a loss (the
+    auxiliary-loss-free balancing of DeepSeek-V3, as torchtitan applies
+    it under ``load_balance_coeff``): with ``counts`` ``[experts]`` the
+    (token, choice) pairs that chose each of ALL the router's experts
+    over the step's whole batch, ``d_e = step * sign(mean(counts) -
+    counts_e)`` (an expert under the mean load is raised, one over it
+    lowered, one at it left alone) and ``bias_e + d_e - mean(d)``: the
+    bias stays centred.  Written on the signs, ``step * (sign -
+    mean(sign))``: their sum is a whole number whatever order it is
+    added in, so two programs that count one choice move the bias alike
+    but for the last bit (a compiler may round the product and the sum
+    once or twice).  No gradient, no optimizer: the caller applies it
+    to the ``expert_bias`` leaves after the optimizer's update."""
+    counts = counts.astype(jnp.float32)
+    sign = jnp.sign(counts.mean() - counts)
+    return bias + step * (sign - sign.mean())
+
+
 def dispatch_rows(tokens: int, top_k: int, held: int) -> int:
     """Length of one layer's sorted buffer: as many pairs as the routing
     can give the experts held, every token choosing them alone.  Not a

@@ -141,6 +141,33 @@ def deepseek_v3_partition_rules(tp: str = "tp", fsdp: Optional[str] = None,
     ]
 
 
+def afmoe_partition_rules(tp: str = "tp", fsdp: Optional[str] = None,
+                          ep: str = "ep") -> Rules:
+    """Sharding for the AFMoE family (models/afmoe.py).  Over ``tp``:
+    attention over its heads (the query, the gate, whose columns are
+    the query heads' channels, the output projection, and the fused key
+    and value where ``tp`` divides the key-value heads), the dense
+    SwiGLU, the shared expert and every routed expert over their
+    columns.  The stacked experts' leading axis lies on ``ep`` where
+    the mesh has one, the router and its selection bias whole
+    everywhere (the rule that moves the bias counts ALL experts'
+    loads), and no exchange is written for ``ep`` yet, as for
+    :func:`lfm2_partition_rules`.  The embedding and the head, two
+    matrices, both by rows of the vocabulary."""
+    f = fsdp
+    return [
+        (r"word_embeddings/embedding$", P(tp, f)),
+        (r"lm_head$", P(tp, f)),
+        (r"attention/(query|key_value|gate)/kernel$", P(f, tp, None)),
+        (r"attention/out/kernel$", P(tp, None, f)),
+        (r"(mlp|moe/shared)/(gate|up)/kernel$", P(f, tp)),
+        (r"(mlp|moe/shared)/out/kernel$", P(tp, f)),
+        (r"moe/(gate|up)$", P(ep, f, tp)),
+        (r"moe/down$", P(ep, tp, f)),
+        (r".*", P()),  # norms, the router and its bias replicated
+    ]
+
+
 def qwen3_next_partition_rules(tp: str = "tp", fsdp: Optional[str] = None,
                                ep: str = "ep") -> Rules:
     """Sharding for the Qwen3-Next family (models/qwen3_next.py).  Over

@@ -35,8 +35,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .common import metrics, timeline
 from .common.compile_cache import first_call
 from .models.bert import BertConfig, BertForMaskedLM, mlm_loss
-from .models.layers import (chunked_lm_loss, given_choices,
-                            kept_across_remat, loss_chunks)
+from .models.layers import (choices_of, chunked_lm_loss, counts_by_expert,
+                            given_choices, kept_across_remat, loss_chunks)
 from .parallel.sharding import (bert_partition_rules,
                                 gather_over_data_axis, infer_shardings,
                                 Rules, shard_over_data_axis)
@@ -401,7 +401,8 @@ def _tied_head_loss(sharding: Optional[NamedSharding], hidden, embedding,
 
 
 def causal_lm_step_loss(model, params, ids, chosen=None,
-                        logits_scale: float = 1.0):
+                        logits_scale: float = 1.0,
+                        with_choices: bool = False):
     """The loss of every causal-LM step: the stack's final hidden states
     and the head's matrix (``model.hidden_and_embedding``: the token
     embedding where the head is tied to it), then the loss a chunk of
@@ -409,13 +410,23 @@ def causal_lm_step_loss(model, params, ids, chosen=None,
     the value and gradients of ``lm_loss(model.apply(...) * logits_scale,
     ids)`` without the ``[B, S, V]`` logits.  ``chosen`` (a family's
     ``expert_choices``) hands the sparse layers their choice of experts;
-    a step itself hands none.  No dropout: a model's ``deterministic``
-    stays at its default."""
+    a step itself hands none.  ``with_choices`` returns beside the loss
+    what the sparse layers chose (``{layer index: [T, top_k]}``, the
+    choice each names and sows), for a step that moves their selection
+    bias by it.  No dropout: a model's ``deterministic`` stays at its
+    default."""
     given = given_choices(chosen) if chosen else {}
-    hidden, head = model.apply({"params": params, **given}, ids,
-                               method="hidden_and_embedding")
+    variables = {"params": params, **given}
+    if not with_choices:
+        hidden, head = model.apply(variables, ids,
+                                   method="hidden_and_embedding")
+        return _tied_head_loss(model.heads_sharding, hidden, head, ids,
+                               logits_scale)
+    (hidden, head), sown = model.apply(
+        variables, ids, method="hidden_and_embedding",
+        mutable=["intermediates"])
     return _tied_head_loss(model.heads_sharding, hidden, head, ids,
-                           logits_scale)
+                           logits_scale), choices_of(sown)
 
 
 def _bytes_on_one_device(tree, shardings) -> int:
@@ -541,10 +552,64 @@ _ATTENTION_HEAD_DIM = metrics.gauge(
     "hvd_attention_head_dim",
     "Width of a head of a step's softmax attention (set when the step is "
     "traced)")
+_ATTENTION_WINDOW = metrics.gauge(
+    "hvd_attention_window",
+    "Keys a query of a step's window-attention layers sees, its own "
+    "position among them (set when the step is traced)")
+_FLASH_WINDOW_TILES = metrics.gauge(
+    "hvd_flash_window_tiles",
+    "Tiles of one head's square of scores in a window layer's flash "
+    "kernels: which=walked computed, which=masked of those the ones that "
+    "straddle the diagonal or the band's left edge, which=skipped those "
+    "under the diagonal that the band does not reach (from the static "
+    "shapes, set when the step is traced)")
+_FLASH_WINDOW_FILL = metrics.gauge(
+    "hvd_flash_window_fill",
+    "Scores inside a window layer's band over the scores of the tiles its "
+    "flash kernels walk (set when the step is traced)")
+_MOE_BIAS_STEP = metrics.gauge(
+    "hvd_moe_bias_step",
+    "Step of the rule that moves a sparse layer's selection bias once a "
+    "training step; 0 where the step's family has none (set when the step "
+    "is traced)")
+_MOE_BIAS_UPDATES = metrics.counter(
+    "hvd_moe_bias_updates_total",
+    "Calls of a step whose program moves its sparse layers' selection bias "
+    "by its own rule after the optimizer's update")
+MOE_LOAD_MAX_OVER_MEAN = metrics.gauge(
+    "hvd_moe_load_max_over_mean",
+    "The fullest expert's pairs over the mean of ALL the router's experts, "
+    "of one batch, by layer: what the moved selection bias pulls towards 1 "
+    "(set by whoever counts a batch's choices)")
 MOE_PAIRS_HELD = metrics.gauge(
     "hvd_moe_pairs_held",
     "Pairs of token and expert that fell on the experts held, of one "
     "batch, by layer (set by whoever counts a batch's choices)")
+
+
+def move_selection_bias(params, choices: dict, step: float):
+    """``params`` with the selection bias of every sparse layer moved
+    one step of the rule that balances its experts' loads
+    (``parallel/moe.py`` ``moved_bias``) by ``choices`` (``{layer
+    index: [T, top_k]}``, the step's own, over its whole batch: under
+    GSPMD ``T`` is every device's tokens, so the counts are summed over
+    the data axis by the compiler).  The ``expert_bias`` leaves alone,
+    after the optimizer's update, which leaves them where they were (no
+    gradient reaches them and no decay touches a vector): the step's
+    state holds the bias as a leaf of the parameters and nothing else
+    writes it."""
+    from .parallel import moe
+    with jax.named_scope("bias_update"):
+        params = dict(params)
+        for index, chosen in choices.items():
+            layer = dict(params["layer_%d" % index])
+            sparse = dict(layer["moe"])
+            bias = sparse["expert_bias"]
+            sparse["expert_bias"] = moe.moved_bias(
+                bias, counts_by_expert(chosen, bias.shape[0]), step)
+            layer["moe"] = sparse
+            params["layer_%d" % index] = layer
+    return params
 
 
 def _adamw_on_matrices(learning_rate: float, weight_decay: float):
@@ -556,6 +621,15 @@ def _adamw_on_matrices(learning_rate: float, weight_decay: float):
     return optax.adamw(
         learning_rate, weight_decay=weight_decay,
         mask=lambda params: jax.tree.map(lambda p: p.ndim >= 2, params))
+
+
+class _bias_moving_step(first_call):
+    """A step whose program moves its sparse layers' selection bias:
+    its calls are counted on the host, where its program cannot."""
+
+    def __call__(self, *args, **kwargs):
+        _MOE_BIAS_UPDATES.inc()
+        return super().__call__(*args, **kwargs)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -587,6 +661,11 @@ class CausalLMFamily:
     record: Callable = lambda config, mesh, sequences, seq: None
     # The step program's compiler options, of the mesh.
     compiler_options: Callable = lambda mesh: None
+    # The step of the rule that moves the sparse layers' selection bias
+    # once a step, of the config (``move_selection_bias``); None where
+    # the family has no rule: its bias stays where it was initialised
+    # and its step counts no choice.
+    bias_step: Callable = lambda config: None
 
 
 def _heads_sharding(mesh, batch_axis: str) -> NamedSharding:
@@ -628,6 +707,7 @@ def _make_causal_lm_train_step(family: CausalLMFamily, config, mesh,
     model = family.model(config, heads_sharding=heads_sharding)
     step_loss = partial(causal_lm_step_loss,
                         logits_scale=family.logits_scale(config))
+    bias_step = family.bias_step(config)
 
     def traced_model(state, sequences: int, seq: int):
         """The model of this trace: with ``remat``, keeping what fits
@@ -665,15 +745,24 @@ def _make_causal_lm_train_step(family: CausalLMFamily, config, mesh,
         count, length = loss_chunks(ids.shape[1], sequences)
         _LOSS_CHUNKS.set(count)
         _LOSS_CHUNK_TOKENS.set(sequences * length)
-        loss, grads = jax.value_and_grad(partial(
-            step_loss, traced_model((params, opt_state), sequences,
-                                    ids.shape[1])))(params, ids)
+        of_model = partial(step_loss, traced_model(
+            (params, opt_state), sequences, ids.shape[1]))
+        if bias_step is None:
+            loss, grads = jax.value_and_grad(of_model)(params, ids)
+        else:
+            (loss, choices), grads = jax.value_and_grad(
+                partial(of_model, with_choices=True), has_aux=True)(
+                    params, ids)
         with jax.named_scope("optimizer"):
             updates, opt_state = tx.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
+        if bias_step is not None:
+            params = move_selection_bias(params, choices, bias_step)
         return params, opt_state, loss
 
-    return init_fn, first_call(step_fn, "step"), batch_sharding
+    step = (first_call if bias_step is None else _bias_moving_step)(
+        step_fn, "step")
+    return init_fn, step, batch_sharding
 
 
 def _gpt_family() -> CausalLMFamily:
@@ -835,6 +924,58 @@ def deepseek_v3_step_loss(model, params, ids, chosen=None):
     that is a matrix of its own; ``chosen``
     (``models.deepseek_v3.expert_choices``'s) hands the sparse layers
     their choice of experts."""
+    return causal_lm_step_loss(model, params, ids, chosen)
+
+
+def _record_afmoe(config, mesh, sequences: int, seq: int):
+    from .models import afmoe
+    from .ops import pallas_attention
+    _ATTENTION_WINDOW.set(config.sliding_window)
+    tiles = pallas_attention.band_tiles(seq, config.sliding_window)
+    for which in ("walked", "masked", "skipped"):
+        _FLASH_WINDOW_TILES.set(tiles[which], which=which)
+    _FLASH_WINDOW_FILL.set(tiles["fill"])
+    _ATTENTION_KV_REPEAT.set(config.num_attention_heads
+                             // config.num_key_value_heads)
+    _ATTENTION_HEAD_DIM.set(config.head_dim)
+    _record_moe(config, sequences * seq, config.num_experts)
+    _MOE_ROUTER.set(1, kind="sigmoid")
+    _MOE_SHARED_WIDTH.set(config.shared_width)
+    _MOE_BIAS_STEP.set(config.load_balance_coeff)
+    for kind in (afmoe.SLIDING, afmoe.FULL):
+        _HYBRID_LAYERS.set(config.layer_types.count(kind), kind=kind)
+    for kind in (afmoe.DENSE, afmoe.SPARSE):
+        _HYBRID_LAYERS.set(config.ffn_types.count(kind), kind=kind)
+
+
+def _afmoe_family() -> CausalLMFamily:
+    from .models import afmoe
+    from .parallel.sharding import afmoe_partition_rules
+    return CausalLMFamily(
+        "afmoe", afmoe.AfmoeLMHeadModel, afmoe_partition_rules,
+        afmoe.REMAT_CANDIDATES, afmoe.remat_bytes, record=_record_afmoe,
+        bias_step=lambda config: config.load_balance_coeff or None)
+
+
+def make_afmoe_train_step(config, mesh, learning_rate: float = 1e-4,
+                          weight_decay: float = 0.1,
+                          fsdp: Optional[str] = None):
+    """Sharded causal-LM training step for the AFMoE family
+    (``models/afmoe.py``: window and full attention in one stack, each
+    head gated, a dense SwiGLU or routed experts beside a shared
+    expert), ``_make_causal_lm_train_step`` over its row: AdamW with
+    decay on matrices only, and after it the rule that moves the sparse
+    layers' selection bias by the step's own choices
+    (``move_selection_bias``)."""
+    return _make_causal_lm_train_step(
+        _afmoe_family(), config, mesh, fsdp, learning_rate,
+        weight_decay=weight_decay)
+
+
+def afmoe_step_loss(model, params, ids, chosen=None):
+    """The loss of ``make_afmoe_train_step``'s step, over a head that is
+    a matrix of its own; ``chosen`` (``models.afmoe.expert_choices``'s)
+    hands the sparse layers their choice of experts."""
     return causal_lm_step_loss(model, params, ids, chosen)
 
 

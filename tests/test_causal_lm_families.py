@@ -16,8 +16,8 @@ from flax.traverse_util import flatten_dict
 
 import horovod_tpu as hvd
 from horovod_tpu import training
-from horovod_tpu.models import (deepseek_v3, gpt, granite, layers, lfm2,
-                                qwen3_next)
+from horovod_tpu.models import (afmoe, deepseek_v3, gpt, granite, layers,
+                                lfm2, qwen3_next)
 from horovod_tpu.parallel.mesh import build_mesh
 
 # A v5e's ``memory_stats()["bytes_limit"]``.
@@ -86,6 +86,23 @@ FAMILIES = {
             (1, 8192, 10_000_000_000, qwen3_next.KEPT_NAMES),
             # at two sequences the routed experts' buffers no longer fit
             (2, 8192, V5E, qwen3_next.REMAT_NAMES[:-2])]),
+    "afmoe": dict(
+        row=training._afmoe_family, make=training.make_afmoe_train_step,
+        tiny=afmoe.afmoe_tiny_config,
+        cell=afmoe.AfmoeConfig(
+            vocab_size=25024, num_hidden_layers=5, num_dense_layers=1,
+            experts_held=16, layer_types=(
+                afmoe.SLIDING, afmoe.SLIDING, afmoe.FULL, afmoe.SLIDING,
+                afmoe.SLIDING)),
+        state=12 * 705_474_304, kept=[
+            # attention's three input projections (1.51 GB over five
+            # layers) fit beside gate and up, the routed experts' gate
+            # and up (2.15 GB over a buffer an eighth full) do not
+            (1, 16384, V5E, afmoe.KEPT_NAMES + (
+                "gate_up", afmoe.ATTENTION_IN_NAME)),
+            (1, 16384, None, afmoe.REMAT_NAMES),
+            (2, 16384, V5E, afmoe.KEPT_NAMES + ("gate_up",)),
+            (1, 16384, 12_000_000_000, afmoe.KEPT_NAMES)]),
 }
 every_family = pytest.mark.parametrize("family", list(FAMILIES))
 
@@ -304,7 +321,8 @@ def test_lfm2_takes_a_given_choice_as_the_other_two_do():
 
 
 MODELS = os.path.dirname(layers.__file__)
-FAMILY_MODULES = ["gpt", "granite", "lfm2", "deepseek_v3", "qwen3_next"]
+FAMILY_MODULES = ["gpt", "granite", "lfm2", "deepseek_v3", "qwen3_next",
+                  "afmoe"]
 
 
 @pytest.mark.parametrize("module", FAMILY_MODULES + ["layers"])

@@ -31,6 +31,7 @@ from jax.sharding import SingleDeviceSharding
 
 import horovod_tpu as hvd
 from horovod_tpu import training
+from horovod_tpu.models.afmoe import afmoe_tiny_config
 from horovod_tpu.models.bert import bert_tiny_config
 from horovod_tpu.models.deepseek_v3 import deepseek_v3_tiny_config
 from horovod_tpu.models.gpt import gpt_tiny_config
@@ -41,13 +42,15 @@ from horovod_tpu.ops import pallas_attention, pallas_moe
 from horovod_tpu.ops.pallas_attention import flash_attention
 from horovod_tpu.parallel import moe
 from horovod_tpu.parallel.mesh import build_mesh
-from horovod_tpu.parallel.sharding import (deepseek_v3_partition_rules,
+from horovod_tpu.parallel.sharding import (afmoe_partition_rules,
+                                           deepseek_v3_partition_rules,
                                            gpt_partition_rules,
                                            granite_partition_rules,
                                            infer_shardings,
                                            lfm2_partition_rules,
                                            qwen3_next_partition_rules)
-from horovod_tpu.training import (make_bert_pretrain_step,
+from horovod_tpu.training import (make_afmoe_train_step,
+                                  make_bert_pretrain_step,
                                   make_deepseek_v3_train_step,
                                   make_gpt_train_step,
                                   make_granite_train_step,
@@ -142,6 +145,40 @@ def test_flash_backward_compiles_for_v5e(v5e_chip, shape, dtype, causal):
                              "hvd_flash_bwd_dkv": 1}), kernels
     # No array anywhere near the size of the scores (H * D may equal
     # S, as in this cell, so sizes are compared and not dimensions).
+    largest = max(math.prod(int(n) for n in dims.split(","))
+                  for dims in re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", text))
+    assert largest * 4 <= batch * heads * seq * seq, largest
+
+
+@pytest.mark.parametrize("shape,window,steps", [
+    # The trinity-mini cell's window layers: 16 x 16 blocks of 1024 of
+    # which a q block meets three, a float32 dQ of 16384 x 128 (8 MiB).
+    ((1, 16384, 32, 128), 2048, 3),
+    ((2, 4096, 8, 64), 512, 2),     # two heads a block, a band of a tile
+    ((1, 1536, 4, 128), 700, 2),    # a padded block
+    ((2, 512, 4, 64), 128, 1)],     # one block: every decision static
+    ids=["trinity-1x16384-w2048", "4096-w512", "s1536-padded-w700",
+         "one-block-w128"])
+def test_flash_window_kernels_compile_for_v5e(v5e_chip, shape, window, steps):
+    """A window call lowers to two Mosaic kernels under their own names,
+    forward and ONE fused backward, whose grids' last dimension is the
+    band's steps; and no score square is left for XLA to hold."""
+    batch, seq, heads = shape[:3]
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=v5e_chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, window=window)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q)
+    text = lowered.compile().as_text()
+    kernels = Counter(re.findall(
+        r'custom_call_target="tpu_custom_call".*?/(hvd_flash_\w+)/', text))
+    assert kernels == {"hvd_flash_fwd_window": 1,
+                       "hvd_flash_bwd_window": 1}, kernels
+    assert pallas_attention.band_steps(
+        window, min(seq, pallas_attention.SEQ_BLOCK),
+        -(-seq // pallas_attention.SEQ_BLOCK)) == steps
     largest = max(math.prod(int(n) for n in dims.split(","))
                   for dims in re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", text))
     assert largest * 4 <= batch * heads * seq * seq, largest
@@ -297,6 +334,41 @@ def test_bert_dp_exchange_updates_a_shard_and_gathers(v5e_2x2):
     assert all(int(shape.split(",")[0]) < 64
                for shape in shapes("collective-permute-start")), \
         shapes("collective-permute-start")
+
+
+@pytest.mark.parametrize("axes", [{"dp": 1}, {"dp": 2, "tp": 2}],
+                         ids=["1chip", "dp2xtp2"])
+def test_afmoe_step_compiles_with_both_kinds_of_kernels(v5e_2x2, axes):
+    """``make_afmoe_train_step`` on a mesh of TPU devices: the window
+    layers run the band's kernels and the full layer the triangle's,
+    once each a layer (the forward's output is kept across ``remat``;
+    under ``tp`` shard by shard), and the step holds the bias's
+    update."""
+    chips = math.prod(axes.values())
+    batch, seq = 2 * chips, 96
+    cfg = afmoe_tiny_config(remat=True)
+    mesh = build_mesh(axes, v5e_2x2.devices[:chips])
+    init_fn, step_fn, batch_sharding = make_afmoe_train_step(cfg, mesh)
+    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
+                               sharding=batch_sharding)
+    state = jax.eval_shape(init_fn, jax.ShapeDtypeStruct((2,), jnp.uint32),
+                           ids)
+    state = jax.tree.map(
+        lambda leaf, sharding: jax.ShapeDtypeStruct(
+            leaf.shape, leaf.dtype, sharding=sharding),
+        state, infer_shardings(state, mesh, afmoe_partition_rules()))
+    text = step_fn.lower(*state, ids).compile().as_text()
+    kernels = Counter(re.findall(
+        r'custom_call_target="tpu_custom_call".*?'
+        r'(layer_\d+)/attention/[^"]*?/(hvd_flash_\w+)/', text))
+    assert kernels == {
+        ("layer_%d" % i, name + ("" if kind == "full_attention"
+                                 else "_window")): 1
+        for i, kind in enumerate(cfg.layer_types)
+        for name in ("hvd_flash_fwd", "hvd_flash_bwd")}, kernels
+    assert "bias_update" in text
+    assert not re.search(r"(f32|bf16)\[[0-9,]*\b%d,%d\b" % (seq, seq),
+                         text)
 
 
 @pytest.mark.parametrize("axes", [{"dp": 1}, {"dp": 2, "tp": 2}],

@@ -404,3 +404,136 @@ def test_flash_default_scale_is_the_queries_width():
     ids=["gpt2", "latent", "d128", "toy", "gpt2-small", "wider-values"])
 def test_a_block_holds_the_same_heads_of_both_widths(heads, d, dv, g):
     assert pallas_attention._heads_per_block(heads, d, dv) == g
+
+
+def _window_reference(q, k, v, window):
+    """The einsum path's own mask (``models/layers.py``
+    ``visible_keys``) on plain full scores."""
+    from horovod_tpu.models.layers import visible_keys
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    s = jnp.where(visible_keys(q.shape[1], window), s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+
+
+# (sequence, tile, rows a block, window): a window under a tile, a
+# block's length, longer than the sequence, one key (the query's own),
+# a sequence that is no multiple of the tile, a band that needs three
+# blocks, and one a tile long over blocks of one tile.
+WINDOWS = [(64, 16, 32, 8), (64, 16, 32, 32), (64, 16, 32, 100),
+           (48, 16, 32, 1), (50, 16, 32, 20), (96, 16, 32, 33),
+           (64, 16, 16, 17)]
+
+
+@pytest.mark.parametrize("heads,d,dv", [(2, 16, 16), (4, 24, 16)],
+                         ids=["one-width", "values-narrower"])
+@pytest.mark.parametrize("seq,tile,seq_block,window", WINDOWS, ids=[
+    "under-a-tile", "a-block", "over-the-sequence", "own-key-alone",
+    "ragged-sequence", "three-blocks", "a-tile-over-small-blocks"])
+def test_flash_window_matches_the_einsum_mask(seq, tile, seq_block, window,
+                                              heads, d, dv, form):
+    """A causal call with a window, forward and all three gradients
+    (fused, and as two kernels): the band's walk over ``band_steps``
+    blocks, its clamped steps skipped, tiles left of the band skipped
+    and the two edges masked, against plain scores under the einsum
+    path's mask."""
+    rng = np.random.RandomState(5)
+    q, k = (jnp.asarray(rng.randn(2, seq, heads, d).astype(np.float32))
+            for _ in range(2))
+    v = jnp.asarray(rng.randn(2, seq, heads, dv).astype(np.float32))
+
+    def attend(q, k, v):
+        return pallas_attention._flash(q, k, v, d ** -0.5, True,
+                                       (tile, tile), seq_block, True, window)
+    ref = functools.partial(_window_reference, window=window)
+    got = jax.jit(attend)(q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+    got_g = jax.jit(functools.partial(_grads, attend))(q, k, v)
+    for name, a, b in zip("qkv", got_g, _grads(ref, q, k, v)):
+        b = np.asarray(b)
+        # one key alone: dQ is 0 and the kernels' a rounding of it
+        np.testing.assert_allclose(
+            np.asarray(a), b, rtol=2e-4,
+            atol=2e-5 * max(float(np.abs(b).max()), 1e-3),
+            err_msg="d" + name)
+
+
+def test_a_window_call_is_named_and_walks_the_band_alone():
+    """The window call's kernels carry their own names, the grid's last
+    dimension has the band's steps and not the sequence's blocks, and a
+    call with no window is the one it was."""
+    q = jnp.zeros((1, 64, 2, 16))
+
+    def kernels(window):
+        jaxpr = jax.make_jaxpr(functools.partial(_grads, lambda q, k, v: (
+            pallas_attention._flash(q, k, v, 0.25, True, (16, 16), 16, True,
+                                    window))))(q, q, q)
+        found = {}
+
+        def walk(j):
+            for eqn in j.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    found[eqn.params["name"]] = \
+                        eqn.params["grid_mapping"].grid
+                    continue
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+        walk(jaxpr.jaxpr)
+        return found
+    assert kernels(None) == {"hvd_flash_fwd": (1, 1, 4, 4),
+                             "hvd_flash_bwd": (1, 1, 4, 4)}
+    # 17 keys back reach the block before a query's own: two steps
+    assert kernels(17) == {"hvd_flash_fwd_window": (1, 1, 4, 2),
+                           "hvd_flash_bwd_window": (1, 1, 4, 2)}
+    # 16 back still reach it from a block's first query; one key alone
+    # is the query's own
+    assert kernels(16)["hvd_flash_fwd_window"] == (1, 1, 4, 2)
+    assert kernels(1)["hvd_flash_fwd_window"] == (1, 1, 4, 1)
+    assert kernels(1000)["hvd_flash_bwd_window"] == (1, 1, 4, 4)
+    assert pallas_attention.band_steps(2048, 1024, 16) == 3
+    assert pallas_attention.band_steps(2049, 1024, 16) == 3
+    assert pallas_attention.band_steps(2050, 1024, 16) == 4
+    assert pallas_attention.band_steps(1, 1024, 16) == 1
+
+
+@pytest.mark.parametrize("seq,window,tile", [
+    (16384, 2048, 512), (8192, 2048, 512), (4096, 512, 512),
+    (2048, 4096, 512), (1024, 700, 512), (64, 8, 16)])
+def test_the_bands_tiles_by_a_formula(seq, window, tile):
+    """``band_tiles`` (the kernels' own rule, tile by tile) against a
+    count by rows of tiles: row ``a`` walks the tiles from the one that
+    holds its first query's oldest key to its own, masks its own and,
+    where the oldest key of its LAST query lies past a tile's first key,
+    that tile, and skips what the triangle has beside them."""
+    got = pallas_attention.band_tiles(seq, window, (tile, tile))
+    rows = -(-seq // tile)
+    walked = masked = 0
+    for a in range(rows):
+        first = max(0, (a * tile - (window - 1)) // tile)
+        walked += a - first + 1
+        edge = {c for c in range(first, a + 1)
+                if c * tile <= a * tile + tile - 1 - window}
+        masked += len(edge | {a})
+    triangle = rows * (rows + 1) // 2
+    assert (got["walked"], got["masked"], got["skipped"]) == \
+        (walked, masked, triangle - walked)
+    inside = min(window, seq)
+    assert got["fill"] == pytest.approx(
+        (inside * (inside + 1) / 2 + (seq - inside) * inside)
+        / (walked * min(tile, seq) ** 2))
+    if (seq, window) == (16384, 2048):
+        assert (walked, masked, triangle) == (150, 60, 528)
+        assert 0.80 < got["fill"] < 0.8001
+
+
+@pytest.mark.parametrize("bad,match", [
+    (dict(causal=False, window=8), "causal"),
+    (dict(causal=True, window=0), "own position"),
+    (dict(causal=True, window=8, block_q=16, block_k=32), "blocks")],
+    ids=["not-causal", "no-key", "blocks-of-two-sizes"])
+def test_a_window_call_refuses_what_it_cannot_walk(qkv, bad, match):
+    # 40 rows: three tiles of 16 (a block of 48), two of 32 (one of 64)
+    q, k, v = (t[:, :40] for t in qkv)
+    with pytest.raises(ValueError, match=match):
+        jax.jit(functools.partial(flash_attention, interpret=True,
+                                  **bad))(q, k, v)
