@@ -43,7 +43,10 @@ float32 parameters; the router, the norms, the rotation, ``g`` and
 ``beta`` in float32; the recurrence in its chunked form with what
 ``ops/gated_delta.py`` keeps in float32.  On a TPU attention is the
 Pallas flash kernels with keys and values repeated to the query heads;
-elsewhere it is grouped einsums.  With ``remat`` a layer is recomputed
+elsewhere it is grouped einsums.  On ONE TPU device the delta rule's
+chunks are solved by the Pallas kernels of ``ops/pallas_gated_delta.py``
+where their tiles divide the widths, elsewhere by XLA's triangular
+solve.  With ``remat`` a layer is recomputed
 in the backward pass but for the flash kernels' output, the routers'
 choice and what the device has room for (``REMAT_CANDIDATES``).
 Parameter names are matched by
@@ -264,8 +267,20 @@ class GatedDeltaNet(nn.Module):
             beta = jax.nn.sigmoid(b.astype(jnp.float32))
             g = -jnp.exp(a_log) * jax.nn.softplus(
                 a.astype(jnp.float32) + dt_bias)
-            o = gated_delta.gated_delta_chunked(q, k, v, g, beta,
-                                                cfg.linear_chunk_size)
+            if self.heads_sharding is not None:
+                # A head's two scalars lie where its keys and values do
+                # (and their types carry the mesh that ``q``, ``k`` and
+                # ``v`` carry, in every layer and pass: the kernels'
+                # one trace hangs on it).
+                of_heads = NamedSharding(
+                    self.heads_sharding.mesh,
+                    jax.sharding.PartitionSpec(*self.heads_sharding.spec[:3]))
+                g, beta = (jax.lax.with_sharding_constraint(t, of_heads)
+                           for t in (g, beta))
+            o = gated_delta.gated_delta_chunked(
+                q, k, v, g, beta, cfg.linear_chunk_size,
+                kernels=(moe.on_one_tpu(mesh_of(self.heads_sharding))
+                         and not self.is_initializing()))
         with jax.named_scope("gated_norm"):
             scale = self.param("norm_scale", nn.initializers.ones, (d_v,),
                                jnp.float32)

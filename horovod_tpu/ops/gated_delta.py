@@ -27,23 +27,40 @@ cum))^T v_new``.
 
 The sibling of ``ops/ssd.py`` and in its manner: plain ``jax.numpy``
 einsums, the stages under ``jax.named_scope``s, differentiable by
-autodiff but for the walk over the chunks, whose backward is written
-out (``_walk``: one reversed scan over the states the forward walk
-left, so a recomputed layer that kept them walks nothing again).
-``g``, its sums, ``A`` and the carried state are float32; ``T`` is
-never formed: ``w`` and ``u`` come out of one triangular solve of
-``(I + A) [w | u] = [k beta exp(cum) | v beta]``, in
-float32 at full precision (rows in turn inside XLA's solver: backward
-stable wherever a chunk's keys are alike, where the product ``(I - A)(I
-+ A^2)(I + A^4)...`` that the matrix unit would take loses its low bits
-to the powers' growth, and on the v5e the quicker of the two: 8.7 ms
-against 13.8 for the inverses of a layer at 2 x 8192, PERF.md, PR 39);
-the wide products take their operands in ``v``'s dtype and accumulate
-in float32.  What the two operators do not share: SSD's state is only
-decayed and added to, so its chunks' states are made side by side and
-its scan carries sums; here a chunk's writes need the state it is
-entered with, so the scan over the chunks carries the products
-themselves.
+autodiff but for two stages whose backward is written out.  The walk
+over the chunks (``_walk``): one reversed scan over the states the
+forward walk left, so a recomputed layer that kept them walks nothing
+again.  And, on one TPU device at shapes their tiles divide
+(:func:`solved_in_vmem`; the model reads the device off its mesh, no
+argument or variable chooses), the chunks' systems, the ``wy`` scope:
+the two Pallas kernels of ``ops/pallas_gated_delta.py``, a grid step of
+which holds a few (head, chunk) systems in VMEM.  There the keys'
+square, ``A``, the right-hand sides, ``T`` and, in the backward kernel,
+all of them again with their cotangents are made in VMEM and never
+reach HBM: the forward reads ``k``, ``v``, ``beta`` and ``cum`` and
+writes ``w`` and ``u``, the backward reads those four and ``dw``,
+``du`` and writes four cotangents.  Everything else stays XLA's: the
+decays, the walk, the two output stages, and for every other input
+(a CPU, several devices, the tiny models' widths) the systems
+themselves, :func:`_wy_by_xla`, which is also the kernels' oracle.
+
+``g``, its sums, ``A`` and the carried state are float32.  As XLA's
+operations ``T`` is never formed: ``w`` and ``u`` come out of one
+triangular solve of ``(I + A) [w | u] = [k beta exp(cum) | v beta]``,
+in float32 at full precision (rows in turn inside XLA's solver:
+backward stable wherever a chunk's keys are alike, where the product
+``(I - A)(I + A^2)(I + A^4)...`` that the matrix unit would take loses
+its low bits to the powers' growth, and on the v5e the quicker of the
+two: 8.7 ms against 13.8 for the inverses of a layer at 2 x 8192,
+PERF.md, PR 39).  The kernels solve by blocked forward substitution,
+the form XLA's expander has: the diagonal blocks by substitution over
+their rows, the blocks below them by float32 products at full
+precision; that product stays out there too.  The wide products take
+their operands in ``v``'s dtype and accumulate in float32.  What the
+two operators do not share: SSD's state is only decayed and added to,
+so its chunks' states are made side by side and its scan carries sums;
+here a chunk's writes need the state it is entered with, so the scan
+over the chunks carries the products themselves.
 """
 
 import jax
@@ -71,20 +88,23 @@ WY_NAME, STATES_NAME = "gdn_wy", "gdn_states"
 
 
 def scan_bytes(batch: int, seq: int, heads: int, key_dim: int,
-               value_dim: int, chunk: int, itemsize: int) -> int:
+               value_dim: int, chunk: int, itemsize: int,
+               in_vmem: bool = False) -> int:
     """Bytes of the arrays :func:`gated_delta_chunked` materialises for
     one layer's forward pass with ``batch`` sequences on the device:
-    the decay between two positions, ``A`` (float32) and the queries'
-    square against the keys (float32, and as weights in the compute
-    dtype), each ``[batch, heads, count, length, length]``; ``w``
-    (compute dtype), ``u`` and ``v_new`` (float32) of every position;
-    and the state every chunk is entered with, ``[batch, count, heads,
-    key_dim, value_dim]`` float32."""
+    the decay between two positions, ``A`` (float32; not where the
+    chunks' systems are made and solved ``in_vmem``, by the kernels:
+    :func:`solved_in_vmem`) and the queries' square against the keys
+    (float32, and as weights in the compute dtype), each ``[batch,
+    heads, count, length, length]``; ``w`` (compute dtype), ``u`` and
+    ``v_new`` (float32) of every position; and the state every chunk is
+    entered with, ``[batch, count, heads, key_dim, value_dim]``
+    float32."""
     count, length = chunks_of(seq, chunk)
     square = batch * heads * count * length * length
     positions = batch * count * length * heads
     states = batch * count * heads * key_dim * value_dim
-    return (square * (3 * 4 + itemsize)
+    return (square * ((2 if in_vmem else 3) * 4 + itemsize)
             + positions * (key_dim * itemsize + 2 * value_dim * 4)
             + states * 4)
 
@@ -95,6 +115,14 @@ _WALK_TRACES = metrics.gauge(
     "by pass: forward (the scan that carries the state) or backward (its "
     "written-out reverse scan, which a step differentiates through in "
     "place of autodiff's); one a distinct shape and trace context")
+KERNEL_TRACES = metrics.gauge(
+    "hvd_gdn_kernel_traces",
+    "Traces of the delta rule's within-chunk Pallas kernels this process "
+    "made, by kernel (wy_fwd, wy_bwd; incremented inside each kernel's "
+    "jitted function, so it counts traces of the body, not calls: one a "
+    "distinct shape and configuration context, however many layers and "
+    "programs call it; 0 where the operator was traced and no layer took "
+    "the kernels)")
 
 
 def _rounded(t, dtype):
@@ -171,7 +199,74 @@ def _walk_backward(kept, cotangents):
 _walk.defvjp(_walk_forward, _walk_backward)
 
 
-def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64):
+def _wy_by_xla(k, v, beta, between, to_here):
+    """``w`` (``k``'s dtype) and ``u`` (float32) of every chunk's system
+    as XLA's operations: ``k`` ``[batch, count, length, heads,
+    key_dim]``, ``v`` likewise, ``beta`` ``[batch, count, length,
+    heads]`` float32, and of the log-decay's running sum the decay
+    ``between`` two positions of a chunk and ``to_here``.  The path of
+    every input the kernels do not take, and their oracle."""
+    key_dim, length = k.shape[-1], k.shape[2]
+    # Position i's correction reads the corrections of the positions
+    # j < i of its chunk through beta_i (k_i . k_j) times the decay
+    # from j to i.
+    beta_h = jnp.transpose(beta, (0, 3, 1, 2))   # [b, h, c, l]
+    keys = jnp.einsum("bclhd,bcshd->bhcls", k, k,
+                      preferred_element_type=jnp.float32)
+    a = jnp.where(jnp.tril(jnp.ones((length, length), bool), -1),
+                  keys * between * beta_h[..., None], 0.0)
+    # ``T`` is never formed: both right-hand sides go through one
+    # triangular solve (the diagonal taken as 1 and not read), the
+    # heads before the chunks as ``a`` has them.
+    k32, v32 = k.astype(jnp.float32), v.astype(jnp.float32)
+    sides = jnp.concatenate([k32 * (beta[..., None] * to_here),
+                             v32 * beta[..., None]], axis=-1)
+    solved = solve_triangular(
+        a, jnp.transpose(sides, (0, 3, 1, 2, 4)), lower=True,
+        unit_diagonal=True)
+    w, u = jnp.split(jnp.transpose(solved, (0, 2, 3, 1, 4)), [key_dim],
+                     axis=-1)
+    return w.astype(k.dtype), u
+
+
+def _wy_kernels():
+    """``ops/pallas_gated_delta.py``, imported where a layer is traced
+    as kernels, as ``parallel/moe.py`` imports its own: a process that
+    runs no kernel does not pay for Pallas."""
+    from . import pallas_gated_delta
+    return pallas_gated_delta
+
+
+def solved_in_vmem(kernels: bool, seq: int, chunk: int, key_dim: int,
+                   value_dim: int, dtype) -> bool:
+    """The rule: the chunks' systems are the Pallas kernels' where the
+    caller found its arrays on one TPU device (``kernels``:
+    ``parallel/moe.py`` ``on_one_tpu``) AND the kernels' tiles divide
+    the static shapes; XLA's operations everywhere else."""
+    return bool(kernels) and _wy_kernels().fits(
+        chunks_of(seq, chunk)[1], key_dim, value_dim, dtype)
+
+
+def _wy_in_vmem(k, v, beta, cum):
+    """:func:`_wy_by_xla`'s results from the two Pallas kernels: a
+    (head, chunk)'s ``A`` made and solved in VMEM.  ``k`` and ``v`` go
+    as they lie (a head's lanes are a block's), ``beta`` and ``cum`` a
+    head's chunk along the lanes."""
+    batch, count, length, heads, _ = k.shape
+    systems = batch * count
+    # The kernels take the chunks two at a time: an odd count gets one
+    # of zeros (``beta`` = 0: it solves to zeros), cut off again.
+    even = lambda t: jnp.pad(t, ((0, systems % 2),) + ((0, 0),) * 2)
+    rows = lambda t: even(t.reshape(systems, length, -1))
+    narrow = lambda t, order: even(jnp.transpose(t, order).reshape(
+        systems, heads, length))
+    w, u = _wy_kernels().wy(rows(k), rows(v), narrow(beta, (0, 1, 3, 2)),
+                            narrow(cum, (0, 2, 1, 3)), heads)
+    return w[:systems].reshape(k.shape), u[:systems].reshape(v.shape)
+
+
+def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64,
+                        kernels: bool = False):
     """``o`` of the recurrence above, ``[batch, seq, heads, value_dim]``
     in ``v``'s dtype, from ``S = 0``.
 
@@ -181,7 +276,14 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64):
     ``[batch, seq, heads]``.  A sequence that the chunk's length does
     not divide is padded at its end (``chunks_of``): a padded position
     has ``g`` = 0 and ``beta`` = 0, so it neither decays the state nor
-    writes to it, and its output is cut off."""
+    writes to it, and its output is cut off.
+
+    ``kernels``: the caller found the layer's arrays on one TPU device
+    (``parallel/moe.py`` ``on_one_tpu``), so the chunks' systems are
+    made and solved by the Pallas kernels of
+    ``ops/pallas_gated_delta.py`` wherever their tiles divide the shapes
+    (:func:`solved_in_vmem`); every other input takes XLA's operations,
+    and the results are the same to a rounding."""
     batch, seq, heads, key_dim = k.shape
     count, length = chunks_of(seq, chunk)
     pad = count * length - seq
@@ -209,33 +311,20 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64):
         chunk_decay = jnp.exp(cum[..., -1])          # [b, h, c]
 
     with jax.named_scope("wy"):
-        # Position i's correction reads the corrections of the
-        # positions j < i of its chunk through beta_i (k_i . k_j) times
-        # the decay from j to i.
-        beta_h = jnp.transpose(beta, (0, 3, 1, 2))   # [b, h, c, l]
-        keys = jnp.einsum("bclhd,bcshd->bhcls", k, k,
-                          preferred_element_type=jnp.float32)
-        a = jnp.where(jnp.tril(lower, -1),
-                      keys * between * beta_h[..., None], 0.0)
-        # ``T`` is never formed: both right-hand sides go through one
-        # triangular solve (the diagonal taken as 1 and not read), the
-        # heads before the chunks as ``a`` has them.
-        k32, v32 = k.astype(jnp.float32), v.astype(jnp.float32)
-        sides = jnp.concatenate([k32 * (beta[..., None] * to_here),
-                                 v32 * beta[..., None]], axis=-1)
-        solved = solve_triangular(
-            a, jnp.transpose(sides, (0, 3, 1, 2, 4)), lower=True,
-            unit_diagonal=True)
-        w, u = jnp.split(jnp.transpose(solved, (0, 2, 3, 1, 4)), [key_dim],
-                         axis=-1)
-        w = checkpoint_name(w.astype(dtype), WY_NAME)
+        for kernel in ("wy_fwd", "wy_bwd"):
+            KERNEL_TRACES.inc(0, kernel=kernel)   # reads 0, not absent
+        if solved_in_vmem(kernels, seq, chunk, key_dim, v.shape[-1], dtype):
+            w, u = _wy_in_vmem(k, v, beta, cum)
+        else:
+            w, u = _wy_by_xla(k, v, beta, between, to_here)
+        w = checkpoint_name(w, WY_NAME)
         u = checkpoint_name(u, WY_NAME)
 
     with jax.named_scope("state_scan"):
         # The walk over the chunks: what a chunk writes is its
         # corrections less what the state it is entered with already
         # holds at its keys.
-        k_to_end = (k32 * to_end).astype(dtype)
+        k_to_end = (k.astype(jnp.float32) * to_end).astype(dtype)
 
         by_chunk = lambda t: jnp.moveaxis(t, 1, 0)
         entered, v_new = _walk(by_chunk(w), by_chunk(u), by_chunk(k_to_end),

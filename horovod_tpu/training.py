@@ -1009,10 +1009,14 @@ def _record_qwen3_next(config, mesh, sequences: int, seq: int):
     _GDN_HEAD_DIMS.set(config.linear_key_head_dim, which="key")
     _GDN_HEAD_DIMS.set(config.linear_value_head_dim, which="value")
     _GDN_CHUNKS.set(gated_delta.chunks_of(seq, config.linear_chunk_size)[0])
+    from .parallel import moe
+    widths = (config.linear_key_head_dim, config.linear_value_head_dim)
     _GDN_SCAN_BYTES.set(gated_delta.scan_bytes(
-        sequences, seq, value_heads, config.linear_key_head_dim,
-        config.linear_value_head_dim, config.linear_chunk_size,
-        np.dtype(config.dtype).itemsize))
+        sequences, seq, value_heads, *widths, config.linear_chunk_size,
+        np.dtype(config.dtype).itemsize,
+        in_vmem=gated_delta.solved_in_vmem(
+            moe.on_one_tpu(mesh), seq, config.linear_chunk_size, *widths,
+            config.dtype)))
     _ATTENTION_KV_REPEAT.set(config.num_attention_heads
                              // config.num_key_value_heads)
     _ATTENTION_HEAD_DIM.set(config.head_dim)

@@ -563,25 +563,10 @@ def test_flash_kernels_compile_at_heads_of_256(v5e_chip, shape):
     assert largest * 4 <= batch * heads * seq * seq, largest
 
 
-@pytest.mark.parametrize("axes", [{"dp": 1}, {"dp": 2, "tp": 2}],
-                         ids=["1chip", "dp2xtp2"])
-def test_qwen3_next_step_compiles_with_the_kernels_in_the_full_layer(v5e_2x2,
-                                                                     axes):
-    """``make_qwen3_next_train_step`` on a mesh of TPU devices: the two
-    flash kernels once in the full-attention layer and in no delta-rule
-    layer (4 query heads over 2 key-value heads of 32, repeated to the
-    kernels' equal counts; under ``tp`` shard by shard); the chunked
-    delta rule compiled as XLA's own operations under its five scopes,
-    its chunks' systems as a triangular solve; the experts' products
-    compiled from ``ragged_dot`` in every layer and the gated shared
-    expert beside them; the routers' ``top_k`` once a layer, not again
-    in the recomputed pass; no S x S array; and no loop copies, a trip,
-    the stack of states or of ``v_new`` that the walk over the chunks
-    left (a recomputed walk that autodiff handed the kept stack did, in
-    each delta-rule layer: 52 of its 54 ms at 1 x 8192 on the chip)."""
+def _qwen3_next_step_text(v5e_2x2, axes, cfg, batch, seq):
+    """The mesh and the optimised HLO of ``make_qwen3_next_train_step``'s
+    step compiled for described chips under the family's rules."""
     chips = math.prod(axes.values())
-    batch, seq = 2 * chips, 160   # five chunks of 32; no width is 160
-    cfg = qwen3_next_tiny_config(remat=True)
     mesh = build_mesh(axes, v5e_2x2.devices[:chips])
     init_fn, step_fn, batch_sharding = make_qwen3_next_train_step(cfg, mesh)
     ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
@@ -592,7 +577,31 @@ def test_qwen3_next_step_compiles_with_the_kernels_in_the_full_layer(v5e_2x2,
         lambda leaf, sharding: jax.ShapeDtypeStruct(
             leaf.shape, leaf.dtype, sharding=sharding),
         state, infer_shardings(state, mesh, qwen3_next_partition_rules()))
-    text = step_fn.lower(*state, ids).compile().as_text()
+    return mesh, step_fn.lower(*state, ids).compile().as_text()
+
+
+@pytest.mark.parametrize("axes", [{"dp": 1}, {"dp": 2, "tp": 2}],
+                         ids=["1chip", "dp2xtp2"])
+def test_qwen3_next_step_compiles_with_the_kernels_in_the_full_layer(v5e_2x2,
+                                                                     axes):
+    """``make_qwen3_next_train_step`` on a mesh of TPU devices: the two
+    flash kernels once in the full-attention layer and in no delta-rule
+    layer (4 query heads over 2 key-value heads of 32, repeated to the
+    kernels' equal counts; under ``tp`` shard by shard); the chunked
+    delta rule compiled as XLA's own operations under its five scopes,
+    its chunks' systems as a triangular solve (the tiny model's heads of
+    16 and chunks of 32 are no whole tiles: the rule's other side, on
+    one chip as on four, and neither of its kernels); the experts' products
+    compiled from ``ragged_dot`` in every layer and the gated shared
+    expert beside them; the routers' ``top_k`` once a layer, not again
+    in the recomputed pass; no S x S array; and no loop copies, a trip,
+    the stack of states or of ``v_new`` that the walk over the chunks
+    left (a recomputed walk that autodiff handed the kept stack did, in
+    each delta-rule layer: 52 of its 54 ms at 1 x 8192 on the chip)."""
+    chips = math.prod(axes.values())
+    batch, seq = 2 * chips, 160   # five chunks of 32; no width is 160
+    cfg = qwen3_next_tiny_config(remat=True)
+    mesh, text = _qwen3_next_step_text(v5e_2x2, axes, cfg, batch, seq)
     _one_loss_chunk_of(batch // mesh.shape["dp"] * seq)
     kernels = Counter(re.findall(
         r'custom_call_target="tpu_custom_call".*?'
@@ -605,6 +614,7 @@ def test_qwen3_next_step_compiles_with_the_kernels_in_the_full_layer(v5e_2x2,
             r"layer_0/linear_attention/delta_rule/%s" % stage, text), stage
     assert re.search(r"layer_1/linear_attention/delta_rule/wy/[^\"]*"
                      r"triangular_solve", text)
+    assert "hvd_gdn_wy" not in text
     assert "rematted_computation/layer_0/linear_attention" in text
     grouped = text.count('op_name="ragged-dot-none"')
     if chips == 1:
@@ -626,6 +636,34 @@ def test_qwen3_next_step_compiles_with_the_kernels_in_the_full_layer(v5e_2x2,
     chunks = seq // cfg.linear_chunk_size
     assert not [dims for dims in _copies_in_while_bodies(text)
                 if len(dims) == 5 and dims[0] == chunks]
+
+
+def test_qwen3_next_delta_rule_solves_its_chunks_in_the_kernels(v5e_2x2):
+    """One chip, the tiny stack with the delta rule's heads 128 | 128
+    wide and chunks of 64, what the kernels' tiles divide: every
+    delta-rule layer's ``wy`` scope holds ``hvd_gdn_wy_fwd`` and
+    ``hvd_gdn_wy_bwd`` once (``w`` and ``u`` are kept, so the recomputed
+    pass solves nothing again) and no triangular solve, and nothing
+    under it makes a float32 ``[.., 64, 64]`` array: ``A``, the keys'
+    square and their cotangents stay in VMEM."""
+    cfg = qwen3_next_tiny_config(
+        remat=True, linear_num_key_heads=1, linear_num_value_heads=2,
+        linear_key_head_dim=128, linear_value_head_dim=128,
+        linear_chunk_size=64)
+    _, text = _qwen3_next_step_text(v5e_2x2, {"dp": 1}, cfg, 2, 192)
+    kernels = Counter(re.findall(
+        r'custom_call_target="tpu_custom_call".*?(layer_\d+)/'
+        r'linear_attention/delta_rule/wy/[^"]*?(hvd_gdn_wy_\w+)', text))
+    assert kernels == {("layer_%d" % layer, name): 1 for layer in range(3)
+                       for name in ("hvd_gdn_wy_fwd", "hvd_gdn_wy_bwd")}, \
+        kernels
+    under_wy = [line for line in text.splitlines()
+                if re.search(r'op_name="[^"]*delta_rule/wy[/"]', line)]
+    assert under_wy
+    assert not [line for line in under_wy if "triangular" in line]
+    assert not [line for line in under_wy
+                if re.search(r"= f32\[[0-9,]*\b64,64\]", line)]
+    assert "triangular_solve" not in text
 
 
 def _copies_in_while_bodies(text):

@@ -15,8 +15,13 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-from horovod_tpu.ops import gated_delta
+import horovod_tpu as hvd
+from horovod_tpu.models import qwen3_next
+from horovod_tpu.ops import gated_delta, pallas_gated_delta
 from horovod_tpu.ops.gated_delta import gated_delta_chunked
+from horovod_tpu.parallel import moe
+from horovod_tpu.parallel.mesh import build_mesh
+from horovod_tpu.training import make_qwen3_next_train_step
 
 
 def recurrence(q, k, v, g, beta):
@@ -175,6 +180,11 @@ def test_scan_bytes_counts_the_arrays_by_hand():
     # a ragged sequence counts its padded chunk
     assert gated_delta.scan_bytes(1, 100, 1, 4, 4, 32, 4) == \
         gated_delta.scan_bytes(1, 128, 1, 4, 4, 32, 4)
+    # where the kernels make ``A`` in VMEM it is not counted: the
+    # benchmark's cell, 1 x 8192, reads 0.71875 GiB for 0.78125
+    in_cell = lambda **kw: gated_delta.scan_bytes(1, 8192, 32, 128, 128, 64,
+                                                  2, **kw) / 2 ** 30
+    assert (in_cell(), in_cell(in_vmem=True)) == (0.78125, 0.71875)
 
 
 def cast_twice(t, dtype):
@@ -304,3 +314,224 @@ def test_kept_states_leave_no_walk_to_recompute():
                                   gated_delta.STATES_NAME) == 2
     assert _scans_in_the_gradient(gated_delta.WY_NAME) == 3
     assert _scans_in_the_gradient() == 3
+
+
+# -- the within-chunk system as Pallas kernels (ops/pallas_gated_delta.py) --
+
+WIDE = dict(heads=2, d_k=128, d_v=128)   # the least the kernels' tiles divide
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """The two kernels' bodies in the interpreter that is pure JAX."""
+    for name in ("wy_fwd", "wy_bwd"):
+        monkeypatch.setattr(pallas_gated_delta, name, functools.partial(
+            getattr(pallas_gated_delta, name), interpret=True))
+
+
+def systems(seq, dtype=jnp.float32, chunk=64, seed=0, **kw):
+    """``_wy_by_xla``'s operands as ``gated_delta_chunked`` makes them
+    of ``inputs(seq)``: chunked, the last chunk padded with ``g`` = 0
+    and ``beta`` = 0."""
+    _, k, v, g, beta = inputs(seq, batch=1, seed=seed, **{**WIDE, **kw})
+    count, length = gated_delta.chunks_of(seq, chunk)
+
+    def chunked(t):
+        t = jnp.pad(t, ((0, 0), (0, count * length - seq))
+                    + ((0, 0),) * (t.ndim - 2))
+        return t.reshape(1, count, length, *t.shape[2:])
+    k, v, g, beta = (chunked(t) for t in (k, v, g, beta))
+    return k.astype(dtype), v.astype(dtype), beta, jnp.cumsum(
+        jnp.transpose(g, (0, 3, 1, 2)), axis=-1)
+
+
+def by_xla(k, v, beta, cum):
+    """``_wy_by_xla`` from the running sum, as the operator hands it
+    the decays."""
+    lower = jnp.tril(jnp.ones((k.shape[2],) * 2, bool))
+    between = jnp.exp(jnp.where(
+        lower, cum[..., :, None] - cum[..., None, :], -jnp.inf))
+    to_here = jnp.transpose(jnp.exp(cum), (0, 2, 3, 1))[..., None]
+    return gated_delta._wy_by_xla(k, v, beta, between, to_here)
+
+
+@pytest.mark.parametrize("seq,dtype", [
+    (150, jnp.float32), (64, jnp.float32), (512, jnp.float32),
+    (150, jnp.bfloat16)],
+    ids=["padded-last-chunk", "one-chunk", "several", "bfloat16"])
+def test_the_kernels_solve_what_xla_solves(interpreted, seq, dtype):
+    """``w`` and ``u`` of every (head, chunk) from ``hvd_gdn_wy_fwd``
+    against the triangular solve's: to 1e-5 of the largest entry in
+    float32, to one step of bfloat16 where ``w`` is rounded to it."""
+    operands = systems(seq, dtype)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(by_xla)(*operands)
+    got = jax.jit(gated_delta._wy_in_vmem)(*operands)
+    for name, g, w in zip("wu", got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        step = 2.0 ** -8 if (name, dtype) == ("w", jnp.bfloat16) else 1e-5
+        np.testing.assert_allclose(g, w, rtol=0, err_msg=name,
+                                   atol=step * float(np.abs(w).max()))
+
+
+@pytest.mark.parametrize("seq", [100, 256], ids=["padded", "several"])
+def test_gradients_through_the_kernels_equal_autodiffs(interpreted, seq):
+    """The operator's output and all five gradients, the chunks'
+    systems through the two kernels against XLA's solve differentiated
+    by autodiff, cotangents on the whole output."""
+    args = inputs(seq, batch=2, seed=3, **WIDE)
+    weights = jax.random.normal(jax.random.PRNGKey(9),
+                                (2, seq, 2, WIDE["d_v"]))
+
+    def gradients(kernels):
+        loss = lambda *a: jnp.sum(weights * gated_delta_chunked(
+            *a, 64, kernels=kernels))
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)))(
+                *args)
+    (got_loss, got), (want_loss, want) = gradients(True), gradients(False)
+    np.testing.assert_allclose(got_loss, want_loss, rtol=1e-5)
+    for name, g, w in zip(("q", "k", "v", "g", "beta"), got, want):
+        w = np.asarray(w)
+        np.testing.assert_allclose(np.asarray(g), w, rtol=0, err_msg=name,
+                                   atol=2e-5 * float(np.abs(w).max()))
+
+
+def test_the_blocked_solve_where_a_chunks_keys_are_alike(interpreted):
+    """The solve's worst case: every key of a chunk the same, ``beta``
+    0.999 and hardly any decay, so ``A`` is all but ones under the
+    diagonal.  Against a float64 solve the kernel's error is no more
+    than four times ``solve_triangular``'s own (the product ``(I - A)(I
+    + A^2)...`` loses four digits here)."""
+    k, v, beta, cum = systems(128, seed=5)
+    k = jnp.broadcast_to(k[:, :, :1], k.shape)
+    beta = jnp.full_like(beta, 0.999)
+    cum = jnp.cumsum(jnp.full_like(cum, -1e-4), axis=-1)
+    with jax.default_matmul_precision("highest"):
+        _, by_solver = jax.jit(by_xla)(k, v, beta, cum)
+    _, by_kernel = jax.jit(gated_delta._wy_in_vmem)(k, v, beta, cum)
+    k64, v64, beta64, cum64 = (np.asarray(t, np.float64)
+                               for t in (k, v, beta, cum))
+    worst = {"kernel": 0.0, "solver": 0.0}
+    for c in range(k.shape[1]):
+        for h in range(k.shape[3]):
+            keys, values = k64[0, c, :, h], v64[0, c, :, h]
+            decay = np.exp(np.tril(cum64[0, h, c][:, None]
+                                   - cum64[0, h, c][None, :]))
+            a = np.tril(beta64[0, c, :, h, None] * (keys @ keys.T) * decay,
+                        -1)
+            exact = np.linalg.solve(np.eye(len(a)) + a,
+                                    values * beta64[0, c, :, h, None])
+            for name, got in (("kernel", by_kernel), ("solver", by_solver)):
+                error = np.abs(np.asarray(got, np.float64)[0, c, :, h]
+                               - exact).max() / np.abs(exact).max()
+                worst[name] = max(worst[name], error)
+    assert worst["kernel"] <= 4 * worst["solver"] + 1e-7, worst
+    assert worst["kernel"] < 1e-5, worst
+
+
+def test_fits_reads_the_tiles():
+    fits = pallas_gated_delta.fits
+    assert fits(64, 128, 128, jnp.bfloat16) and fits(64, 128, 256, "float32")
+    assert fits(16, 128, 128, jnp.bfloat16) and fits(128, 256, 128, "float32")
+    # the tiny models' heads and chunks; a chunk that is no power of two,
+    # one longer than a tile of lanes, one shorter than a diagonal block
+    assert not fits(32, 16, 16, jnp.float32)
+    assert not fits(64, 128, 64, jnp.bfloat16)
+    assert not fits(96, 128, 128, jnp.bfloat16)
+    assert not fits(256, 128, 128, jnp.bfloat16)
+    assert not fits(8, 128, 128, jnp.bfloat16)
+    assert not fits(64, 128, 128, jnp.float16)
+
+
+def _kernel_calls(jaxpr) -> dict:
+    """Equations of ``jaxpr`` and of every jaxpr under it, each where it
+    stands (the printed form writes a repeated sub-jaxpr once)."""
+    calls = {"fwd": 0, "bwd": 0, "solves": 0, "pallas": 0}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                calls["pallas"] += 1
+                name = eqn.params["name"] or ""
+                calls["fwd"] += name == "hvd_gdn_wy_fwd"
+                calls["bwd"] += name == "hvd_gdn_wy_bwd"
+                continue
+            calls["solves"] += eqn.primitive.name == "triangular_solve"
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(jaxpr.jaxpr)
+    return calls
+
+
+@pytest.mark.parametrize("kept,fwd", [
+    ((gated_delta.WY_NAME, gated_delta.STATES_NAME), 1),
+    ((gated_delta.STATES_NAME,), 2), ((), 2)],
+    ids=["wy-kept", "wy-dropped", "nothing-kept"])
+def test_kept_wy_leaves_no_system_to_solve_again(kept, fwd):
+    """A recomputed operator that kept ``w`` and ``u`` calls the forward
+    kernel once; one that dropped them makes them again; the backward
+    kernel once either way, and no triangular solve."""
+    loss = lambda *a: jnp.sum(gated_delta_chunked(*a, 64, kernels=True))
+    policy = jax.checkpoint_policies.save_only_these_names(*kept)
+    gradient = jax.grad(jax.checkpoint(loss, policy=policy),
+                        argnums=(0, 1, 2, 3, 4))
+    calls = _kernel_calls(jax.make_jaxpr(gradient)(*inputs(128, **WIDE)))
+    assert calls == {"fwd": fwd, "bwd": 1, "solves": 0, "pallas": fwd + 1}
+
+
+def _wide_step(monkeypatch, on_one_tpu, **config):
+    """The tiny Qwen3-Next step with ``remat``, its delta rule at widths
+    the kernels' tiles divide unless ``config`` says otherwise, traced
+    where the devices are (or are taken to be) one TPU."""
+    monkeypatch.setattr(moe, "on_one_tpu", lambda mesh: on_one_tpu)
+    widths = dict(linear_num_key_heads=1, linear_num_value_heads=2,
+                  linear_key_head_dim=128, linear_value_head_dim=128,
+                  linear_chunk_size=64)
+    cfg = qwen3_next.qwen3_next_tiny_config(
+        dtype=jnp.float32, remat=True, **{**widths, **config})
+    mesh = build_mesh({"dp": 1}, jax.devices()[:1])
+    init_fn, step_fn, _ = make_qwen3_next_train_step(cfg, mesh)
+    ids = jax.ShapeDtypeStruct((2, 96), jnp.int32)
+    state = jax.eval_shape(init_fn, jax.random.PRNGKey(0), ids)
+    return cfg, step_fn.trace(*state, ids).jaxpr
+
+
+def test_the_rule_reads_the_devices_and_the_tiles(monkeypatch):
+    """Which inputs take the kernels is read from the input: on one TPU
+    device at widths the tiles divide two calls a delta-rule layer (the
+    layers' recomputed pass keeps ``w`` and ``u``) and no triangular
+    solve; off it, or at the tiny model's own widths, XLA's solve and
+    neither kernel.  One trace a kernel serves the three layers and
+    their recomputed pass (``hvd_gdn_kernel_traces``)."""
+    traces = gated_delta.KERNEL_TRACES
+    was = {k: traces.value(kernel=k) for k in ("wy_fwd", "wy_bwd")}
+    cfg, jaxpr = _wide_step(monkeypatch, True)
+    layers = cfg.layer_types.count(qwen3_next.LINEAR)
+    calls = _kernel_calls(jaxpr)
+    assert (calls["fwd"], calls["bwd"], calls["solves"]) == (layers, layers,
+                                                             0)
+    # a shape the process has not traced yet: one trace each, here
+    assert {k: traces.value(kernel=k) - was[k] for k in was} == {
+        "wy_fwd": 1, "wy_bwd": 1}
+    gauges = hvd.metrics_snapshot()["gauges"]
+    assert gauges["hvd_gdn_kernel_traces"] == {
+        "kernel=" + k: traces.value(kernel=k) for k in was}
+    # and the step's record of the operator's arrays leaves ``A`` out
+    assert gauges["hvd_gdn_scan_bytes"] == gated_delta.scan_bytes(
+        2, 96, 2, 128, 128, 64, 4, in_vmem=True)
+    for on_one_tpu, config in ((False, {}), (True, dict(
+            linear_key_head_dim=16, linear_value_head_dim=16,
+            linear_chunk_size=32))):
+        _, jaxpr = _wide_step(monkeypatch, on_one_tpu, **config)
+        calls = _kernel_calls(jaxpr)
+        assert (calls["fwd"], calls["bwd"]) == (0, 0)
+        # two a layer in the forward pass's jaxpr and its recomputed
+        # one, and the solve's own transpose
+        assert calls["solves"] >= 2 * layers
+        assert hvd.metrics_snapshot()["gauges"]["hvd_gdn_scan_bytes"] == \
+            gated_delta.scan_bytes(2, 96, 2, *((16, 16, 32) if config else
+                                               (128, 128, 64)), 4)
+    assert {k: traces.value(kernel=k) - was[k] for k in was} == {
+        "wy_fwd": 1, "wy_bwd": 1}
