@@ -1,5 +1,5 @@
-"""The routed experts' buffer-side passes as Pallas TPU kernels that
-stop where the pairs end.
+"""The routed experts' buffer-side passes and grouped products as
+Pallas TPU kernels that stop where the pairs end.
 
 ``parallel/moe.py`` ``routed_experts`` sorts the (token, expert) pairs
 whose expert is held into a buffer as long as the routing can fill it;
@@ -35,6 +35,18 @@ into a result.
 * ``hvd_moe_gated``, ``hvd_moe_gated_bwd``: ``silu(a) * b * gate``
   over ``[R, F]`` and its backward, float32 throughout and rounded
   once, blocks past the pairs skipped.
+* ``hvd_moe_grouped_rows``, ``hvd_moe_grouped_rows_t``,
+  ``hvd_moe_grouped_weights``: the grouped product ``out[r] = lhs[r] @
+  W[e(r)]`` and its two transposes (what ``lax.ragged_dot`` and its VJP
+  compute, in its arithmetic), over the (row tile of ``GROUPED_TILE``,
+  group) pairs that hold a row: :class:`Walk`, made once a layer from
+  the groups' sizes and prefetched.  A tile two groups share is visited
+  once for each with the other's rows masked, a group's weights are
+  fetched once however many tiles it has (copied into VMEM while the
+  group before it is computed), the grid is as long as the walk (a
+  bound read on the device: an empty step costs 0.1 us and a buffer a
+  sixteenth full would make 600 of them a call), and every tile touched
+  is written whole, zeros in the rows that belong to no group.
 
 Set-up is paid once, and kept small.  Each kernel is reached through
 ONE module-level function under ``jax.jit`` whose block sizes and grid
@@ -66,6 +78,7 @@ and semaphores simulated, uninitialised memory nan).
 import contextlib
 import functools
 import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -525,3 +538,306 @@ def gated_bwd(a, b, row_gate, d_gated, n, *, block=None,
         (a, b, row_gate.astype(jnp.float32)[:, None], d_gated),
         [wide, wide, (1, 1, jnp.float32)], block, interpret)
     return d_a, d_b, d_gate[:, 0]
+
+
+# The grouped products.  ``lax.ragged_dot``'s kernel on the TPU walks row
+# tiles of 512 whatever the groups hold; an expert here gets 160 to 1024
+# rows on average and 59 to 591 by the seed and layer, so most of a tile
+# of 512 is another group's rows or none's.  These three walk tiles of
+# ``GROUPED_TILE`` rows, the best at every fill the benchmark's four
+# sparse cells have and at 2048 and 4096 rows an expert (``PERF.md``,
+# PR 48).
+GROUPED_TILE = 128
+# Elements of a group's weights the row kernels hold (twice: this
+# group's and the next one's) and of the float32 accumulator the
+# weights' kernel holds beside its output block: the columns are one
+# block where that fits, else the largest whole number of lanes that
+# divides them and does.
+GROUPED_BLOCK_ELEMENTS = 3 << 20
+# What the three ask of VMEM at the widest cell (LFM2's 2048 x 1536: two
+# blocks of 6 MiB, the rows' tiles and the product beside them), past
+# the 16 MiB a kernel has where it asks for no more.
+GROUPED_VMEM_BYTES = 48 << 20
+
+
+class Walk(NamedTuple):
+    """What the grouped kernels prefetch: the (row tile, group) pairs a
+    layer's three products and their six transposes visit, in order,
+    made once a layer from ``group_sizes`` on the device
+    (:func:`grouped_walk`).  A group that holds no row is visited once
+    all the same (its weights' cotangent is zeros, which something has
+    to write)."""
+    bounds: jax.Array   # [held + 1] int32: the row a group starts at
+    group: jax.Array    # [steps' bound] int32: the group of a step
+    tile: jax.Array     # [steps' bound] int32: its row tile
+    steps: jax.Array    # [1] int32: the steps there are: the grid's length
+
+
+def grouped_tile(rows: int) -> int:
+    """Rows a grid step of the grouped kernels takes, of a buffer of
+    ``rows``."""
+    return min(GROUPED_TILE, rows)
+
+
+def grouped_steps(rows: int, held: int, tile: int) -> int:
+    """The most steps a walk has: every tile once and once more for each
+    group that starts inside one."""
+    return rows // tile + held - 1
+
+
+def _tile_span(group_sizes, tile: int):
+    """``(sizes, ends, first, last)``: each group's rows, the row it
+    ends at, and the tiles its first and its last row lie in (of a
+    group with no row: the tile it would start in, and one before)."""
+    sizes = lax.convert_element_type(group_sizes, jnp.int32)
+    ends = lax.cumsum(sizes)
+    return (sizes, ends, lax.div(ends - sizes, jnp.int32(tile)),
+            lax.div(ends - 1, jnp.int32(tile)))
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "tile"))
+def grouped_walk(group_sizes, rows, tile=None):
+    """The :class:`Walk` over a buffer of ``rows`` sorted by group,
+    ``group_sizes`` ``[held]`` rows each: group ``g`` visits the tiles
+    from the one its first row lies in to the one its last row lies in
+    (one tile where it has no row), so a tile two groups share is
+    visited once for each.  Past the last step the lists name the
+    last's tile and group again (a step may look one ahead)."""
+    tile = tile or grouped_tile(rows)
+    held, tiles = group_sizes.shape[0], rows // tile
+    sizes, ends, first, last = _tile_span(group_sizes, tile)
+    first = lax.min(first, jnp.int32(tiles - 1))
+    last = lax.select(sizes > 0, last, first)
+    visits = last - first + 1
+    visited = lax.cumsum(visits)
+    steps = visited[held - 1:]
+    at = lax.min(lax.iota(jnp.int32, grouped_steps(rows, held, tile)),
+                 steps - 1)
+    group = lax.reduce(
+        lax.convert_element_type(
+            lax.le(lax.broadcast_in_dim(visited, (at.shape[0], held), (1,)),
+                   lax.broadcast_in_dim(at, (at.shape[0], held), (0,))),
+            jnp.int32),
+        jnp.int32(0), lax.add, (1,))
+    tile_of = (jnp.take(first, group)
+               + at - jnp.take(visited - visits, group))
+    bounds = lax.concatenate([jnp.zeros(1, jnp.int32), ends], 0)
+    return Walk(bounds, group, tile_of, steps)
+
+
+def grouped_tile_fill(group_sizes, rows: int, tile: Optional[int] = None):
+    """Rows that hold a pair over the rows of the tiles the grouped
+    kernels walk for them (a tile two groups share counted once for
+    each, a group with no row not at all): what
+    ``hvd_moe_grouped_tile_fill{layer}`` reads.  1 where no tile is
+    walked."""
+    tile = tile or grouped_tile(rows)
+    sizes, _, first, last = _tile_span(jnp.asarray(group_sizes), tile)
+    walked = jnp.where(sizes > 0, last - first + 1, 0).sum() * tile
+    return jnp.where(walked > 0, sizes.sum() / jnp.maximum(walked, 1), 1.0)
+
+
+def _columns(columns: int, other: int, elements: int) -> int:
+    """The columns a block takes: all of them where a block of ``other``
+    rows of them has at most ``elements``, else the largest whole number
+    of lanes that divides them and has."""
+    fits = [c for c in range(LANES, columns + 1, LANES)
+            if columns % c == 0 and c * other <= elements]
+    return fits[-1] if fits else LANES
+
+
+def _rows_of_group(bounds_ref, group, tile_of, shape, tile: int):
+    """Which rows of the tile ``tile_of`` lie in ``group``, over
+    ``shape`` ``[tile, width]``."""
+    row = lax.broadcasted_iota(jnp.int32, shape, 0) + tile_of * tile
+    return lax.bitwise_and(lax.ge(row, bounds_ref[group]),
+                           lax.lt(row, bounds_ref[group + 1]))
+
+
+def _changes_at(ref, step):
+    """Whether ``ref[step]`` is the first step's or another than the
+    step before's."""
+    return lax.bitwise_or(step == 0, ref[lax.max(step - 1, 0)] != ref[step])
+
+
+def _some_rows(bounds_ref, group, tile_of, tile: int):
+    """Whether any row of the tile ``tile_of`` lies in ``group``."""
+    return lax.lt(lax.max(bounds_ref[group], tile_of * tile),
+                  lax.min(bounds_ref[group + 1], (tile_of + 1) * tile))
+
+
+def _grouped_rows_kernel(bounds_ref, group_ref, tile_ref, steps_ref, lhs_ref,
+                         w_ref, o_ref, w_buf, sem, *, tile, transposed):
+    column, step = pl.program_id(0), pl.program_id(1)
+    group, tile_of = group_ref[step], tile_ref[step]
+    # The weights stay in HBM and a group's are copied while the group
+    # BEFORE it is computed: the pipeline's own prefetch starts a step
+    # ahead, and a step is shorter than the copy (4 us for 8 at LFM2's
+    # 2048 x 1536).  Every group is visited, in order, so the group
+    # after ``g`` is ``g + 1`` and its slot the other one.
+    held, block = w_ref.shape[0], w_buf.shape[1 if transposed else 2]
+    slot = lax.rem(group, 2)
+
+    def copy(g, slot):
+        columns = pl.ds(column * block, block)
+        source = (w_ref.at[g, columns, :] if transposed
+                  else w_ref.at[g, :, columns])
+        return pltpu.make_async_copy(source, w_buf.at[slot], sem.at[slot])
+
+    @pl.when(step == 0)
+    def _():
+        copy(group, slot).start()
+
+    @pl.when(_changes_at(group_ref, step))
+    def _():
+        copy(group, slot).wait()
+
+        @pl.when(group + 1 < held)
+        def _():
+            copy(group + 1, 1 - slot).start()
+    # A tile is written whole the first time it is visited, zeros in the
+    # rows that are not this group's: another group's are written at its
+    # own visit, the next, and the rows past the last group's end stay
+    # zeros.  A visit that finds no row of its group (a group with none)
+    # computes nothing.
+    fresh = _changes_at(tile_ref, step)
+    some = _some_rows(bounds_ref, group, tile_of, tile)
+
+    @pl.when(some)
+    def _():
+        product = lax.dot_general(
+            lhs_ref[...], w_buf[slot],
+            (((1,), (1 if transposed else 0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        mine = _rows_of_group(bounds_ref, group, tile_of, product.shape, tile)
+
+        @pl.when(fresh)
+        def _():
+            o_ref[...] = lax.convert_element_type(
+                lax.select(mine, product, lax.full_like(product, 0)),
+                o_ref.dtype)
+
+        @pl.when(lax.bitwise_not(fresh))
+        def _():
+            o_ref[...] = lax.convert_element_type(
+                lax.select(mine, product, _float(o_ref)), o_ref.dtype)
+
+    @pl.when(lax.bitwise_and(lax.bitwise_not(some), fresh))
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+
+def _grouped_rows(name, lhs, weights, walk, tile, transposed, interpret):
+    rows = lhs.shape[0]
+    tile = tile or grouped_tile(rows)
+    contracted = weights.shape[2 if transposed else 1]
+    columns = weights.shape[1 if transposed else 2]
+    block = _columns(columns, contracted, GROUPED_BLOCK_ELEMENTS)
+    _traced(name, tile)
+    w_block = (block, contracted) if transposed else (contracted, block)
+    return pl.pallas_call(
+        functools.partial(_grouped_rows_kernel, tile=tile,
+                          transposed=transposed),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(columns // block, walk.steps[0]),
+            in_specs=[
+                pl.BlockSpec((tile, contracted),
+                             lambda c, s, b, g, tile_ref, n: (tile_ref[s], 0)),
+                pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(
+                (tile, block), lambda c, s, b, g, tile_ref, n: (tile_ref[s], c)),
+            scratch_shapes=[pltpu.VMEM((2,) + w_block, weights.dtype),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=jax.ShapeDtypeStruct((rows, columns), lhs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=GROUPED_VMEM_BYTES),
+        interpret=interpret, name="hvd_moe_" + name)(
+            *walk, lhs, weights)
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def grouped_rows(lhs, weights, walk: Walk, *, tile=None, interpret=False):
+    """``out[r] = lhs[r] @ weights[e(r)]``: ``lhs`` ``[R, K]`` sorted by
+    group as ``walk`` says, ``weights`` ``[held, K, N]`` of ``lhs``'s
+    type; float32 accumulation, rounded once to ``lhs``'s type (what
+    ``lax.ragged_dot(preferred_element_type=lhs.dtype)`` gives).  Every
+    tile a group reaches is written whole, zeros in the rows past the
+    last group's end; a tile past them is not written."""
+    return _grouped_rows("grouped_rows", lhs, weights, walk, tile, False,
+                         interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def grouped_rows_t(d_out, weights, walk: Walk, *, tile=None,
+                   interpret=False):
+    """``d_lhs[r] = d_out[r] @ weights[e(r)]^T``, ``d_out`` ``[R, N]``:
+    :func:`grouped_rows`'s transpose in ``lhs``, the same walk
+    contracting over the weights' LAST axis, so no transposed copy of
+    them is made."""
+    return _grouped_rows("grouped_rows_t", d_out, weights, walk, tile, True,
+                         interpret)
+
+
+def _grouped_weights_kernel(bounds_ref, group_ref, tile_ref, steps_ref,
+                            lhs_ref, d_ref, o_ref, acc, *, tile):
+    step = pl.program_id(1)
+    group, tile_of = group_ref[step], tile_ref[step]
+
+    @pl.when(_changes_at(group_ref, step))
+    def _():
+        acc[...] = jnp.zeros(acc.shape, acc.dtype)
+
+    @pl.when(_some_rows(bounds_ref, group, tile_of, tile))
+    def _():
+        # Both operands are masked by a select: a row of another group
+        # is finite, a row past the last group's end may be anything.
+        lhs, d = (lax.convert_element_type(
+            lax.select(_rows_of_group(bounds_ref, group, tile_of, ref.shape,
+                                      tile), _float(ref),
+                       jnp.zeros(ref.shape, jnp.float32)), ref.dtype)
+            for ref in (lhs_ref, d_ref))
+        acc[...] += lax.dot_general(lhs, d, (((0,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+
+    # Past the last step the walk names the last group again.
+    @pl.when(lax.bitwise_or(
+        step == steps_ref[0] - 1,
+        group_ref[lax.min(step + 1, group_ref.shape[0] - 1)] != group))
+    def _():
+        o_ref[...] = lax.convert_element_type(acc[...], o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def grouped_weights(lhs, d_out, walk: Walk, *, tile=None, interpret=False):
+    """``d_weights[e] = lhs[rows of e]^T @ d_out[rows of e]`` ``[held,
+    K, N]``: :func:`grouped_rows`'s transpose in ``weights``, summed in
+    a float32 accumulator in VMEM over the row tiles of a group and
+    rounded once to ``lhs``'s type; zeros for a group that holds no
+    row."""
+    rows, held = lhs.shape[0], walk.bounds.shape[0] - 1
+    tile = tile or grouped_tile(rows)
+    inner, columns = lhs.shape[1], d_out.shape[1]
+    block = _columns(columns, inner, GROUPED_BLOCK_ELEMENTS)
+    _traced("grouped_weights", tile)
+    return pl.pallas_call(
+        functools.partial(_grouped_weights_kernel, tile=tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(columns // block, walk.steps[0]),
+            in_specs=[
+                pl.BlockSpec((tile, inner),
+                             lambda c, s, b, g, tile_ref, n: (tile_ref[s], 0)),
+                pl.BlockSpec((tile, block),
+                             lambda c, s, b, g, tile_ref, n: (tile_ref[s], c))],
+            out_specs=pl.BlockSpec(
+                (None, inner, block),
+                lambda c, s, b, group_ref, t, n: (group_ref[s], 0, c)),
+            scratch_shapes=[pltpu.VMEM((inner, block), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((held, inner, columns), lhs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=GROUPED_VMEM_BYTES),
+        interpret=interpret, name="hvd_moe_grouped_weights")(
+            *walk, lhs, d_out)

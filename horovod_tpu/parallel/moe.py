@@ -6,9 +6,13 @@
   selection bias, :func:`sigmoid_top_k`, or a softmax,
   :func:`softmax_top_k`: the caller says which), the (token, expert)
   pairs whose expert is held sorted by expert, gathered, run
-  through grouped matrix products (``jax.lax.ragged_dot``, which the
-  TPU compiler lowers to a Mosaic kernel over the groups' tiles) and
-  added back into their tokens.  No capacity and no exchange: on one
+  through three grouped matrix products and added back into their
+  tokens.  The products are ``jax.lax.ragged_dot``, which the TPU
+  compiler lowers to a Mosaic kernel over row tiles of 512, or, where
+  the wide passes below are kernels, a custom VJP over the three
+  grouped kernels of ``ops/pallas_moe.py`` (:func:`_grouped`), which
+  walk the (row tile of 128, group) pairs that hold a row: the same
+  arithmetic, and ``ragged_dot`` the tests' oracle.  No capacity and no exchange: on one
   chip of an expert-parallel group it computes this chip's part of
   the layer.  The sorted buffer is as long as the routing can fill it;
   the pairs held are a prefix of it, its length known on the device
@@ -58,7 +62,7 @@ CHOICE_NAME = "moe_chosen"
 GATE_SUM_EPS = 1e-6
 # Rows of the sorted buffer a walk (:func:`_gates_of_rows`) takes at a
 # time: it stops at the first chunk that starts past the pairs.  A
-# multiple of the grouped kernel's 512-row tile.
+# multiple of the grouped kernels' row tiles (128; ``ragged_dot``'s 512).
 WALK_CHUNK_ROWS = 2048
 
 
@@ -380,13 +384,16 @@ def kernels_fit(tokens: int, top_k: int, held: int, hidden: int, width: int,
     """Whether the kernels' tiles divide a layer's static shapes: a row
     of ``hidden`` whole (8, 128) tiles of 32-bit words (2048 bfloat16,
     1024 float32), the experts' ``width`` whole lanes, buffer and
-    tokens whole blocks.  The three sparse cells' do; the tests' tiny
-    models' do not, and take XLA's passes anywhere."""
+    tokens whole blocks, the buffer whole row tiles of the grouped
+    kernels.  The one rule for every kernel of the layer: the four
+    sparse cells' shapes fit; the tests' tiny models' do not, and take
+    XLA's passes and ``ragged_dot`` anywhere."""
     pallas_moe = _kernels()
     rows = dispatch_rows(tokens, top_k, held)
     return (pallas_moe.row_sublanes(hidden, dtype) > 0
             and width % pallas_moe.LANES == 0
             and rows % min(rows, pallas_moe.ROW_BLOCK) == 0
+            and rows % pallas_moe.grouped_tile(rows) == 0
             and tokens % min(tokens, pallas_moe.TOKEN_BLOCK) == 0
             and min(rows, tokens) % pallas_moe.SUBLANES == 0)
 
@@ -500,6 +507,30 @@ def _gated_bwd(kept, d_gated):
 _gated.defvjp(_gated_fwd, _gated_bwd)
 
 
+@jax.custom_vjp
+def _grouped(lhs, weights, walk):
+    """``lhs[r] @ weights[e(r)]`` over the rows that hold a pair, as
+    kernels forward and in both transposes (``lax.ragged_dot``'s
+    arithmetic: operands of ``lhs``'s type, float32 accumulation, one
+    rounding).  Every row tile a group reaches is written whole, zeros
+    past the last group's end; a tile past them holds what the memory
+    held."""
+    return _kernel("grouped_rows", lhs, weights, walk)
+
+
+def _grouped_fwd(lhs, weights, walk):
+    return _grouped(lhs, weights, walk), (lhs, weights, walk)
+
+
+def _grouped_bwd(kept, d_out):
+    lhs, weights, walk = kept
+    return (_kernel("grouped_rows_t", d_out, weights, walk),
+            _kernel("grouped_weights", lhs, d_out, walk), None)
+
+
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
 def routed_experts(x: jax.Array, router_kernel: jax.Array,
                    selection_bias: jax.Array, gate_kernels: jax.Array,
                    up_kernels: jax.Array, down_kernels: jax.Array, *,
@@ -519,7 +550,8 @@ def routed_experts(x: jax.Array, router_kernel: jax.Array,
     the caller found the layer's tokens on one TPU device
     (:func:`on_one_tpu`; the models ask outside ``init``, which wants
     the parameters' shapes and nothing of the layer), so the wide
-    passes are Pallas kernels wherever their tiles divide the shapes
+    passes AND the three grouped products with their transposes are
+    Pallas kernels wherever their tiles divide the shapes
     (:func:`kernels_fit`); the results are the same to a rounding.
 
     ``x``: ``[T, D]``.  ``router_kernel`` ``[D, E]`` and
@@ -533,7 +565,9 @@ def routed_experts(x: jax.Array, router_kernel: jax.Array,
     the routing can make it (:func:`dispatch_rows`), so its size grows
     with ``T x top_k`` and not with ``T x E``.  What grows with the
     pairs really sent is the grouped products' work, which skip the
-    rows past the groups' ends, the gather of the rows' gates, which
+    rows past the groups' ends (``ragged_dot`` by tiles of 512 rows, the
+    kernels by the (tile of 128, group) pairs that hold a row, walked
+    from the plan's ``group_sizes``), the gather of the rows' gates, which
     stops at the first chunk that starts past them
     (:func:`_gates_of_rows`), and, as kernels, the rows' gathers both
     ways, the sum of the rows' two cotangents and the gated product
@@ -565,8 +599,15 @@ def routed_experts(x: jax.Array, router_kernel: jax.Array,
         rows, rows_again = (checkpoint_name(rows, ROWS_NAME)
                             for rows in _dispatch(x, plan, kernels))
     with jax.named_scope("experts"):
-        def grouped(lhs, kernels):
-            return lax.ragged_dot(lhs, kernels.astype(dtype),
+        # The (row tile, group) pairs the three products and their six
+        # transposes walk, made once.
+        walk = kernels and _kernel("grouped_walk", plan.group_sizes,
+                                   rows.shape[0])
+
+        def grouped(lhs, weights):
+            if kernels:
+                return _grouped(lhs, weights.astype(dtype), walk)
+            return lax.ragged_dot(lhs, weights.astype(dtype),
                                   plan.group_sizes,
                                   preferred_element_type=dtype)
         a = checkpoint_name(grouped(rows, gate_kernels), EXPERT_GATE_UP_NAME)

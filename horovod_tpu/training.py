@@ -587,6 +587,32 @@ MOE_PAIRS_HELD = metrics.gauge(
     "batch, by layer (set by whoever counts a batch's choices)")
 
 
+MOE_GROUPED_TILE_FILL = metrics.gauge(
+    "hvd_moe_grouped_tile_fill",
+    "Rows of a sparse layer's sorted buffer that hold a pair over the rows "
+    "of the tiles its grouped kernels walk for them (a tile two experts "
+    "share counted once for each), of one batch, by layer: the share of the "
+    "grouped products' work that is some pair's (set by whoever counts a "
+    "batch's choices)")
+
+
+def set_grouped_tile_fill(choices: dict, first_expert: int, held: int):
+    """``hvd_moe_grouped_tile_fill{layer}`` from a batch's choices
+    (``{layer index: [T, top_k]}``, concrete: ``models/layers.py``
+    ``expert_choices`` gives them) for the experts ``first_expert ..
+    first_expert + held - 1``: what the grouped kernels of
+    ``ops/pallas_moe.py`` would walk for that batch, by their own
+    rule."""
+    from .ops import pallas_moe
+    from .parallel import moe
+    for layer, chosen in choices.items():
+        tokens, top_k = chosen.shape
+        counts = counts_by_expert(chosen, first_expert + held)[first_expert:]
+        MOE_GROUPED_TILE_FILL.set(float(pallas_moe.grouped_tile_fill(
+            counts, moe.dispatch_rows(tokens, top_k, held))),
+            layer=str(layer))
+
+
 def move_selection_bias(params, choices: dict, step: float):
     """``params`` with the selection bias of every sparse layer moved
     one step of the rule that balances its experts' loads

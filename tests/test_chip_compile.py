@@ -721,54 +721,113 @@ def test_moe_kernels_compile_at_the_cells_shapes(v5e_2x2, v5e_chip, cell,
                                          v5e_2x2.devices))
 
 
+def test_grouped_kernels_compile_at_the_widest_cells_shapes(v5e_chip):
+    """The three grouped kernels of ``ops/pallas_moe.py`` at the widest
+    of the four sparse cells' buffers (LFM2's: the others' blocks are
+    smaller, and the sparse steps below compile all three at a narrow
+    width for every family), into the experts' width and out of it,
+    with a group's weights twice over (2048 x 1536) and the float32
+    accumulator inside the VMEM they ask for."""
+    tokens, top_k, held, hidden, width = SPARSE_CELLS["lfm2-2x4096"]
+    assert moe.kernels_fit(tokens, top_k, held, hidden, width, jnp.bfloat16)
+    rows = moe.dispatch_rows(tokens, top_k, held)
+    of = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=v5e_chip)
+    walk = jax.tree.map(
+        lambda leaf: of(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda sizes: pallas_moe.grouped_walk(sizes, rows),
+                       of((held,), jnp.int32)))
+    assert walk.group.shape == (rows // 128 + held - 1,)
+    for k, n in ((hidden, width), (width, hidden)):
+        for which, args in (
+                ("grouped_rows", (of((rows, k)), of((held, k, n)))),
+                ("grouped_rows_t", (of((rows, n)), of((held, k, n)))),
+                ("grouped_weights", (of((rows, k)), of((rows, n))))):
+            text = getattr(pallas_moe, which).lower(
+                *args, walk).compile().as_text()
+            assert "hvd_moe_%s" % which in text
+
+
+# The four sparse families: the tiny config, the step builder, the
+# rules, the sparse layers of the tiny stack and the config's name for
+# the router's experts.
+SPARSE_FAMILIES = {
+    "lfm2": (lfm2_tiny_config, make_lfm2_train_step, lfm2_partition_rules,
+             2, "num_experts"),
+    "deepseek_v3": (deepseek_v3_tiny_config, make_deepseek_v3_train_step,
+                    deepseek_v3_partition_rules, 2, "n_routed_experts"),
+    "qwen3_next": (qwen3_next_tiny_config, make_qwen3_next_train_step,
+                   qwen3_next_partition_rules, 4, "num_experts"),
+    "afmoe": (afmoe_tiny_config, make_afmoe_train_step,
+              afmoe_partition_rules, 4, "num_experts")}
+# 512 tokens a chip: buffers of 1024 and (top 3) 1536 rows, whole
+# blocks of 512 and whole grouped tiles of 128
+SPARSE_TOKENS = 512
+
+
+@pytest.fixture(scope="module")
+def sparse_step_text(v5e_2x2):
+    """``text_of(family, axes)``: a sparse family's step at a hidden
+    size of whole tiles a row and experts of whole lanes (2048 and 128
+    bfloat16; the tiny models' 64 and 32 take XLA's passes anywhere),
+    four experts held of 32, made ONCE a family and mesh: compiled for
+    one described chip; on a 2 x 2 mesh lowered, which is where a
+    kernel would be chosen (the compile there adds nothing that is
+    asked)."""
+    texts = {}
+
+    def text_of(family, axes):
+        key = (family, tuple(axes.items()))
+        if key not in texts:
+            tiny, make, rules, _, experts = SPARSE_FAMILIES[family]
+            chips = math.prod(axes.values())
+            cfg = tiny(remat=True, hidden_size=2048,
+                       moe_intermediate_size=128, **{experts: 32})
+            assert cfg.experts_held == 4
+            mesh = build_mesh(axes, v5e_2x2.devices[:chips])
+            init_fn, step_fn, batch_sharding = make(cfg, mesh)
+            ids = jax.ShapeDtypeStruct((2 * chips, SPARSE_TOKENS // 2),
+                                       jnp.int32, sharding=batch_sharding)
+            state = jax.eval_shape(
+                init_fn, jax.ShapeDtypeStruct((2,), jnp.uint32), ids)
+            state = jax.tree.map(
+                lambda leaf, sharding: jax.ShapeDtypeStruct(
+                    leaf.shape, leaf.dtype, sharding=sharding),
+                state, infer_shardings(state, mesh, rules()))
+            lowered = step_fn.lower(*state, ids)
+            texts[key] = (cfg, lowered.compile().as_text() if chips == 1
+                          else lowered.as_text())
+        return texts[key]
+    return text_of
+
+
 @pytest.mark.parametrize("axes", [{"dp": 1}, {"dp": 2, "tp": 2}],
                          ids=["1chip", "dp2xtp2"])
-@pytest.mark.parametrize("family", ["lfm2", "deepseek_v3", "qwen3_next"])
+@pytest.mark.parametrize("family", list(SPARSE_FAMILIES))
 def test_sparse_steps_run_the_wide_passes_as_kernels_on_one_device(
-        v5e_2x2, family, axes):
-    """The three sparse steps at a hidden size of whole tiles a row
-    and experts of whole lanes (2048 and 128 bfloat16; the tiny models'
-    64 and 32 take XLA's passes anywhere), four experts held of 32:
-    on one described chip every sparse layer holds the ``hvd_moe_``
-    custom calls under its ``moe/dispatch`` and ``moe/combine`` scopes
-    (rows of tokens and tokens of rows, each forward and as the
-    other's transpose) and the gated product's under ``moe/experts``,
-    and neither a gather nor a ``select`` of the buffer's full ``[R,
-    D]`` shape is left under ``moe/dispatch`` or ``moe/combine``; on a
-    2 x 2 mesh no kernel, because GSPMD does not partition a Mosaic
-    call."""
-    tiny, make, rules, sparse_layers, experts = {
-        "lfm2": (lfm2_tiny_config, make_lfm2_train_step,
-                 lfm2_partition_rules, 2, "num_experts"),
-        "deepseek_v3": (deepseek_v3_tiny_config,
-                        make_deepseek_v3_train_step,
-                        deepseek_v3_partition_rules, 2, "n_routed_experts"),
-        "qwen3_next": (qwen3_next_tiny_config, make_qwen3_next_train_step,
-                       qwen3_next_partition_rules, 4, "num_experts")}[family]
-    chips = math.prod(axes.values())
-    cfg = tiny(remat=True, hidden_size=2048, moe_intermediate_size=128,
-               **{experts: 32})
-    assert cfg.experts_held == 4
-    mesh = build_mesh(axes, v5e_2x2.devices[:chips])
-    init_fn, step_fn, batch_sharding = make(cfg, mesh)
-    # 512 tokens a chip: buffers of 1024 and (top 3) 1536 rows, whole
-    # blocks of 512
-    tokens = 512
-    ids = jax.ShapeDtypeStruct((2 * chips, tokens // 2), jnp.int32,
-                               sharding=batch_sharding)
-    state = jax.eval_shape(init_fn, jax.ShapeDtypeStruct((2,), jnp.uint32),
-                           ids)
-    state = jax.tree.map(
-        lambda leaf, sharding: jax.ShapeDtypeStruct(
-            leaf.shape, leaf.dtype, sharding=sharding),
-        state, infer_shardings(state, mesh, rules()))
-    text = step_fn.lower(*state, ids).compile().as_text()
-    calls = Counter(re.findall(
-        r'custom_call_target="tpu_custom_call".*?moe/(\w+)/[^"]*?'
-        r'/(hvd_moe_\w+)/pallas_call', text))
-    if chips > 1:
+        sparse_step_text, family, axes):
+    """The four sparse steps: on one described chip every sparse layer
+    holds the ``hvd_moe_`` custom calls under its ``moe/dispatch`` and
+    ``moe/combine`` scopes (rows of tokens and tokens of rows, each
+    forward and as the other's transpose), the gated product's and the
+    three grouped kernels' under ``moe/experts`` (the product three
+    times, each transpose three times, none a second time: every name
+    of the layer is kept) and no ``ragged-dot``; neither a gather nor a
+    ``select`` of the buffer's full ``[R, D]`` shape is left under
+    ``moe/dispatch`` or ``moe/combine``.  On a 2 x 2 mesh no kernel,
+    because GSPMD does not partition a Mosaic call: the grouped
+    products are ``ragged_dot``."""
+    sparse_layers = SPARSE_FAMILIES[family][3]
+    cfg, text = sparse_step_text(family, axes)
+    if math.prod(axes.values()) > 1:
         assert "hvd_moe_" not in text
+        assert "ragged_dot" in text
         return
+    calls = Counter(re.findall(
+        r'custom_call_target="tpu_custom_call".*?(layer_\d+)/moe/(\w+)/'
+        r'[^"]*?/(hvd_moe_\w+)/pallas_call', text))
+    layers = sorted({layer for layer, _, _ in calls})
+    assert len(layers) == sparse_layers, layers
     # a layer: rows of tokens forward (dispatch) and backward (combine),
     # tokens of rows forward (combine) and backward (dispatch); no name
     # of the layer is dropped, so nothing runs a second time but the
@@ -777,15 +836,27 @@ def test_sparse_steps_run_the_wide_passes_as_kernels_on_one_device(
                  ("combine", "hvd_moe_rows_of_tokens"): 1,
                  ("combine", "hvd_moe_tokens_of_rows"): 1,
                  ("dispatch", "hvd_moe_tokens_of_rows"): 1,
-                 ("dispatch", "hvd_moe_add_rows"): 1}
-    for key, count in per_layer.items():
-        assert calls[key] == count * sparse_layers, (key, calls)
-    assert calls[("experts", "hvd_moe_gated")] >= sparse_layers
-    assert calls[("experts", "hvd_moe_gated_bwd")] >= sparse_layers
-    assert text.count('op_name="ragged-dot-none"') == 9 * sparse_layers
+                 ("dispatch", "hvd_moe_add_rows"): 1,
+                 ("experts", "hvd_moe_grouped_rows"): 3,
+                 ("experts", "hvd_moe_grouped_rows_t"): 3,
+                 ("experts", "hvd_moe_grouped_weights"): 3}
+    # trinity-mini's tiny stack drops names (its rule keeps attention's
+    # inputs first), so its forward kernels run again in the recomputed
+    # pass; the backward's own run once everywhere
+    backward = ("hvd_moe_add_rows", "hvd_moe_grouped_rows_t",
+                "hvd_moe_grouped_weights")
+    for layer in layers:
+        for (scope, name), count in per_layer.items():
+            ran = calls[(layer, scope, name)]
+            assert ran == count or (family == "afmoe" and ran > count
+                                    and name not in backward), (
+                layer, name, calls)
+        assert calls[(layer, "experts", "hvd_moe_gated")] >= 1
+        assert calls[(layer, "experts", "hvd_moe_gated_bwd")] >= 1
+    assert "ragged-dot" not in text and "ragged_dot" not in text
     # and XLA holds no pass over a whole buffer under the two scopes
     top_k = cfg.num_experts_per_tok
-    buffer = r"(bf16|f32)\[%d,2048\]" % (tokens * min(top_k, 4))
+    buffer = r"(bf16|f32)\[%d,2048\]" % (SPARSE_TOKENS * min(top_k, 4))
     left = [line for line in text.splitlines()
             if re.search(r"moe/(dispatch|combine)/", line)
             and re.search(r"= %s\S* (gather|select|fusion)\(" % buffer, line)
