@@ -16,6 +16,7 @@ from .lfm2 import LFM2Config, LFM2LMHeadModel, lfm2_tiny_config
 from .deepseek_v3 import (DeepseekV3Config, DeepseekV3LMHeadModel,
                           deepseek_v3_tiny_config)
 from .afmoe import AfmoeConfig, AfmoeLMHeadModel, afmoe_tiny_config
+from .keye_vl import KeyeVLConfig, KeyeVLLMHeadModel, keye_vl_tiny_config
 from .mnist import MnistCNN, MnistMLP, cross_entropy_loss
 from .dlrm import (DLRMConfig, DLRMDense, bce_logits_loss,
                    dlrm_tiny_config, synthetic_click_batch)
@@ -31,6 +32,7 @@ __all__ = [
     "LFM2Config", "LFM2LMHeadModel", "lfm2_tiny_config",
     "DeepseekV3Config", "DeepseekV3LMHeadModel", "deepseek_v3_tiny_config",
     "AfmoeConfig", "AfmoeLMHeadModel", "afmoe_tiny_config",
+    "KeyeVLConfig", "KeyeVLLMHeadModel", "keye_vl_tiny_config",
     "MnistCNN", "MnistMLP", "cross_entropy_loss",
     "DLRMConfig", "DLRMDense", "bce_logits_loss", "dlrm_tiny_config",
     "synthetic_click_batch",

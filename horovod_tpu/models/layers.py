@@ -4,9 +4,10 @@ positions, the sparse feed-forward, the rule for what a recomputed
 layer keeps and the loss over chunks of the sequence.
 
 A family's module (``gpt.py``, ``granite.py``, ``lfm2.py``,
-``deepseek_v3.py``, ``qwen3_next.py``, ``afmoe.py``) imports from here,
-from ``ops/`` and from ``parallel/``, never from another family's: a layer two
-families need lives here from the day the second one needs it.  Every
+``deepseek_v3.py``, ``qwen3_next.py``, ``afmoe.py``, ``keye_vl.py``)
+imports from here, from ``ops/`` and from ``parallel/``, never from
+another family's: a layer two families need lives here from the day the
+second one needs it.  Every
 module below is built with an explicit ``name=`` by its caller, so a
 parameter's path says nothing of this file.
 """
@@ -18,6 +19,7 @@ from typing import Any, Callable, Optional, Sequence, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding
 
@@ -174,12 +176,32 @@ def causal_depthwise_conv(x, kernel, bias):
     return out
 
 
-def rotary_tables(seq: int, head_dim: int, theta: float):
+def rotary_tables(seq: int, head_dim: int, theta: float,
+                  sections: Optional[Sequence[int]] = None,
+                  positions=None):
     """``cos`` and ``sin`` of ``position x theta^(-2i / head_dim)``,
-    each ``[seq, head_dim / 2]`` in float32."""
+    each ``[seq, head_dim / 2]`` in float32.  ``positions`` ``[streams,
+    seq]`` gives every position stream its own numbers and ``sections``
+    says how many consecutive frequency pairs ``i`` read each stream
+    (three sections of 16, 24 and 24: pair ``i`` of 64 reads stream 0
+    under 16, stream 1 under 40, stream 2 from there); with no
+    ``positions`` every stream counts 0, 1, 2, ..., which is the one
+    stream's tables whatever the sections."""
     inverse = 1.0 / theta ** (
         jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
-    angles = jnp.arange(seq, dtype=jnp.float32)[:, None] * inverse
+    if positions is None:
+        angles = jnp.arange(seq, dtype=jnp.float32)[:, None] * inverse
+        return jnp.cos(angles), jnp.sin(angles)
+    positions = jnp.asarray(positions, jnp.float32).reshape(-1, seq)
+    sections = tuple(sections or (head_dim // 2,))
+    if (len(sections) != positions.shape[0]
+            or sum(sections) != head_dim // 2):
+        raise ValueError(
+            "sections %r give each of %d position streams its share of the "
+            "%d frequency pairs" % (sections, positions.shape[0],
+                                    head_dim // 2))
+    stream = np.repeat(np.arange(len(sections)), sections)
+    angles = positions[stream].T * inverse
     return jnp.cos(angles), jnp.sin(angles)
 
 
@@ -191,6 +213,15 @@ def rotate(x, cos, sin):
     cos, sin = cos[:, None, :], sin[:, None, :]
     return jnp.concatenate([first * cos - second * sin,
                             second * cos + first * sin], -1).astype(x.dtype)
+
+
+def scaled_lecun_normal(scale: float = 1.0, **axes):
+    """``lecun_normal`` at ``scale`` times its standard deviation; at 1
+    the initialiser itself, value for value."""
+    if scale == 1.0:
+        return nn.initializers.lecun_normal(**axes)
+    return nn.initializers.variance_scaling(
+        scale * scale, "fan_in", "truncated_normal", **axes)
 
 
 class SparseFFN(nn.Module):
@@ -220,6 +251,9 @@ class SparseFFN(nn.Module):
     gate_sum_eps: float = moe.GATE_SUM_EPS
     shared: int = 0
     shared_gate: bool = False
+    # The factor on the initial scale of the stack that writes into the
+    # residual stream (``down``); 1 is every other matrix's.
+    down_scale: float = 1.0
     # The mesh the step this model is traced in lays its arrays on (the
     # step builder says, through ``heads_sharding``); None where the
     # model is applied directly.
@@ -228,8 +262,8 @@ class SparseFFN(nn.Module):
     @nn.compact
     def __call__(self, x):
         hidden, width = x.shape[-1], self.width
-        stacked = lambda name, fan_in, fan_out: self.param(
-            name, nn.initializers.lecun_normal(batch_axis=(0,)),
+        stacked = lambda name, fan_in, fan_out, scale=1.0: self.param(
+            name, scaled_lecun_normal(scale, batch_axis=(0,)),
             (self.held, fan_in, fan_out), jnp.float32)
         router = self.param("router", nn.initializers.lecun_normal(),
                             (hidden, self.experts), jnp.float32)
@@ -248,7 +282,7 @@ class SparseFFN(nn.Module):
         y, routing = moe.routed_experts(
             x.reshape(-1, hidden), router, bias,
             stacked("gate", hidden, width), stacked("up", hidden, width),
-            stacked("down", width, hidden),
+            stacked("down", width, hidden, self.down_scale),
             first_expert=self.first_expert, top_k=self.top_k,
             normalize=self.normalize, scale=self.scale,
             gate_sum_eps=self.gate_sum_eps, chosen=given,

@@ -206,15 +206,35 @@ def _tile_state(q0, k0, *, causal, window, sq, sk, skv):
     return run, masked
 
 
+def _both(a, b):
+    """``a and b`` of two conditions, each a plain bool or traced."""
+    if isinstance(a, bool):
+        return b if a else False
+    if isinstance(b, bool):
+        return a if b else False
+    return a & b
+
+
+def _no(a):
+    return (not a) if isinstance(a, bool) else jnp.logical_not(a)
+
+
+SELECTED = "selected"
+
+
 def _tiles(fn, i, j, *, causal, bq, bk, sq, sk, skv=None,
-           keys_outer=False, window=None, valid=True):
+           keys_outer=False, window=None, valid=True, selected_from=None):
     """Walk block (i, j) of the scores tile by tile: ``fn(masked, rows,
     cols, q0, k0)`` for every tile that is neither wholly above the
     causal diagonal, nor wholly left of the ``window``'s band, nor
     wholly padding; ``masked`` says whether the tile straddles one of
     the three (``skv``: the true number of keys, given where the keys
     are padded at all).  Nothing where the grid step is not ``valid``
-    (``_Walk``)."""
+    (``_Walk``).  In a call with a selection (``selected_from``: the
+    first query that does not keep every causal key) a tile that holds
+    such a query is ``masked`` ``SELECTED``: its scores count where the
+    selection's bits say, which hold the diagonal and the padding too;
+    a tile of earlier queries alone is the causal call's."""
     pairs = [(a, c) for a in range(bq // sq) for c in range(bk // sk)]
     if keys_outer:
         pairs.sort(key=lambda ac: (ac[1], ac[0]))
@@ -227,7 +247,13 @@ def _tiles(fn, i, j, *, causal, bq, bk, sq, sk, skv=None,
                                   sq=sq, sk=sk, skv=skv)
         if valid is not True:
             run = run & valid
-        if isinstance(masked, bool):
+        if selected_from is not None:
+            chosen = q0 + (sq - 1) >= selected_from
+            plain = _both(run, _no(chosen))
+            _when(_both(run, chosen), functools.partial(tile, SELECTED))
+            _when(_both(plain, masked), functools.partial(tile, True))
+            _when(_both(plain, _no(masked)), functools.partial(tile, False))
+        elif isinstance(masked, bool):
             _when(run, functools.partial(tile, masked))
         else:
             _when(run & masked, functools.partial(tile, True))
@@ -260,6 +286,18 @@ def _mask(s, offsets, q0, k0, q_dim: int, skv=None, window=None):
     return jnp.where(keep, s, NEG_INF)
 
 
+def _selected_scores(s, sel_ref, rows, cols):
+    """The scores ``s`` ``[keys, queries]`` of a tile with those the
+    selection leaves out at ``NEG_INF``.  ``sel_ref`` holds the block's
+    words of the packed mask (``ops/dsa.py``: ``[1, keys / 32,
+    queries]`` int32, a tile of keys ``keys / 32`` rows of it, bit ``b``
+    of row ``r`` the tile's key ``b * keys / 32 + r``): 32 shifts of the
+    tile's words, each ``keys / 32`` consecutive rows of the scores."""
+    from .dsa import unpack_tile
+    words = sel_ref[0, cols.start // 32:cols.stop // 32, rows]
+    return jnp.where(unpack_tile(words), s, NEG_INF)
+
+
 def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
     return jax.lax.dot_general(a, b, dims,
                                preferred_element_type=jnp.float32)
@@ -273,7 +311,7 @@ def _head_lanes(h: int, d: int, dv: int):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
                 vt_ref, *, causal, nq, nk, heads, d, dv, bq, bk, sq, sk, skv,
-                window, steps):
+                window, steps, sel_ref=None, selected_from=None):
     """Scores, statistics and accumulator all transposed ([keys,
     queries], [1, queries], [D, queries]): the running max and sum of
     a query then lie along the lanes, a few registers a tile, and
@@ -304,7 +342,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
             vt = vt_ref[vlanes, cols]                       # [Dv, sk]
             st = _dot(k_ref[0, cols, lanes], q_ref[0, rows, lanes],
                       _NT)                          # q arrives scaled
-            if masked:
+            if masked == SELECTED:
+                st = _selected_scores(st, sel_ref, rows, cols)
+            elif masked:
                 st = _mask(st, offsets, q0, k0, 1, skv, window)  # [sk, sq]
             m_prev = m_ref[h, :, rows]                      # [1, sq]
             m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
@@ -317,7 +357,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
             m_ref[h, :, rows] = m_new
 
         _tiles(tile, i, j, causal=causal, bq=bq, bk=bk, sq=sq, sk=sk,
-               skv=skv, keys_outer=True, window=window, valid=walk.valid)
+               skv=skv, keys_outer=True, window=window, valid=walk.valid,
+               selected_from=selected_from)
 
     def finalize():
         l = l_ref[:]                                        # [heads, 1, bq]
@@ -331,7 +372,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
                 dk_ref, dv_ref, *rest,
                 scale, causal, nq, nk, heads, d, dv, bq, bk, sq, sk, skv,
-                window, steps):
+                window, steps, sel_ref=None, selected_from=None):
     """Scores transposed, as in the forward: ``lse`` and ``di`` come
     as rows and broadcast down the sublanes.  dK and dV are summed over
     the q blocks, the grid's last dimension.
@@ -375,7 +416,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
                  vlanes=vlanes):
             q, do = q_ref[0, rows, lanes], do_ref[0, rows, vlanes]
             st = _dot(k_ref[0, cols, lanes], q, _NT)        # [sk, sq]
-            if masked:
+            if masked == SELECTED:
+                st = _selected_scores(st, sel_ref, rows, cols)
+            elif masked:
                 st = _mask(st, offsets, q0, k0, 1, skv, window)
             pt = jnp.exp(st - lse_ref[0, h, :, rows])       # rows: [1, sq]
             dv_acc[cols, vlanes] += _dot(pt.astype(do.dtype), do)
@@ -386,7 +429,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
                 dqt_acc[i, lanes, rows] += _dot(kt_ref[lanes, cols], dst)
 
         _tiles(tile, i, j, causal=causal, bq=bq, bk=bk, sq=sq, sk=sk,
-               skv=skv, keys_outer=True, window=window, valid=walk.valid)
+               skv=skv, keys_outer=True, window=window, valid=walk.valid,
+               selected_from=selected_from)
 
     def finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
@@ -570,6 +614,13 @@ class _Plan:
         return pl.BlockSpec((1, self.g, 1, self.bq),
                             lambda *ids: (ids[0], ids[1], 0, of(ids)))
 
+    def sel_words(self):
+        """The words of a packed selection (``ops/dsa.py``) that a q
+        block and a kv block share: ``[B, keys / 32, queries]``."""
+        q_of, k_of = self._block(self.q_at), self._block(self.k_at)
+        return pl.BlockSpec((1, self.bk // 32, self.bq),
+                            lambda *ids: (ids[0], k_of(ids), q_of(ids)))
+
     def q_whole(self):
         """Every (padded) row of the group's queries, whichever block
         the grid is at: the fused backward's dQ, written back once."""
@@ -594,24 +645,56 @@ class _Plan:
             interpret=interpret, name=name)
 
 
-def _named(kernel: str, window) -> str:
-    """A window call's kernels under names of their own, so that a
-    trace tells a band's walk from a triangle's."""
+def _named(kernel: str, window, selected=None) -> str:
+    """A window call's kernels, and those of a call with a selection,
+    under names of their own, so that a trace tells a band's walk and a
+    masked triangle's from a triangle's."""
+    if selected is not None:
+        return kernel + "_selected"
     return kernel if window is None else kernel + "_window"
 
 
+def _with_selection(kernel, inputs: int, p: "_Plan", selected, topk):
+    """``kernel`` taking the block's words of ``selected`` (the packed
+    mask, padded to ``p``'s blocks) as one more input after the
+    ``inputs`` it has, or as it is where ``selected`` is None:
+    ``(kernel, operands, specs)``, the last two to be appended to the
+    call's."""
+    if selected is None:
+        return kernel, (), []
+    if p.sk % 32 or (p.sq_len, p.bq) != (p.skv_len, p.bk):
+        raise ValueError(
+            "a selection is of one sequence's own keys, in tiles of whole "
+            "words: as many keys as queries in blocks of one size (got %d "
+            "queries in blocks of %d, %d keys in blocks of %d, tiles of %d)"
+            % (p.sq_len, p.bq, p.skv_len, p.bk, p.sk))
+    words = jnp.pad(selected, (
+        (0, 0), (0, p.skv_pad // 32 - selected.shape[1]),
+        (0, p.sq_pad - selected.shape[2])))
+
+    def taking(*refs, **sizes):
+        return kernel(*refs[:inputs], *refs[inputs + 1:],
+                      sel_ref=refs[inputs], selected_from=topk, **sizes)
+    return taking, (words,), [p.sel_words()]
+
+
 @functools.partial(jax.jit, static_argnames=(
-    "heads", "causal", "tile", "seq_block", "interpret", "window"))
-def _fwd_call(q, k, v, *, heads, causal, tile, seq_block, interpret,
-              window=None):
+    "heads", "causal", "tile", "seq_block", "interpret", "window", "topk"))
+def _fwd_call(q, k, v, selected=None, *, heads, causal, tile, seq_block,
+              interpret, window=None, topk=None):
     """``q`` (scaled), ``k``: [B, S, H * D]; ``v``: [B, S, H * Dv].
     Returns the output [B, Sq, H * Dv] and the log-sum-exp of every
-    (padded) row, [B, H, 1, Sq padded]."""
+    (padded) row, [B, H, 1, Sq padded].  ``selected``: a packed
+    selection of keys a query (``ops/dsa.py``), ``topk`` the first query
+    that does not keep every causal key."""
     p = _Plan(q, k, v, heads, tile, seq_block, 2, window)
+    kernel, words, words_spec = _with_selection(_fwd_kernel, 3, p, selected,
+                                                topk)
     out, lse = p.call(
-        functools.partial(_fwd_kernel, causal=causal, **p.sizes),
-        _named("hvd_flash_fwd", window),
-        [p.q_rows(), p.k_rows(), p.v_rows()], [p.o_rows(), p.q_stats()],
+        functools.partial(kernel, causal=causal, **p.sizes),
+        _named("hvd_flash_fwd", window, selected),
+        [p.q_rows(), p.k_rows(), p.v_rows()] + words_spec,
+        [p.o_rows(), p.q_stats()],
         [jax.ShapeDtypeStruct((p.batch, p.sq_pad, heads * p.dv), q.dtype),
          jax.ShapeDtypeStruct((p.batch, heads, 1, p.sq_pad), jnp.float32)],
         [pltpu.VMEM((p.g, 1, p.bq), jnp.float32),
@@ -619,7 +702,8 @@ def _fwd_call(q, k, v, *, heads, causal, tile, seq_block, interpret,
          pltpu.VMEM((p.g, p.dv, p.bq), jnp.float32),
          pltpu.VMEM((p.g * p.dv, p.bk), v.dtype)],
         interpret,
-    )(_pad_seq(q, p.sq_pad), _pad_seq(k, p.skv_pad), _pad_seq(v, p.skv_pad))
+    )(_pad_seq(q, p.sq_pad), _pad_seq(k, p.skv_pad), _pad_seq(v, p.skv_pad),
+      *words)
     return out[:, :p.sq_len], lse
 
 
@@ -652,9 +736,9 @@ _DQ_VMEM_BYTES = metrics.gauge(
 
 @functools.partial(jax.jit, static_argnames=(
     "heads", "scale", "causal", "tile", "seq_block", "interpret",
-    "dq_budget", "window"))
-def _bwd_call(q, k, v, lse, do, di, *, heads, scale, causal, tile,
-              seq_block, interpret, dq_budget, window=None):
+    "dq_budget", "window", "topk"))
+def _bwd_call(q, k, v, lse, do, di, selected=None, *, heads, scale, causal,
+              tile, seq_block, interpret, dq_budget, window=None, topk=None):
     """dQ, dK, dV of ``_fwd_call`` (``q`` scaled; dQ is for the
     unscaled one).  ``di`` is ``sum(o * do)`` a row, [B, H, 1, Sq].
     One kernel where dQ's accumulator fits ``dq_budget`` bytes, the
@@ -664,6 +748,13 @@ def _bwd_call(q, k, v, lse, do, di, *, heads, scale, causal, tile,
     _LOWERINGS.inc(1, form="fused" if fused else "split")
     _LOWERINGS.inc(0, form="split" if fused else "fused")   # reads 0, not absent
     args, specs = _bwd_operands(p, q, k, v, lse, do, di)
+    kernel, words, words_spec = _with_selection(_bwd_kernel, len(args), p,
+                                                selected, topk)
+    if selected is not None and not fused:
+        raise ValueError(
+            "a call with a selection has the fused backward alone: dQ of %d "
+            "rows takes %d bytes of VMEM, over %d"
+            % (p.sq_pad, p.dq_bytes(), dq_budget))
     out_specs = [p.k_rows(), p.v_rows()]
     out_shape = [jax.ShapeDtypeStruct(args[1].shape, k.dtype),
                  jax.ShapeDtypeStruct(args[2].shape, v.dtype)]
@@ -681,12 +772,12 @@ def _bwd_call(q, k, v, lse, do, di, *, heads, scale, causal, tile,
         vmem_limit = (p.dq_bytes() + 2 * p.sq_pad * p.g * p.d
                       * q.dtype.itemsize + DEFAULT_VMEM_BYTES)
     dk, dv, *dq = p.call(
-        functools.partial(_bwd_kernel, causal=causal, scale=scale,
-                          **p.sizes),
-        _named("hvd_flash_bwd" if fused else "hvd_flash_bwd_dkv", window),
-        specs, out_specs, out_shape, scratch, interpret,
+        functools.partial(kernel, causal=causal, scale=scale, **p.sizes),
+        _named("hvd_flash_bwd" if fused else "hvd_flash_bwd_dkv", window,
+               selected),
+        specs + words_spec, out_specs, out_shape, scratch, interpret,
         carried="arbitrary" if fused else "parallel",
-        vmem_limit=vmem_limit)(*args)
+        vmem_limit=vmem_limit)(*args, *words)
     if fused:
         dq, = dq
     else:
@@ -744,6 +835,85 @@ def _flash_vjp_bwd(scale, causal, tile, seq_block, interpret, window, res,
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_selected(q, k, v, selected, scale, tile, seq_block, interpret,
+                    topk):
+    return _flash_selected_fwd(q, k, v, selected, scale, tile, seq_block,
+                               interpret, topk)[0]
+
+
+def _flash_selected_fwd(q, k, v, selected, scale, tile, seq_block, interpret,
+                        topk):
+    B, Sq, H, D = q.shape
+    q, k, v = ((q * scale).reshape(B, Sq, H * D),
+               k.reshape(B, -1, H * D), v.reshape(B, -1, H * D))
+    out, lse = _fwd_call(q, k, v, selected, heads=H, causal=True, tile=tile,
+                         seq_block=seq_block, interpret=interpret, topk=topk)
+    out = checkpoint_name(out.reshape(B, Sq, H, D), "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")
+    return (out, lse[:, :, 0, :Sq]), (q, k, v, selected, out, lse)
+
+
+def _flash_selected_bwd(scale, tile, seq_block, interpret, topk, res, cts):
+    q, k, v, selected, out, lse = res
+    do, _ = cts     # the statistics are handed on detached
+    B, Sq, H, D = do.shape
+    di = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+    di = di.transpose(0, 2, 1)[:, :, None, :]
+    grads = _bwd_call(q, k, v, lse, do.reshape(B, Sq, H * D), di, selected,
+                      heads=H, scale=scale, causal=True, tile=tile,
+                      seq_block=seq_block, interpret=interpret,
+                      dq_budget=FUSED_DQ_BYTES, topk=topk)
+    return tuple(g.reshape(B, g.shape[1], H, -1) for g in grads) + (None,)
+
+
+_flash_selected.defvjp(_flash_selected_fwd, _flash_selected_bwd)
+
+
+def selected_tiles(seq: int, topk: int, tile=TILE,
+                   seq_block: int = SEQ_BLOCK) -> dict:
+    """What a call with a selection does with one head's square of
+    ``seq`` queries and keys, from the static shapes and by the
+    kernels' own rule (``_tiles``): tiles ``walked`` (every tile on or
+    under the diagonal: the selected keys lie scattered over all of
+    them), of them ``masked`` (on the diagonal, or holding a query from
+    ``topk`` on, whose scores count by the selection's bits), and
+    ``skipped`` for holding no selected pair, which no occupancy table
+    says yet: 0."""
+    sq, _, padded = _blocking(seq, tile[0], seq_block)
+    sk = _blocking(seq, tile[1], seq_block)[0]
+    walked = masked = 0
+    for q0 in range(0, padded, sq):
+        for k0 in range(0, padded, sk):
+            run, mask = _tile_state(q0, k0, causal=True, window=None,
+                                    sq=sq, sk=sk, skv=None)
+            walked += run
+            masked += run and (mask or q0 + sq - 1 >= topk)
+    return {"walked": walked, "masked": masked, "skipped": 0}
+
+
+def flash_attention_selected(q: jax.Array, k: jax.Array, v: jax.Array,
+                             selected: jax.Array, topk: int,
+                             scale: Optional[float] = None,
+                             interpret: bool = False):
+    """Causal flash attention over the keys a SELECTION keeps for each
+    query, one selection for every head: ``selected`` is the packed mask
+    of ``ops/dsa.py`` (``[B, S / 32, S]`` int32, a subset of the causal
+    pairs, in tiles of the kernels' ``TILE`` of keys, or of the whole
+    sequence where it is shorter), ``topk`` the first query that does
+    not keep every causal key (tiles of earlier queries carry the
+    causal call's arithmetic and never read the words).  Returns the output ``[B, S, H, D]``,
+    differentiable in ``q``, ``k`` and ``v``, and each row's log-sum-exp
+    over its keys ``[B, H, S]`` float32, which is not.  The kernels are
+    ``hvd_flash_fwd_selected`` and ``hvd_flash_bwd_selected``: every
+    tile on or under the diagonal is walked."""
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
+    tile = (TILE[0], min(TILE[1], q.shape[1]))
+    return _flash_selected(q, k, v, selected, float(scale), tile, SEQ_BLOCK,
+                           bool(interpret), int(topk))
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,

@@ -168,6 +168,30 @@ def afmoe_partition_rules(tp: str = "tp", fsdp: Optional[str] = None,
     ]
 
 
+def keye_vl_partition_rules(tp: str = "tp", fsdp: Optional[str] = None,
+                            ep: str = "ep") -> Rules:
+    """Sharding for the Keye-VL family (models/keye_vl.py).  Attention
+    and its indexer are whole on every device of ``tp`` (the alignment
+    loss reads ALL heads' probabilities of a query, and one selection
+    serves them all): data-parallel, as the published deployment has
+    them.  Over ``tp``: every routed expert over its columns, the
+    embedding and the head, two matrices, by rows of the vocabulary.
+    The stacked experts' leading axis lies on ``ep`` where the mesh has
+    one, the router whole everywhere, and no exchange is written for
+    ``ep`` yet, as for :func:`lfm2_partition_rules`."""
+    f = fsdp
+    return [
+        (r"word_embeddings/embedding$", P(tp, f)),
+        (r"lm_head$", P(tp, f)),
+        (r"attention/(query|key|value|indexer_query)/kernel$",
+         P(f, None, None)),
+        (r"attention/out/kernel$", P(None, None, f)),
+        (r"moe/(gate|up)$", P(ep, f, tp)),
+        (r"moe/down$", P(ep, tp, f)),
+        (r".*", P()),  # norms, the router, the indexer's key and weights
+    ]
+
+
 def qwen3_next_partition_rules(tp: str = "tp", fsdp: Optional[str] = None,
                                ep: str = "ep") -> Rules:
     """Sharding for the Qwen3-Next family (models/qwen3_next.py).  Over

@@ -587,6 +587,47 @@ MOE_PAIRS_HELD = metrics.gauge(
     "batch, by layer (set by whoever counts a batch's choices)")
 
 
+_DSA_TOPK = metrics.gauge(
+    "hvd_dsa_topk",
+    "Keys a query of a step's sparse-attention layers keeps, chosen by the "
+    "layer's indexer (set when the step is traced)")
+_DSA_INDEXER_HEADS = metrics.gauge(
+    "hvd_dsa_indexer_heads",
+    "Heads of a sparse-attention layer's indexer, over its one key head "
+    "(set when the step is traced)")
+_DSA_INDEXER_HEAD_DIM = metrics.gauge(
+    "hvd_dsa_indexer_head_dim",
+    "Width of a head of a sparse-attention layer's indexer (set when the "
+    "step is traced)")
+_DSA_PAIRS = metrics.gauge(
+    "hvd_dsa_pairs",
+    "Pairs of query and key of one sequence and one head in a "
+    "sparse-attention layer: which=selected those the selection keeps, "
+    "which=causal those on or under the diagonal (set when the step is "
+    "traced)")
+_DSA_SELECT_BYTES = metrics.gauge(
+    "hvd_dsa_select_bytes",
+    "Bytes one layer's selection writes on one device in its forward pass: "
+    "the packed mask, a bit a pair of query and key, and a float32 a query "
+    "of the selected index scores' log-sum-exp (set when the step is traced)")
+_DSA_TILES = metrics.gauge(
+    "hvd_dsa_tiles",
+    "Tiles of one head's square of scores in a sparse-attention layer's "
+    "flash kernels: which=walked computed, which=masked of those the ones "
+    "that read the selection's bits or straddle the diagonal, which=skipped "
+    "those left out for holding no selected pair (from the static shapes, "
+    "set when the step is traced)")
+_ROPE_SECTIONS = metrics.gauge(
+    "hvd_rope_sections",
+    "Frequency pairs of a head that read each position stream of a step's "
+    "sectioned rotary positions, by stream (set when the step is traced)")
+DSA_SELECTION_AGREEMENT = metrics.gauge(
+    "hvd_dsa_selection_agreement",
+    "Share of the pairs of query and key a reference selection keeps that "
+    "the program's own selection keeps too, of one batch, by layer (set by "
+    "whoever compares the two)")
+
+
 MOE_GROUPED_TILE_FILL = metrics.gauge(
     "hvd_moe_grouped_tile_fill",
     "Rows of a sparse layer's sorted buffer that hold a pair over the rows "
@@ -687,6 +728,10 @@ class CausalLMFamily:
     record: Callable = lambda config, mesh, sequences, seq: None
     # The step program's compiler options, of the mesh.
     compiler_options: Callable = lambda mesh: None
+    # ``step_loss(model, params, ids, logits_scale=)``: the loss the
+    # step differentiates; where the row has a ``bias_step`` it takes
+    # ``with_choices=True`` too and returns the choices beside it.
+    step_loss: Callable = causal_lm_step_loss
     # The step of the rule that moves the sparse layers' selection bias
     # once a step, of the config (``move_selection_bias``); None where
     # the family has no rule: its bias stays where it was initialised
@@ -731,7 +776,7 @@ def _make_causal_lm_train_step(family: CausalLMFamily, config, mesh,
     batch_sharding = NamedSharding(mesh, P(batch_axis, None))
     heads_sharding = _heads_sharding(mesh, batch_axis)
     model = family.model(config, heads_sharding=heads_sharding)
-    step_loss = partial(causal_lm_step_loss,
+    step_loss = partial(family.step_loss,
                         logits_scale=family.logits_scale(config))
     bias_step = family.bias_step(config)
 
@@ -1003,6 +1048,81 @@ def afmoe_step_loss(model, params, ids, chosen=None):
     a matrix of its own; ``chosen`` (``models.afmoe.expert_choices``'s)
     hands the sparse layers their choice of experts."""
     return causal_lm_step_loss(model, params, ids, chosen)
+
+
+def keye_vl_loss_parts(model, params, ids, chosen=None, selected=None,
+                       positions=None, logits_scale: float = 1.0):
+    """The two parts of the Keye-VL step's objective and what the
+    layers sowed: ``(L_lm, sum over the layers of L_I, sown)``.  The
+    next-token loss as every causal-LM step has it; ``L_I`` a layer's
+    alignment loss of its indexer against its attention's own
+    probabilities (``ops/dsa.py`` ``indexer_loss``).  ``chosen`` hands
+    the sparse layers their choice of experts, ``selected`` (``{layer:
+    packed mask}``) the attention layers their selection of keys;
+    ``positions`` ``[3, S]`` the three position streams."""
+    from .models import keye_vl
+    (hidden, head), sown = model.apply(
+        {"params": params, **keye_vl.given(chosen, selected)}, ids,
+        positions, method="hidden_and_embedding", mutable=["intermediates"])
+    lm = _tied_head_loss(model.heads_sharding, hidden, head, ids,
+                         logits_scale)
+    aligned = sum(keye_vl.sown_of(sown, "indexer_loss").values())
+    return lm, aligned, sown
+
+
+def keye_vl_step_loss(model, params, ids, chosen=None, selected=None,
+                      positions=None, logits_scale: float = 1.0):
+    """The loss of ``make_keye_vl_train_step``'s step, ``L_lm + sum of
+    L_I`` (``keye_vl_loss_parts``): by construction the indexer's leaves
+    get their gradient from the second part alone and every other leaf
+    from the first alone."""
+    lm, aligned, _ = keye_vl_loss_parts(
+        model, params, ids, chosen, selected, positions, logits_scale)
+    return lm + aligned
+
+
+def _record_keye_vl(config, mesh, sequences: int, seq: int):
+    from .ops import dsa, pallas_attention
+    _DSA_TOPK.set(config.topk)
+    _DSA_INDEXER_HEADS.set(config.indexer_num_heads)
+    _DSA_INDEXER_HEAD_DIM.set(config.indexer_head_dim)
+    _DSA_PAIRS.set(dsa.selected_pairs(seq, config.topk), which="selected")
+    _DSA_PAIRS.set(dsa.causal_pairs(seq), which="causal")
+    _DSA_SELECT_BYTES.set(dsa.select_bytes(sequences, seq))
+    tiles = pallas_attention.selected_tiles(seq, config.topk)
+    for which in ("walked", "masked", "skipped"):
+        _DSA_TILES.set(tiles[which], which=which)
+    for stream, pairs in enumerate(config.mrope_section):
+        _ROPE_SECTIONS.set(pairs, stream=str(stream))
+    _ATTENTION_KV_REPEAT.set(config.num_attention_heads
+                             // config.num_key_value_heads)
+    _ATTENTION_HEAD_DIM.set(config.head_dim)
+    _record_moe(config, sequences * seq, config.num_experts)
+    _MOE_ROUTER.set(1, kind="softmax")
+    _MOE_SHARED_WIDTH.set(0)
+
+
+def _keye_vl_family() -> CausalLMFamily:
+    from .models import keye_vl
+    from .parallel.sharding import keye_vl_partition_rules
+    return CausalLMFamily(
+        "keye_vl", keye_vl.KeyeVLLMHeadModel, keye_vl_partition_rules,
+        keye_vl.REMAT_CANDIDATES, keye_vl.remat_bytes,
+        record=_record_keye_vl, step_loss=keye_vl_step_loss)
+
+
+def make_keye_vl_train_step(config, mesh, learning_rate: float = 1e-4,
+                            weight_decay: float = 0.1,
+                            fsdp: Optional[str] = None):
+    """Sharded causal-LM training step for the Keye-VL family
+    (``models/keye_vl.py``: attention over the keys a learned indexer
+    keeps, softmax-routed experts in every layer),
+    ``_make_causal_lm_train_step`` over its row: the next-token loss
+    plus every layer's alignment loss, AdamW with decay on matrices
+    only."""
+    return _make_causal_lm_train_step(
+        _keye_vl_family(), config, mesh, fsdp, learning_rate,
+        weight_decay=weight_decay)
 
 
 def _like_layers_compiled_once(mesh) -> Optional[dict]:

@@ -16,8 +16,8 @@ from flax.traverse_util import flatten_dict
 
 import horovod_tpu as hvd
 from horovod_tpu import training
-from horovod_tpu.models import (afmoe, deepseek_v3, gpt, granite, layers,
-                                lfm2, qwen3_next)
+from horovod_tpu.models import (afmoe, deepseek_v3, gpt, granite, keye_vl,
+                                layers, lfm2, qwen3_next)
 from horovod_tpu.parallel.mesh import build_mesh
 
 # A v5e's ``memory_stats()["bytes_limit"]``.
@@ -103,6 +103,20 @@ FAMILIES = {
             (1, 16384, None, afmoe.REMAT_NAMES),
             (2, 16384, V5E, afmoe.KEPT_NAMES + ("gate_up",)),
             (1, 16384, 12_000_000_000, afmoe.KEPT_NAMES)]),
+    "keye_vl": dict(
+        row=training._keye_vl_family, make=training.make_keye_vl_train_step,
+        tiny=keye_vl.keye_vl_tiny_config,
+        cell=keye_vl.KeyeVLConfig(
+            vocab_size=18992, num_hidden_layers=6, experts_held=16),
+        state=12 * 659_190_016, kept=[
+            # attention's projections (1.0 GB) and the routed experts'
+            # gate and up (2.4 GB) fit, the sorted rows (3.2 GB) do not
+            (1, 16384, V5E, keye_vl.KEPT_NAMES + (
+                keye_vl.ATTENTION_IN_NAME, "moe_gate_up")),
+            (2, 16384, V5E, keye_vl.KEPT_NAMES + (
+                keye_vl.ATTENTION_IN_NAME,)),
+            (1, 16384, None, keye_vl.REMAT_NAMES),
+            (1, 16384, 11_000_000_000, keye_vl.KEPT_NAMES)]),
 }
 every_family = pytest.mark.parametrize("family", list(FAMILIES))
 
@@ -322,7 +336,7 @@ def test_lfm2_takes_a_given_choice_as_the_other_two_do():
 
 MODELS = os.path.dirname(layers.__file__)
 FAMILY_MODULES = ["gpt", "granite", "lfm2", "deepseek_v3", "qwen3_next",
-                  "afmoe"]
+                  "afmoe", "keye_vl"]
 
 
 @pytest.mark.parametrize("module", FAMILY_MODULES + ["layers"])

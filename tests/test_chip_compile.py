@@ -184,6 +184,52 @@ def test_flash_window_kernels_compile_for_v5e(v5e_chip, shape, window, steps):
     assert largest * 4 <= batch * heads * seq * seq, largest
 
 
+@pytest.mark.parametrize("seq,heads,kv_heads", [
+    # The Keye-VL cell's layers: 32 x 32 tiles of 512, 2048 keys a query.
+    (16384, 32, 4),
+    (1024, 4, 2)],                  # one block of the flash kernels
+    ids=["keye-1x16384-top2048", "s1024"])
+def test_the_selection_and_its_kernels_compile_for_v5e(v5e_chip, seq, heads,
+                                                      kv_heads):
+    """A sparse-attention layer's core at the published widths lowers to
+    its four Mosaic kernels, the selection, the two flash kernels under
+    its bits and the alignment loss, once each; and nothing with two
+    sequence dimensions is left for XLA to hold but the packed mask, a
+    bit a pair."""
+    from horovod_tpu.ops import dsa
+    from horovod_tpu.ops.pallas_attention import flash_attention_selected
+    topk, scale = seq // 8, 128 ** -0.5
+    of = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=v5e_chip)
+
+    def loss(q_i, k_i, w, q, k, v):
+        selected, lse_i = dsa.select(q_i, k_i, w, topk, kernels=True)
+        group = heads // kv_heads
+        out, lse = flash_attention_selected(
+            q, jnp.repeat(k, group, 2), jnp.repeat(v, group, 2), selected,
+            topk, scale=scale)
+        return jnp.sum(out.astype(jnp.float32) ** 2) + dsa.indexer_loss(
+            q_i, k_i, w, q, k, lse, selected, lse_i, scale, kernels=True)
+
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        of(1, seq, 16, 64), of(1, seq, 64), of(1, seq, 16, dtype=jnp.float32),
+        of(1, seq, heads, 128), of(1, seq, kv_heads, 128),
+        of(1, seq, kv_heads, 128)).compile().as_text()
+    kernels = Counter(re.findall(
+        r'custom_call_target="tpu_custom_call".*?/(hvd_\w+)/', text))
+    assert kernels == {"hvd_dsa_select": 1, "hvd_flash_fwd_selected": 1,
+                       "hvd_flash_bwd_selected": 1,
+                       "hvd_dsa_indexer_loss": 1}, kernels
+    largest = max(math.prod(int(n) for n in dims.split(","))
+                  for dims in re.findall(r"(?:f32|bf16|s32)\[([0-9,]+)\]",
+                                         text))
+    # the keys laid out a query head (or the index queries) are the
+    # largest; the packed mask is S x S / 32 words and the alignment
+    # loss's parts of the keys' gradient [blocks of 512 queries, S, 64]
+    assert largest == seq * max(heads * 128, 16 * 64), largest
+    assert largest * 4 <= seq * seq or seq < 4096, largest
+
+
 def _one_loss_chunk_of(tokens: int):
     """What the step just traced put on record of its loss's walk: one
     chunk (these batches are short) of the ``tokens`` ONE described

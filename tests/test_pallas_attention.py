@@ -65,13 +65,31 @@ def _grads(attend, q, k, v):
     return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
 
+def _out_and_grads(attend, q, k, v):
+    """``attend``'s output (its first, where it returns several, and
+    then the rest beside it) and ``_grads``'s three gradients from ONE
+    jitted program: the forward kernel is traced and lowered once, which
+    is most of what an interpreter case costs."""
+    def loss(q, k, v):
+        got = attend(q, k, v)
+        out = got[0] if isinstance(got, tuple) else got
+        return jnp.mean(out.astype(jnp.float32) ** 2), got
+    (_, got), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    return got, grads
+
+
 # ``test_flash_gradients_match`` of PR 21 is the first case.
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-4),
                                        (jnp.bfloat16, 3e-2)],
                          ids=["f32", "bf16"])
-@pytest.mark.parametrize("blocks", [(16, 16), (48, 24)],
-                         ids=["16x16", "48x24"])
-@pytest.mark.parametrize("seq", [64, 50], ids=["even", "ragged"])
+# 32 and 26 rows: two tiles of 16 a side (and three of 12 keys under two
+# of 24 queries), the smallest that still cross a tile, leave a ragged
+# one and pad a block; every tile is a body traced, which is what an
+# interpreter case costs.
+@pytest.mark.parametrize("blocks", [(16, 16), (24, 12)],
+                         ids=["16x16", "24x12"])
+@pytest.mark.parametrize("seq", [32, 26], ids=["even", "ragged"])
 @pytest.mark.parametrize("causal", [True, False],
                          ids=["causal", "full"])
 def test_flash_backward_kernels_match_reference(qkv, causal, seq, blocks,
@@ -353,9 +371,12 @@ def test_flash_compiled_is_refused_off_tpu(qkv):
 # on queries and keys alone).  Neither case has a count of heads under
 # all of them that fills whole lanes at both widths, so a block takes
 # them all: 4 heads of 24 and 16, 6 heads of 64 and 32.
-@pytest.mark.parametrize("heads,d,dv", [(4, 24, 16), (6, 64, 32)],
+# Two heads at each pair of widths: of neither does a count fill the
+# lanes at both (``test_a_block_holds_the_same_heads_of_both_widths``
+# has the counts), so a block holds every head, as it held four and six.
+@pytest.mark.parametrize("heads,d,dv", [(2, 24, 16), (2, 64, 32)],
                          ids=["24-16", "64-32"])
-@pytest.mark.parametrize("seq,seq_block", [(64, 64), (50, 32)],
+@pytest.mark.parametrize("seq,seq_block", [(32, 32), (50, 32)],
                          ids=["one-block", "ragged-blocks"])
 def test_flash_values_narrower_than_keys(heads, d, dv, seq, seq_block, form):
     """Forward and the three gradients against the einsum, causal,
@@ -370,11 +391,10 @@ def test_flash_values_narrower_than_keys(heads, d, dv, seq, seq_block, form):
         return pallas_attention._flash(q, k, v, d ** -0.5, True, (16, 16),
                                        seq_block, True)
     ref = functools.partial(reference_attention, causal=True)
-    got = jax.jit(attend)(q, k, v)
+    got, got_g = _out_and_grads(attend, q, k, v)
     assert got.shape == (2, seq, heads, dv)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref(q, k, v)),
                                atol=2e-5, rtol=2e-5)
-    got_g = jax.jit(functools.partial(_grads, attend))(q, k, v)
     for name, a, b in zip("qkv", got_g, _grads(ref, q, k, v)):
         assert a.shape == b.shape, name
         b = np.asarray(b)
@@ -400,8 +420,10 @@ def test_flash_default_scale_is_the_queries_width():
 
 @pytest.mark.parametrize("heads,d,dv,g", [
     (16, 64, 64, 2), (32, 192, 128, 2), (16, 128, 128, 1), (4, 24, 16, 4),
-    (12, 64, 64, 2), (32, 64, 128, 2)],
-    ids=["gpt2", "latent", "d128", "toy", "gpt2-small", "wider-values"])
+    (12, 64, 64, 2), (32, 64, 128, 2), (2, 24, 16, 2), (2, 64, 32, 2),
+    (6, 64, 32, 6)],
+    ids=["gpt2", "latent", "d128", "toy", "gpt2-small", "wider-values",
+         "two-toys", "two-halves", "six-halves"])
 def test_a_block_holds_the_same_heads_of_both_widths(heads, d, dv, g):
     assert pallas_attention._heads_per_block(heads, d, dv) == g
 
@@ -424,7 +446,9 @@ WINDOWS = [(64, 16, 32, 8), (64, 16, 32, 32), (64, 16, 32, 100),
            (64, 16, 16, 17)]
 
 
-@pytest.mark.parametrize("heads,d,dv", [(2, 16, 16), (4, 24, 16)],
+# Two heads of 24 | 16: the second head's lanes start off a tile at both
+# widths, and no count of heads fills the lanes, so a block holds both.
+@pytest.mark.parametrize("heads,d,dv", [(2, 16, 16), (2, 24, 16)],
                          ids=["one-width", "values-narrower"])
 @pytest.mark.parametrize("seq,tile,seq_block,window", WINDOWS, ids=[
     "under-a-tile", "a-block", "over-the-sequence", "own-key-alone",
@@ -445,10 +469,9 @@ def test_flash_window_matches_the_einsum_mask(seq, tile, seq_block, window,
         return pallas_attention._flash(q, k, v, d ** -0.5, True,
                                        (tile, tile), seq_block, True, window)
     ref = functools.partial(_window_reference, window=window)
-    got = jax.jit(attend)(q, k, v)
+    got, got_g = _out_and_grads(attend, q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref(q, k, v)),
                                atol=2e-5, rtol=2e-5)
-    got_g = jax.jit(functools.partial(_grads, attend))(q, k, v)
     for name, a, b in zip("qkv", got_g, _grads(ref, q, k, v)):
         b = np.asarray(b)
         # one key alone: dQ is 0 and the kernels' a rounding of it
@@ -537,3 +560,98 @@ def test_a_window_call_refuses_what_it_cannot_walk(qkv, bad, match):
     with pytest.raises(ValueError, match=match):
         jax.jit(functools.partial(flash_attention, interpret=True,
                                   **bad))(q, k, v)
+
+
+def _a_selection(seq, topk, tile, seed=3):
+    """A selection as an indexer makes it (``ops/dsa.py``): ``topk`` of a
+    query's causal keys, every one of them where there are no more."""
+    from horovod_tpu.ops import dsa
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    packed, _ = dsa.select(jax.random.normal(ks[0], (2, seq, 3, 8)),
+                           jax.random.normal(ks[1], (2, seq, 8)),
+                           jax.random.normal(ks[2], (2, seq, 3)), topk,
+                           key_tile=tile)
+    return packed, dsa.unpack_mask(packed, tile)
+
+
+# 96 rows in blocks of 64 and tiles of 32: a padded block, three tiles of
+# keys, and the 40th key in the second tile, so that the first tile of
+# queries is the causal call's and the rest read the bits.
+@pytest.mark.parametrize("seq,tile,seq_block,topk", [
+    (96, 32, 64, 40), (64, 32, 64, 200)],
+    ids=["a-block-a-tile-the-topkth-key", "every-query-keeps-all"])
+def test_flash_selected_matches_the_einsum_mask(seq, tile, seq_block, topk):
+    """A call with a selection, forward, the rows' log-sum-exp and all
+    three gradients, against plain scores under the unpacked mask; one
+    selection serves every head."""
+    heads, d = 2, 16
+    packed, keep = _a_selection(seq, topk, tile)
+    rng = np.random.RandomState(5)
+    q, k, v = (jnp.asarray(rng.randn(2, seq, heads, d).astype(np.float32))
+               for _ in range(3))
+    assert int(keep[0, -1].sum()) == min(topk, seq)
+
+    def plain(q, k, v):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d ** -0.5
+        s = jnp.where(keep[:, None], s, -jnp.inf)
+        return (jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v),
+                jax.nn.logsumexp(s, -1))
+
+    def attend(q, k, v):
+        return pallas_attention._flash_selected(
+            q, k, v, packed, d ** -0.5, (tile, tile), seq_block, True, topk)
+    (got, got_lse), got_g = _out_and_grads(attend, q, k, v)
+    want, want_lse = plain(q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(got_lse), np.asarray(want_lse),
+                               atol=2e-5, rtol=2e-5)
+    for name, a, b in zip("qkv", got_g,
+                          _grads(lambda *a: plain(*a)[0], q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=2e-5 * float(np.abs(b).max()),
+                                   err_msg="d" + name)
+
+
+def test_a_selected_call_is_named_and_a_plain_call_is_what_it_was():
+    """The kernels of a call with a selection carry their own names and
+    one operand more, the packed mask's block; a call without has the
+    operands it had."""
+    q = jnp.zeros((1, 64, 2, 16))
+    packed = jnp.zeros((1, 2, 64), jnp.int32)
+
+    def kernels(fn):
+        found = {}
+
+        def walk(j):
+            for eqn in j.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    found[eqn.params["name"]] = len(eqn.invars)
+                    continue
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+        walk(jax.make_jaxpr(functools.partial(_grads, fn))(q, q, q).jaxpr)
+        return found
+    assert kernels(lambda q, k, v: pallas_attention._flash(
+        q, k, v, 0.25, True, (32, 32), 32, True)) == {
+            "hvd_flash_fwd": 3, "hvd_flash_bwd": 6}
+    assert kernels(lambda q, k, v: pallas_attention._flash_selected(
+        q, k, v, packed, 0.25, (32, 32), 32, True, 40)[0]) == {
+            "hvd_flash_fwd_selected": 4, "hvd_flash_bwd_selected": 7}
+    with pytest.raises(ValueError, match="fused backward alone"):
+        jax.eval_shape(functools.partial(
+            pallas_attention._bwd_call, heads=2, scale=0.25, causal=True,
+            tile=(32, 32), seq_block=32, interpret=True, dq_budget=0,
+            topk=40), *(jnp.zeros((1, 64, 32)),) * 3,
+            jnp.zeros((1, 2, 1, 64)), jnp.zeros((1, 64, 32)),
+            jnp.zeros((1, 2, 1, 64)), packed)
+
+
+def test_the_selected_calls_tiles_by_hand():
+    # 32 tiles of 512 a side: 528 on or under the diagonal; all but the
+    # 6 under it among the first 2048 queries read the bits or the
+    # diagonal.
+    assert pallas_attention.selected_tiles(16384, 2048) == {
+        "walked": 528, "masked": 522, "skipped": 0}
+    assert pallas_attention.selected_tiles(96, 40, (32, 32), 64) == {
+        "walked": 10, "masked": 10, "skipped": 0}
